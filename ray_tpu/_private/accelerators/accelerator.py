@@ -36,6 +36,10 @@ class AcceleratorManager:
         raise NotImplementedError
 
     @staticmethod
+    def hide_accelerators_from_current_process() -> None:
+        raise NotImplementedError
+
+    @staticmethod
     def get_current_node_additional_resources() -> Dict[str, float]:
         return {}
 

@@ -19,6 +19,7 @@ import json
 import logging
 import os
 import re
+import sys
 from typing import Dict, List, Optional
 
 from ray_tpu._private.accelerators.accelerator import AcceleratorManager
@@ -26,6 +27,15 @@ from ray_tpu._private.accelerators.accelerator import AcceleratorManager
 logger = logging.getLogger(__name__)
 
 TPU_VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
+# libtpu's own sub-host partition variables: a process that is shown
+# fewer chips than the host has must also be told the shape of what it
+# sees (reference: tpu.py:155 set_current_process_visible_accelerator_ids)
+TPU_CHIPS_PER_HOST_BOUNDS_ENV = "TPU_CHIPS_PER_HOST_BOUNDS"
+TPU_HOST_BOUNDS_ENV = "TPU_HOST_BOUNDS"
+_SUBHOST_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
+JAX_PLATFORMS_ENV = "JAX_PLATFORMS"
+# libtpu's switch for hosts that have no metadata server to ask
+TPU_SKIP_MDS_QUERY_ENV = "TPU_SKIP_MDS_QUERY"
 # GCE TPU-VM metadata (gated: zero-egress or non-GCE boxes skip silently)
 GCE_TPU_ACCEL_TYPE_ENV = "TPU_ACCELERATOR_TYPE"   # e.g. v4-32, v5litepod-8
 GCE_TPU_NAME_ENV = "TPU_NAME"
@@ -40,6 +50,51 @@ def _chips_per_host(accel_type: str) -> int:
     return _SINGLE_HOST_CHIPS.get(gen, 4)
 
 
+def _chips_on_this_host(accel_type: str) -> int:
+    """Chips one host of this accelerator type holds: a sub-host type
+    (v5litepod-1, v5litepod-4) has fewer than a full host's."""
+    m = re.match(r"^([^-]+)-(\d+)$", accel_type)
+    if not m:
+        return _chips_per_host(accel_type)
+    gen, count = m.group(1), int(m.group(2))
+    total = count // 2 if gen in ("v2", "v3", "v4", "v5p") else count
+    return max(1, min(total, _chips_per_host(accel_type)))
+
+
+# ------------------------------------------------- one process per chip
+# A chip belongs to one process at a time, and a process keeps the one it
+# opened until it exits. So the runtime decides, per worker process, which
+# JAX backends it may open: a worker starts pinned to the CPU, and only a
+# lease that carries chips lifts the pin (to what the node itself was
+# started with) — before JAX opens a backend, which is final.
+_NEVER_PINNED = object()
+_node_jax_platforms = _NEVER_PINNED    # the node's own setting, once pinned
+
+
+def _pin_jax_platforms(value: Optional[str]) -> None:
+    """Set which JAX backends this process may open: through the env var
+    (read when jax is imported) and, where jax is imported already,
+    through its config. Raises once a different backend choice is open."""
+    global _node_jax_platforms
+    if _node_jax_platforms is _NEVER_PINNED:
+        _node_jax_platforms = os.environ.get(JAX_PLATFORMS_ENV) or None
+    if value:
+        os.environ[JAX_PLATFORMS_ENV] = value
+    else:
+        os.environ.pop(JAX_PLATFORMS_ENV, None)
+    jax = sys.modules.get("jax")
+    if jax is None or (jax.config.jax_platforms or None) == (value or None):
+        return
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError(
+            f"this process already opened JAX backends "
+            f"{sorted(xla_bridge.backends())} and cannot move to "
+            f"{value or 'the default platform'}: a chip lease needs a "
+            f"worker process of its own")
+    jax.config.update("jax_platforms", value or None)
+
+
 # --------------------------------------------------- GCE metadata autodetect
 # Real TPU-VMs publish accelerator-type / worker-number / instance-id on the
 # GCE metadata server (reference: tpu.py:198 pod-type detection). Consulted
@@ -52,7 +107,8 @@ _metadata_cache: Dict[str, Optional[str]] = {}
 
 
 def _on_gce() -> bool:
-    if os.environ.get("RAY_TPU_DISABLE_GCE_METADATA"):
+    if os.environ.get("RAY_TPU_DISABLE_GCE_METADATA") \
+            or os.environ.get(TPU_SKIP_MDS_QUERY_ENV):
         return False
     try:
         with open("/sys/class/dmi/id/product_name") as f:
@@ -123,6 +179,42 @@ def check_preemption_notice() -> bool:
         return False
 
 
+def _count_device_nodes() -> int:
+    """Chips attached to this host, by their device nodes: /dev/accel*
+    (v2-v4) or one /dev/vfio/<n> each beside the /dev/vfio/vfio control
+    node (v5e and later). chip_smoke.py compares what the node advertises
+    from this with jax.device_count() on the machine it runs on."""
+    n = len(glob.glob("/dev/accel*"))
+    if n == 0:
+        n = len([p for p in glob.glob("/dev/vfio/*")
+                 if os.path.basename(p) != "vfio"])
+    return n
+
+
+def processes_holding_chips() -> List[int]:
+    """PIDs that have one of this host's chip device nodes open — the
+    processes a chip belongs to right now. Reads /proc, needs no JAX."""
+    held = []
+    for pid in os.listdir("/proc"):
+        if not pid.isdigit():
+            continue
+        try:
+            fds = os.listdir(f"/proc/{pid}/fd")
+        except OSError:
+            continue    # gone, or not ours to read
+        for fd in fds:
+            try:
+                target = os.readlink(f"/proc/{pid}/fd/{fd}")
+            except OSError:
+                continue
+            if target.startswith("/dev/accel") or (
+                    target.startswith("/dev/vfio/")
+                    and os.path.basename(target) != "vfio"):
+                held.append(int(pid))
+                break
+    return held
+
+
 class TPUAcceleratorManager(AcceleratorManager):
     @staticmethod
     def get_resource_name() -> str:
@@ -137,19 +229,14 @@ class TPUAcceleratorManager(AcceleratorManager):
         visible = TPUAcceleratorManager.get_current_process_visible_accelerator_ids()
         if visible is not None:
             return len(visible)
-        # /dev/accel* (TPU VM) or vfio devices
-        n = len(glob.glob("/dev/accel*"))
-        if n == 0:
-            n = len(glob.glob("/dev/vfio/*")) - (1 if os.path.exists(
-                "/dev/vfio/vfio") else 0)
-            n = max(0, n)
+        n = _count_device_nodes()
         if n == 0:
             # no device nodes visible (some TPU-VM images mount them
             # late): infer the per-host chip count from the detected
             # accelerator type so unattended bring-up still advertises TPU
             accel = TPUAcceleratorManager.get_current_node_accelerator_type()
             if accel:
-                n = _chips_per_host(accel)
+                n = _chips_on_this_host(accel)
         if n == 0 and os.environ.get("RAY_TPU_FAKE_CHIPS"):
             n = int(os.environ["RAY_TPU_FAKE_CHIPS"])
         return n
@@ -170,9 +257,22 @@ class TPUAcceleratorManager(AcceleratorManager):
 
     @staticmethod
     def set_current_process_visible_accelerator_ids(ids: List[str]) -> None:
-        os.environ[TPU_VISIBLE_CHIPS_ENV] = ",".join(str(i) for i in ids)
-        # JAX on TPU-VM also honors TPU_PROCESS_BOUNDS-style vars; chip
-        # masking alone suffices for same-host isolation.
+        """Grant this process exactly the chips `ids`, and let JAX open
+        the platform the node was started with."""
+        ids = [str(i) for i in ids]
+        os.environ[TPU_VISIBLE_CHIPS_ENV] = ",".join(ids)
+        bounds = _SUBHOST_BOUNDS.get(len(ids))
+        if bounds and len(ids) < _count_device_nodes():
+            os.environ[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = bounds
+            os.environ[TPU_HOST_BOUNDS_ENV] = "1,1,1"
+        if _node_jax_platforms is not _NEVER_PINNED:
+            _pin_jax_platforms(_node_jax_platforms)
+
+    @staticmethod
+    def hide_accelerators_from_current_process() -> None:
+        """A process whose lease carries no chip may open only the CPU
+        backend, so it can never take a chip from the lease that owns it."""
+        _pin_jax_platforms("cpu")
 
     @staticmethod
     def get_current_node_tpu_pod_name() -> Optional[str]:

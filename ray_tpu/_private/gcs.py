@@ -498,9 +498,24 @@ class GcsServer:
         return {nid: _node_view(n) for nid, n in self.nodes.items()}
 
     async def _check_node_deaths(self):
+        # A monitor that was not running cannot count the silence against
+        # anyone: when this loop itself wakes late — the whole host froze
+        # (opening or closing the TPU runtime stalls a v5e host for
+        # seconds at a time; measured 5.4 s), or this process was
+        # starved — every node is credited the time we were out, and the
+        # beats queued meanwhile are read before anyone is judged.
+        expected = time.monotonic() + cfg.health_check_interval_s
         while True:
             await asyncio.sleep(cfg.health_check_interval_s)
             now = time.monotonic()
+            late = now - expected
+            expected = now + cfg.health_check_interval_s
+            if late > cfg.health_check_interval_s:
+                logger.warning("health check woke %.1fs late; crediting "
+                               "every node that much silence", late)
+                for info in self.nodes.values():
+                    info["last_heartbeat"] += late
+                continue
             for node_id, info in list(self.nodes.items()):
                 if info["alive"] and now - info["last_heartbeat"] > cfg.node_death_timeout_s:
                     await self._mark_node_dead(node_id, "heartbeat timeout")

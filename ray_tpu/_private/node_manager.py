@@ -47,7 +47,7 @@ logger = logging.getLogger(__name__)
 class WorkerProc:
     __slots__ = ("worker_id", "address", "pid", "conn", "proc", "state",
                  "actor_id", "lease_id", "registered", "env_hash",
-                 "idle_since")
+                 "idle_since", "used")
 
     def __init__(self, proc=None):
         self.worker_id = None
@@ -64,6 +64,9 @@ class WorkerProc:
         # worker_pool.h:174)
         self.env_hash: Optional[str] = None
         self.idle_since: float = 0.0
+        # has served a lease or an actor: its JAX backend choice may be
+        # made already, so a chip lease never adopts it
+        self.used = False
 
 
 class NodeManager:
@@ -1031,17 +1034,23 @@ class NodeManager:
 
     async def _obtain_worker(self, timeout: float = 60.0,
                              env_hash: Optional[str] = None,
-                             proc_env: Optional[Dict] = None) -> WorkerProc:
+                             proc_env: Optional[Dict] = None,
+                             fresh: bool = False) -> WorkerProc:
         """Pop an idle worker compatible with the requested runtime env
         (matching env, or a fresh untagged worker that becomes tagged),
         spawning a new process if none fits. Process-scope envs
         (container) can never adopt an untagged worker — the process was
-        not started inside the image — so they match exactly or spawn."""
+        not started inside the image — so they match exactly or spawn.
+        fresh=True (a lease that carries chips) takes only a process
+        that has never served anything: which JAX backend a process
+        opens is final, and a used worker may have opened the CPU's."""
         while True:
             picked = fallback = None
             for w in list(self._idle):
                 if w.state != "idle":
                     self._idle.remove(w)
+                    continue
+                if fresh and w.used:
                     continue
                 if w.env_hash == env_hash:
                     picked = w          # exact env match wins
@@ -1053,6 +1062,7 @@ class NodeManager:
             if picked is not None:
                 self._idle.remove(picked)
                 picked.env_hash = env_hash or picked.env_hash
+                picked.used = True
                 return picked
             w = self._spawn_worker(proc_env, env_hash)
             # temporary key until registration rebinds by worker_id
@@ -1066,6 +1076,7 @@ class NodeManager:
             if w.state == "idle" and w in self._idle:
                 self._idle.remove(w)
                 w.env_hash = env_hash
+                w.used = True
                 return w
             # else someone else grabbed it; loop
 
@@ -1135,7 +1146,8 @@ class NodeManager:
                 chips = self._allocate_chips(resources)
                 try:
                     w = await self._obtain_worker(env_hash=env_hash,
-                                                  proc_env=proc_env)
+                                                  proc_env=proc_env,
+                                                  fresh=bool(chips))
                 except RuntimeError as e:
                     self._free_chips.extend(chips)
                     scheduling_addback(pool_avail, resources)
@@ -1310,15 +1322,35 @@ class NodeManager:
         info = self._leases.pop(lease_id, None)
         if info is None:
             return
-        self._free_chips.extend(info.get("chips") or [])
+        chips = info.get("chips") or []
         pool_avail = info["bundle"]["available"] if info["bundle"] else self.available
         scheduling_addback(pool_avail, info["resources"])
         w = info["worker"]
         w.lease_id = None
-        if not worker_dead and w.state == "leased":
+        if chips:
+            # one process per chip: the process that opened a chip keeps
+            # it until it exits, so the worker is retired, never pooled,
+            # and the chips are free again only once it is gone
+            if not worker_dead and w.state == "leased":
+                asyncio.ensure_future(self._on_worker_death(
+                    w, "chip lease ended: worker retired"))
+            asyncio.ensure_future(self._free_chips_after_exit(w, chips))
+        elif not worker_dead and w.state == "leased":
             w.state = "idle"
             w.idle_since = time.monotonic()
             self._idle.append(w)
+        self._wake_lease_waiters()
+
+    async def _free_chips_after_exit(self, w: WorkerProc, chips: List[str]):
+        if w.proc is not None:
+            loop = asyncio.get_event_loop()
+            try:
+                await asyncio.wait_for(
+                    loop.run_in_executor(None, w.proc.wait), timeout=30.0)
+            except asyncio.TimeoutError:
+                logger.error("worker %s held chips %s past its kill; "
+                             "freeing them anyway", w.pid, chips)
+        self._free_chips.extend(chips)
         self._wake_lease_waiters()
 
     # ---------------------------------------------------------------- actors
@@ -1395,7 +1427,8 @@ class NodeManager:
         t_phase = self._launch_enter(lt, "worker_obtain")
         try:
             w = await self._obtain_worker(env_hash=env_hash,
-                                          proc_env=proc_env)
+                                          proc_env=proc_env,
+                                          fresh=bool(chips))
         except BaseException:
             self._free_chips.extend(chips)
             scheduling_addback(pool_avail, resources)

@@ -149,13 +149,6 @@ def store_path(session_name: str, node_id_hex: str) -> str:
     return f"/dev/shm/raytpu_{session_name}_{node_id_hex[:12]}"
 
 
-if sys.version_info < (3, 12):  # pragma: no cover
-    raise ImportError(
-        "ray_tpu requires Python >= 3.12: zero-copy object reads tie shm "
-        "pins to derived views via the PEP 688 __buffer__ protocol "
-        "(see pyproject.toml requires-python)")
-
-
 class _PinnedRegion:
     """Buffer exporter for one pinned object in the shared arena.
 

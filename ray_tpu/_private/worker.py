@@ -251,8 +251,6 @@ class CoreWorker:
         # multi-consumer workers run tasks concurrently and instance
         # attributes would cross-contaminate their traces
         self._root_trace_id = os.urandom(8).hex()
-        self._orig_visible: Dict[str, Optional[str]] = {}
-        self._visible_dirty: set = set()
         self._cancelled_tasks: set = set()
         self._exec_ema: Dict[str, float] = {}   # method -> avg duration
         self._exec_streak: Dict[str, int] = {}  # consecutive fast runs
@@ -2590,33 +2588,20 @@ class CoreWorker:
                 fut.set_result(result)
 
     def _apply_accelerator_ids(self, spec: Dict):
-        ids = spec.get("accelerator_ids")
-        try:
-            from ray_tpu._private.accelerators import (all_accelerator_managers,
-                                                       get_accelerator_manager)
-            if not ids:
-                # restore the process's original visibility so a reused
-                # worker doesn't leak a previous task's chip mask
-                for res, mgr in all_accelerator_managers().items():
-                    orig = self._orig_visible.get(res)
-                    var = mgr.get_visible_accelerator_ids_env_var()
-                    if res in self._visible_dirty:
-                        if orig is None:
-                            os.environ.pop(var, None)
-                        else:
-                            os.environ[var] = orig
-                        self._visible_dirty.discard(res)
-                return
-            for res, chip_ids in ids.items():
-                mgr = get_accelerator_manager(res)
-                if mgr is not None:
-                    var = mgr.get_visible_accelerator_ids_env_var()
-                    self._orig_visible.setdefault(res, os.environ.get(var))
-                    self._visible_dirty.add(res)
-                    mgr.set_current_process_visible_accelerator_ids(
-                        [str(c) for c in chip_ids])
-        except Exception:
-            logger.exception("failed to set accelerator visibility")
+        """One process per chip: a lease that carries chips shows this
+        process exactly those and lifts the CPU pin the worker started
+        with; a lease that carries none keeps (or puts) the process on
+        the CPU backend. Raises — failing the task — when the process
+        already opened a backend the lease cannot live with; the node
+        manager gives chip leases a process of their own and retires it
+        afterwards, so that does not happen in a healthy pool."""
+        from ray_tpu._private.accelerators import all_accelerator_managers
+        ids = spec.get("accelerator_ids") or {}
+        for res, mgr in all_accelerator_managers().items():
+            if ids.get(res):
+                mgr.set_current_process_visible_accelerator_ids(ids[res])
+            else:
+                mgr.hide_accelerators_from_current_process()
 
     async def _package_runtime_env(self, renv: Dict) -> Dict:
         """Submission side: zip local working_dir / py_modules dirs into

@@ -27,19 +27,17 @@ def main():
         level=os.environ.get("RAY_TPU_LOG_LEVEL", "WARNING"),
         format=f"[worker {os.getpid()}] %(levelname)s %(message)s")
 
-    # Worker-side jax platform pin. Some environments register device
-    # plugins through sitecustomize and override the JAX_PLATFORMS env
-    # var with jax.config at interpreter start; tests (and CPU-only
-    # deployments) need workers pinned to a platform the same way the
-    # driver pins itself with jax.config.update.
-    plat = os.environ.get("RAY_TPU_JAX_PLATFORMS")
-    if plat:
-        try:
-            import jax
-            jax.config.update("jax_platforms", plat)
-        except Exception:
-            logging.getLogger(__name__).exception(
-                "jax platform pin %r failed", plat)
+    # One process per chip: a worker starts pinned to the CPU backend and
+    # only a lease that carries chips lifts the pin (worker.py
+    # _apply_accelerator_ids), so a CPU-only task or actor that touches
+    # JAX can never take the chip from the lease that owns it. Nothing
+    # here imports jax; the pin and the compile-cache directory travel
+    # through the environment it reads at import.
+    from ray_tpu._private.accelerators import all_accelerator_managers
+    for mgr in all_accelerator_managers().values():
+        mgr.hide_accelerators_from_current_process()
+    from ray_tpu._private.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     from ray_tpu._private import worker as worker_mod
     from ray_tpu._private.worker import CoreWorker, Worker

@@ -247,6 +247,12 @@ class LLMDeployment:
     def stats(self) -> Dict:
         return self.engine.stats()
 
+    def device_report(self) -> Dict:
+        """Which device this replica's process holds, as its own JAX
+        reports it (util/profiling.py device_report)."""
+        from ray_tpu.util.profiling import device_report
+        return device_report()
+
     def begin_drain(self):
         """Preemption notice (serve/replica.py relays it here): the
         engine refuses new submissions — the handle layer re-routes

@@ -186,6 +186,12 @@ class InferenceEngine:
                     P(None, "batch", None, "kv_heads", None), rules),
                 pool_shape, mesh)
             self._pool_sharding = NamedSharding(mesh, spec)
+            # the key lives on the mesh from the start: the step program
+            # hands it back replicated there, and a first call that saw
+            # it on one device would make the second one retrace
+            self._rng = jax.jit(
+                lambda: jax.random.PRNGKey(seed),
+                out_shardings=NamedSharding(mesh, P()))()
         self._pool_k = self._zeros(pool_shape, dtype)
         self._pool_v = self._zeros(pool_shape, dtype)
         self._cache_dtype = dtype
@@ -323,9 +329,9 @@ class InferenceEngine:
         cfg = self.config
         model = self.model
         top_k, top_p = cfg.top_k, cfg.top_p
-        # donation rebinds the pool buffers in place on TPU; CPU (tests)
-        # doesn't implement donation and would warn every call
-        donate = jax.default_backend() != "cpu"
+        # donation rebinds the pool buffers in place — on every backend,
+        # so the CPU tests exercise the same rebinding the chip runs
+        # (a read of a donated buffer after its call raises there too)
 
         def prefill(params, sk, sv, tokens, pos0, n_real, rng, temp):
             # one budgeted chunk of prompt through the cached path;
@@ -366,11 +372,11 @@ class InferenceEngine:
             return tok.astype(jnp.int32), new["k"], new["v"], rng
 
         self._prefill_fn = jax.jit(
-            prefill, donate_argnums=(1, 2) if donate else ())
+            prefill, donate_argnums=(1, 2))
         self._insert_fn = jax.jit(
-            insert, donate_argnums=(0, 1) if donate else ())
+            insert, donate_argnums=(0, 1))
         self._decode_fn = jax.jit(
-            decode, donate_argnums=(1, 2) if donate else ())
+            decode, donate_argnums=(1, 2))
 
         self._spec_step_fn = None
         self._draft_prefill_fn = None
@@ -389,7 +395,7 @@ class InferenceEngine:
                 build_spec_step(model, draft_model, self._spec.k,
                                 top_k, top_p,
                                 on_trace=_count_verify_trace),
-                donate_argnums=(2, 3, 4, 5) if donate else ())
+                donate_argnums=(2, 3, 4, 5))
 
             def draft_prefill(dparams, sk, sv, tokens, pos0):
                 # prompt KV for the draft cache: same chunked path as
@@ -403,10 +409,10 @@ class InferenceEngine:
                 return new["k"], new["v"]
 
             self._draft_prefill_fn = jax.jit(
-                draft_prefill, donate_argnums=(1, 2) if donate else ())
+                draft_prefill, donate_argnums=(1, 2))
 
         if self.prefix_cache is not None and self._kv_quant:
-            self._build_quant_span_fns(donate)
+            self._build_quant_span_fns()
         elif self.prefix_cache is not None:
             mcfg = self.model.cfg
             span = (mcfg.n_layers, 1, cfg.prefill_chunk,
@@ -455,14 +461,14 @@ class InferenceEngine:
                 return bk, bv
 
             self._save_span_fn = jax.jit(
-                save_span, donate_argnums=(0, 1) if donate else ())
+                save_span, donate_argnums=(0, 1))
             self._load_span_fn = jax.jit(
-                load_span, donate_argnums=(0, 1) if donate else ())
+                load_span, donate_argnums=(0, 1))
             self._export_span_fn = jax.jit(export_span)
             self._import_span_fn = jax.jit(
-                import_span, donate_argnums=(0, 1) if donate else ())
+                import_span, donate_argnums=(0, 1))
 
-    def _build_quant_span_fns(self, donate):
+    def _build_quant_span_fns(self):
         """int8 variants of the four span programs: same fixed span
         shape + traced offsets (= one compile each, ever), but the block
         side carries int8 values plus fp32 per-(position, head) scale
@@ -516,12 +522,12 @@ class InferenceEngine:
             return bk, bv, bks, bvs
 
         self._save_span_fn = jax.jit(
-            save_spanq, donate_argnums=(0, 1, 2, 3) if donate else ())
+            save_spanq, donate_argnums=(0, 1, 2, 3))
         self._load_span_fn = jax.jit(
-            load_spanq, donate_argnums=(0, 1) if donate else ())
+            load_spanq, donate_argnums=(0, 1))
         self._export_span_fn = jax.jit(export_spanq)
         self._import_span_fn = jax.jit(
-            import_spanq, donate_argnums=(0, 1, 2, 3) if donate else ())
+            import_spanq, donate_argnums=(0, 1, 2, 3))
 
     # -------------------------------------------------------------- intake
     def submit(self, tokens, max_new_tokens: int = 64,

@@ -1,12 +1,13 @@
 """Build the native components on demand.
 
-The native library is compiled once per source change into
+The native library is compiled once per source CONTENT into
 ``ray_tpu/native/_build/`` and loaded via ctypes (no pybind11 in this image;
 the C ABI + ctypes keeps the binding dependency-free).
 """
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import threading
@@ -25,17 +26,35 @@ def lib_path(name: str) -> str:
     return os.path.join(_BUILD_DIR, f"lib{name}.so")
 
 
+def _content_key(srcs, flags) -> str:
+    h = hashlib.sha256(" ".join(flags).encode())
+    for src in srcs:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
 def _compile(srcs, out, flags) -> str:
-    """Compile (if stale vs source mtimes) srcs -> out; atomic replace."""
+    """Compile srcs -> out unless out was built from these very bytes
+    with these flags: the key is the sources' content, kept beside the
+    output (mtimes mean nothing in a copied or checked-out tree). Atomic
+    replace of both."""
     with _LOCK:
-        src_mtime = max(os.path.getmtime(s) for s in srcs)
-        if os.path.exists(out) and os.path.getmtime(out) >= src_mtime:
-            return out
+        key, key_path = _content_key(srcs, flags), f"{out}.key"
+        try:
+            with open(key_path) as f:
+                if f.read() == key and os.path.exists(out):
+                    return out
+        except FileNotFoundError:
+            pass
         os.makedirs(_BUILD_DIR, exist_ok=True)
         tmp = f"{out}.tmp.{os.getpid()}"  # per-process tmp; os.replace is atomic
         cmd = ["g++", "-std=c++17", *flags, "-o", tmp, *srcs, "-lpthread"]
         subprocess.run(cmd, check=True, capture_output=True)
         os.replace(tmp, out)
+        with open(f"{key_path}.tmp.{os.getpid()}", "w") as f:
+            f.write(key)
+        os.replace(f.name, key_path)
     return out
 
 

@@ -6,8 +6,11 @@
 `jax.custom_vjp`, so it is usable inside `jax.grad` train steps. Head dims
 that aren't lane-aligned (e.g. 64) are zero-padded to 128 outside the
 custom_vjp — padding q/k with zeros leaves the logits unchanged and AD
-slices the gradients back. On non-TPU backends it falls back to the XLA
-reference implementation so the same model code runs on the CPU test mesh.
+slices the gradients back. `flash_attention` always runs the kernel: it
+raises on lengths or blocks the kernel cannot tile (`flash_fits` says
+which), and off the TPU the kernel only runs with `interpret=True`. The
+choice between this kernel and `mha_reference` — by platform and shape —
+belongs to `ops/dispatch.py` `attention(impl="auto")` alone.
 
 The reference framework has no attention kernels at all (it orchestrates
 torch models); these exist because long-context parallelism is first-class
@@ -258,22 +261,32 @@ _flash_core.defvjp(_flash_fwd, _flash_bwd)
 
 
 # ------------------------------------------------------------ public entry
+def flash_fits(Lq: int, Lk: int, block_q: int = 256,
+               block_k: int = 256) -> bool:
+    """Whether the kernel can tile these lengths: whole 128-row tiles,
+    and blocks (clamped to the length) that divide it and each other."""
+    block_q, block_k = min(block_q, Lq), min(block_k, Lk)
+    return not (Lq % 128 or Lk % 128 or Lq % block_q or Lk % block_k
+                or block_q % block_k)
+
+
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
                     block_k: int = 256, scale: Optional[float] = None,
                     interpret: bool = False):
     """Tiled attention, differentiable. q[B,Lq,H,D], k/v[B,Lk,Hkv,D]
-    (GQA ok). Head dim is zero-padded up to a multiple of 128 lanes."""
+    (GQA ok). Head dim is zero-padded up to a multiple of 128 lanes.
+    Always the Pallas kernel, never the reference: raises ValueError on
+    lengths it cannot tile."""
     B, Lq, H, D = q.shape
     _, Lk, Hkv, _ = k.shape
     scale = scale if scale is not None else D ** -0.5
-    from ray_tpu.ops.dispatch import _on_tpu
-    on_tpu = _on_tpu()
-    if not (on_tpu or interpret) or Lq % 128 or Lk % 128:
-        return mha_reference(q, k, v, causal=causal, scale=scale)
+    if not flash_fits(Lq, Lk, block_q, block_k):
+        raise ValueError(
+            f"flash attention cannot tile Lq={Lq}, Lk={Lk} with blocks "
+            f"({block_q}, {block_k}): lengths must be multiples of 128 "
+            f"and of their block, and block_q of block_k")
     block_q = min(block_q, Lq)
     block_k = min(block_k, Lk)
-    if Lq % block_q or Lk % block_k or block_q % block_k:
-        return mha_reference(q, k, v, causal=causal, scale=scale)
     if Hkv != H:
         k = jnp.repeat(k, H // Hkv, axis=2)
         v = jnp.repeat(v, H // Hkv, axis=2)

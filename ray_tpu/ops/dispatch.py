@@ -3,35 +3,30 @@
 Under a multi-device mesh the attention runs as a shard_map island inside
 the jitted step — Pallas kernels and ring collectives both need per-shard
 (local) views, which GSPMD alone can't give them. On one device it's the
-Pallas flash kernel (TPU) or the XLA reference (CPU tests).
+Pallas flash kernel or the XLA reference.
+
+Only `impl="auto"` chooses: ring where the mesh shards `seq`, else the
+flash kernel where the backend is a TPU and the lengths tile
+(`flash_fits`), else the reference. An implementation asked for by name
+runs or raises — `"flash"` never returns the reference (under a mesh it
+means the kernel per shard), `"ring"` never skips the ring.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
-from ray_tpu.ops.attention import flash_attention, mha_reference
+from ray_tpu.ops.attention import flash_attention, flash_fits, mha_reference
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.parallel import mesh as mesh_lib
 from ray_tpu.parallel.mesh import AXIS_DATA, AXIS_FSDP, AXIS_SEQ, AXIS_TENSOR
 
 
 def _on_tpu() -> bool:
-    """True on real TPU hardware, including device plugins whose platform
-    string isn't literally "tpu" (the device kind names the generation)."""
-    if jax.default_backend() == "tpu":
-        return True
-    try:
-        d = jax.devices()[0]
-    except Exception:
-        return False
-    return "tpu" in (getattr(d, "device_kind", "") or "").lower() \
-        or "tpu" in (d.platform or "").lower()
+    return jax.default_backend() == "tpu"
 
 
 def attention(q, k, v, causal: bool = True, impl: str = "auto"):
@@ -39,25 +34,33 @@ def attention(q, k, v, causal: bool = True, impl: str = "auto"):
     mesh = mesh_lib.current_mesh()
     multi = mesh is not None and mesh.size > 1
     seq_sharded = multi and mesh.shape[AXIS_SEQ] > 1
+    B, L, H, D = q.shape
+    use_flash = impl == "flash" or (
+        impl == "auto" and _on_tpu() and flash_fits(L, k.shape[1]))
     if impl == "auto":
-        if seq_sharded:
+        if seq_sharded and L % mesh.shape[AXIS_SEQ] == 0:
             impl = "ring"
-        elif multi:
+        elif multi and not seq_sharded:
             impl = "sharded_local"   # per-shard flash/ref under shard_map
-        elif _on_tpu():
-            impl = "flash"
         else:
-            impl = "reference"
+            impl = "flash" if use_flash and not multi else "reference"
+    elif impl == "flash" and multi:
+        if seq_sharded:
+            raise ValueError(
+                "the flash kernel needs the whole sequence on each shard; "
+                "a mesh that shards `seq` runs impl='ring' (or 'auto')")
+        impl = "sharded_local"
     if impl in ("ring", "sharded_local"):
         if mesh is None:
             raise ValueError("sharded attention needs a mesh (use_mesh(...))")
-        B, L, H, D = q.shape
         Hkv = k.shape[2]
         t = mesh.shape[AXIS_TENSOR]
         s = mesh.shape[AXIS_SEQ]
         bsz = mesh.shape[AXIS_DATA] * mesh.shape[AXIS_FSDP]
         if impl == "ring" and L % s != 0:
-            return mha_reference(q, k, v, causal=causal)
+            raise ValueError(
+                f"ring attention needs L={L} divisible by the mesh's "
+                f"seq axis ({s})")
         batch_ax = (AXIS_DATA, AXIS_FSDP) if B % bsz == 0 else None
         # heads shard over tensor only when q AND kv head counts divide it
         # (keeps the GQA repeat factor consistent per shard)
@@ -68,12 +71,15 @@ def attention(q, k, v, causal: bool = True, impl: str = "auto"):
                                      causal=causal)
         else:
             # seq axis unsharded: each (batch, head) shard holds the full
-            # sequence — run the flash kernel (or XLA ref on CPU) locally;
-            # pallas can't be auto-partitioned by GSPMD, hence shard_map
+            # sequence — run the flash kernel (or the reference, where
+            # "auto" chose it) locally; pallas can't be auto-partitioned
+            # by GSPMD, hence shard_map
             spec = P(batch_ax, None, head_ax, None)
-            body = functools.partial(flash_attention, causal=causal)
-        fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
+            body = functools.partial(
+                flash_attention if use_flash else mha_reference,
+                causal=causal)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=spec, check_vma=False)
         return fn(q, k, v)
     if impl == "flash":
         return flash_attention(q, k, v, causal=causal)

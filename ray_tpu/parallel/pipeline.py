@@ -27,7 +27,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.parallel.mesh import AXIS_STAGE
@@ -93,7 +93,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
     y = shard_map(per_stage, mesh=mesh,
                   in_specs=(p_specs, x_spec),
                   out_specs=P(stage_axis),
-                  check_rep=False)(stacked_params, microbatches)
+                  check_vma=False)(stacked_params, microbatches)
     # y: [S, M, mb, ...]; the final stage's row is the pipeline output
     return y[-1]
 

@@ -206,7 +206,10 @@ def make_train_fns(model: nn.Module, optimizer,
     blocked on the loss, emitting runtime_<name>_mfu gauges + timeline
     spans (the in-runtime answer to the stuck train_step_mfu ratchet)."""
     rules = rules or sharding_lib.DEFAULT_RULES
-    tokens0 = jnp.zeros(batch_shape, jnp.int32)
+    # init traces the model at the shape the step feeds it — the batch
+    # minus its last position (inputs are tokens[:, :-1]) — so a kernel
+    # asked for by name sees a length it can tile, not L + 1
+    tokens0 = jnp.zeros((batch_shape[0], batch_shape[1] - 1), jnp.int32)
 
     def init_state(rng):
         variables = model.init(rng, tokens0)
@@ -299,6 +302,14 @@ def make_train_fns(model: nn.Module, optimizer,
                 out = profiled_step(state, tokens, mask)
             sc.block(out[1]["loss"])
         return out
+
+    def lower_with_mesh(state, tokens, mask=None):
+        with use_mesh(mesh):
+            return jit_step.lower(state, tokens, mask)
+
+    # the step's Lowered, for reading what the program contains
+    # (as_text(): collectives, the Pallas call) or compiling it ahead
+    step_with_mesh.lower = lower_with_mesh
 
     def init_with_mesh(rng):
         with use_mesh(mesh):

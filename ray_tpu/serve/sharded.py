@@ -123,21 +123,35 @@ class ShardedEngineReplica:
         self._requests_served = 0
 
     def _build_params(self, params_fn, seed: int, max_len: int):
-        """Same-seed init on every rank gives bit-identical local
-        values; under a multi-process mesh they are promoted to GLOBAL
+        """Seeded init lands SHARDED over the mesh by the rule table
+        (ZeRO-3 over `fsdp`, megatron over `tensor`): the init program
+        is jitted with those output shardings, so no parameter ever
+        sits whole on the default device and no step re-places one.
+        The values do not depend on the sharding (partitionable
+        threefry), so every rank and every mesh shape draws the same
+        weights from the same seed. params_fn (checkpoint restore /
+        weight arena) must already return mesh-consistent values; under
+        a multi-process mesh its per-rank values are promoted to GLOBAL
         (replicated) arrays so the engine's jitted programs see one
-        logical param tree. params_fn (checkpoint restore / weight
-        arena) must already return mesh-consistent values."""
+        logical param tree."""
         import jax
         import numpy as np
 
-        if params_fn is not None:
-            params = params_fn()
-        else:
+        if params_fn is None:
             import jax.numpy as jnp
+
+            from ray_tpu.parallel.mesh import use_mesh
+            from ray_tpu.parallel.train_step import state_shardings
             tokens0 = jnp.zeros((1, min(8, max_len)), jnp.int32)
-            params = self.model.init(jax.random.PRNGKey(seed),
-                                     tokens0)["params"]
+
+            def init(rng):
+                return self.model.init(rng, tokens0)["params"]
+
+            key = jax.random.PRNGKey(seed)
+            shardings = state_shardings(jax.eval_shape(init, key), self.mesh)
+            with use_mesh(self.mesh):
+                return jax.jit(init, out_shardings=shardings)(key)
+        params = params_fn()
         if jax.process_count() > 1:
             from jax.sharding import NamedSharding, PartitionSpec
             sh = NamedSharding(self.mesh, PartitionSpec())

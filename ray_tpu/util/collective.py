@@ -166,7 +166,7 @@ def _xla_allreduce(tensor, op: str):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     if op not in ("sum", "max", "min", "product"):
         raise ValueError(f"unsupported allreduce op {op!r}")
@@ -197,7 +197,7 @@ def _xla_allreduce(tensor, op: str):
             return out
 
         fn = jax.jit(shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                               check_rep=False))
+                               check_vma=False))
         _XLA_FNS[key] = fn
     return fn(tensor)
 
@@ -215,7 +215,7 @@ def _xla_allgather(tensor) -> List:
     per process (mirrors the store backend's per-rank list)."""
     import jax
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh, n_local = _xla_mesh()
     key = ("ag", mesh.size)
@@ -227,7 +227,7 @@ def _xla_allgather(tensor) -> List:
             return jax.lax.all_gather(x, "all")
 
         fn = jax.jit(shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                               check_rep=False))
+                               check_vma=False))
         _XLA_FNS[key] = fn
     out = fn(tensor)
     # one representative copy per process (each process's tensor was
@@ -281,7 +281,7 @@ def _xla_broadcast(tensor, src_rank: int, group: CollectiveGroup):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh, n_local = _xla_mesh()
     contrib = (jnp.asarray(tensor) if group.rank == src_rank
@@ -298,7 +298,7 @@ def _xla_broadcast(tensor, src_rank: int, group: CollectiveGroup):
             return (s / n_local).astype(x.dtype)
 
         fn = jax.jit(shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                               check_rep=False))
+                               check_vma=False))
         _XLA_FNS[key] = fn
     return fn(contrib)
 

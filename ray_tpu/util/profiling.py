@@ -52,10 +52,14 @@ from typing import Any, Dict, Optional
 
 logger = logging.getLogger(__name__)
 
-# Peak dense-matmul FLOP/s per chip by accelerator kind (bf16). The CPU
-# entry is a NOMINAL figure — CPU MFU is a relative utilization signal
-# for tests/dev boxes, not a hardware claim. RAY_TPU_PEAK_FLOPS
-# overrides everything.
+# Peak rates per chip, one table keyed by ``device_kind`` (lower-cased
+# substring match). Source: Google Cloud TPU documentation, the "System
+# architecture" page of each generation (v4, v5e, v5p, v6e): peak bf16
+# compute per chip and HBM bandwidth per chip — for the v5e ("TPU v5
+# lite"): 197 TFLOP/s bf16, 819 GB/s. A kind that is not in the table is
+# an error, never a default. The CPU row is a NOMINAL figure — CPU MFU is
+# a relative utilization signal for tests/dev boxes, not a hardware
+# claim. RAY_TPU_PEAK_FLOPS / RAY_TPU_PEAK_BYTES_PER_S override the table.
 _PEAK_FLOPS_BY_KIND = {
     "tpu v4": 275e12,
     "tpu v5 lite": 197e12,
@@ -68,9 +72,9 @@ _PEAK_FLOPS_BY_KIND = {
 # HBM bandwidth (bytes/s) per chip for the roofline machine balance.
 _PEAK_BYTES_BY_KIND = {
     "tpu v4": 1.2e12,
-    "tpu v5 lite": 8.2e11,
-    "tpu v5e": 8.2e11,
-    "tpu v5p": 2.77e12,
+    "tpu v5 lite": 8.19e11,
+    "tpu v5e": 8.19e11,
+    "tpu v5p": 2.765e12,
     "tpu v6 lite": 1.64e12,
     "tpu v6e": 1.64e12,
     "cpu": 5e10,
@@ -78,32 +82,46 @@ _PEAK_BYTES_BY_KIND = {
 
 
 def _device_kind() -> str:
-    try:
-        import jax
-        return jax.devices()[0].device_kind.lower()
-    except Exception:
-        return "cpu"
+    import jax
+    return jax.devices()[0].device_kind.lower()
 
 
-def _lookup(table: Dict[str, float], kind: str, default: float) -> float:
+def _lookup(table: Dict[str, float], kind: str) -> float:
     for key, v in table.items():
         if key in kind:
             return v
-    return default
+    raise KeyError(
+        f"device kind {kind!r} is not in the peak table "
+        f"(ray_tpu/util/profiling.py): add its published rates there")
 
 
 def detect_peak_flops() -> float:
     env = os.environ.get("RAY_TPU_PEAK_FLOPS")
     if env:
         return float(env)
-    return _lookup(_PEAK_FLOPS_BY_KIND, _device_kind(), 1e11)
+    return _lookup(_PEAK_FLOPS_BY_KIND, _device_kind())
 
 
 def detect_peak_bytes_per_s() -> float:
     env = os.environ.get("RAY_TPU_PEAK_BYTES_PER_S")
     if env:
         return float(env)
-    return _lookup(_PEAK_BYTES_BY_KIND, _device_kind(), 5e10)
+    return _lookup(_PEAK_BYTES_BY_KIND, _device_kind())
+
+
+def device_report() -> Dict[str, Any]:
+    """What this process's JAX runs on, as JAX reports it, with the
+    table's peaks for that kind (raises for a kind the table lacks)."""
+    import jax
+    devices = jax.devices()
+    stats = devices[0].memory_stats() or {}
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+            "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+            "bytes_limit": stats.get("bytes_limit"),
+            "table_peak_flops_per_s": detect_peak_flops(),
+            "table_peak_bytes_per_s": detect_peak_bytes_per_s()}
 
 
 def cost_of_compiled(compiled) -> Dict[str, float]:

@@ -363,6 +363,8 @@ def run_multi_model(spec):
 
 
 if __name__ == "__main__":
+    from ray_tpu._private.compile_cache import configure_compile_cache
+    configure_compile_cache()   # before the first compile; children inherit
     spec = json.loads(sys.argv[sys.argv.index("--one") + 1])
     fn = run_multi_model if spec.get("mode") == "multi_model" else run
     print("RESULT " + json.dumps(fn(spec)), flush=True)

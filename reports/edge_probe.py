@@ -398,6 +398,8 @@ def run(spec):
 
 
 if __name__ == "__main__":
+    from ray_tpu._private.compile_cache import configure_compile_cache
+    configure_compile_cache()   # before the first compile; children inherit
     args = sys.argv[1:]
     spec = json.loads(args[args.index("--one") + 1]) \
         if "--one" in args else {}

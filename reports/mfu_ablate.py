@@ -1,4 +1,4 @@
-"""Train-step MFU ablation on the real TPU chip.
+"""Train-step MFU ablation on the TPU chip this process holds.
 
 Grid: model size x attention impl x remat policy x batch x seq len
 (+ head-dim variants: 8 heads of 128 lanes vs 16 of 64). Each config runs
@@ -23,8 +23,6 @@ import time
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
-
-V5E_PEAK_FLOPS = 197e12
 
 GRID = [
     # baseline (round-2 headline shape)
@@ -177,7 +175,8 @@ def run_one(spec: dict) -> dict:
         state, m = step_fn(state, tokens)
     float(m["loss"])
     dt = (time.perf_counter() - t0) / steps
-    mfu = train_step_flops(cfg, B, L) / dt / V5E_PEAK_FLOPS
+    from ray_tpu.util.profiling import detect_peak_flops
+    mfu = train_step_flops(cfg, B, L) / dt / detect_peak_flops()
     return {**spec, "ms_per_step": round(dt * 1e3, 2),
             "tokens_per_s": round(B * L / dt, 1),
             "mfu": round(mfu, 4), "compile_s": round(t_compile, 1),
@@ -236,4 +235,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from ray_tpu._private.compile_cache import configure_compile_cache
+    configure_compile_cache()   # before the first compile; children inherit
     main()

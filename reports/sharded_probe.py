@@ -176,5 +176,7 @@ def run(spec):
 
 
 if __name__ == "__main__":
+    from ray_tpu._private.compile_cache import configure_compile_cache
+    configure_compile_cache()   # before the first compile; children inherit
     spec = json.loads(sys.argv[sys.argv.index("--one") + 1])
     print("RESULT " + json.dumps(run(spec)), flush=True)

@@ -322,4 +322,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from ray_tpu._private.compile_cache import configure_compile_cache
+    configure_compile_cache()   # before the first compile; children inherit
     main()

@@ -1,0 +1,124 @@
+"""The main path's kernels, compiled for a described (not attached) v5e
+chip at the shapes tpu-1b really produces. Nothing runs: this guards what
+the TPU compiler would refuse (tiling, VMEM, partitioning) at no chip time.
+
+All of it lives in this one file and in fixtures: only the xdist worker
+that is handed the file loads the TPU library, and it compiles in its own
+process with the persistent compile cache off (an entry written for a
+described chip cannot be read back without one).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import (NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# [B*H, L, 128] as ops/attention.py lays a batch out for the kernel
+CORE_SHAPES = {
+    "tpu-1b-train-B8-H16-L1024": (128, 1024, 128),
+    "tpu-1b-max_seq_len-4096": (32, 4096, 128),
+    "tpu-1b-shard-of-fsdp2xtensor2": (32, 1024, 128),
+}
+
+
+@pytest.mark.parametrize("mode", ["fwd", "grad"])
+@pytest.mark.parametrize("shape", list(CORE_SHAPES.values()),
+                         ids=list(CORE_SHAPES))
+def test_flash_core_compiles_for_v5e(one_chip, no_compile_cache, shape,
+                                     mode):
+    from ray_tpu.ops.attention import _flash_core
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def core(q, k, v):
+        return _flash_core(True, 256, 256, shape[-1] ** -0.5, False,
+                           q, k, v)
+
+    fn = core if mode == "fwd" else jax.grad(
+        lambda q, k, v: core(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    _compile(fn, x, x, x)
+
+
+@pytest.mark.parametrize("mode", ["fwd", "grad"])
+def test_head_dim_64_pads_to_128_and_compiles(one_chip, no_compile_cache,
+                                              mode):
+    """The llama-* layout (head_dim 64, GQA 4:1) takes the pad-to-128
+    branch of flash_attention; asked for by name it is the kernel."""
+    from ray_tpu.ops.dispatch import attention
+    q = jax.ShapeDtypeStruct((2, 1024, 32, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 1024, 8, 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def att(q, k, v):
+        return attention(q, k, v, causal=True, impl="flash")
+
+    fn = att if mode == "fwd" else jax.grad(
+        lambda q, k, v: att(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    _compile(fn, q, kv, kv)
+
+
+def test_flash_by_name_shards_over_fsdp2_tensor2(topo, no_compile_cache):
+    """impl="flash" under a 2x2 mesh is the kernel per (batch, head)
+    shard inside shard_map, partitioned for four described chips."""
+    from ray_tpu.ops.dispatch import attention
+    from ray_tpu.parallel.mesh import MeshConfig, make_mesh, use_mesh
+    mesh = make_mesh(MeshConfig(fsdp=2, tensor=2), devices=topo.devices)
+    spec = NamedSharding(mesh, P(("data", "fsdp"), None, "tensor", None))
+    x = jax.ShapeDtypeStruct((8, 1024, 16, 128), jnp.bfloat16,
+                             sharding=spec)
+
+    def loss(q, k, v):
+        return attention(q, k, v, causal=True,
+                         impl="flash").astype(jnp.float32).sum()
+
+    with use_mesh(mesh):
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+
+
+def test_flash_by_name_never_returns_the_reference():
+    """A length the kernel cannot tile raises; it is "auto" that chooses
+    by platform and shape (here, on the CPU: the reference)."""
+    from ray_tpu.ops.dispatch import attention
+    x = jnp.zeros((1, 100, 2, 128), jnp.float32)
+    with pytest.raises(ValueError, match="cannot tile"):
+        attention(x, x, x, impl="flash")
+    assert attention(x, x, x, impl="auto").shape == x.shape

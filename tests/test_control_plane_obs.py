@@ -9,7 +9,6 @@ import asyncio
 import json
 import os
 import signal
-import sys
 import time
 
 import pytest
@@ -18,10 +17,6 @@ from ray_tpu._private import blackbox, events, gcs_obs
 from ray_tpu._private.gcs import GcsServer
 from ray_tpu.util import metrics as metrics_mod
 from ray_tpu.util.chaos import GcsRpcDelayer
-
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
 
 
 # ------------------------------------------------- handler instrumentation
@@ -433,7 +428,6 @@ def test_replay_reestablishes_delta_baseline(monkeypatch,
 
 
 # ------------------------------------------------------- cluster tier
-@needs_cluster
 def test_nm_sigkill_mid_launch_leaves_black_box(tmp_path, monkeypatch):
     """SIGKILL a node manager while an actor launch is in flight on it;
     its black box (continuously appended — nothing runs at death) must

@@ -12,7 +12,6 @@ store runtime like every other multi-node suite."""
 
 import asyncio
 import socket
-import sys
 import threading
 import time
 
@@ -20,10 +19,6 @@ import pytest
 
 from ray_tpu._private import data_plane as dp
 from ray_tpu._private.config import cfg
-
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
 
 OID = b"\x01" * 20
 OID2 = b"\x02" * 20
@@ -256,7 +251,6 @@ def _ping_rtts(address, n, spacing=0.005):
     return asyncio.run(go())
 
 
-@needs_cluster
 def test_control_plane_responsive_during_bulk_transfer():
     """THE acceptance property: control-plane ping p99 to the receiving
     node manager during an active 256 MB push stays < 5x the idle p99.
@@ -320,7 +314,6 @@ def test_control_plane_responsive_during_bulk_transfer():
         cluster.shutdown()
 
 
-@needs_cluster
 def test_pusher_death_mid_stripe_pull_retries():
     """Striped-transfer extension of the pusher-death reap path: the
     holder node dies mid-push, the receiver aborts the poisoned receive
@@ -386,7 +379,6 @@ def test_pusher_death_mid_stripe_pull_retries():
         cluster.shutdown()
 
 
-@needs_cluster
 def test_transfer_span_reports_stripes():
     """store.transfer flight-recorder spans carry the transport path,
     stream count, and per-stripe byte counts."""
@@ -429,7 +421,6 @@ def test_transfer_span_reports_stripes():
         cluster.shutdown()
 
 
-@needs_cluster
 def test_msgpack_fallback_when_data_plane_disabled():
     """RAY_TPU_DATA_PLANE_ENABLED=0 for the whole daemon tree: no
     data-plane advertisement, transfers ride the legacy msgpack chunk

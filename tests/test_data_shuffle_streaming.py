@@ -13,7 +13,6 @@ Two tiers of coverage:
    runtime's Python floor, slow tier where multi-node.
 """
 
-import sys
 
 import numpy as np
 import pytest
@@ -22,10 +21,6 @@ import ray_tpu
 from ray_tpu.data import block as block_lib
 from ray_tpu.data import exchange
 from ray_tpu.data import shuffle as shuffle_lib
-
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
 
 
 # --------------------------------------------------------- fake runtime
@@ -281,7 +276,6 @@ def _fat_dataset(total_bytes: int, parallelism: int = 16):
         .map_batches(fatten)
 
 
-@needs_cluster
 @pytest.mark.slow
 def test_shuffle_2x_store_budget_completes_via_spill():
     """Acceptance: random_shuffle on a dataset >= 2x the object-store
@@ -307,7 +301,6 @@ def test_shuffle_2x_store_budget_completes_via_spill():
         ray_tpu.shutdown()
 
 
-@needs_cluster
 def test_shuffle_seed_deterministic_on_cluster():
     import ray_tpu.data as rd
     ray_tpu.init(num_cpus=2, object_store_memory=128 * 1024 * 1024)
@@ -325,7 +318,6 @@ def test_shuffle_seed_deterministic_on_cluster():
         ray_tpu.shutdown()
 
 
-@needs_cluster
 @pytest.mark.slow
 def test_reduce_output_killed_mid_shuffle_recovers_via_lineage():
     """A shuffle output living only on a killed node is reconstructed

@@ -12,20 +12,13 @@
   (including the PrefillExportKiller chaos spec)
 - idle-span spill eligibility (ROADMAP item 4 leftover)
 
-Everything above the `needs_cluster` line is CPU-pinned and
-cluster-free (tier-1 on any interpreter); the cluster tier (full Serve
-app, cross-replica route, prefill replica killed mid-export) is
-3.12-gated."""
+The first part is cluster-free; the cluster tier (full Serve app,
+cross-replica route, prefill replica killed mid-export) follows it."""
 
-import sys
 import time
 
 import numpy as np
 import pytest
-
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
 
 
 # --------------------------------------------------------------------------
@@ -589,7 +582,6 @@ def _span(size=10, age=100, pins=0, sealed=True, now=1000):
             "sealed": sealed, "flags": 0}
 
 
-@needs_cluster
 def test_idle_unpinned_spans_spill_oldest_first_until_target():
     store = _FakeSpanStore({
         b"old": _span(size=40, age=500),
@@ -609,7 +601,6 @@ def test_idle_unpinned_spans_spill_oldest_first_until_target():
     assert set(store._spans) == {b"new", b"pin", b"raw"}
 
 
-@needs_cluster
 def test_span_spill_noop_without_spans_or_eligible_rows():
     store = _FakeSpanStore({})
     nm, spilled = _nm_with(store)
@@ -619,7 +610,6 @@ def test_span_spill_noop_without_spans_or_eligible_rows():
     assert nm2._spill_idle_spans(None) == (0, 0) and spilled2 == []
 
 
-@needs_cluster
 def test_list_spans_filters_spanning_objects():
     pytest.importorskip("ray_tpu._private.object_store")
     import tempfile
@@ -666,7 +656,6 @@ def ray_start():
     ray_tpu.shutdown()
 
 
-@needs_cluster
 def test_disagg_serving_cross_replica_route_and_handoff(ray_start):
     """Acceptance: a request whose prefix was prefilled on a DIFFERENT
     replica is routed by cluster-wide longest match, skips local
@@ -722,7 +711,6 @@ def test_disagg_serving_cross_replica_route_and_handoff(ray_start):
     serve.delete("llm-disagg")
 
 
-@needs_cluster
 def test_prefill_replica_killed_mid_export_falls_back(ray_start):
     """Chaos satellite: kill the prefill replica while the decode tier
     depends on it — every stream must still deliver exactly-once tokens

@@ -28,7 +28,6 @@ reported cold-start p99.
 
 import collections
 import itertools
-import sys
 import threading
 import time
 
@@ -40,10 +39,6 @@ from ray_tpu.serve.fleet import (DeficitRoundRobin, FleetManager,
                                  ShellPool, TenantAdmission,
                                  TenantQuotaExceeded, decide_scale_to_zero,
                                  fallback_has_headroom, plan_spread)
-
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
 
 
 # --------------------------------------------------------------------------
@@ -958,7 +953,6 @@ def ray_start():
     ray_tpu.shutdown()
 
 
-@needs_cluster
 def test_scale_to_zero_and_shell_revival_exactly_once(ray_start):
     """Acceptance: a deployment scales to zero after its idle window,
     then concurrent first requests revive it through a pre-warmed shell
@@ -1024,7 +1018,6 @@ def test_scale_to_zero_and_shell_revival_exactly_once(ray_start):
         serve.shutdown()
 
 
-@needs_cluster
 def test_tenant_quota_429_through_http_proxy(ray_start):
     """Per-tenant admission at the ingress: a tenant with quota 1 gets
     429 + Retry-After on its second concurrent request; untagged

@@ -5,7 +5,6 @@ the end-to-end Serve streaming trace (proxy -> replica -> engine-slot
 -> first-token under ONE trace id)."""
 
 import json
-import sys
 import time
 
 import pytest
@@ -13,10 +12,6 @@ import pytest
 import ray_tpu
 from ray_tpu._private import events
 from ray_tpu.util.tracing import task_events_to_chrome, task_events_to_otlp
-
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
 
 
 @pytest.fixture(autouse=True)
@@ -310,7 +305,6 @@ def cluster():
     c.shutdown()
 
 
-@needs_cluster
 def test_spans_survive_worker_shutdown_flush(cluster):
     """Spans recorded by a driver that exits before the 1s flusher
     cadence reach the GCS through the stop_async flush."""
@@ -331,7 +325,6 @@ def test_spans_survive_worker_shutdown_flush(cluster):
         ray_tpu.shutdown()
 
 
-@needs_cluster
 def test_serve_streaming_end_to_end_trace(cluster, tmp_path):
     """Acceptance: one streaming Serve request through the HTTP proxy
     produces a single trace — proxy, replica task, engine

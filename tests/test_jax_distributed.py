@@ -93,7 +93,7 @@ def _dp_train_fn(config):
     jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from ray_tpu import train as rt_train
 
@@ -118,7 +118,7 @@ def _dp_train_fn(config):
         return jax.lax.pmean(g, "data")
 
     f = jax.jit(shard_map(per_shard, mesh=mesh, in_specs=(P(), P("data")),
-                          out_specs=P(), check_rep=False))
+                          out_specs=P(), check_vma=False))
     # global batch assembled from process-local shards; under
     # multi-process jit each process supplies only its local rows
     sharding = NamedSharding(mesh, P("data"))

@@ -280,8 +280,6 @@ def test_off_loop_marker_is_pure_annotation():
     assert C.m.__rt_off_loop__ == {"lock": "_mu"}
 
 
-@pytest.mark.skipif(sys.version_info < (3, 12),
-                    reason="object_store requires 3.12 (PEP 688)")
 def test_arena_client_methods_are_marked():
     from ray_tpu._private.object_store import ObjectStoreClient
     for name in ("create", "get", "put_bytes", "_release", "close"):

@@ -13,10 +13,6 @@ import time
 import numpy as np
 import pytest
 
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
-
 from ray_tpu._private import events
 from ray_tpu._private.gcs import GcsServer
 from ray_tpu._private.metrics_ts import (MetricsTimeSeries,
@@ -411,7 +407,6 @@ def cluster():
     c.shutdown()
 
 
-@needs_cluster
 def test_live_windowed_query_reconstructs_percentile(cluster):
     """Acceptance: query_metrics("serve_ttft_ms", window=30, agg="p95")
     returns a correct percentile reconstructed from histogram deltas
@@ -453,7 +448,6 @@ def test_live_windowed_query_reconstructs_percentile(cluster):
         ray_tpu.shutdown()
 
 
-@needs_cluster
 def test_induced_load_produces_slo_violation_event(cluster):
     """Acceptance: a Serve deployment with an SLO, driven past its TTFT
     target, yields an slo.violation runtime event visible via

@@ -8,7 +8,7 @@ import numpy as np
 import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ray_tpu.models import MODEL_REGISTRY, TransformerLM
 from ray_tpu.ops.attention import flash_attention, mha_reference
@@ -47,7 +47,7 @@ def test_ring_attention_exact():
     fn = shard_map(
         lambda q, k, v: ring_attention(q, k, v, axis_name=AXIS_SEQ),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     out = jax.jit(fn)(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
@@ -65,7 +65,7 @@ def test_ring_attention_gqa():
     fn = shard_map(
         lambda q, k, v: ring_attention(q, k, v, axis_name=AXIS_SEQ),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     out = jax.jit(fn)(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)

@@ -6,7 +6,6 @@ store import); the cluster tier (synthetic leak flagged within one
 sweep, arena-full fragmentation breakdown) is 3.12-gated."""
 
 import asyncio
-import sys
 import time
 
 import pytest
@@ -15,10 +14,6 @@ from ray_tpu._private import ledger
 from ray_tpu._private.config import cfg
 from ray_tpu._private.gcs import GcsServer
 from ray_tpu.util.state import _merge_object_rows
-
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
 
 OID = bytes(range(20))
 OID_HEX = OID.hex()
@@ -376,7 +371,6 @@ def test_cli_memory_pane_renders_available_metrics():
 
 
 # ------------------------------------------------------------ cluster tier
-@needs_cluster
 def test_arena_full_error_carries_fragmentation_breakdown(tmp_path):
     from ray_tpu._private import events
     from ray_tpu._private.object_store import ObjectStoreClient
@@ -407,7 +401,6 @@ def test_arena_full_error_carries_fragmentation_breakdown(tmp_path):
         store.close()
 
 
-@needs_cluster
 def test_node_manager_consumes_evict_hints():
     from ray_tpu._private.node_manager import NodeManager
     from ray_tpu._private.object_store import ObjectStoreClient
@@ -435,7 +428,6 @@ def test_node_manager_consumes_evict_hints():
             store.close()
 
 
-@needs_cluster
 def test_cluster_synthetic_leak_flagged_within_one_sweep():
     """Acceptance: a sealed object whose owner (an actor worker) was
     killed with no pins outstanding is flagged by ONE explicit ledger

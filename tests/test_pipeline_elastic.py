@@ -20,7 +20,6 @@ restart_policy="stage".
 """
 
 import os
-import sys
 import time
 
 import numpy as np
@@ -33,10 +32,6 @@ from ray_tpu.train.mpmd import (LocalStageHandle, MicrobatchReplayBuffer,
                                 PipelineDegradedError, StageDefinition,
                                 StageLostError)
 from ray_tpu.util.chaos import StageKiller
-
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
 
 D, MB, M, S = 8, 4, 4, 3
 
@@ -661,7 +656,6 @@ def test_backend_executor_replace_gating():
 
 # ------------------------------------------------------- cluster tier
 
-@needs_cluster
 def test_actor_gang_stage_kill_bit_identical():
     """Real PipelineStageActor gang: stage 1's actor is SIGKILLed
     mid-run; recovery restores its shard from the object store and the
@@ -704,7 +698,6 @@ def test_actor_gang_stage_kill_bit_identical():
         ray_tpu.shutdown()
 
 
-@needs_cluster
 def test_jax_trainer_per_worker_replace():
     """restart_policy="stage": a worker whose loop raises once is
     replaced in its bundle and resumes from the latest checkpoint; the

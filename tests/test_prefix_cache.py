@@ -5,18 +5,12 @@ contract with the cache on, coalesced-stream exactly-once semantics
 (including resume mid-coalesced-chunk under replica death), session
 affinity routing, and the bench-side decode plausibility guard.
 
-Everything above the `needs_cluster` line is CPU-pinned and cluster-free
-(tier-1 on any interpreter)."""
+The first part is cluster-free; the cluster tier follows it."""
 
-import sys
 import time
 
 import numpy as np
 import pytest
-
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
 
 
 # --------------------------------------------------------------------------
@@ -461,7 +455,6 @@ def ray_start():
     ray_tpu.shutdown()
 
 
-@needs_cluster
 def test_coalesced_stream_exactly_once_under_preempt_chaos(ray_start):
     """PR 9's preempt_one() against PR 10's coalesced streams: a replica
     preempted (and a second one hard-killed) mid-coalesced-chunk must

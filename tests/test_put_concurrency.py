@@ -11,17 +11,11 @@ Covers the three regressions the redesign could introduce:
 """
 
 import os
-import sys
 import threading
 import time
 import zlib
 
 import pytest
-
-if sys.version_info < (3, 12):
-    pytest.skip("ray_tpu runtime requires Python >= 3.12 (shm store "
-                "zero-copy pins use the PEP 688 buffer protocol)",
-                allow_module_level=True)
 
 import numpy as np
 

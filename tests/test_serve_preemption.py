@@ -19,7 +19,6 @@ stream resume across a real replica kill.
 """
 
 import itertools
-import sys
 import threading
 import time
 
@@ -27,10 +26,6 @@ import pytest
 
 import ray_tpu
 from ray_tpu import serve
-
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
 
 
 # --------------------------------------------------------------------------
@@ -742,7 +737,6 @@ def ray_start():
     ray_tpu.shutdown()
 
 
-@needs_cluster
 def test_preempt_one_drains_replaces_with_zero_errors(ray_start):
     """Notice-based preemption: the in-flight stream completes on the
     draining replica, new requests land on the replacement, the client
@@ -771,7 +765,6 @@ def test_preempt_one_drains_replaces_with_zero_errors(ray_start):
     serve.delete("llm-preempt")
 
 
-@needs_cluster
 def test_stream_resumes_on_survivor_after_kill(ray_start):
     """Hard replica death mid-stream: the handle resubmits with
     resume_tokens and the client sees the exact greedy continuation —

@@ -7,17 +7,12 @@ mid-generation must run the replica-side generator's finally path NOW
 (freeing inference-engine slots etc.), and a replica killed mid-stream
 must come back with a clean slot pool."""
 
-import sys
 import time
 
 import pytest
 
 import ray_tpu
 from ray_tpu import serve
-
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
 
 
 @pytest.fixture(scope="module")
@@ -28,7 +23,6 @@ def ray_start():
     ray_tpu.shutdown()
 
 
-@needs_cluster
 def test_streaming_handle(ray_start):
     @serve.deployment
     class Streamer:
@@ -135,7 +129,6 @@ def test_llm_deployment_generator_exit_frees_slot():
         dep.engine.stop()
 
 
-@needs_cluster
 def test_stream_cancellation_frees_slot_over_serve(ray_start):
     """Client drops a Serve streaming iterator mid-generation: the
     engine slot frees and the queue metrics decrement."""
@@ -168,7 +161,6 @@ def test_stream_cancellation_frees_slot_over_serve(ray_start):
     serve.delete("llm-cancel")
 
 
-@needs_cluster
 def test_kill_replica_mid_stream_reclaims_slots(ray_start):
     """Chaos: a replica killed mid-stream is replaced by the controller
     and the replacement's slot pool is fully free (no leaked slots from

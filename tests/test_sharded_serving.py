@@ -20,15 +20,10 @@ The cluster tier (real gang attach over a Serve app, rank death
 mid-decode, whole-gang drain -> shell revival -> exactly-once stream
 resume) is 3.12-gated like every other cluster suite."""
 
-import sys
 import time
 
 import numpy as np
 import pytest
-
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
 
 
 @pytest.fixture(scope="module")
@@ -373,7 +368,6 @@ def ray_start():
     ray_tpu.shutdown()
 
 
-@needs_cluster
 def test_gang_attach_and_rank_death_recovery(ray_start):
     """Acceptance: a 2-host sharded deployment serves greedy streams;
     GangRankKiller SIGKILLs rank 1 mid-decode; the gang wedges, drains

@@ -11,17 +11,12 @@ tier needs the Python 3.12 store runtime like every other multi-node
 suite."""
 
 import asyncio
-import sys
 import time
 
 import pytest
 
 from ray_tpu._private import data_plane as dp
 from ray_tpu._private.config import cfg
-
-needs_cluster = pytest.mark.skipif(
-    sys.version_info < (3, 12),
-    reason="cluster runtime requires Python >= 3.12 (PEP 688 store reads)")
 
 OID = b"\x07" * 20
 
@@ -278,7 +273,6 @@ def test_restore_from_broadcast_places_leaves(monkeypatch):
 
 # ----------------------------------------------------------- cluster tier
 
-@needs_cluster
 def test_broadcast_weights_cluster_delivery_and_arrivals():
     """256 KB blob (small for CI; the spanning path has native selftest
     + store-level coverage) reaches every node via the relay tree; each
@@ -320,7 +314,6 @@ def test_broadcast_weights_cluster_delivery_and_arrivals():
         cluster.shutdown()
 
 
-@needs_cluster
 def test_broadcast_weights_retries_via_surviving_holders(monkeypatch):
     """Relay-death chaos: every relay-carrying push fails (the interior
     of the tree dies), the root's await surfaces the subtree failure,
