@@ -1,0 +1,13 @@
+"""Train step: useful FLOPs of a step (yardstick.train_step_flops;
+recomputed operations do not count) times steps per second of the window,
+over the chip's peak."""
+from perfbench import yardstick
+
+
+def read(run):
+    if run.get("kind") != "train" or not run.get("window_steps"):
+        return None
+    flops = yardstick.train_step_flops(
+        run["config"], run["mix"]["batch"], run["mix"]["seq_len"])
+    peak = yardstick.peaks(run["device"]["kind"])["flops_per_s"]
+    return flops * run["window_steps"] / run["window_s"] / peak * 100.0
