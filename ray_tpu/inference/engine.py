@@ -4,10 +4,15 @@ a persistent decode loop.
 Architecture (the TPU-serving shape — cf. slot-based continuous
 batching in the Gemma-on-TPU serving stack):
 
-- The engine owns ``n_slots`` KV-cache slots, allocated once as
-  ``[n_layers, n_slots, max_len, Hkv, D]`` per-layer stacked arrays and
-  donated through every step, so the decode step compiles exactly ONCE
-  and then mutates the pool in place for the life of the engine.
+- The engine owns ``n_slots`` KV-cache slots, allocated once as two
+  pools (K and V) of ``[n_layers, n_slots, max_len, Hkv, D]`` and
+  donated through every step. The cached forward's layer loop only
+  reads a pool; one write after the loop adds the step's new rows of
+  all layers to it (models/transformer.py ``_decode``,
+  ``_cache_write``), so the program's output pool IS the donated input
+  buffer: the decode step compiles exactly ONCE and then mutates the
+  pool in place for the life of the engine, moving ``n_slots`` rows a
+  layer and never the pool.
 - Each iteration of the loop (a) admits queued prompts via *chunked
   prefill* under a per-step prefill-token budget — a long prompt is
   split into fixed-shape chunks that run through the cached-attention

@@ -148,16 +148,35 @@ def _cached_attention(q, k_cache, v_cache, q_pos0):
 
 
 def _cache_write(cache, new, idx):
-    """Write `new` [B,L,Hkv,D] into `cache` [B,M,Hkv,D] at position
-    `idx`: a scalar (all rows share one write offset) or a [B] vector
-    (per-slot offsets — each row lands at its own length). XLA clamps
-    out-of-range starts, so a full/free slot writes at M-L harmlessly."""
+    """Write `new` [..., B, L, Hkv, D] into `cache` [..., B, M, Hkv, D]
+    at position `idx` of every row: a scalar (all rows share one write
+    offset) or a [B] vector (per-slot offsets — each row lands at its
+    own length). The leading dims are none (one layer's K or V, as
+    attention reads it) or [n_layers] (the whole pool, which takes all
+    layers' new rows in ONE write). Only the new rows move: the update
+    operand is `new`, never [.., M, ..], so a donated pool is updated in
+    place. Out-of-range starts are clamped, so a full/free slot writes
+    at M-L harmlessly."""
     new = new.astype(cache.dtype)
+    b_axis = cache.ndim - 4
     if jnp.ndim(idx) == 0:
-        return jax.lax.dynamic_update_slice(cache, new, (0, idx, 0, 0))
-    return jax.vmap(
-        lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
-    )(cache, new, idx)
+        start = [0] * cache.ndim
+        start[b_axis + 1] = idx
+        return jax.lax.dynamic_update_slice(cache, new, start)
+    # one scatter batched over the rows (so a cache sharded over rows
+    # stays local to its shard): row b's window [..., L, Hkv, D] lands
+    # at position idx[b]
+    return jax.lax.scatter(
+        cache, idx[:, None], new,
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=tuple(
+                a for a in range(cache.ndim) if a != b_axis),
+            inserted_window_dims=(),
+            scatter_dims_to_operand_dims=(b_axis + 1,),
+            operand_batching_dims=(b_axis,),
+            scatter_indices_batching_dims=(0,)),
+        indices_are_sorted=True, unique_indices=True,
+        mode=jax.lax.GatherScatterMode.CLIP)
 
 
 class Attention(nn.Module):
@@ -170,10 +189,12 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, positions, cache=None):
         """cache=None: training/prefill forward (flash/ring dispatch),
-        returns out. cache=(k_cache, v_cache, idx): serving decode —
-        writes this call's K/V at [idx, idx+L) (idx scalar or per-slot
-        [B] vector), attends against the cache, returns
-        (out, (k_cache', v_cache'))."""
+        returns out. cache=(k_layer, v_layer, idx): serving decode —
+        this layer's K and V [B,M,Hkv,D] as read out of the pool; the
+        call's own K/V rows are placed in that read-out at [idx, idx+L)
+        (idx scalar or per-slot [B] vector) for attention to see, and
+        returned as (out, (k_rows, v_rows)) [B,L,Hkv,D] for the caller
+        to add to the pool: the pool itself is not written here."""
         cfg = self.cfg
         B, L, E = x.shape
         H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -195,9 +216,7 @@ class Attention(nn.Module):
             out = attention_dispatch(q, k, v, causal=True,
                                      impl=cfg.attention_impl)
             return proj(out)
-        k_cache, v_cache, idx = cache
-        k_cache = _cache_write(k_cache, k, idx)
-        v_cache = _cache_write(v_cache, v, idx)
+        k_layer, v_layer, idx = cache
         if L > 1 and not self.chunked:
             # one-shot prefill (L is static): the block attends only
             # within itself, so the fused flash/ring kernel computes it
@@ -209,8 +228,9 @@ class Attention(nn.Module):
             out = attention_dispatch(q, k, v, causal=True,
                                      impl=cfg.attention_impl)
         else:
-            out = _cached_attention(q, k_cache, v_cache, idx)
-        return proj(out), (k_cache, v_cache)
+            out = _cached_attention(q, _cache_write(k_layer, k, idx),
+                                    _cache_write(v_layer, v, idx), idx)
+        return proj(out), (k, v)
 
 
 class MLP(nn.Module):
@@ -239,9 +259,9 @@ class Block(nn.Module):
         att = Attention(cfg, self.chunked, name="attn")(
             RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x),
             positions, cache)
-        new_cache = None
+        new_rows = None
         if cache is not None:
-            att, new_cache = att
+            att, new_rows = att
         h = x + att
         normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(h)
         if cfg.n_experts > 0:
@@ -250,7 +270,7 @@ class Block(nn.Module):
         else:
             y, aux = MLP(cfg, name="mlp")(normed), jnp.zeros((), jnp.float32)
         if cache is not None:
-            return h + y, aux, new_cache
+            return h + y, aux, new_rows
         return h + y, aux
 
 
@@ -274,25 +294,28 @@ class ScanBlock(nn.Module):
 
 
 class DecodeScanBlock(nn.Module):
-    """Scan body for the serving decode path: the layer's KV cache
-    rides as a scanned input (axis 0 = layers) and the updated cache
-    comes back in the ys. Param names mirror ScanBlock ('block' under
-    the scan) so the SAME trained/stacked params apply."""
+    """Scan body for the serving decode path: the layer's K and V ride
+    in as a scanned input (axis 0 of the pools = layers), READ-ONLY, and
+    only the call's new rows [B,L,Hkv,D] come back in the ys — never the
+    layer, so no pool is stacked up again. Param names mirror ScanBlock
+    ('block' under the scan) so the SAME trained/stacked params apply."""
     cfg: TransformerConfig
     chunked: bool = False
 
     @nn.compact
     def __call__(self, carry, cache_kv):
         x, positions, idx = carry
-        out, _aux, new_cache = Block(self.cfg, self.chunked, name="block")(
+        out, _aux, new_rows = Block(self.cfg, self.chunked, name="block")(
             x, positions, (cache_kv[0], cache_kv[1], idx))
-        return (out, positions, idx), new_cache
+        return (out, positions, idx), new_rows
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                dtype=None):
     """Fresh KV cache pytree: {'k','v': [n_layers,B,max_len,Hkv,D],
-    'idx': next write position (scalar int32)}."""
+    'idx': next write position (scalar int32)}. Each of 'k' and 'v' is
+    ONE pool for all layers; the cached forward returns the same pool
+    with this call's rows added in place (see TransformerLM._decode)."""
     dtype = dtype or cfg.dtype
     shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
@@ -414,9 +437,17 @@ class TransformerLM(nn.Module):
     def _decode(self, x, positions, cache, embed, return_hidden,
                 chunked_prefill=False):
         """Serving decode forward: applies every layer against the KV
-        cache and returns (logits|hidden, new_cache). Shares the
-        training param tree — the decode scan mirrors ScanBlock's
-        naming ('layers'/'block')."""
+        cache and returns (logits|hidden, new_cache). The two pools
+        cache["k"], cache["v"] [n_layers,B,M,Hkv,D] are only READ by the
+        layer loop: layer i reads pool[i], places its new [B,L,Hkv,D]
+        rows in that read-out for its attention, and hands the rows
+        back; after the loop ONE write per pool adds all layers' rows
+        at (.., b, idx_b), so new_cache holds the input pools updated
+        in place (a jitted caller that donates them gets its own
+        buffers back; nothing pool-shaped is copied or stacked).
+        Shares the training param tree — the decode scan mirrors
+        ScanBlock's naming ('layers'/'block'); the unscanned layout
+        reads and writes the same way."""
         cfg = self.cfg
         L = x.shape[1]
         idx = cache["idx"]
@@ -429,18 +460,18 @@ class TransformerLM(nn.Module):
                 length=cfg.n_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, chunked_prefill, name="layers")
-            (x, _, _), (k_new, v_new) = stack((x, positions, idx),
-                                              (cache["k"], cache["v"]))
+            (x, _, _), (k_rows, v_rows) = stack(
+                (x, positions, idx), (cache["k"], cache["v"]))
         else:
-            ks, vs = [], []
+            rows = []
             for i in range(cfg.n_layers):
-                x, _aux, (k_i, v_i) = Block(
+                x, _aux, new_rows = Block(
                     cfg, chunked_prefill, name=f"layer_{i}")(
                     x, positions, (cache["k"][i], cache["v"][i], idx))
-                ks.append(k_i)
-                vs.append(v_i)
-            k_new = jnp.stack(ks)
-            v_new = jnp.stack(vs)
+                rows.append(new_rows)
+            k_rows, v_rows = (jnp.stack(r) for r in zip(*rows))
+        k_new = _cache_write(cache["k"], k_rows, idx)
+        v_new = _cache_write(cache["v"], v_rows, idx)
         new_cache = {"k": k_new, "v": v_new, "idx": idx + L}
         x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
         if return_hidden:

@@ -7,6 +7,7 @@ Runs on the CPU (conftest): a chip's bf16 default matmuls would turn
 these exactness checks into noise comparisons."""
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -203,3 +204,139 @@ def test_generate_sharded_mesh(jax_cpu):
     a = np.asarray(gen_t(params, prompt, jax_cpu.random.PRNGKey(3)))
     b = np.asarray(gen_t(params, prompt, jax_cpu.random.PRNGKey(4)))
     assert (a != b).any()
+
+
+# ---- the cached forward against the uncached one, shape by shape --------
+#
+# One pool buffer passes through the cached forward: the layers read it
+# and one write adds their new rows to it (models/transformer.py
+# `_decode`, `_cache_write`). The cases below hold that path to the
+# full causal forward (`cache=None`)
+# at 1e-5 in float32, and to leaving every other position of the pool
+# bit-identical, for every way a caller addresses the pool.
+
+_PARITY_M = 12           # pool positions per row
+_PARITY_MODES = {
+    # name: (idx per row | scalar, new tokens L, chunked_prefill)
+    # a row at 0, a row mid-way and a row at M-1, one token each: the
+    # engine's decode step
+    "rows_decode": ([0, 5, _PARITY_M - 1], 1, False),
+    # per-row idx with L > 1: the speculative verify forward
+    "rows_verify": ([0, 3, _PARITY_M - 4], 4, True),
+    # scalar idx, one token: make_generate_fn's decode step
+    "scalar_decode": (6, 1, False),
+    # scalar idx, L > 1 continuing an occupied cache: a prefill chunk
+    "scalar_chunk": (5, 4, True),
+}
+
+
+@pytest.fixture(scope="module")
+def parity_models(jax_cpu):
+    """(scan_layers, n_experts) -> (cfg, model, params), built once."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import TransformerConfig, TransformerLM
+
+    @functools.lru_cache(maxsize=None)
+    def get(scan_layers, n_experts):
+        cfg = TransformerConfig(
+            vocab_size=64, d_model=32, n_layers=3, n_heads=4,
+            n_kv_heads=2, d_ff=64, max_seq_len=32, dtype=jnp.float32,
+            param_dtype=jnp.float32, remat=False,
+            scan_layers=scan_layers, n_experts=n_experts, expert_top_k=2,
+            # capacity = the group's length: no token is dropped, so a
+            # row scored alone and in a batch route alike
+            capacity_factor=n_experts / 2 if n_experts else 1.25)
+        model = TransformerLM(cfg)
+        params = model.init(jax_cpu.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+        return cfg, model, params
+    return get
+
+
+@pytest.fixture(scope="module")
+def parity_run(jax_cpu, parity_models):
+    """One cached forward per case, shared by the two tests that read
+    it: returns (logits, want, pool_before, pool_after, starts, L).
+    Every case of a model shares one uncached forward and one noise
+    pool, so that a case compiles only its own cached forward."""
+    import jax.numpy as jnp
+    B, M, LMAX = 3, _PARITY_M, 4
+
+    @functools.lru_cache(maxsize=None)
+    def base(scan_layers, n_experts):
+        cfg, model, params = parity_models(scan_layers, n_experts)
+        kt, kh, kk, kv = jax_cpu.random.split(jax_cpu.random.PRNGKey(3), 4)
+        tokens = jax_cpu.random.randint(kt, (B, M + LMAX), 0,
+                                        cfg.vocab_size)
+        other = jax_cpu.random.randint(kh, (B, M), 0, cfg.vocab_size)
+        shape = (cfg.n_layers, B, M, cfg.n_kv_heads, cfg.head_dim)
+        noise = {"k": jax_cpu.random.normal(kk, shape, jnp.float32),
+                 "v": jax_cpu.random.normal(kv, shape, jnp.float32)}
+        full = model.apply({"params": params}, tokens)
+        return tokens, other, noise, np.asarray(full)
+
+    @functools.lru_cache(maxsize=None)
+    def run(scan_layers, n_experts, mode):
+        cfg, model, params = parity_models(scan_layers, n_experts)
+        tokens, other, noise, full = base(scan_layers, n_experts)
+        idx, L, chunked = _PARITY_MODES[mode]
+        starts = np.asarray(idx if isinstance(idx, list) else [idx] * B)
+        # every row's history in one forward over a pool of noise; from
+        # a row's length on, the pool holds K/V of OTHER tokens, so what
+        # the call fails to write or to mask shows in the logits
+        hist = jnp.where(np.arange(M)[None] < starts[:, None],
+                         tokens[:, :M], other)
+        _, pool = model.apply(
+            {"params": params}, hist,
+            cache=dict(noise, idx=jnp.zeros((B,), jnp.int32)),
+            chunked_prefill=True)
+        new_toks = jnp.stack([tokens[b, n:n + L]
+                              for b, n in enumerate(starts)])
+        want = np.stack([full[b, n:n + L] for b, n in enumerate(starts)])
+        logits, new = model.apply(
+            {"params": params}, new_toks,
+            cache=dict(pool, idx=jnp.asarray(idx, jnp.int32)),
+            chunked_prefill=chunked)
+        np.testing.assert_array_equal(np.asarray(new["idx"]),
+                                      np.asarray(idx) + L)
+        return (np.asarray(logits), want,
+                {k: np.asarray(pool[k]) for k in "kv"},
+                {k: np.asarray(new[k]) for k in "kv"}, starts, L)
+    return run
+
+
+_PARITY_CASES = [
+    pytest.param(scan, experts, mode,
+                 id=f"{'scan' if scan else 'unrolled'}-"
+                    f"{'moe' if experts else 'dense'}-{mode}")
+    for scan in (True, False) for experts in (0, 4)
+    for mode in _PARITY_MODES]
+
+
+@pytest.mark.parametrize("scan_layers,n_experts,mode", _PARITY_CASES)
+def test_cached_forward_matches_uncached(parity_run, scan_layers,
+                                         n_experts, mode):
+    """Logits of the cached forward equal the full causal forward's at
+    the same positions, row by row, over a pool whose unused positions
+    hold noise."""
+    logits, want, *_ = parity_run(scan_layers, n_experts, mode)
+    np.testing.assert_allclose(logits, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("scan_layers,n_experts,mode", _PARITY_CASES)
+def test_cached_forward_touches_only_its_rows(parity_run, scan_layers,
+                                              n_experts, mode):
+    """Across the call the pool changes at [idx_b, idx_b + L) of row b
+    in every layer and nowhere else: other rows' positions, a row's
+    history and everything above idx + L are bit-identical."""
+    _, _, before, after, starts, L = parity_run(scan_layers, n_experts,
+                                                mode)
+    written = np.zeros(before["k"].shape, bool)
+    for b, n in enumerate(starts):
+        written[:, b, n:n + L] = True
+    for name in "kv":
+        np.testing.assert_array_equal(after[name][~written],
+                                      before[name][~written])
+        # and the new rows did land (noise before, K/V after)
+        assert (after[name][written] != before[name][written]).mean() > 0.99
