@@ -1653,18 +1653,20 @@ def main():
         _bb.configure(gcs._blackbox_dir(), "gcs",
                       worker_id="gcs")
         import signal
-
-        def _on_term(signum, frame):
-            _bb.seal(f"signal_{signum}")
-            raise SystemExit(0)
-
+        # handled on the loop, never inside whatever the main thread was
+        # doing: a SIGKILLed multi-threaded driver sends its parent-death
+        # SIGTERM once per dying thread, and a second one arriving inside
+        # a handler that seals (non-reentrant lock) hung the GCS forever
+        stop_evt = asyncio.Event()
         try:
-            signal.signal(signal.SIGTERM, _on_term)
-        except (ValueError, OSError):
+            asyncio.get_running_loop().add_signal_handler(
+                signal.SIGTERM, stop_evt.set)
+        except (NotImplementedError, ValueError, OSError):
             pass
         # announce the bound address on stdout for the supervisor
         print(f"GCS_ADDRESS={addr}", flush=True)
-        await asyncio.Event().wait()
+        await stop_evt.wait()
+        _bb.seal(f"signal_{int(signal.SIGTERM)}")
 
     try:
         asyncio.run(run())

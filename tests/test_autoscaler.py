@@ -4,19 +4,13 @@ python/ray/tests/autoscaler + FakeMultiNodeProvider)."""
 
 import time
 
-import pytest
-
 import ray_tpu
 from ray_tpu.autoscaler import (Autoscaler, AutoscalerConfig,
                                 FakeMultiNodeProvider)
 from ray_tpu.autoscaler.autoscaler import NodeTypeConfig
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=1, object_store_memory=64 * 1024 * 1024)
-    yield ctx
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=1, object_store_memory=64 * 1024 * 1024)
 
 
 def test_scale_up_then_down(ray_start):

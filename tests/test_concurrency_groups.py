@@ -9,16 +9,10 @@ or per-call via .options(concurrency_group=...)."""
 
 import time
 
-import pytest
-
 import ray_tpu
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ray_tpu.init(num_cpus=2, object_store_memory=128 * 1024 * 1024)
-    yield
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=2, object_store_memory=128 * 1024 * 1024)
 
 
 @ray_tpu.remote(concurrency_groups={"io": 2})

@@ -9,11 +9,7 @@ import pytest
 import ray_tpu
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=4, object_store_memory=256 * 1024 * 1024)
-    yield ctx
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=256 * 1024 * 1024)
 
 
 def test_put_get_small(ray_start):

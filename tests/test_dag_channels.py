@@ -2,26 +2,21 @@
 python/ray/dag/tests/experimental/test_accelerated_dag.py shapes)."""
 
 import multiprocessing
+import os
 import time
-
-import pytest
 
 import ray_tpu
 from ray_tpu.dag import InputNode, MultiOutputNode
 from ray_tpu.experimental.channel import Channel, ChannelClosed
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=6, object_store_memory=128 * 1024 * 1024)
-    yield ctx
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=6, object_store_memory=128 * 1024 * 1024)
 
 
 def test_channel_same_process():
-    ch = Channel("/dev/shm/rt_test_chan1", max_size=1 << 16,
-                 num_readers=1, create=True)
-    reader = Channel("/dev/shm/rt_test_chan1")
+    path = f"/dev/shm/rt_test_chan1_{os.getpid()}"
+    ch = Channel(path, max_size=1 << 16, num_readers=1, create=True)
+    reader = Channel(path)
     ch.write({"x": 1})
     assert reader.read() == {"x": 1}
     ch.write([1, 2, 3])
@@ -41,18 +36,23 @@ def _reader_proc(path, out_q):
 
 
 def test_channel_cross_process_backpressure():
-    path = "/dev/shm/rt_test_chan2"
+    path = f"/dev/shm/rt_test_chan2_{os.getpid()}"
     ch = Channel(path, max_size=1 << 16, num_readers=1, create=True)
-    q = multiprocessing.Queue()
-    p = multiprocessing.Process(target=_reader_proc, args=(path, q))
+    ctx = multiprocessing.get_context("spawn")
+    q = ctx.Queue()
+    p = ctx.Process(target=_reader_proc, args=(path, q))
     p.start()
-    for i in range(20):
-        ch.write(i)     # blocks until reader acks previous version
-    ch.close()
-    vals = q.get(timeout=30)
-    p.join(timeout=10)
-    assert vals == list(range(20))   # every version seen exactly once
-    ch.destroy()
+    try:
+        for i in range(20):
+            ch.write(i)     # blocks until reader acks previous version
+        ch.close()
+        vals = q.get(timeout=60)
+        p.join(timeout=30)
+        assert vals == list(range(20))   # every version seen exactly once
+    finally:
+        if p.is_alive():
+            p.kill()
+        ch.destroy()
 
 
 def test_compiled_dag_linear(ray_start):

@@ -3,17 +3,12 @@ iteration incl. the jax device-feed path (reference:
 python/ray/data/tests/test_map.py, test_iterator.py shapes)."""
 
 import numpy as np
-import pytest
 
 import ray_tpu
 from ray_tpu import data as rd
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=4, object_store_memory=256 * 1024 * 1024)
-    yield ctx
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=256 * 1024 * 1024)
 
 
 def test_range_count(ray_start):

@@ -3,18 +3,12 @@ python/ray/data/_internal/logical/rules/operator_fusion.py and
 _internal/stats.py — fused map chains pay one task per block; ds.stats()
 reports tasks/rows/bytes/wall per operator)."""
 
-import pytest
-
 import ray_tpu
 import ray_tpu.data as rd
 from ray_tpu.data import execution as exe
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ray_tpu.init(num_cpus=2, object_store_memory=128 * 1024 * 1024)
-    yield
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=2, object_store_memory=128 * 1024 * 1024)
 
 
 def test_fusion_rule_plan_shape():

@@ -14,11 +14,7 @@ from ray_tpu import data as rd
 from ray_tpu.data import execution as exe
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ray_tpu.init(num_cpus=4, object_store_memory=128 * 1024 * 1024)
-    yield
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=128 * 1024 * 1024)
 
 
 @pytest.fixture()

@@ -3,18 +3,13 @@ locality_hints, output_splitter.py — bundles deal to the consumer on the
 block's node within a bounded row-imbalance slack)."""
 
 import numpy as np
-import pytest
 
 import ray_tpu
 from ray_tpu import data as rd
 from ray_tpu.data.split import _QUEUE_CAP, _SplitCoordinator
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ray_tpu.init(num_cpus=4, object_store_memory=128 * 1024 * 1024)
-    yield
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=128 * 1024 * 1024)
 
 
 def _drain(coord, idx):

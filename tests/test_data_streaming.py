@@ -4,20 +4,15 @@ expansion, and parquet reads stream per row group (reference:
 map_transformer generator UDFs; parquet fragment reads)."""
 
 import numpy as np
-import pytest
 
 import ray_tpu
 import ray_tpu.data as rd
 
 
-@pytest.fixture(scope="module")
-def cluster():
-    ray_tpu.init(num_cpus=2, object_store_memory=128 * 1024 * 1024)
-    yield
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=2, object_store_memory=128 * 1024 * 1024)
 
 
-def test_generator_udf_streams_chunks(cluster):
+def test_generator_udf_streams_chunks(ray_start):
     """A map_batches UDF that yields K chunks per input block produces
     K output blocks, in order."""
     def expand(batch):
@@ -34,7 +29,7 @@ def test_generator_udf_streams_chunks(cluster):
     assert ids == [i * 10 + 1 for i in range(40)]
 
 
-def test_generator_udf_fuses_with_downstream_map(cluster):
+def test_generator_udf_fuses_with_downstream_map(ray_start):
     """Fusion across a generator UDF: each streamed chunk flows through
     the fused downstream op inside the same task."""
     def expand(batch):
@@ -50,7 +45,7 @@ def test_generator_udf_fuses_with_downstream_map(cluster):
     assert vals == expect
 
 
-def test_parquet_row_groups_stream_as_blocks(cluster, tmp_path):
+def test_parquet_row_groups_stream_as_blocks(ray_start, tmp_path):
     import pyarrow as pa
     import pyarrow.parquet as pq
     table = pa.table({"x": list(range(1000))})
@@ -62,7 +57,7 @@ def test_parquet_row_groups_stream_as_blocks(cluster, tmp_path):
     assert ds.num_blocks() == 10
 
 
-def test_stats_cover_streamed_stages(cluster):
+def test_stats_cover_streamed_stages(ray_start):
     def expand(batch):
         yield {"a": batch["id"]}
         yield {"a": batch["id"]}

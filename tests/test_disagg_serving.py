@@ -646,16 +646,6 @@ def _tiny_llm_config():
         param_dtype=jnp.float32, remat=False)
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    import ray_tpu
-    from ray_tpu import serve
-    ctx = ray_tpu.init(num_cpus=4)
-    yield ctx
-    serve.shutdown()
-    ray_tpu.shutdown()
-
-
 def test_disagg_serving_cross_replica_route_and_handoff(ray_start):
     """Acceptance: a request whose prefix was prefilled on a DIFFERENT
     replica is routed by cluster-wide longest match, skips local

@@ -945,12 +945,7 @@ def test_rtlint_rt001_clean_on_fleet_hold_paths():
 # cluster tier (Python >= 3.12)
 # ==========================================================================
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=6)
-    yield ctx
-    serve.shutdown()
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=6)
 
 
 def test_scale_to_zero_and_shell_revival_exactly_once(ray_start):

@@ -13,11 +13,7 @@ from ray_tpu.util.actor_pool import ActorPool
 from ray_tpu.util.queue import Queue
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=4, object_store_memory=64 * 1024 * 1024)
-    yield ctx
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=64 * 1024 * 1024)
 
 
 def test_actor_pool(ray_start):

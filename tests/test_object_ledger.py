@@ -418,6 +418,7 @@ def test_node_manager_consumes_evict_hints():
             stub = Stub()
             stub.store = store
             stub._evict_hints = set()
+            stub.node_id = "n1"     # the eviction is recorded under it
             NodeManager.h_ledger_evict_hint(stub, None, [oid.hex()])
             assert oid in stub._evict_hints
             freed = NodeManager._consume_evict_hints(stub, {0}, False)

@@ -123,11 +123,15 @@ def _child_read(path, key, expected):
 
 def test_multiprocess_visibility(store):
     store.put_bytes(oid(40), b"shared-payload")
-    ctx = multiprocessing.get_context("fork")
+    ctx = multiprocessing.get_context("spawn")
     p = ctx.Process(target=_child_read, args=(store.path, oid(40), b"shared-payload"))
     p.start()
-    p.join(30)
-    assert p.exitcode == 0
+    try:
+        p.join(60)
+        assert p.exitcode == 0
+    finally:
+        if p.is_alive():
+            p.kill()
     buf = store.get(b"\x99" * 20)
     assert bytes(buf.data) == b"from-child"
 
@@ -253,7 +257,7 @@ def test_multiprocess_put_contention():
     path = "/dev/shm/raytpu_test_contend_%d" % os.getpid()
     s = ObjectStoreClient(path, create=True, size=256 * 1024 * 1024,
                           stripes=4)
-    ctx = multiprocessing.get_context("fork")
+    ctx = multiprocessing.get_context("spawn")
     try:
         duration = 0.8
 
@@ -262,12 +266,17 @@ def test_multiprocess_put_contention():
             procs = [ctx.Process(target=_contend_worker,
                                  args=(path, duration, seed0 + k, q))
                      for k in range(n_clients)]
-            for p in procs:
-                p.start()
-            results = [q.get(timeout=60) for _ in procs]
-            for p in procs:
-                p.join(30)
-                assert p.exitcode == 0
+            try:
+                for p in procs:
+                    p.start()
+                results = [q.get(timeout=120) for _ in procs]
+                for p in procs:
+                    p.join(30)
+                    assert p.exitcode == 0
+            finally:
+                for p in procs:
+                    if p.is_alive():
+                        p.kill()
             return results
 
         single = run(1, seed0=10)
@@ -311,7 +320,11 @@ def test_kill_mid_create_repairs_stripe(striped_store):
     victim = ctx.Process(target=_chaos_put_loop,
                          args=(s.path, killer.spec()))
     victim.start()
-    killer.assert_killed(victim)
+    try:
+        killer.assert_killed(victim)
+    finally:
+        if victim.is_alive():
+            victim.kill()
     # stats() itself walks every stripe (seqlock -> locked fallback on the
     # stuck one), so the first poll performs the EOWNERDEAD repair
     st = s.stats()
@@ -464,7 +477,11 @@ def test_kill_mid_spanning_create_repairs_whole_span(striped_store):
     victim = ctx.Process(target=_chaos_span_loop,
                          args=(s.path, killer.spec()))
     victim.start()
-    killer.assert_killed(victim)
+    try:
+        killer.assert_killed(victim)
+    finally:
+        if victim.is_alive():
+            victim.kill()
     # the gc sweep runs both repair levels (EOWNERDEAD on span mutex +
     # poisoned member stripe)
     s.gc_unsealed(0)

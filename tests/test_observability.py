@@ -17,11 +17,7 @@ from ray_tpu.util.tracing import (cluster_stacks, export_otlp,
 pytestmark = pytest.mark.slow
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ray_tpu.init(num_cpus=4, object_store_memory=128 * 1024 * 1024)
-    yield
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=128 * 1024 * 1024)
 
 
 def test_otlp_mapping_unit():

@@ -445,16 +445,6 @@ def _tiny_llm_config():
         param_dtype=jnp.float32, remat=False)
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    import ray_tpu
-    from ray_tpu import serve
-    ctx = ray_tpu.init(num_cpus=4)
-    yield ctx
-    serve.shutdown()
-    ray_tpu.shutdown()
-
-
 def test_coalesced_stream_exactly_once_under_preempt_chaos(ray_start):
     """PR 9's preempt_one() against PR 10's coalesced streams: a replica
     preempted (and a second one hard-killed) mid-coalesced-chunk must

@@ -2,17 +2,12 @@
 pattern: rllib/tuned_examples as threshold tests)."""
 
 import numpy as np
-import pytest
 
 import ray_tpu
 from ray_tpu.rl import AlgorithmConfig
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=6, object_store_memory=128 * 1024 * 1024)
-    yield ctx
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=6, object_store_memory=128 * 1024 * 1024)
 
 
 def test_ppo_cartpole_learns(ray_start):

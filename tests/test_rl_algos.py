@@ -3,17 +3,9 @@ vtrace tests and tuned-example regressions; V-trace is checked against a
 plain-python recursion, algorithms against CartPole smoke training)."""
 
 import numpy as np
-import pytest
 
 import ray_tpu
 from ray_tpu.rl import AlgorithmConfig, ReplayBuffer
-
-
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=4)
-    yield ctx
-    ray_tpu.shutdown()
 
 
 def test_vtrace_matches_python_recursion():

@@ -45,11 +45,7 @@ def test_pipeline_build_and_shape():
         build_pipeline([("nope", {})])
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ray_tpu.init(num_cpus=4, object_store_memory=128 * 1024 * 1024)
-    yield
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=128 * 1024 * 1024)
 
 
 def test_ppo_with_connectors_learns(ray_start):

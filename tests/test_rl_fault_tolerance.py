@@ -12,11 +12,7 @@ from ray_tpu.rl import AlgorithmConfig
 from ray_tpu.rl.actor_manager import RunnerSetBroken
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ray_tpu.init(num_cpus=4, object_store_memory=256 * 1024 * 1024)
-    yield
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=256 * 1024 * 1024)
 
 
 def _config(**training):

@@ -4,7 +4,6 @@ pick matching actions — learnable only if each policy adapts to the
 other's behavior through the shared reward."""
 
 import numpy as np
-import pytest
 
 import ray_tpu
 from ray_tpu.rl.multi_agent import (MultiAgentConfig, MultiAgentEnv,
@@ -65,11 +64,7 @@ class MatchEnv(MultiAgentEnv):
         return self._obs(), rewards, terms, truncs, {}
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=2, object_store_memory=128 * 1024 * 1024)
-    yield ctx
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=2, object_store_memory=128 * 1024 * 1024)
 
 
 def test_multi_agent_ppo_learns_cooperative_env(ray_start):

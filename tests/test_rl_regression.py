@@ -7,17 +7,12 @@ continuous (Pendulum), CNN/discrete (the built-in GridTarget pixel env).
 """
 
 import numpy as np
-import pytest
 
 import ray_tpu
 from ray_tpu.rl import AlgorithmConfig
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ray_tpu.init(num_cpus=4, object_store_memory=256 * 1024 * 1024)
-    yield
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=256 * 1024 * 1024)
 
 
 def _run_until(config, stop_reward, max_iters, patience_improve=None):

@@ -15,11 +15,7 @@ import ray_tpu
 from ray_tpu.rl import AlgorithmConfig, PrioritizedReplayBuffer
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ray_tpu.init(num_cpus=4, object_store_memory=256 * 1024 * 1024)
-    yield
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=256 * 1024 * 1024)
 
 
 # ----------------------------------------------------------- unit tests

@@ -4,18 +4,11 @@ cluster, hit over handle and HTTP)."""
 
 import time
 
-import pytest
-
 import ray_tpu
 from ray_tpu import serve
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=8, object_store_memory=128 * 1024 * 1024)
-    yield ctx
-    serve.shutdown()
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=8, object_store_memory=128 * 1024 * 1024)
 
 
 def test_basic_deployment(ray_start):

@@ -15,12 +15,7 @@ from ray_tpu import serve
 pytestmark = pytest.mark.slow
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ray_tpu.init(num_cpus=8, object_store_memory=128 * 1024 * 1024)
-    yield
-    serve.shutdown()
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=8, object_store_memory=128 * 1024 * 1024)
 
 
 def test_typed_grpc_servicer(ray_start):

@@ -15,12 +15,7 @@ import ray_tpu
 from ray_tpu import serve
 
 
-@pytest.fixture(scope="module")
-def serve_session():
-    ray_tpu.init(num_cpus=4, object_store_memory=128 * 1024 * 1024)
-    yield
-    serve.shutdown()
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=128 * 1024 * 1024)
 
 
 @serve.deployment(num_replicas=2, max_ongoing_requests=16)
@@ -36,7 +31,7 @@ class Worker:
         return self.uid
 
 
-def test_fresh_handle_avoids_buried_replica(serve_session):
+def test_fresh_handle_avoids_buried_replica(ray_start):
     handle1 = serve.run(Worker.bind(), name="loadsig")
     # bury ONE replica via sticky multiplex routing: every slow call with
     # the same model id pins to the replica that served it first
@@ -71,7 +66,7 @@ def test_fresh_handle_avoids_buried_replica(serve_session):
         del c
 
 
-def test_shared_load_included_in_info(serve_session):
+def test_shared_load_included_in_info(ray_start):
     handle = serve.run(Worker.bind(), name="loadsig2",
                        route_prefix="/loadsig2")
     handle.remote("x").result(timeout=30)

@@ -729,12 +729,7 @@ def test_replica_preemption_file_flips_draining(tmp_path, monkeypatch):
 # cluster tier: the real lifecycle (notice -> drain -> replace -> resume)
 # --------------------------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=6)
-    yield ctx
-    serve.shutdown()
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=6)
 
 
 def test_preempt_one_drains_replaces_with_zero_errors(ray_start):

@@ -4,18 +4,11 @@ replicas before retiring old ones, so capacity never drops to zero)."""
 
 import time
 
-import pytest
-
 import ray_tpu
 from ray_tpu import serve
 
 
-@pytest.fixture(scope="module")
-def serve_session():
-    ray_tpu.init(num_cpus=4, object_store_memory=128 * 1024 * 1024)
-    yield
-    serve.shutdown()
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=128 * 1024 * 1024)
 
 
 @serve.deployment(num_replicas=2)
@@ -27,7 +20,7 @@ class Tagged:
         return self.tag
 
 
-def test_rolling_update_no_downtime(serve_session):
+def test_rolling_update_no_downtime(ray_start):
     handle = serve.run(Tagged.bind("v1"), name="roll")
     assert handle.remote("x").result(timeout=60) == "v1"
 

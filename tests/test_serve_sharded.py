@@ -23,12 +23,7 @@ from ray_tpu import serve
 pytestmark = pytest.mark.slow
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=8, object_store_memory=128 * 1024 * 1024)
-    yield ctx
-    serve.shutdown()
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=8, object_store_memory=128 * 1024 * 1024)
 
 
 class ShardedSum:

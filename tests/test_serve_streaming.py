@@ -15,14 +15,6 @@ import ray_tpu
 from ray_tpu import serve
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=4)
-    yield ctx
-    serve.shutdown()
-    ray_tpu.shutdown()
-
-
 def test_streaming_handle(ray_start):
     @serve.deployment
     class Streamer:

@@ -358,14 +358,7 @@ def test_build_sharded_app_shape(tiny):
 # cluster tier: real gang attach + rank-death recovery (3.12-gated)
 # ==========================================================================
 
-@pytest.fixture(scope="module")
-def ray_start():
-    import ray_tpu
-    from ray_tpu import serve
-    ctx = ray_tpu.init(num_cpus=8)
-    yield ctx
-    serve.shutdown()
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=8)
 
 
 def test_gang_attach_and_rank_death_recovery(ray_start):

@@ -6,17 +6,11 @@ import sys
 import time
 import urllib.request
 
-import pytest
-
 import ray_tpu
 from ray_tpu.util import state
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=4, object_store_memory=128 * 1024 * 1024)
-    yield ctx
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=128 * 1024 * 1024)
 
 
 def test_list_nodes(ray_start):
@@ -124,15 +118,16 @@ def test_user_metrics_and_prometheus(ray_start):
     h.observe(0.5)
     h.observe(5.0)
 
-    deadline = time.time() + 15
-    snap = {}
+    # the GCS ingests its own gcs_* rows too, so a non-empty snapshot
+    # is not yet this driver's push: wait for the driver's own rows
+    deadline = time.time() + 60
+    text = ""
     while time.time() < deadline:
-        snap = ray_tpu._get_worker().gcs_call("get_metrics")
-        if snap:
+        text = render_prometheus(
+            ray_tpu._get_worker().gcs_call("get_metrics") or {})
+        if "test_latency_s_count" in text:
             break
         time.sleep(0.5)
-    assert snap, "metrics never reached GCS"
-    text = render_prometheus(snap)
     assert 'test_requests_total{route="/a"} 3.0' in text
     assert "test_queue_depth 7.0" in text
     assert 'test_latency_s_bucket{le="0.1"} 1' in text

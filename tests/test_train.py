@@ -5,19 +5,13 @@ worker group, sessions/report, checkpointing, failure restart
 import os
 import time
 
-import pytest
-
 import ray_tpu
 from ray_tpu import train
 from ray_tpu.train import (Checkpoint, CheckpointConfig, FailureConfig,
                            JaxTrainer, RunConfig, ScalingConfig)
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=6, object_store_memory=128 * 1024 * 1024)
-    yield ctx
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=6, object_store_memory=128 * 1024 * 1024)
 
 
 def test_trainer_basic(ray_start, tmp_path):

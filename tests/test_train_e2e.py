@@ -3,18 +3,13 @@ JaxTrainer → worker actor → sharded train step on a device mesh, with
 Data ingest and checkpointing — loss must drop."""
 
 import numpy as np
-import pytest
 
 import ray_tpu
 from ray_tpu import train
 from ray_tpu.train import Checkpoint, JaxTrainer, RunConfig, ScalingConfig
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=4, object_store_memory=256 * 1024 * 1024)
-    yield ctx
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=4, object_store_memory=256 * 1024 * 1024)
 
 
 def _loop(config):

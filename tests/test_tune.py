@@ -3,18 +3,12 @@ stopping (reference: python/ray/tune/tests/test_tune_* shapes)."""
 
 import time
 
-import pytest
-
 import ray_tpu
 from ray_tpu import tune
 from ray_tpu.tune import ASHAScheduler, TuneConfig, Tuner, grid_search
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=8, object_store_memory=128 * 1024 * 1024)
-    yield ctx
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=8, object_store_memory=128 * 1024 * 1024)
 
 
 def test_grid_search(ray_start):

@@ -3,16 +3,10 @@ python/ray/util/joblib/ and python/ray/_private/usage/usage_lib.py)."""
 
 import math
 
-import pytest
-
 import ray_tpu
 
 
-@pytest.fixture(scope="module")
-def ray_start():
-    ctx = ray_tpu.init(num_cpus=2)
-    yield ctx
-    ray_tpu.shutdown()
+RAY_START = dict(num_cpus=2)
 
 
 def test_joblib_backend(ray_start):

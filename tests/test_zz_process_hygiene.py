@@ -1,63 +1,29 @@
-"""Suite-final process-hygiene gate (zz prefix: pytest collects files
-alphabetically, so this runs after every other test file).
+"""The last assertion of the rule in tests/conftest.py (what a test file
+starts ends with the file): nothing this pytest process started is still
+alive.
 
 Round-4 audit: a green 250-test run left 131 ray_tpu daemons alive —
 GCS servers and node managers from crashed fixtures, node managers
 retrying a dead GCS forever, workers orphaned by SIGKILLed node
-managers. Every daemon spawned during this session carries
-RAY_TPU_TEST_SESSION in its environment (tests/conftest.py); here we
-assert none survived. The reference enforces the same invariant through
-its test fixture teardown (ray.tests.conftest shutdown_only) plus the
-raylet's bounded GCS-reconnect exit.
+managers. Every daemon a test file spawns carries that file's marker in
+RAY_TPU_TEST_SESSION and the file's finalizer ends it and charges the
+file. This test looks at the markers of its OWN worker only, whenever
+xdist happens to hand it out: other workers are still inside their
+files, and their daemons are theirs. It kills nothing.
 """
 
 import os
-import time
 
 from ray_tpu._private.proc_util import find_session_processes
-
-
-def _describe(pid: int) -> str:
-    try:
-        with open(f"/proc/{pid}/cmdline", "rb") as f:
-            return f.read().replace(b"\0", b" ").decode()[:160]
-    except OSError:
-        return "<gone>"
+from tests.conftest import WORKER_MARKER, describe_process, note_strays
 
 
 def test_no_daemons_survive_the_suite():
-    marker = os.environ.get("RAY_TPU_TEST_SESSION")
-    assert marker, "conftest did not set RAY_TPU_TEST_SESSION"
-    import ray_tpu
-    ray_tpu.shutdown()
-    # teardown is asynchronous (SIGTERM -> worker reap, plus the node
-    # manager's bounded GCS-reconnect exit): allow a grace period for
-    # the tree to drain before calling anything a leak — generous,
-    # because at the tail of a 35-minute full-suite run the box is
-    # still digesting the last fixtures' teardown. The r4 pathology
-    # this gate exists for was daemons alive HOURS later.
-    deadline = time.monotonic() + 45
-    strays = []
-    while time.monotonic() < deadline:
-        strays = list(find_session_processes(marker))
-        if not strays:
-            return
-        time.sleep(0.5)
-    detail = "\n".join(f"  pid {p}: {_describe(p)}" for p in strays)
-    # persist the evidence: the assertion detail is truncated under -q,
-    # and the strays are about to be killed
-    try:
-        with open("/tmp/raytpu/hygiene_strays.log", "a") as f:
-            f.write(f"session {marker} at {time.time()}:\n{detail}\n")
-    except OSError:
-        pass
-    # reap them so one leak doesn't poison subsequent runs on this box —
-    # but still fail loudly
-    for p in strays:
-        try:
-            os.kill(p, 9)
-        except OSError:
-            pass
-    raise AssertionError(
-        f"{len(strays)} ray_tpu daemon(s) outlived the test session "
-        f"(killed now):\n{detail}")
+    assert os.environ.get("RAY_TPU_TEST_SESSION", "").startswith(
+        WORKER_MARKER), "conftest did not give this file a marker"
+    strays = [f"pid {p}: {describe_process(p)}"
+              for p in find_session_processes(WORKER_MARKER)]
+    assert not strays, (
+        f"{len(strays)} ray_tpu process(es) outlived their file's "
+        f"finalizer on this worker:\n  "
+        + note_strays(WORKER_MARKER, strays))
