@@ -15,21 +15,27 @@ batching in the Gemma-on-TPU serving stack):
   layer and never the pool.
 - Each iteration of the loop (a) admits queued prompts via *chunked
   prefill* under a per-step prefill-token budget — a long prompt is
-  split into fixed-shape chunks that run through the cached-attention
-  path (``chunked_prefill=True``) into a scratch cache, so admission
-  never stalls in-flight decodes for more than ``prefill_budget``
-  tokens of work — and (b) advances EVERY occupied slot one token in a
-  single batched decode step (per-slot ``idx`` vector: each row attends
-  and writes at its own length).
+  split across steps, and the tokens one step gives one request run as
+  ONE fixed-shape tile through the cached-attention path
+  (``chunked_prefill=True``) into a scratch cache, so the weights
+  stream once a request a step and admission never stalls in-flight
+  decodes for more than ``prefill_budget`` tokens of work; the tile is
+  chosen from the prompt's length, so every token of a prompt passes
+  through one program whatever shared its steps — and (b)
+  advances EVERY occupied slot one token in a single batched decode
+  step (per-slot ``idx`` vector: each row attends and writes at its own
+  length).
 - Tokens stream out per request through ``RequestHandle`` queues;
   slots are evicted (and immediately reusable) on EOS, max-tokens,
   slot-capacity, cancellation, or deadline.
 
 Shapes are static everywhere — tokens [n_slots], lengths [n_slots],
-prompt chunks [1, prefill_chunk] — so XLA compiles three programs
-(prefill chunk, slot insert, decode step) and nothing ever recompiles
-across admissions/evictions. ``decode_compile_count`` counts decode
-retraces; tests assert it stays at 1.
+prompt tiles [1, T] with T ``prefill_budget`` and, where that is four
+chunks or more, a handful of shorter lengths (``prefill_tiles``), every
+one compiled when the engine is built — so XLA compiles the prefill tiles,
+the slot insert and the decode step, and nothing ever recompiles across
+admissions/evictions. ``decode_compile_count`` counts decode retraces;
+tests assert it stays at 1.
 
 Sampling is shared with ``make_generate_fn`` via models/sampling.py:
 greedy engine output is bit-identical to the one-program generator.
@@ -64,16 +70,39 @@ from ray_tpu.inference.scheduler import (FINISH_LENGTH, PrefillChunk,
                                          RequestState, Scheduler)
 
 
+def prefill_tiles(chunk: int, budget: int) -> tuple:
+    """The static lengths T of a prefill call, ascending: the budget in
+    whole chunks, and below it a quarter of the tile above (in whole
+    chunks) while that is a chunk or more. A handful however large the
+    ratio ({32, 128, 512, 2048} at 16 / 2048), ONE member where the
+    budget is under four chunks ({256} at 128 / 256; the budget <= chunk
+    defaults): a tile costs a trace and an executable's load at every
+    start (1.2 s in a replica, PERF.md section 6), and a tile a quarter
+    the size saves at most three quarters of a compute-bound call and
+    next to nothing of one bound by the weights' stream. A prompt runs
+    in the smallest tile that holds the longest span a step can give it
+    (``InferenceEngine._tile_of``)."""
+    tiles = [max(1, -(-budget // chunk)) * chunk]
+    while tiles[0] >= 4 * chunk:
+        tiles.insert(0, -(-tiles[0] // (4 * chunk)) * chunk)
+    return tuple(tiles)
+
+
 @dataclasses.dataclass
 class EngineConfig:
     """Knobs of the slot pool and admission policy.
 
     n_slots: decode batch width (slots advance together every step).
     max_len: per-slot KV capacity (prompt + generated tokens).
-    prefill_chunk: static shape of one prefill call; prompts are split
-        into chunks of exactly this many tokens (last chunk padded).
-    prefill_budget: max prompt tokens admitted per engine step — the
-        knob that trades TTFT (higher = prompts land faster) against
+    prefill_chunk: the length of a prefix-cache block, and the least a
+        prefill tile can be (a prefill call's static shape is [1, T],
+        its tail padded; see ``prefill_tiles``).
+    prefill_budget: max prompt tokens admitted per engine step, and the
+        largest tile: what a step gives one request runs as one call,
+        in the tile of the prompt's length (a prompt of the budget's
+        length or more: this one).
+        The knob that trades TTFT (higher = prompts land faster, and a
+        call's weight reads are shared by more tokens) against
         inter-token latency of in-flight decodes (lower = decode steps
         between prefill work come sooner).
     eos_id: default EOS (<0 disables); per-request override on Request.
@@ -172,9 +201,18 @@ class InferenceEngine:
         dtype = cfg.cache_dtype or mcfg.dtype
         pool_shape = (mcfg.n_layers, cfg.n_slots, self._pool_len,
                       mcfg.n_kv_heads, mcfg.head_dim)
-        # scratch is prefill_chunk longer than a slot so a padded final
-        # chunk can never clamp its write window back onto real entries
-        self._scratch_len = cfg.max_len + cfg.prefill_chunk
+        # the tiles a prefill call may take. int8 blocks are written
+        # through chunk by chunk (_publish_chunk_quant: a chunk attends
+        # the dequantised values of the chunks before it, which is what
+        # makes a prefix hit bit-identical to the miss that filled it),
+        # so there the chunk is the only tile
+        self._prefill_tiles = prefill_tiles(
+            cfg.prefill_chunk,
+            cfg.prefill_chunk if self._kv_quant and self.prefix_cache
+            is not None else cfg.prefill_budget)
+        # scratch is the largest tile longer than a slot so a padded
+        # tile can never clamp its write window back onto real entries
+        self._scratch_len = cfg.max_len + self._prefill_tiles[-1]
         self._scratch_shape = (mcfg.n_layers, 1, self._scratch_len,
                                mcfg.n_kv_heads, mcfg.head_dim)
         self._pool_sharding = None
@@ -264,6 +302,8 @@ class InferenceEngine:
 
         self.decode_compile_count = 0
         self.prefill_compile_count = 0
+        self.prefill_dispatches = 0
+        self.prefill_tokens = 0       # real prompt tokens, not padding
         # spec decode accounting (greedy rows only: sampled rows always
         # force accept = 0 and would just dilute the rate)
         self.spec_verify_compile_count = 0
@@ -294,6 +334,7 @@ class InferenceEngine:
             self.profiler = profiling.StepProfiler(
                 "decode_step", emit_span=False)
         self._build_fns()
+        self._compile_prefill_tiles()
 
     # ------------------------------------------------------------ device fns
     def _zeros(self, shape, dtype, sharding=None):
@@ -339,10 +380,11 @@ class InferenceEngine:
         # (a read of a donated buffer after its call raises there too)
 
         def prefill(params, sk, sv, tokens, pos0, n_real, rng, temp):
-            # one budgeted chunk of prompt through the cached path;
-            # samples the would-be next token (used only on the last
-            # chunk, where it is the request's first generated token)
-            self.prefill_compile_count += 1    # traces once: fixed shapes
+            # one request's share of a step's prompt budget, a [1, T]
+            # tile, through the cached path; samples the would-be next
+            # token (used only on the prompt's last tile, where it is
+            # the request's first generated token)
+            self.prefill_compile_count += 1    # traces once a tile
             cache = {"k": sk, "v": sv, "idx": pos0}
             logits, new = model.apply({"params": params}, tokens,
                                       cache=cache, chunked_prefill=True)
@@ -353,7 +395,7 @@ class InferenceEngine:
             return tok[0].astype(jnp.int32), new["k"], new["v"]
 
         def insert(pk, pv, sk, sv, slot):
-            # scratch carries prefill_chunk of padding tail; the slot
+            # scratch carries the largest tile of padding tail; the slot
             # takes the first max_len entries
             sk = sk[:, :, :cfg.max_len]
             sv = sv[:, :, :cfg.max_len]
@@ -472,6 +514,40 @@ class InferenceEngine:
             self._export_span_fn = jax.jit(export_span)
             self._import_span_fn = jax.jit(
                 import_span, donate_argnums=(0, 1))
+
+    def _compile_prefill_tiles(self):
+        """Run every prefill tile on a throwaway scratch, so no request
+        is the first user of a shape: ``prefill_compile_count`` (and the
+        draft's) reads the family's size before the first submit and
+        never moves again. Each tile runs twice, on a new scratch and on
+        the one it handed back, as a prompt's first and later spans do:
+        on a mesh the two differ in sharding and XLA compiles each. The
+        engine's key is not advanced."""
+        import jax
+        import jax.numpy as jnp
+        _, key = jax.random.split(self._rng)    # as _run_prefill splits
+        with self._mesh_ctx():
+            for tile in self._prefill_tiles:
+                tokens = jnp.zeros((1, tile), jnp.int32)
+                sk = self._zeros(self._scratch_shape, self._cache_dtype)
+                sv = self._zeros(self._scratch_shape, self._cache_dtype)
+                for _ in range(2):
+                    _, sk, sv = self._prefill_fn(
+                        self.params, sk, sv, tokens, np.int32(0),
+                        np.int32(tile), key, np.float32(0.0))
+                if self._spec is not None:
+                    dk = self._zeros(self._draft_scratch_shape,
+                                     self._cache_dtype)
+                    dv = self._zeros(self._draft_scratch_shape,
+                                     self._cache_dtype)
+                    for _ in range(2):
+                        dk, dv = self._draft_prefill_fn(
+                            self._draft_params, dk, dv, tokens,
+                            np.int32(0))
+                events.record_instant(
+                    "engine.compile", category="engine",
+                    trace_id=self._trace_id, fn="prefill", tile=tile,
+                    compile_count=self.prefill_compile_count)
 
     def _build_quant_span_fns(self):
         """int8 variants of the four span programs: same fixed span
@@ -619,9 +695,10 @@ class InferenceEngine:
 
     # --------------------------------------------------------------- step
     def step(self) -> bool:
-        """One engine iteration: reap cancels/deadlines, run budgeted
-        prefill chunks (admission), advance every occupied slot one
-        token. Returns True if any device work ran."""
+        """One engine iteration: reap cancels/deadlines, run the step's
+        budgeted prefill (admission; one dispatch a request), advance
+        every occupied slot one token. Returns True if any device work
+        ran."""
         import jax
 
         with self._lock:
@@ -630,10 +707,9 @@ class InferenceEngine:
             for st in self.sched.reap(now):
                 self._scratch.pop(st.rid, None)
                 self._draft_scratch.pop(st.rid, None)
-            chunks = self.sched.plan_prefill()
             did = False
-            for ch in chunks:
-                self._run_prefill_chunk(ch, now)
+            for span in self._prefill_spans(self.sched.plan_prefill()):
+                self._run_prefill(span, now)
                 did = True
             t_admit = time.perf_counter()
 
@@ -768,14 +844,45 @@ class InferenceEngine:
                                     "host_gap_ms", "data_wait_ms",
                                     "roofline_bound") if k in rec}
 
-    def _run_prefill_chunk(self, ch: PrefillChunk, now: float):
+    def _prefill_spans(self, chunks: List[PrefillChunk]):
+        """The step's plan, one piece a dispatch: the consecutive chunks
+        the scheduler gave one request are one span of its prompt, up to
+        the largest compiled tile (a budget raised past it at run time
+        makes more dispatches, never a new shape)."""
+        cap = self._prefill_tiles[-1]
+        spans: List[PrefillChunk] = []
+        for ch in chunks:
+            last = spans[-1] if spans else None
+            if (last is not None and last.state is ch.state
+                    and last.start + last.length == ch.start
+                    and last.length + ch.length <= cap):
+                spans[-1] = PrefillChunk(
+                    state=ch.state, start=last.start,
+                    length=last.length + ch.length, is_last=ch.is_last)
+            else:
+                spans.append(ch)
+        return spans
+
+    def _tile_of(self, prompt_len: int) -> int:
+        """The tile every span of a prompt runs in: the smallest that
+        holds the longest span a step can give it, its tail padded
+        (``n_real`` selects the logits row). Chosen from the prompt and
+        not from the span: how a prompt is cut into spans depends on
+        what else the step's budget went to, and two tiles are two
+        programs whose sums XLA may order differently, so a tile chosen
+        by the span would let co-traffic (and a prefix hit) change a
+        prompt's K/V in a last bit and with it, at a near-tie of logits
+        or of an MoE router, its greedy tokens."""
+        tiles = self._prefill_tiles
+        return next(t for t in tiles if t >= min(prompt_len, tiles[-1]))
+
+    def _run_prefill(self, ch: PrefillChunk, now: float):
         import jax
         import jax.numpy as jnp
 
-        cfg = self.config
         st = ch.state
         if st.span is None:
-            # first chunk == admission: open the engine-slot span. It
+            # first span == admission: open the engine-slot span. It
             # parents under the submitting request's propagated context
             # (Serve path) or roots its own trace (direct engine use),
             # and carries the queue-wait the built-in scheduler-latency
@@ -813,19 +920,23 @@ class InferenceEngine:
                     # stays aligned with the target's
                     dk_dv = self._draft_replay(st, *dk_dv)
         prompt = st.request.tokens
-        chunk = np.zeros((1, cfg.prefill_chunk), np.int32)
-        chunk[0, :ch.length] = prompt[ch.start:ch.start + ch.length]
+        tile = self._tile_of(len(prompt))
+        tokens = np.zeros((1, tile), np.int32)
+        tokens[0, :ch.length] = prompt[ch.start:ch.start + ch.length]
+        tokens = jnp.asarray(tokens)
         self._rng, k = jax.random.split(self._rng)
         pspan = events.start_span(
             "engine.prefill", category="engine",
             trace_id=st.span.trace_id, parent_span_id=st.span.span_id,
             rid=st.rid, slot=st.slot, offset=ch.start, length=ch.length,
-            is_last=ch.is_last,
+            tile=tile, is_last=ch.is_last,
             slots_occupied=self.sched.occupancy())
         compiles0 = self.prefill_compile_count
+        self.prefill_dispatches += 1
+        self.prefill_tokens += ch.length
         with self._mesh_ctx():
             tok, sk, sv = self._prefill_fn(
-                self.params, sk, sv, jnp.asarray(chunk),
+                self.params, sk, sv, tokens,
                 np.int32(ch.start), np.int32(ch.length), k,
                 np.float32(st.temperature))
         if self.prefill_compile_count > compiles0:
@@ -839,7 +950,7 @@ class InferenceEngine:
             with self._mesh_ctx():
                 ndk, ndv = self._draft_prefill_fn(
                     self._draft_params, dk_dv[0], dk_dv[1],
-                    jnp.asarray(chunk), np.int32(ch.start))
+                    tokens, np.int32(ch.start))
             dk_dv = (ndk, ndv)
         if ch.is_last:
             slot = st.slot
@@ -1082,6 +1193,8 @@ class InferenceEngine:
             "active": len(self.sched.active_slots()),
             "steps": self.steps,
             "tokens_generated": self.tokens_generated,
+            "prefill_dispatches": self.prefill_dispatches,
+            "prefill_tokens": self.prefill_tokens,
             "decode_compile_count": self.decode_compile_count,
             "draining": self.sched.draining,
         }

@@ -22,19 +22,23 @@ def jax_cpu():
     return jax
 
 
-@pytest.fixture(scope="module")
-def tiny(jax_cpu):
+def _tiny_model(jax, seed=0, **kw):
     import jax.numpy as jnp
 
     from ray_tpu.models.transformer import TransformerConfig, TransformerLM
     cfg = TransformerConfig(
         vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
         d_ff=128, max_seq_len=128, dtype=jnp.float32,
-        param_dtype=jnp.float32, remat=False)
+        param_dtype=jnp.float32, remat=False, **kw)
     model = TransformerLM(cfg)
-    params = model.init(jax_cpu.random.PRNGKey(0),
+    params = model.init(jax.random.PRNGKey(seed),
                         jnp.zeros((1, 8), jnp.int32))["params"]
     return cfg, model, params
+
+
+@pytest.fixture(scope="module")
+def tiny(jax_cpu):
+    return _tiny_model(jax_cpu)
 
 
 def _engine(model, params, **kw):
@@ -170,9 +174,11 @@ def test_decode_compiles_exactly_once(tiny):
     hs[3].cancel()
     assert _run_until(eng, lambda: all(h.finish_reason for h in hs))
     assert eng.decode_compile_count == 1
-    assert eng.prefill_compile_count == 1
+    # every prefill tile compiled when the engine was built, none since
+    assert eng.prefill_compile_count == len(eng._prefill_tiles) == 1
     # the jit caches agree with the trace counters
     assert eng._decode_fn._cache_size() == 1
+    assert eng._prefill_fn._cache_size() == 1
 
 
 def _sub_jaxprs(eqn):
@@ -204,15 +210,14 @@ def test_pool_is_updated_in_place_not_rebuilt(tiny, program):
     import jax.numpy as jnp
     mcfg, model, params = tiny
     eng = _engine(model, params, n_slots=3)
-    chunk = eng.config.prefill_chunk
+    chunk = eng._prefill_tiles[-1]
     if program == "decode":
         fn, pools = eng._decode_fn, (eng._pool_k, eng._pool_v)
         args = (eng.params, *pools, eng._lengths, eng._last_tok,
                 eng._rng, eng._temps)
         rows, new_len = 3, 1
     else:
-        shape = (mcfg.n_layers, 1, eng.config.max_len + chunk,
-                 mcfg.n_kv_heads, mcfg.head_dim)
+        shape = eng._scratch_shape
         fn, pools = eng._prefill_fn, (jnp.zeros(shape, jnp.float32),
                                       jnp.ones(shape, jnp.float32))
         args = (eng.params, *pools, jnp.zeros((1, chunk), jnp.int32),
@@ -313,3 +318,334 @@ def test_scheduler_prefill_budget_caps_per_step_tokens(tiny):
             else:
                 sched.advance_prefill(c.state, c.length)
     assert sum(seen) == 13 + 9 + 2
+
+
+# ==========================================================================
+# prefill tiles: what a step gives one request runs as one dispatch
+# ==========================================================================
+
+def test_prefill_tile_family():
+    """The budget in whole chunks, and below it quarters while they are
+    a chunk or more: a handful however large the ratio, one member
+    where the budget is under four chunks (the cells' 128 / 256, and
+    the defaults, budget == chunk, where nothing changes)."""
+    from ray_tpu.inference.engine import prefill_tiles
+    assert prefill_tiles(64, 64) == (64,)
+    assert prefill_tiles(64, 32) == (64,)
+    assert prefill_tiles(128, 256) == (256,)
+    assert prefill_tiles(4, 8) == (8,)
+    assert prefill_tiles(4, 12) == prefill_tiles(4, 10) == (12,)
+    assert prefill_tiles(4, 16) == (4, 16)
+    assert prefill_tiles(4, 40) == (12, 40)
+    assert prefill_tiles(128, 1024) == (256, 1024)
+    assert prefill_tiles(16, 2048) == (32, 128, 512, 2048)
+
+
+@pytest.fixture(scope="module")
+def tiny_moe(jax_cpu):
+    """Dropless MoE (capacity_factor = E/K: an expert's capacity is its
+    group's length, whatever the tile)."""
+    return _tiny_model(jax_cpu, seed=1, n_experts=4, expert_top_k=2,
+                       capacity_factor=2.0)
+
+
+@pytest.fixture(scope="module")
+def tile_engines(tiny, tiny_moe):
+    """(kind, budget) -> a one-slot engine at chunk 4, built once: the
+    parity cases below only send requests. Budget 4 is the
+    chunk-by-chunk path (one tile, today's dispatches)."""
+    built = {}
+
+    def get(kind, budget):
+        if (kind, budget) not in built:
+            _, model, params = tiny if kind == "dense" else tiny_moe
+            built[kind, budget] = _engine(model, params, n_slots=1,
+                                          prefill_budget=budget)
+        return built[kind, budget]
+    return get
+
+
+def _prefill_then_decode(eng, prompt, n_new):
+    """Greedy tokens of one request, and the K/V its prefill left in
+    slot 0 (read when the first token is out, before decode adds)."""
+    h = eng.submit(prompt, max_new_tokens=n_new)
+    assert _run_until(eng, lambda: h.first_token_t is not None, 40)
+    n = len(prompt)
+    kv = (np.asarray(eng._pool_k[:, 0, :n]),
+          np.asarray(eng._pool_v[:, 0, :n]))
+    assert _run_until(eng, lambda: h.finish_reason is not None)
+    return h.tokens(), kv
+
+
+@pytest.fixture(scope="module")
+def uncached_greedy():
+    """(model, params, prompt, toks) -> what the uncached forward would
+    have decoded greedily after prompt + toks[:i], for every i: one
+    causal pass over the sequence padded to a fixed length (one compile
+    a model)."""
+    import jax
+    import jax.numpy as jnp
+    compiled = {}
+
+    def greedy(model, params, prompt, toks):
+        if id(model) not in compiled:
+            def fwd(params, seq):
+                out = model.apply({"params": params}, seq)
+                return jnp.argmax(
+                    out[0] if isinstance(out, tuple) else out, -1)
+            compiled[id(model)] = jax.jit(fwd)
+        seq = np.zeros((1, 48), np.int32)
+        full = list(prompt) + list(toks[:-1])
+        seq[0, :len(full)] = full
+        got = np.asarray(compiled[id(model)](params, jnp.asarray(seq)))[0]
+        return [int(t) for t in got[len(prompt) - 1:len(full)]]
+    return greedy
+
+
+# prompt lengths on both sides of every tile edge (8 / 4, 16) and chunk,
+# and prompts of several steps whose last span is short, exact and long
+_TILE_CASES = [(8, n) for n in (1, 3, 4, 5, 7, 8, 9, 12, 13, 16, 23)] + \
+              [(16, n) for n in (3, 4, 5, 7, 8, 9, 15, 16, 17, 24, 25, 33,
+                                 40)]
+
+
+@pytest.mark.parametrize("kind", ["dense", "moe"])
+@pytest.mark.parametrize("budget,n", _TILE_CASES)
+def test_tiled_prefill_matches_chunks_and_uncached(tiny, tiny_moe,
+                                                   tile_engines,
+                                                   uncached_greedy, kind,
+                                                   budget, n):
+    """A prompt prefilled through tiles leaves the K/V the
+    chunk-by-chunk path leaves and decodes the tokens of the uncached
+    forward (float32 on the CPU)."""
+    _, model, params = tiny if kind == "dense" else tiny_moe
+    prompt = np.random.RandomState(100 * budget + n).randint(0, 128, n)
+    toks, (k, v) = _prefill_then_decode(tile_engines(kind, budget),
+                                        prompt, 5)
+    toks0, (k0, v0) = _prefill_then_decode(tile_engines(kind, 4),
+                                           prompt, 5)
+    np.testing.assert_allclose(k, k0, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(v, v0, rtol=1e-5, atol=1e-5)
+    assert toks == toks0
+    assert toks == uncached_greedy(model, params, prompt, toks)
+
+
+@pytest.mark.parametrize("budget", [8, 16])
+def test_one_prefill_dispatch_per_request_per_step(tiny, budget):
+    """A step never runs more than prefill_budget prompt tokens and
+    makes exactly one dispatch for each request it gives prompt tokens
+    to; prefill_tokens counts real tokens, not padding."""
+    _, model, params = tiny
+    eng = _engine(model, params, n_slots=4, prefill_budget=budget)
+    planned = []                  # per step: {rid: tokens}, as planned
+    plan = eng.sched.plan_prefill
+
+    def spy():
+        chunks = plan()
+        per = {}
+        for c in chunks:
+            per[c.state.rid] = per.get(c.state.rid, 0) + c.length
+        planned.append(per)
+        return chunks
+    eng.sched.plan_prefill = spy
+    rng = np.random.RandomState(11)
+    lens = (19, 3, 9, 26, 5, 14)
+    hs = [eng.submit(rng.randint(0, 128, n), max_new_tokens=3)
+          for n in lens]
+    seen = []
+    while not all(h.finish_reason for h in hs):
+        d0, t0 = eng.prefill_dispatches, eng.prefill_tokens
+        eng.step()
+        seen.append((eng.prefill_dispatches - d0, eng.prefill_tokens - t0))
+        assert len(seen) < 300
+    assert len(planned) == len(seen)
+    for per, (dispatches, tokens) in zip(planned, seen):
+        assert tokens == sum(per.values()) <= budget
+        assert dispatches == len(per)
+    assert any(len(per) > 1 for per in planned)      # a shared step
+    assert any(max(per.values(), default=0) > 4 for per in planned)
+    st = eng.stats()
+    assert st["prefill_tokens"] == sum(lens)
+    assert st["prefill_dispatches"] == sum(d for d, _ in seen)
+
+
+def _tiles_and_tokens(eng, fillers, prompt, n_new=4):
+    """Tokens of `prompt` submitted behind `fillers` (which share its
+    steps' budget), its K/V when its first token is out, and the
+    (tile, offset, real tokens) of every prefill dispatch it got."""
+    own, cur = [], {}
+    run, fn = eng._run_prefill, eng._prefill_fn
+
+    def spy_run(ch, now):
+        cur["st"] = ch.state
+        return run(ch, now)
+
+    def spy_fn(params, sk, sv, tokens, pos0, n_real, *rest):
+        if cur["st"].handle is h:
+            own.append((tokens.shape[1], int(pos0), int(n_real)))
+        return fn(params, sk, sv, tokens, pos0, n_real, *rest)
+    rng = np.random.RandomState(len(prompt))
+    hs = [eng.submit(rng.randint(0, 128, n), max_new_tokens=1)
+          for n in fillers]
+    h = eng.submit(prompt, max_new_tokens=n_new)
+    eng._run_prefill, eng._prefill_fn = spy_run, spy_fn
+    try:
+        assert _run_until(eng, lambda: h.first_token_t is not None, 60)
+    finally:
+        eng._run_prefill, eng._prefill_fn = run, fn
+    n, slot = len(prompt), cur["st"].slot
+    assert cur["st"].handle is h       # the last prefill before its token
+    kv = (np.asarray(eng._pool_k[:, slot, :n]),
+          np.asarray(eng._pool_v[:, slot, :n]))
+    assert _run_until(eng, lambda: all(
+        x.finish_reason for x in (h, *hs)))
+    assert sum(n_real for _, _, n_real in own) == n
+    return h.tokens(), kv, own
+
+
+@pytest.mark.parametrize("kind", ["dense", "moe"])
+@pytest.mark.parametrize("n,tile", [(3, 4), (4, 4), (5, 16), (8, 16),
+                                    (9, 16), (16, 16), (17, 16),
+                                    (37, 16)])
+def test_a_prompts_tile_does_not_depend_on_co_traffic(tiny, tiny_moe, kind,
+                                                      n, tile):
+    """Every span of a prompt runs in the tile of the prompt's length,
+    however the step's budget was shared: what else is in flight changes
+    how a prompt is cut, never which program computes its K/V (on the
+    chip two tiles differ in a last bit, and a near-tie then decodes
+    another token)."""
+    _, model, params = tiny if kind == "dense" else tiny_moe
+    eng = _engine(model, params, n_slots=3, prefill_budget=16)
+    prompt = np.random.RandomState(300 + n).randint(0, 128, n)
+    alone, kv0, own0 = _tiles_and_tokens(eng, (), prompt)
+    cuts = {tuple(c[1:] for c in own0)}
+    for fillers in ((3,), (13,), (21, 6)):
+        toks, kv, own = _tiles_and_tokens(eng, fillers, prompt)
+        assert {t for t, _, _ in own} == {tile} == {t for t, _, _ in own0}
+        assert toks == alone
+        np.testing.assert_allclose(kv[0], kv0[0], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(kv[1], kv0[1], rtol=1e-5, atol=1e-5)
+        cuts.add(tuple(c[1:] for c in own))
+    if n > 3:
+        assert len(cuts) > 1          # the co-traffic did cut it otherwise
+
+
+def test_prefill_tiles_compile_before_first_submit_and_never_again(tiny):
+    """Every tile is compiled when the engine is built; mixed traffic
+    and a budget raised at run time (LLMDeployment.reconfigure does
+    exactly this assignment) use those shapes and no other: a span is
+    capped at the largest tile and the step makes more dispatches."""
+    _, model, params = tiny
+    eng = _engine(model, params, n_slots=2, prefill_budget=16)
+    assert eng._prefill_tiles == (4, 16)
+    assert eng.prefill_compile_count == 2
+    assert eng._prefill_fn._cache_size() == 2
+    rng = np.random.RandomState(12)
+    hs = [eng.submit(rng.randint(0, 128, n), max_new_tokens=2)
+          for n in (1, 4, 6, 11, 16, 17, 30, 41)]
+    assert _run_until(eng, lambda: all(h.finish_reason for h in hs))
+    assert eng.prefill_compile_count == 2
+    eng.sched.prefill_budget = 40
+    want = eng.submit(np.arange(45) % 128, max_new_tokens=4)
+    d0 = eng.prefill_dispatches
+    eng.step()
+    # 40 tokens planned for one request: spans of 16 + 16 + 8
+    assert eng.prefill_dispatches - d0 == 3
+    assert _run_until(eng, lambda: want.finish_reason is not None)
+    assert eng.prefill_compile_count == 2
+    assert eng._prefill_fn._cache_size() == 2
+    assert eng.decode_compile_count == 1
+    ref = _engine(model, params, prefill_budget=4)
+    h = ref.submit(np.arange(45) % 128, max_new_tokens=4)
+    assert _run_until(ref, lambda: h.finish_reason is not None)
+    assert want.tokens() == h.tokens()
+    # budget == chunk (the defaults): one tile, today's dispatches
+    assert ref._prefill_tiles == (4,) and ref.prefill_compile_count == 1
+    assert ref.prefill_dispatches == 12 and ref.prefill_tokens == 45
+
+
+def test_int8_prefix_cache_keeps_single_chunk_dispatches(tiny):
+    """kv_quant="int8" with a prefix cache writes each completed chunk
+    through and reloads its dequantised values before the next chunk
+    attends it, so the chunk stays the only tile there and a hit equals
+    the miss that filled it. Either setting alone tiles."""
+    _, model, params = tiny
+    eng = _engine(model, params, prefill_budget=16, kv_quant="int8",
+                  prefix_cache_slots=1)
+    assert eng._prefill_tiles == (4,) and eng.prefill_compile_count == 1
+    prompt = np.random.RandomState(13).randint(0, 128, 26)
+    miss = eng.submit(prompt, max_new_tokens=8)
+    assert _run_until(eng, lambda: miss.finish_reason is not None)
+    assert eng.prefill_dispatches == 7          # ceil(26 / 4)
+    hit = eng.submit(prompt, max_new_tokens=8)
+    assert _run_until(eng, lambda: hit.finish_reason is not None)
+    assert hit.prefix_matched == 24
+    assert hit.tokens() == miss.tokens()
+    assert eng.prefill_compile_count == 1
+    assert _engine(model, params, prefill_budget=16, prefix_cache_slots=1
+                   )._prefill_tiles == (4, 16)
+
+
+def test_spec_draft_pool_after_tiled_prefill_equals_chunked(tiny):
+    """With speculative decoding the draft's prefill takes the same
+    tile as the target's: the draft pool a tiled prefill leaves is the
+    chunk-by-chunk one, and both families compile when the engine is
+    built."""
+    import jax.numpy as jnp
+
+    from ray_tpu.inference import EngineConfig, InferenceEngine
+    from ray_tpu.models.transformer import TransformerConfig
+    _, model, params = tiny
+    draft = TransformerConfig(
+        vocab_size=128, d_model=32, n_layers=1, n_heads=2, n_kv_heads=1,
+        d_ff=64, max_seq_len=128, dtype=jnp.float32,
+        param_dtype=jnp.float32, remat=False)
+    prompt = np.random.RandomState(14).randint(0, 128, 27)
+    pools, toks = [], []
+    for budget in (16, 4):
+        eng = InferenceEngine(
+            model, params, EngineConfig(n_slots=1, max_len=48,
+                                        prefill_chunk=4,
+                                        prefill_budget=budget),
+            spec={"draft_model": draft, "k": 3})
+        assert eng.draft_prefill_compile_count == len(eng._prefill_tiles)
+        h = eng.submit(prompt, max_new_tokens=9)
+        assert _run_until(eng, lambda: h.first_token_t is not None, 40)
+        pools.append((np.asarray(eng._dpool_k[:, 0, :27]),
+                      np.asarray(eng._dpool_v[:, 0, :27])))
+        assert _run_until(eng, lambda: h.finish_reason is not None)
+        toks.append(h.tokens())
+        assert eng.draft_prefill_compile_count == len(eng._prefill_tiles)
+        assert eng.prefill_compile_count == len(eng._prefill_tiles)
+    np.testing.assert_allclose(pools[0][0], pools[1][0], rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(pools[0][1], pools[1][1], rtol=1e-5,
+                               atol=1e-5)
+    assert toks[0] == toks[1]
+
+
+def test_no_prefill_executable_after_build_on_a_mesh(tiny, jax_cpu):
+    """On a mesh a tile has two executables, one for a new scratch and
+    one for the scratch a prefill handed back (their shardings differ):
+    the engine compiles both when it is built, and prompts of one span,
+    of several, and with a prefix hit add none."""
+    from ray_tpu.serve.sharded import (ShardedEngineReplica,
+                                       default_serving_mesh)
+    if len(jax_cpu.devices()) < 2:
+        pytest.skip("needs a mesh of several devices")
+    _, model, params = tiny
+    rep = ShardedEngineReplica(
+        model, n_slots=2, max_len=64, prefill_chunk=4, prefill_budget=16,
+        prefix_cache_slots=1, params_fn=lambda: params, seed=0,
+        mesh=default_serving_mesh(jax_cpu.devices()))
+    eng = rep.engine
+    built = eng._prefill_fn._cache_size()
+    assert eng.prefill_compile_count == len(eng._prefill_tiles) == 2
+    rng = np.random.RandomState(15)
+    shared = [int(t) for t in rng.randint(0, 128, 13)]
+    for prompt in ([5, 6, 7], shared, shared + [1, 2, 3, 4, 5, 6],
+                   [int(t) for t in rng.randint(0, 128, 30)]):
+        assert len(rep.generate(prompt, max_new_tokens=3)) == 3
+    assert eng.stats()["prefix_hits"] >= 1
+    assert eng._prefill_fn._cache_size() == built
+    assert eng.prefill_compile_count == 2

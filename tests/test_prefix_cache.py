@@ -174,7 +174,10 @@ def test_decode_compiles_exactly_once_with_cache_on(tiny):
     st = eng.stats()
     assert st["prefix_hits"] >= 4, st
     assert eng.decode_compile_count == 1
-    assert eng.prefill_compile_count == 1
+    # the prefill family (chunk 4 / budget 8: the one tile of 8)
+    # compiled when the engine was built and has not grown
+    assert eng.prefill_compile_count == len(eng._prefill_tiles) == 1
+    assert eng._prefill_fn._cache_size() == 1
     assert eng._decode_fn._cache_size() == 1
     assert eng._load_span_fn._cache_size() == 1
     assert eng._save_span_fn._cache_size() == 1
