@@ -49,7 +49,7 @@ SIZES = {
     "n_slots": 8, "max_len": 2048, "prefill_chunk": 128,
     "prefill_budget": 256, "prompt_lens": (5, 100, 1000),
     "max_new_tokens": 32,
-    # train (the geometry bench.py's train-step probe uses for tpu-1b)
+    # train (tpu-1b at 8 x 1024 tokens a step)
     "train_batch": 8, "train_len": 1024, "train_steps": 5,
     "attention_impl": "flash",
     # --chips 4

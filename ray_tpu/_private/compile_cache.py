@@ -1,13 +1,13 @@
 """Where JAX keeps compiled programs between processes and runs.
 
-One rule for every process that compiles (workers, chip_smoke.py, the
-probes under reports/): if ``JAX_COMPILATION_CACHE_DIR`` is set, that
+One rule for every process that compiles (workers, chip_smoke.py,
+perfbench/): if ``JAX_COMPILATION_CACHE_DIR`` is set, that
 directory is used and no other is set in code; otherwise the cache lives
 at ``<checkout>/.jax_cache``, derived from where this package sits —
 never a temp name, a pid or a time, because the path is part of the
 cache key and a directory that moves never hits. The choice is written
 back to the environment, so every process started from here (raylet,
-workers, probe children) uses the same directory.
+workers, a benchmark's children) uses the same directory.
 """
 
 from __future__ import annotations

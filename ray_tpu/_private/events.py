@@ -16,9 +16,7 @@ Design constraints, in order:
 
 1. **Hot-path cost**: a disabled recorder is one global-flag read; an
    enabled one is two clock reads plus a locked list append. No
-   serialization, no RPC, no allocation beyond the record dict. The
-   acceptance bench (`bench.py observability_overhead`) holds the enabled
-   recorder under 5% on the put and decode-step paths.
+   serialization, no RPC, no allocation beyond the record dict.
 2. **Bounded memory with deterministic drop accounting**: the ring
    keeps the NEWEST `capacity` records; every overwrite increments a
    counter that is reported in-band (an ``events.dropped`` instant

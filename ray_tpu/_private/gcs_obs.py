@@ -170,7 +170,7 @@ class GcsObservability:
         # Hot path: every GCS RPC funnels through here, so globals and
         # attributes are pre-bound as defaults (LOAD_FAST) and the
         # common sync-return case touches nothing slower than counter
-        # bumps — see reports/trace_probe.py's gcs_rpc_wrap_us guard.
+        # bumps.
         def call(conn, _fn=fn, _stats=stats, _name=name,
                  _perf=time.perf_counter, _delay=delay_for,
                  _finish=self._finish, _Future=asyncio.Future,

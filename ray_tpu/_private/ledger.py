@@ -17,8 +17,7 @@ order of importance:
 1. **Hot-path cost**: a disabled ledger is one global-flag read; an
    enabled one is a dict build plus a locked list append. No
    serialization, no RPC, no native calls beyond what the caller already
-   paid. The acceptance bench (`bench.py observability_overhead`) holds
-   the enabled put path under the same 5% guard as the recorder.
+   paid.
 2. **Bounded memory with deterministic drop accounting**: the ring keeps
    the NEWEST `capacity` records; overwrites are counted and shipped
    in-band as a ``dropped`` field on the next flushed batch, so a

@@ -5,11 +5,12 @@ Architecture (the TPU-serving shape — cf. slot-based continuous
 batching in the Gemma-on-TPU serving stack):
 
 - The engine owns ``n_slots`` KV-cache slots, allocated once as two
-  pools (K and V) of ``[n_layers, n_slots, max_len, Hkv, D]`` and
-  donated through every step. The cached forward's layer loop only
-  reads a pool; one write after the loop adds the step's new rows of
-  all layers to it (models/transformer.py ``_decode``,
-  ``_cache_write``), so the program's output pool IS the donated input
+  pools (K and V) of ``[n_layers, n_slots, max_len, Hkv, D]``
+  (kv_cache.py ``SlotPool``; the prefix blocks and their fp/int8 format
+  are its ``BlockStore``) and donated through every step. The cached
+  forward's layer loop only reads a pool; one write after the loop adds
+  the step's new rows of all layers to it (models/transformer.py
+  ``_decode``, ``_cache_write``), so the program's output pool IS the donated input
   buffer: the decode step compiles exactly ONCE and then mutates the
   pool in place for the life of the engine, moving ``n_slots`` rows a
   layer and never the pool.
@@ -44,27 +45,14 @@ greedy engine output is bit-identical to the one-program generator.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-
-@functools.lru_cache(maxsize=None)
-def _sharded_zeros(sharding):
-    """Jitted zeros with an explicit output sharding, memoized per
-    sharding (jit caches per (shape, dtype) static args underneath).
-    Allocating through jit is what makes the result a GLOBAL array when
-    the mesh spans multiple processes — a host-side ``jnp.zeros`` +
-    ``device_put`` only ever produces a single-process value."""
-    import jax
-    import jax.numpy as jnp
-    return jax.jit(jnp.zeros, static_argnums=(0, 1),
-                   out_shardings=sharding)
-
 from ray_tpu._private import events
+from ray_tpu.inference import kv_cache
 from ray_tpu.inference.scheduler import (FINISH_LENGTH, PrefillChunk,
                                          Request, RequestHandle,
                                          RequestState, Scheduler)
@@ -118,8 +106,8 @@ class EngineConfig:
     top_k: int = 0
     top_p: float = 1.0
     cache_dtype: Any = None       # default: model activation dtype
-    # prefix-block quantization (kv_quant.py): "int8" stores the BLOCK
-    # pool as int8 values + fp32 per-(position, head) scale rows —
+    # prefix-block format (kv_cache.py BlockStore): "int8" stores the
+    # BLOCKS as int8 values + fp32 per-(position, head) scale rows —
     # ~itemsize*D/(D+4) more cached chunks per HBM byte, and the disagg
     # hand-off ships the same compressed spans. The decode slot pool
     # stays full precision (it is transient and donated through the hot
@@ -135,10 +123,6 @@ class EngineConfig:
     # matched blocks instead of re-running prefill over them; the copy
     # programs are fixed-shape, so the compile-once invariant holds.
     prefix_cache_slots: int = 0
-    # per-step time/FLOP attribution (util/profiling.py): emits
-    # runtime_decode_step_mfu + compute/host-gap/data-wait phase gauges;
-    # the observability-overhead bench toggles this off for its baseline
-    step_profile: bool = True
 
 
 class InferenceEngine:
@@ -156,19 +140,16 @@ class InferenceEngine:
         self.params = params
         self.config = config or EngineConfig()
         self.mesh = mesh
-        self._rules = rules
         cfg = self.config
         mcfg = model.cfg
         from ray_tpu.inference import spec_decode as spec_lib
-        from ray_tpu.inference.kv_quant import check_mode
-        self._kv_quant = check_mode(cfg.kv_quant) == "int8"
         # speculative decoding (spec_decode.py): both slot pools grow by
         # k positions so the fixed [len, len+k+1) verify write window
         # never clamps back onto live entries
         self._spec = spec_lib.resolve_spec(spec)
         self._spec_k = self._spec.k if self._spec is not None else 0
-        self._pool_len = cfg.max_len + self._spec_k
-        if self._pool_len > mcfg.max_seq_len:
+        pool_len = cfg.max_len + self._spec_k
+        if pool_len > mcfg.max_seq_len:
             raise ValueError(
                 f"max_len={cfg.max_len} (+ spec k={self._spec_k}) exceeds "
                 f"the model's max_seq_len={mcfg.max_seq_len}")
@@ -176,129 +157,69 @@ class InferenceEngine:
         if self._spec is not None:
             self._draft_model, self._draft_params = spec_lib.resolve_draft(
                 self._spec, mcfg)
-            if self._pool_len > self._draft_model.cfg.max_seq_len:
+            if pool_len > self._draft_model.cfg.max_seq_len:
                 raise ValueError(
                     f"draft max_seq_len={self._draft_model.cfg.max_seq_len}"
-                    f" < max_len + k = {self._pool_len}")
-        self.prefix_cache = None
-        if cfg.prefix_cache_slots > 0:
-            from ray_tpu.inference.prefix_cache import RadixPrefixCache
-            self._blocks_per_slot = cfg.max_len // cfg.prefill_chunk
-            self.prefix_cache = RadixPrefixCache(
-                cfg.prefill_chunk,
-                cfg.prefix_cache_slots * self._blocks_per_slot)
-        self.sched = Scheduler(cfg.n_slots, cfg.prefill_budget,
-                               default_temperature=cfg.temperature,
-                               eos_id=cfg.eos_id,
-                               chunk_size=cfg.prefill_chunk,
-                               prefix_cache=self.prefix_cache)
+                    f" < max_len + k = {pool_len}")
+        dtype = cfg.cache_dtype or mcfg.dtype
+        self._kv_quant = kv_cache.check_format(cfg.kv_quant)
         self._lock = threading.RLock()
         self._work = threading.Condition(self._lock)
         self._stop = False
         self._thread: Optional[threading.Thread] = None
         self._rng = jax.random.PRNGKey(seed)
-
-        dtype = cfg.cache_dtype or mcfg.dtype
-        pool_shape = (mcfg.n_layers, cfg.n_slots, self._pool_len,
-                      mcfg.n_kv_heads, mcfg.head_dim)
-        # the tiles a prefill call may take. int8 blocks are written
-        # through chunk by chunk (_publish_chunk_quant: a chunk attends
-        # the dequantised values of the chunks before it, which is what
-        # makes a prefix hit bit-identical to the miss that filled it),
-        # so there the chunk is the only tile
-        self._prefill_tiles = prefill_tiles(
-            cfg.prefill_chunk,
-            cfg.prefill_chunk if self._kv_quant and self.prefix_cache
-            is not None else cfg.prefill_budget)
-        # scratch is the largest tile longer than a slot so a padded
-        # tile can never clamp its write window back onto real entries
-        self._scratch_len = cfg.max_len + self._prefill_tiles[-1]
-        self._scratch_shape = (mcfg.n_layers, 1, self._scratch_len,
-                               mcfg.n_kv_heads, mcfg.head_dim)
-        self._pool_sharding = None
-        self._target_pool_shape = pool_shape
         if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from ray_tpu.parallel import sharding as sharding_lib
-            from ray_tpu.parallel.train_step import (_prune_indivisible,
-                                                     logical_pspec_to_mesh)
-            rules = rules or sharding_lib.DEFAULT_RULES
-            spec = _prune_indivisible(
-                logical_pspec_to_mesh(
-                    P(None, "batch", None, "kv_heads", None), rules),
-                pool_shape, mesh)
-            self._pool_sharding = NamedSharding(mesh, spec)
             # the key lives on the mesh from the start: the step program
             # hands it back replicated there, and a first call that saw
             # it on one device would make the second one retrace
-            self._rng = jax.jit(
-                lambda: jax.random.PRNGKey(seed),
-                out_shardings=NamedSharding(mesh, P()))()
-        self._pool_k = self._zeros(pool_shape, dtype)
-        self._pool_v = self._zeros(pool_shape, dtype)
-        self._cache_dtype = dtype
-        self._fp_itemsize = int(jnp.dtype(dtype).itemsize)
-        self._dpool_k = self._dpool_v = None
-        self._draft_scratch_shape = None
-        if self._spec is not None:
-            # draft slot pool: same layout as the target's (incl. the k
-            # padding), replicated — the draft is small by design and
-            # its scan runs inside the one fused program
-            dcfg = self._draft_model.cfg
-            dshape = (dcfg.n_layers, cfg.n_slots, self._pool_len,
-                      dcfg.n_kv_heads, dcfg.head_dim)
-            dsh = None
-            if mesh is not None:
-                # same logical layout as the target pool, pruned against
-                # the DRAFT shape (its kv-head count may not divide the
-                # tensor axis)
-                from jax.sharding import (NamedSharding,
-                                          PartitionSpec as P)
+            self._rng = jax.jit(lambda: jax.random.PRNGKey(seed),
+                                out_shardings=kv_cache.replicated(mesh))()
 
-                from ray_tpu.parallel import sharding as sharding_lib
-                from ray_tpu.parallel.train_step import (
-                    _prune_indivisible, logical_pspec_to_mesh)
-                drules = self._rules or sharding_lib.DEFAULT_RULES
-                dsh = NamedSharding(mesh, _prune_indivisible(
-                    logical_pspec_to_mesh(
-                        P(None, "batch", None, "kv_heads", None), drules),
-                    dshape, mesh))
-            self._dpool_k = self._zeros(dshape, dtype, sharding=dsh)
-            self._dpool_v = self._zeros(dshape, dtype, sharding=dsh)
-            self._draft_scratch_shape = (
-                dcfg.n_layers, 1, self._scratch_len, dcfg.n_kv_heads,
-                dcfg.head_dim)
-        self._blocks_k = self._blocks_v = None
-        self._blocks_ks = self._blocks_vs = None
-        if self.prefix_cache is not None:
-            # block storage: prefix_cache_slots more rows of the same
-            # per-slot shape, replicated (blocks are read via copies
-            # into the replicated scratch cache, never attended over
-            # in place, so they need no batch sharding). kv_quant="int8"
-            # stores int8 values + fp32 per-(position, head) scale rows.
-            bdtype = jnp.int8 if self._kv_quant else dtype
-            block_shape = (mcfg.n_layers, cfg.prefix_cache_slots,
-                           cfg.max_len, mcfg.n_kv_heads, mcfg.head_dim)
-            rsh = None
-            if mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec
-                rsh = NamedSharding(mesh, PartitionSpec())
-            self._blocks_k = self._zeros(block_shape, bdtype, sharding=rsh)
-            self._blocks_v = self._zeros(block_shape, bdtype, sharding=rsh)
-            if self._kv_quant:
-                scale_shape = block_shape[:-1]
-                self._blocks_ks = self._zeros(scale_shape, jnp.float32,
-                                              sharding=rsh)
-                self._blocks_vs = self._zeros(scale_shape, jnp.float32,
-                                              sharding=rsh)
+        # the tiles a prefill call may take. A store that is written
+        # through chunk by chunk (_publish_chunk: a chunk attends what
+        # the store gives back for the chunks before it, which is what
+        # makes a prefix hit bit-identical to the miss that filled it)
+        # keeps the chunk as the only tile
+        self._write_through = (cfg.prefix_cache_slots > 0
+                               and kv_cache.writes_through(self._kv_quant))
+        self._prefill_tiles = prefill_tiles(
+            cfg.prefill_chunk, cfg.prefill_chunk if self._write_through
+            else cfg.prefill_budget)
+        # scratch is the largest tile longer than a slot so a padded
+        # tile can never clamp its write window back onto real entries
+        scratch_len = cfg.max_len + self._prefill_tiles[-1]
+        self._slots = kv_cache.SlotPool(
+            mcfg, cfg.n_slots, pool_len, cfg.max_len, scratch_len, dtype,
+            mesh, rules)
+        # the draft's slots: the same positions (incl. the k padding)
+        # in the draft's own widths
+        self._draft_slots = None
+        if self._spec is not None:
+            self._draft_slots = kv_cache.SlotPool(
+                self._draft_model.cfg, cfg.n_slots, pool_len, cfg.max_len,
+                scratch_len, dtype, mesh, rules)
+        self._pools = [p for p in (self._slots, self._draft_slots)
+                       if p is not None]
+        # prefix blocks: prefix_cache_slots more rows of a slot's shape,
+        # allocated after the slot pools
+        self.prefix_cache = self._blocks = None
+        if cfg.prefix_cache_slots > 0:
+            from ray_tpu.inference.prefix_cache import RadixPrefixCache
+            self._blocks = kv_cache.BlockStore(
+                mcfg, cfg.prefix_cache_slots, cfg.max_len,
+                cfg.prefill_chunk, dtype, cfg.kv_quant, mesh)
+            self.prefix_cache = RadixPrefixCache(cfg.prefill_chunk,
+                                                 self._blocks.n_blocks)
+        self.sched = Scheduler(cfg.n_slots, cfg.prefill_budget,
+                               default_temperature=cfg.temperature,
+                               eos_id=cfg.eos_id,
+                               chunk_size=cfg.prefill_chunk,
+                               prefix_cache=self.prefix_cache)
 
         # host-side slot state (fixed width, mirrors the device arrays)
         self._lengths = np.zeros((cfg.n_slots,), np.int32)
         self._last_tok = np.zeros((cfg.n_slots,), np.int32)
         self._temps = np.zeros((cfg.n_slots,), np.float32)
-        self._scratch: Dict[int, Any] = {}    # rid -> (sk, sv)
-        self._draft_scratch: Dict[int, Any] = {}    # rid -> (dk, dv)
 
         self.decode_compile_count = 0
         self.prefill_compile_count = 0
@@ -323,43 +244,18 @@ class InferenceEngine:
         # step attribution: decode FLOPs are computed analytically
         # (re-lowering the decode program for cost_analysis would trip
         # the compile-once invariant the tests assert on)
-        self.profiler = None
-        if cfg.step_profile:
-            from ray_tpu.util import profiling
-            leaves = jax.tree_util.tree_leaves(params)
-            self._n_params = int(sum(x.size for x in leaves))
-            self._param_bytes = float(sum(
-                x.size * getattr(x.dtype, "itemsize", 4) for x in leaves))
-            self._kv_elt_bytes = float(jnp.dtype(dtype).itemsize)
-            self.profiler = profiling.StepProfiler(
-                "decode_step", emit_span=False)
+        from ray_tpu.util import profiling
+        leaves = jax.tree_util.tree_leaves(params)
+        self._n_params = int(sum(x.size for x in leaves))
+        self._param_bytes = float(sum(
+            x.size * getattr(x.dtype, "itemsize", 4) for x in leaves))
+        self._kv_itemsize = int(jnp.dtype(dtype).itemsize)
+        self.profiler = profiling.StepProfiler(
+            "decode_step", emit_span=False)
         self._build_fns()
         self._compile_prefill_tiles()
 
     # ------------------------------------------------------------ device fns
-    def _zeros(self, shape, dtype, sharding=None):
-        import jax.numpy as jnp
-        with self._mesh_ctx():
-            if self.mesh is not None:
-                # allocate THROUGH a jitted zeros with explicit output
-                # sharding: under a multi-process mesh this yields a
-                # global array directly (device_put of a host value
-                # cannot), and on one process it is equivalent. The
-                # TARGET slot pool shards batch/kv_heads; callers pass
-                # their own sharding for anything whose divisibility was
-                # pruned against a different shape; everything else is
-                # replicated.
-                from jax.sharding import NamedSharding, PartitionSpec
-                sh = sharding
-                if sh is None:
-                    if self._pool_sharding is not None \
-                            and tuple(shape) == self._target_pool_shape:
-                        sh = self._pool_sharding
-                    else:
-                        sh = NamedSharding(self.mesh, PartitionSpec())
-                return _sharded_zeros(sh)(tuple(shape), jnp.dtype(dtype))
-            return jnp.zeros(shape, dtype)
-
     def _mesh_ctx(self):
         if self.mesh is None:
             import contextlib
@@ -394,15 +290,6 @@ class InferenceEngine:
                                         top_k=top_k, top_p=top_p)
             return tok[0].astype(jnp.int32), new["k"], new["v"]
 
-        def insert(pk, pv, sk, sv, slot):
-            # scratch carries the largest tile of padding tail; the slot
-            # takes the first max_len entries
-            sk = sk[:, :, :cfg.max_len]
-            sv = sv[:, :, :cfg.max_len]
-            pk = jax.lax.dynamic_update_slice(pk, sk, (0, slot, 0, 0, 0))
-            pv = jax.lax.dynamic_update_slice(pv, sv, (0, slot, 0, 0, 0))
-            return pk, pv
-
         def decode(params, pk, pv, lengths, toks, rng, temps):
             # ONE program for the life of the engine: fixed [n_slots]
             # shapes, per-slot idx vector. Python side effect below runs
@@ -420,8 +307,6 @@ class InferenceEngine:
 
         self._prefill_fn = jax.jit(
             prefill, donate_argnums=(1, 2))
-        self._insert_fn = jax.jit(
-            insert, donate_argnums=(0, 1))
         self._decode_fn = jax.jit(
             decode, donate_argnums=(1, 2))
 
@@ -458,63 +343,6 @@ class InferenceEngine:
             self._draft_prefill_fn = jax.jit(
                 draft_prefill, donate_argnums=(1, 2))
 
-        if self.prefix_cache is not None and self._kv_quant:
-            self._build_quant_span_fns()
-        elif self.prefix_cache is not None:
-            mcfg = self.model.cfg
-            span = (mcfg.n_layers, 1, cfg.prefill_chunk,
-                    mcfg.n_kv_heads, mcfg.head_dim)
-
-            def save_span(bk, bv, sk, sv, slot, dst, src):
-                # one completed prefill chunk: scratch[src:src+C] ->
-                # block storage (slot row, dst offset). Fixed span
-                # shape + traced scalar offsets = one compile, ever.
-                ck = jax.lax.dynamic_slice(sk, (0, 0, src, 0, 0), span)
-                cv = jax.lax.dynamic_slice(sv, (0, 0, src, 0, 0), span)
-                bk = jax.lax.dynamic_update_slice(bk, ck,
-                                                  (0, slot, dst, 0, 0))
-                bv = jax.lax.dynamic_update_slice(bv, cv,
-                                                  (0, slot, dst, 0, 0))
-                return bk, bv
-
-            def load_span(sk, sv, bk, bv, slot, src, dst):
-                # hit path: cached block -> this request's scratch; the
-                # suffix prefill then attends over it exactly as if the
-                # chunk had just been computed (bit-identical values).
-                ck = jax.lax.dynamic_slice(bk, (0, slot, src, 0, 0), span)
-                cv = jax.lax.dynamic_slice(bv, (0, slot, src, 0, 0), span)
-                sk = jax.lax.dynamic_update_slice(sk, ck, (0, 0, dst, 0, 0))
-                sv = jax.lax.dynamic_update_slice(sv, cv, (0, 0, dst, 0, 0))
-                return sk, sv
-
-            def export_span(bk, bv, slot, src):
-                # disagg hand-off, sender half: one cached block out of
-                # the pool (device value; the caller materializes it to
-                # host for the wire). Fixed span shape + traced offsets
-                # = one compile, ever — same contract as load/save.
-                ck = jax.lax.dynamic_slice(bk, (0, slot, src, 0, 0), span)
-                cv = jax.lax.dynamic_slice(bv, (0, slot, src, 0, 0), span)
-                return ck, cv
-
-            def import_span(bk, bv, ck, cv, slot, dst):
-                # disagg hand-off, receiver half: a span computed on
-                # ANOTHER replica lands in this engine's block pool; the
-                # normal load_span hit path then serves it exactly like
-                # a locally prefilled block.
-                bk = jax.lax.dynamic_update_slice(bk, ck,
-                                                  (0, slot, dst, 0, 0))
-                bv = jax.lax.dynamic_update_slice(bv, cv,
-                                                  (0, slot, dst, 0, 0))
-                return bk, bv
-
-            self._save_span_fn = jax.jit(
-                save_span, donate_argnums=(0, 1))
-            self._load_span_fn = jax.jit(
-                load_span, donate_argnums=(0, 1))
-            self._export_span_fn = jax.jit(export_span)
-            self._import_span_fn = jax.jit(
-                import_span, donate_argnums=(0, 1))
-
     def _compile_prefill_tiles(self):
         """Run every prefill tile on a throwaway scratch, so no request
         is the first user of a shape: ``prefill_compile_count`` (and the
@@ -529,17 +357,13 @@ class InferenceEngine:
         with self._mesh_ctx():
             for tile in self._prefill_tiles:
                 tokens = jnp.zeros((1, tile), jnp.int32)
-                sk = self._zeros(self._scratch_shape, self._cache_dtype)
-                sv = self._zeros(self._scratch_shape, self._cache_dtype)
+                sk, sv = self._slots.new_scratch()
                 for _ in range(2):
                     _, sk, sv = self._prefill_fn(
                         self.params, sk, sv, tokens, np.int32(0),
                         np.int32(tile), key, np.float32(0.0))
                 if self._spec is not None:
-                    dk = self._zeros(self._draft_scratch_shape,
-                                     self._cache_dtype)
-                    dv = self._zeros(self._draft_scratch_shape,
-                                     self._cache_dtype)
+                    dk, dv = self._draft_slots.new_scratch()
                     for _ in range(2):
                         dk, dv = self._draft_prefill_fn(
                             self._draft_params, dk, dv, tokens,
@@ -548,67 +372,6 @@ class InferenceEngine:
                     "engine.compile", category="engine",
                     trace_id=self._trace_id, fn="prefill", tile=tile,
                     compile_count=self.prefill_compile_count)
-
-    def _build_quant_span_fns(self):
-        """int8 variants of the four span programs: same fixed span
-        shape + traced offsets (= one compile each, ever), but the block
-        side carries int8 values plus fp32 per-(position, head) scale
-        rows and the scratch side stays full precision — quantize on
-        save, dequantize on load, ship compressed on export."""
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.inference.kv_quant import dequantize_kv, quantize_kv
-        cfg = self.config
-        mcfg = self.model.cfg
-        span = (mcfg.n_layers, 1, cfg.prefill_chunk,
-                mcfg.n_kv_heads, mcfg.head_dim)
-        sspan = span[:-1]
-        cdtype = self._cache_dtype
-
-        def save_spanq(bk, bv, bks, bvs, sk, sv, slot, dst, src):
-            ck = jax.lax.dynamic_slice(sk, (0, 0, src, 0, 0), span)
-            cv = jax.lax.dynamic_slice(sv, (0, 0, src, 0, 0), span)
-            qk, ks = quantize_kv(ck)
-            qv, vs = quantize_kv(cv)
-            bk = jax.lax.dynamic_update_slice(bk, qk, (0, slot, dst, 0, 0))
-            bv = jax.lax.dynamic_update_slice(bv, qv, (0, slot, dst, 0, 0))
-            bks = jax.lax.dynamic_update_slice(bks, ks, (0, slot, dst, 0))
-            bvs = jax.lax.dynamic_update_slice(bvs, vs, (0, slot, dst, 0))
-            return bk, bv, bks, bvs
-
-        def load_spanq(sk, sv, bk, bv, bks, bvs, slot, src, dst):
-            qk = jax.lax.dynamic_slice(bk, (0, slot, src, 0, 0), span)
-            qv = jax.lax.dynamic_slice(bv, (0, slot, src, 0, 0), span)
-            ks = jax.lax.dynamic_slice(bks, (0, slot, src, 0), sspan)
-            vs = jax.lax.dynamic_slice(bvs, (0, slot, src, 0), sspan)
-            sk = jax.lax.dynamic_update_slice(
-                sk, dequantize_kv(qk, ks, cdtype), (0, 0, dst, 0, 0))
-            sv = jax.lax.dynamic_update_slice(
-                sv, dequantize_kv(qv, vs, cdtype), (0, 0, dst, 0, 0))
-            return sk, sv
-
-        def export_spanq(bk, bv, bks, bvs, slot, src):
-            qk = jax.lax.dynamic_slice(bk, (0, slot, src, 0, 0), span)
-            qv = jax.lax.dynamic_slice(bv, (0, slot, src, 0, 0), span)
-            ks = jax.lax.dynamic_slice(bks, (0, slot, src, 0), sspan)
-            vs = jax.lax.dynamic_slice(bvs, (0, slot, src, 0), sspan)
-            return qk, qv, ks, vs
-
-        def import_spanq(bk, bv, bks, bvs, qk, qv, ks, vs, slot, dst):
-            bk = jax.lax.dynamic_update_slice(bk, qk, (0, slot, dst, 0, 0))
-            bv = jax.lax.dynamic_update_slice(bv, qv, (0, slot, dst, 0, 0))
-            bks = jax.lax.dynamic_update_slice(bks, ks, (0, slot, dst, 0))
-            bvs = jax.lax.dynamic_update_slice(bvs, vs, (0, slot, dst, 0))
-            return bk, bv, bks, bvs
-
-        self._save_span_fn = jax.jit(
-            save_spanq, donate_argnums=(0, 1, 2, 3))
-        self._load_span_fn = jax.jit(
-            load_spanq, donate_argnums=(0, 1))
-        self._export_span_fn = jax.jit(export_spanq)
-        self._import_span_fn = jax.jit(
-            import_spanq, donate_argnums=(0, 1, 2, 3))
 
     # -------------------------------------------------------------- intake
     def submit(self, tokens, max_new_tokens: int = 64,
@@ -705,8 +468,8 @@ class InferenceEngine:
             t_iter0 = time.perf_counter()
             now = time.monotonic()
             for st in self.sched.reap(now):
-                self._scratch.pop(st.rid, None)
-                self._draft_scratch.pop(st.rid, None)
+                for pool in self._pools:
+                    pool.scratch.pop(st.rid, None)
             did = False
             for span in self._prefill_spans(self.sched.plan_prefill()):
                 self._run_prefill(span, now)
@@ -743,25 +506,22 @@ class InferenceEngine:
                     queue_depth=self.sched.queue_depth())
                 compiles0 = self.decode_compile_count
                 t_dec0 = time.perf_counter()
+                pool, dpool = self._slots, self._draft_slots
                 if self._spec is not None:
                     with self._mesh_ctx():
-                        (out, acc, self._pool_k, self._pool_v,
-                         self._dpool_k, self._dpool_v, self._rng) = \
-                            self._spec_step_fn(
-                                self.params, self._draft_params,
-                                self._pool_k, self._pool_v,
-                                self._dpool_k, self._dpool_v,
-                                self._lengths, self._last_tok,
-                                self._rng, self._temps)
+                        (out, acc, pool.k, pool.v, dpool.k, dpool.v,
+                         self._rng) = self._spec_step_fn(
+                            self.params, self._draft_params,
+                            pool.k, pool.v, dpool.k, dpool.v,
+                            self._lengths, self._last_tok,
+                            self._rng, self._temps)
                     out_host = np.asarray(out)
                     acc_host = np.asarray(acc)
                 else:
                     with self._mesh_ctx():
-                        toks, self._pool_k, self._pool_v, self._rng = \
-                            self._decode_fn(
-                                self.params, self._pool_k, self._pool_v,
-                                self._lengths, self._last_tok, self._rng,
-                                self._temps)
+                        toks, pool.k, pool.v, self._rng = self._decode_fn(
+                            self.params, pool.k, pool.v, self._lengths,
+                            self._last_tok, self._rng, self._temps)
                     toks_host = np.asarray(toks)
                 t_dec1 = time.perf_counter()
                 # capture before decode_emit: an evicted state's slot is
@@ -807,11 +567,9 @@ class InferenceEngine:
                         "engine.compile", category="engine",
                         trace_id=d_trace, parent_span_id=dspan.span_id,
                         fn="decode", compile_count=self.decode_compile_count)
-                attribution = {}
-                if self.profiler is not None:
-                    attribution = self._profile_decode(
-                        [int(self._lengths[s]) for s in slots],
-                        t_iter0, t_admit, t_dec0, t_dec1)
+                attribution = self._profile_decode(
+                    [int(self._lengths[s]) for s in slots],
+                    t_iter0, t_admit, t_dec0, t_dec1)
                 dspan.end(tokens=n_emitted, **attribution)
                 did = True
             self.steps += 1
@@ -835,7 +593,7 @@ class InferenceEngine:
             kv_lens)
         nbytes = profiling.decode_step_bytes(
             self._param_bytes, mcfg.n_layers, mcfg.n_kv_heads,
-            mcfg.head_dim, kv_lens, self._kv_elt_bytes)
+            mcfg.head_dim, kv_lens, self._kv_itemsize)
         rec = self.profiler.observe(
             compute_s=t_dec1 - t_dec0, data_s=t_admit - t_iter0,
             begin_t=t_iter0, end_t=t_dec1, tokens=len(kv_lens),
@@ -896,24 +654,20 @@ class InferenceEngine:
                 prompt_tokens=len(st.request.tokens),
                 queue_wait_ms=round(
                     (now - st.handle.submitted_t) * 1e3, 3))
-        sk_sv = self._scratch.get(st.rid)
+        sk_sv = self._slots.scratch.get(st.rid)
         if sk_sv is None:
-            sk_sv = (self._zeros(self._scratch_shape, self._cache_dtype),
-                     self._zeros(self._scratch_shape, self._cache_dtype))
+            sk_sv = self._slots.new_scratch()
             if st.prefix_nodes:
                 # radix hit: the matched span's KV comes out of the
-                # block pool as device-side copies — no forward pass
+                # block store as device-side copies — no forward pass
                 # runs over [0, prefix_matched)
-                sk_sv = self._restore_prefix(st, *sk_sv)
+                sk_sv = self._restore_prefix(st, sk_sv)
         sk, sv = sk_sv
         dk_dv = None
         if self._spec is not None:
-            dk_dv = self._draft_scratch.get(st.rid)
+            dk_dv = self._draft_slots.scratch.get(st.rid)
             if dk_dv is None:
-                dk_dv = (self._zeros(self._draft_scratch_shape,
-                                     self._cache_dtype),
-                         self._zeros(self._draft_scratch_shape,
-                                     self._cache_dtype))
+                dk_dv = self._draft_slots.new_scratch()
                 if st.prefix_matched:
                     # the block pool holds TARGET KV only; the (cheap)
                     # draft re-prefills the matched range so its cache
@@ -955,50 +709,34 @@ class InferenceEngine:
         if ch.is_last:
             slot = st.slot
             if self.prefix_cache is not None:
-                self._populate_prefix(st, sk, sv)
-            with self._mesh_ctx():
-                self._pool_k, self._pool_v = self._insert_fn(
-                    self._pool_k, self._pool_v, sk, sv, np.int32(slot))
-                if self._spec is not None:
-                    self._dpool_k, self._dpool_v = self._insert_fn(
-                        self._dpool_k, self._dpool_v, dk_dv[0], dk_dv[1],
-                        np.int32(slot))
-            self._scratch.pop(st.rid, None)
-            self._draft_scratch.pop(st.rid, None)
+                self._populate_prefix(st, (sk, sv))
+            self._slots.insert((sk, sv), slot)
+            if self._spec is not None:
+                self._draft_slots.insert(dk_dv, slot)
+            for pool in self._pools:
+                pool.scratch.pop(st.rid, None)
             self._lengths[slot] = len(prompt)
             first = int(tok)
             self._last_tok[slot] = first
             self._temps[slot] = st.temperature
             self.sched.prefill_done(st, first, time.monotonic())
         else:
-            if self._kv_quant and self.prefix_cache is not None:
-                sk, sv = self._publish_chunk_quant(st, sk, sv, ch)
-            self._scratch[st.rid] = (sk, sv)
+            if self._write_through:
+                sk, sv = self._publish_chunk(st, (sk, sv), ch)
+            self._slots.scratch[st.rid] = (sk, sv)
             if self._spec is not None:
-                self._draft_scratch[st.rid] = dk_dv
+                self._draft_slots.scratch[st.rid] = dk_dv
             self.sched.advance_prefill(st, ch.length)
 
     # ------------------------------------------------------- prefix cache
-    def _restore_prefix(self, st, sk, sv):
+    def _restore_prefix(self, st, scratch):
         """Copy the matched trie blocks into this request's scratch
         cache ([0, prefix_matched) chunk by chunk), then unpin them.
         Runs once, on the request's first prefill chunk, under the
         engine lock — eviction cannot race the copies."""
         C = self.config.prefill_chunk
-        with self._mesh_ctx():
-            for i, node in enumerate(st.prefix_nodes):
-                bslot, boff = divmod(node.block, self._blocks_per_slot)
-                if self._kv_quant:
-                    sk, sv = self._load_span_fn(
-                        sk, sv, self._blocks_k, self._blocks_v,
-                        self._blocks_ks, self._blocks_vs,
-                        np.int32(bslot), np.int32(boff * C),
-                        np.int32(i * C))
-                else:
-                    sk, sv = self._load_span_fn(
-                        sk, sv, self._blocks_k, self._blocks_v,
-                        np.int32(bslot), np.int32(boff * C),
-                        np.int32(i * C))
+        for i, node in enumerate(st.prefix_nodes):
+            scratch = self._blocks.load(scratch, node.block, i * C)
         events.record_instant(
             "engine.prefix_hit", category="engine",
             trace_id=st.span.trace_id if st.span else None,
@@ -1006,58 +744,31 @@ class InferenceEngine:
             rid=st.rid, slot=st.slot, matched_tokens=st.prefix_matched,
             prompt_tokens=len(st.request.tokens))
         self.sched.unpin_prefix(st)
-        return sk, sv
+        return scratch
 
-    def _populate_prefix(self, st, sk, sv):
+    def _populate_prefix(self, st, scratch):
         """Miss path, at prefill completion: extend the trie over every
         full chunk of the prompt and fill the newly allocated blocks
         from scratch (already-present chunks are skipped — their KV is
         identical by construction)."""
-        C = self.config.prefill_chunk
-        created = self.prefix_cache.insert(st.request.tokens)
-        if not created:
-            return
-        with self._mesh_ctx():
-            for off, block in created:
-                bslot, boff = divmod(block, self._blocks_per_slot)
-                self._save_block(sk, sv, bslot, boff * C, off)
+        for off, block in self.prefix_cache.insert(st.request.tokens):
+            self._blocks.save(scratch, block, off)
 
-    def _save_block(self, sk, sv, bslot, dst, src):
-        """One chunk scratch -> block pool, quantizing when int8 is on
-        (caller holds the lock and the mesh context)."""
-        if self._kv_quant:
-            (self._blocks_k, self._blocks_v, self._blocks_ks,
-             self._blocks_vs) = self._save_span_fn(
-                self._blocks_k, self._blocks_v, self._blocks_ks,
-                self._blocks_vs, sk, sv,
-                np.int32(bslot), np.int32(dst), np.int32(src))
-        else:
-            self._blocks_k, self._blocks_v = self._save_span_fn(
-                self._blocks_k, self._blocks_v, sk, sv,
-                np.int32(bslot), np.int32(dst), np.int32(src))
-
-    def _publish_chunk_quant(self, st, sk, sv, ch):
-        """int8 miss path, non-final chunks: publish each COMPLETED full
-        chunk into the quantized block pool as it finishes, then reload
-        the dequantized values into this request's OWN scratch — the
-        miss request attends exactly the numbers a later prefix-cache
-        hit will restore, so greedy output is bit-identical hit vs miss
-        (write-through caching, compile-once edition). The final chunk
-        (full or padded) is save-only in _populate_prefix: the admission
-        match is capped one token short of the prompt, so no hit ever
-        restores it and both paths attend it raw."""
-        C = self.config.prefill_chunk
+    def _publish_chunk(self, st, scratch, ch):
+        """Write-through miss path (a store whose blocks are not the
+        computed values: int8), non-final spans: publish each COMPLETED
+        full chunk as it finishes, then reload what the store holds into
+        this request's OWN scratch — the miss attends exactly the
+        numbers a later prefix-cache hit will restore, so greedy output
+        is bit-identical hit vs miss. The final chunk (full or padded)
+        is save-only in _populate_prefix: the admission match is capped
+        one token short of the prompt, so no hit ever restores it and
+        both paths attend it raw."""
         end = ch.start + ch.length
-        created = self.prefix_cache.insert(st.request.tokens[:end])
-        with self._mesh_ctx():
-            for off, block in created:
-                bslot, boff = divmod(block, self._blocks_per_slot)
-                self._save_block(sk, sv, bslot, boff * C, off)
-                sk, sv = self._load_span_fn(
-                    sk, sv, self._blocks_k, self._blocks_v,
-                    self._blocks_ks, self._blocks_vs,
-                    np.int32(bslot), np.int32(boff * C), np.int32(off))
-        return sk, sv
+        for off, block in self.prefix_cache.insert(st.request.tokens[:end]):
+            self._blocks.save(scratch, block, off)
+            scratch = self._blocks.load(scratch, block, off)
+        return scratch
 
     def _draft_replay(self, st, dk, dv):
         """Prefix-hit draft warmup: re-prefill the matched range through
@@ -1080,9 +791,10 @@ class InferenceEngine:
         """Sender half of the prefill/decode hand-off: copy the cached
         KV blocks covering ``tokens``' chunk-aligned prefix out of the
         block pool as host arrays. Returns ``(covered_tokens, spans)``
-        where ``spans`` is ``[(k, v), ...]`` of fixed span shape
-        ``[n_layers, 1, prefill_chunk, Hkv, D]`` — the unit
-        serve/disagg.py frames onto the data plane. Defaults to the
+        where each span is one block in the store's format (kv_cache.py:
+        ``(k, v)`` of ``[n_layers, 1, prefill_chunk, Hkv, D]``, or the
+        int8 values and their scale rows) — the unit serve/disagg.py
+        frames onto the data plane. Defaults to the
         admission cap (one token short of the prompt) so the importing
         engine's match covers exactly what its scheduler would use.
         Blocks stay pinned for the duration of the copy; compile-once
@@ -1094,27 +806,8 @@ class InferenceEngine:
                else max(0, int(max_chunks)))
         with self._lock:
             nodes = self.prefix_cache.walk(tokens, cap)
-            spans = []
             try:
-                with self._mesh_ctx():
-                    for node in nodes:
-                        bslot, boff = divmod(node.block,
-                                             self._blocks_per_slot)
-                        if self._kv_quant:
-                            # int8 wire: values + scale rows — the
-                            # hand-off payload shrinks with the pool
-                            qk, qv, ks, vs = self._export_span_fn(
-                                self._blocks_k, self._blocks_v,
-                                self._blocks_ks, self._blocks_vs,
-                                np.int32(bslot), np.int32(boff * C))
-                            spans.append(
-                                (np.asarray(qk), np.asarray(qv),
-                                 np.asarray(ks), np.asarray(vs)))
-                        else:
-                            ck, cv = self._export_span_fn(
-                                self._blocks_k, self._blocks_v,
-                                np.int32(bslot), np.int32(boff * C))
-                            spans.append((np.asarray(ck), np.asarray(cv)))
+                spans = [self._blocks.export(n.block) for n in nodes]
             finally:
                 self.prefix_cache.release(nodes)
             if spans:
@@ -1132,56 +825,27 @@ class InferenceEngine:
         the number of prompt tokens newly covered."""
         if self.prefix_cache is None or not spans:
             return 0
-        import jax.numpy as jnp
         C = self.config.prefill_chunk
         n = min(len(spans), len(tokens) // C)
         if n <= 0:
             return 0
-        from ray_tpu.inference import kv_quant as kvq
         with self._lock:
             created = self.prefix_cache.insert(
                 [int(t) for t in tokens[:n * C]])
-            with self._mesh_ctx():
-                for off, block in created:
-                    span = spans[off // C]
-                    bslot, boff = divmod(block, self._blocks_per_slot)
-                    if self._kv_quant:
-                        if len(span) == 4:
-                            qk, qv, ks, vs = span
-                        else:
-                            # fp wire from a non-quantized exporter:
-                            # quantize host-side (bit-identical math to
-                            # the device save path)
-                            qk, ks = kvq.quantize_kv_np(span[0])
-                            qv, vs = kvq.quantize_kv_np(span[1])
-                        (self._blocks_k, self._blocks_v, self._blocks_ks,
-                         self._blocks_vs) = self._import_span_fn(
-                            self._blocks_k, self._blocks_v,
-                            self._blocks_ks, self._blocks_vs,
-                            jnp.asarray(qk, jnp.int8),
-                            jnp.asarray(qv, jnp.int8),
-                            jnp.asarray(ks, jnp.float32),
-                            jnp.asarray(vs, jnp.float32),
-                            np.int32(bslot), np.int32(boff * C))
-                    else:
-                        if len(span) == 4:
-                            # int8 wire into an fp pool: dequantize on
-                            # the host before landing the block
-                            ck = kvq.dequantize_kv_np(span[0], span[2])
-                            cv = kvq.dequantize_kv_np(span[1], span[3])
-                        else:
-                            ck, cv = span
-                        self._blocks_k, self._blocks_v = \
-                            self._import_span_fn(
-                                self._blocks_k, self._blocks_v,
-                                jnp.asarray(ck, self._cache_dtype),
-                                jnp.asarray(cv, self._cache_dtype),
-                                np.int32(bslot), np.int32(boff * C))
+            for off, block in created:
+                self._blocks.import_span(spans[off // C], block)
             imported = len(created) * C
             if imported:
                 self.kv_imports += 1
                 self.remote_prefix_tokens += imported
         return imported
+
+    def kv_import_is_exact(self, span) -> bool:
+        """Whether importing a peer's span (one item of what
+        ``export_kv_blocks`` returned there) gives this engine the blocks
+        its own prefill would have saved (BlockStore.imports_exactly)."""
+        return (self._blocks is not None
+                and self._blocks.imports_exactly(span))
 
     # -------------------------------------------------------------- stats
     def stats(self) -> Dict:
@@ -1213,12 +877,6 @@ class InferenceEngine:
             out["spec_accept_rate"] = (
                 round(self.spec_tokens_accepted / prop, 4) if prop
                 else 0.0)
-        if self._kv_quant:
-            from ray_tpu.inference import kv_quant as kvq
-            mcfg = self.model.cfg
-            out["kv_quant"] = "int8"
-            out["kv_quant_slot_gain"] = round(
-                kvq.slot_gain(mcfg.head_dim, self._fp_itemsize), 3)
-            out["kv_quant_slot_gain_vs_fp16"] = round(
-                kvq.slot_gain(mcfg.head_dim, 2), 3)
+        out.update(kv_cache.format_stats(
+            self._kv_quant, self.model.cfg.head_dim, self._kv_itemsize))
         return out

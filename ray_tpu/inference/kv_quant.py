@@ -10,11 +10,11 @@ finest granularity that adds no matmul work on the read path: the
 engine dequantizes a span in one fused multiply when it loads it back
 into fp scratch, and attention itself never sees int8.
 
-Where it plugs in (ray_tpu/inference/engine.py, kv_quant="int8"):
+Where it plugs in (ray_tpu/inference/kv_cache.py ``BlockStore``, the only
+importer; ``EngineConfig.kv_quant="int8"``):
 
-- the prefix-cache BLOCK pool stores int8 + scales; ``save_span`` /
-  ``load_span`` gain quantizing/dequantizing variants (still
-  fixed-shape, still compile-once);
+- the prefix-cache blocks are stored as int8 + scales; the store's save
+  encodes and its load decodes (still fixed-shape, still compile-once);
 - the decode slot pool and prefill scratch stay full precision — the
   pool is donated through the one decode program and rewriting it as
   int8 would put a quantize/dequantize pair on the per-token hot path
@@ -26,9 +26,11 @@ Where it plugs in (ray_tpu/inference/engine.py, kv_quant="int8"):
 - the disagg hand-off (serve/disagg.py) ships int8 spans + scales —
   the wire payload shrinks by ~``itemsize * D / (D + 4)``.
 
-Host (numpy) variants mirror the jnp math bit-for-bit (same round/clip
-on the same fp32 inputs) for cross-mode hand-offs: an fp16 exporter
-feeding an int8 importer quantizes on the host with identical results.
+Cross-mode hand-offs: an fp exporter's span is quantized by the int8
+importer on the device with this arithmetic under jit (a numpy mirror is
+NOT bit-identical to the jitted form: a scale's last bit differs in about
+one row of twenty on the CPU backend); an int8 span is dequantized for an
+fp importer on the host.
 """
 
 from __future__ import annotations
@@ -62,31 +64,11 @@ def dequantize_kv(q, scale, dtype):
     return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
-def quantize_kv_np(x):
-    """Host mirror of :func:`quantize_kv` (same fp32 math)."""
-    xf = np.asarray(x, np.float32)
-    amax = np.max(np.abs(xf), axis=-1)
-    scale = np.where(amax > 0.0, amax / 127.0, 1.0).astype(np.float32)
-    q = np.clip(np.round(xf / scale[..., None]), -127.0, 127.0)
-    return q.astype(np.int8), scale
-
-
 def dequantize_kv_np(q, scale, dtype=np.float32):
-    """Host mirror of :func:`dequantize_kv`."""
+    """Host mirror of :func:`dequantize_kv` (one multiply: the same
+    bits), for an int8 span landing in an fp store."""
     return (np.asarray(q, np.float32)
             * np.asarray(scale, np.float32)[..., None]).astype(dtype)
-
-
-def int8_block_bytes_per_token(n_kv_heads: int, head_dim: int) -> int:
-    """Bytes one cached token position costs in the int8 block pool
-    (K + V values + their scale rows)."""
-    return 2 * n_kv_heads * (head_dim + 4)
-
-
-def fp_block_bytes_per_token(n_kv_heads: int, head_dim: int,
-                             itemsize: int) -> int:
-    """Same position's cost at full precision (K + V)."""
-    return 2 * n_kv_heads * head_dim * itemsize
 
 
 def slot_gain(head_dim: int, fp_itemsize: int) -> float:

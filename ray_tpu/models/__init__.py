@@ -19,8 +19,9 @@ MODEL_REGISTRY = {
         d_ff=11008, max_seq_len=4096),
     # TPU-native flagship geometry: 128-lane heads (head_dim=128) fill the
     # MXU's 128-wide systolic tiles; the classic hd=64 llama layout leaves
-    # half the array idle on QK^T/PV. Measured +10pts MFU on v5e
-    # (reports/mfu_ablation.jsonl: 42.8% vs 32.1% for the same 350m FLOPs)
+    # half the array idle on QK^T/PV. An ablation from before PR 21 read
+    # 42.8% against 32.1% MFU for the same 350m FLOPs (its record went
+    # with the old measuring kit, PR 29; not measured by perfbench/)
     "tpu-125m": TransformerConfig(
         vocab_size=32000, d_model=768, n_layers=12, n_heads=6, n_kv_heads=6,
         d_ff=2048, max_seq_len=2048),
@@ -33,9 +34,9 @@ MODEL_REGISTRY = {
     # Larger rungs keep hd=128 and add GQA (4:1) — KV projections are
     # bandwidth, not FLOPs, and 8 KV heads shard cleanly over an 8-way
     # tensor axis. tpu-3b is the largest single-v5e-chip (16 GB) rung:
-    # it needs bf16 params + adafactor + chunked cross-entropy to fit
-    # (see reports/MFU_ABLATION.md OOM table); tpu-7b (llama-7b-class
-    # FLOPs, MXU-aligned d_ff) is the multi-chip FSDP flagship.
+    # it needs bf16 params + adafactor + chunked cross-entropy to fit;
+    # tpu-7b (llama-7b-class FLOPs, MXU-aligned d_ff) is the multi-chip
+    # FSDP flagship.
     "tpu-3b": TransformerConfig(
         vocab_size=32000, d_model=3072, n_layers=24, n_heads=24,
         n_kv_heads=8, d_ff=8192, max_seq_len=4096),

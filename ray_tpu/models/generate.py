@@ -23,7 +23,8 @@ from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.sampling import sample_logits
-from ray_tpu.models.transformer import init_cache
+from ray_tpu.models.transformer import (init_cache, kv_cache_shape,
+                                        kv_cache_sharding)
 from ray_tpu.parallel import sharding as sharding_lib
 from ray_tpu.parallel.mesh import use_mesh
 from ray_tpu.parallel.train_step import (_prune_indivisible,
@@ -54,16 +55,9 @@ def make_generate_fn(model: nn.Module, mesh: Mesh, rules=None,
     param_sh = state_shardings(abstract, mesh, rules)
     init_fn = jax.jit(init_params, out_shardings=param_sh)
 
-    # cache [n_layers, B, M, Hkv, D]: batch over data axes, KV heads
-    # over tensor (same split the k/v projection weights carry)
-    cache_spec = _prune_indivisible(
-        logical_pspec_to_mesh(P(None, "batch", None, "kv_heads", None),
-                              rules),
-        (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim),
-        mesh)
-    cache_sh = {"k": NamedSharding(mesh, cache_spec),
-                "v": NamedSharding(mesh, cache_spec),
-                "idx": NamedSharding(mesh, P())}
+    kv_sh = kv_cache_sharding(kv_cache_shape(cfg, batch, max_len), mesh,
+                              rules)
+    cache_sh = {"k": kv_sh, "v": kv_sh, "idx": NamedSharding(mesh, P())}
 
     def _pick(logits, rng):
         # shared with the inference engine (models/sampling.py); static

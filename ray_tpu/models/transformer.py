@@ -310,14 +310,38 @@ class DecodeScanBlock(nn.Module):
         return (out, positions, idx), new_rows
 
 
+def kv_cache_shape(cfg: TransformerConfig, batch: int,
+                   max_len: int) -> Tuple[int, ...]:
+    """The layout of a K or V cache, [n_layers, B, max_len, Hkv, D]: what
+    the cached forward reads and writes (TransformerLM._decode,
+    _cache_write). Every cache-shaped array anywhere (a slot pool, a
+    prefill scratch, a prefix block row, a span of one) takes its shape
+    from here."""
+    return (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+
+
+def kv_cache_sharding(shape, mesh, rules=None):
+    """That layout on a mesh: batch over the data axes, KV heads over
+    `tensor` (the split the k/v projection weights carry), an axis the
+    shape does not divide left replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.parallel import sharding as sharding_lib
+    from ray_tpu.parallel.train_step import (_prune_indivisible,
+                                             logical_pspec_to_mesh)
+    spec = logical_pspec_to_mesh(P(None, "batch", None, "kv_heads", None),
+                                 rules or sharding_lib.DEFAULT_RULES)
+    return NamedSharding(mesh, _prune_indivisible(spec, shape, mesh))
+
+
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                dtype=None):
-    """Fresh KV cache pytree: {'k','v': [n_layers,B,max_len,Hkv,D],
+    """Fresh KV cache pytree: {'k','v': kv_cache_shape(...),
     'idx': next write position (scalar int32)}. Each of 'k' and 'v' is
     ONE pool for all layers; the cached forward returns the same pool
     with this call's rows added in place (see TransformerLM._decode)."""
     dtype = dtype or cfg.dtype
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+    shape = kv_cache_shape(cfg, batch, max_len)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
             "idx": jnp.zeros((), jnp.int32)}
 

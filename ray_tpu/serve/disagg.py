@@ -77,6 +77,7 @@ import numpy as np
 from ray_tpu._private import events, rpc
 from ray_tpu._private.config import cfg
 from ray_tpu.inference.api import LLMDeployment
+from ray_tpu.inference.kv_cache import span_format
 
 logger = logging.getLogger(__name__)
 
@@ -101,7 +102,7 @@ def pack_kv_spans(spans: List[Tuple[np.ndarray, ...]]) -> bytes:
     k0 = spans[0][0]
     meta = {"n": len(spans), "shape": list(k0.shape),
             "dtype": str(k0.dtype)}
-    if len(spans[0]) == 4:
+    if span_format(spans[0]) == "int8":
         s0 = spans[0][2]
         meta["quant"] = "int8"
         meta["sshape"] = list(s0.shape)
@@ -614,14 +615,10 @@ class DisaggLLMDeployment(LLMDeployment):
                         f"peer chunk={out.get('chunk')} != {C}")
                 payload = self._fetch_payload(out)
                 spans = unpack_kv_spans(payload)
-                if (spans and len(spans[0]) == 4
-                        and not getattr(eng, "_kv_quant", False)):
-                    # int8 wire into an fp pool is the ONE lossy
-                    # direction (dequantized blocks != fp-prefilled
-                    # blocks); the fabric promises greedy bit-identical,
-                    # so refuse and fall to local prefill. fp wire into
-                    # an int8 pool quantizes with the save-path math and
-                    # stays exact, so that direction imports.
+                if spans and not eng.kv_import_is_exact(spans[0]):
+                    # the fabric promises greedy bit-identical, and int8
+                    # wire into an fp pool is the ONE lossy direction:
+                    # refuse and fall to local prefill
                     self._m_fabric.inc(tags={"kind": "quant_mismatch"})
                     raise ValueError(
                         "quantized peer wire into fp pool; refusing "

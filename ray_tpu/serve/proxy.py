@@ -80,7 +80,7 @@ class HttpProxy:
         return self._addr
 
     def admission_stats(self) -> Dict:
-        """Admission + lease state for probes/tests (reports/edge_probe
+        """Admission + lease state for probes/tests (tests/edge_probe
         asserts zero over-admission across proxies from these)."""
         out = {"admission": None, "lease": None}
         if self._adm is not None:

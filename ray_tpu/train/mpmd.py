@@ -152,7 +152,6 @@ class MPMDConfig:
     storage_path: Optional[str] = None      # durable shard checkpoints
     async_checkpoint: bool = True           # snapshot/seal off the hot path
     donate_buffers: bool = True             # donate opt+param apply inputs
-    step_profile: bool = True               # runtime_mpmd_* attribution
 
     def resolved(self) -> "MPMDConfig":
         c = dataclasses.replace(self)
@@ -1454,7 +1453,7 @@ class MPMDPipelineTrainer:
         last-stage target microbatches. Returns the run summary."""
         from ray_tpu._private import events
         self.start()
-        if self.config.step_profile and self.profiler is None:
+        if self.profiler is None:           # runtime_mpmd_* attribution
             from ray_tpu.util.profiling import StepProfiler
             self.profiler = StepProfiler(name="mpmd", category="train")
         with events.record_span("train.mpmd.fit", category="train",
@@ -1465,17 +1464,13 @@ class MPMDPipelineTrainer:
             step = 0
             while step < n_steps:
                 step += 1
-                scope = self.profiler.step() if self.profiler else None
-                if scope is not None:
-                    scope.__enter__()
+                scope = self.profiler.step()
                 inputs, targets = data_fn(step)
                 self._check_shapes(inputs, targets)
                 self.replay.record(step, inputs, targets)
-                if scope is not None:
-                    scope.data_ready()
+                scope.data_ready()
                 self._run_step_with_recovery(step, inputs, targets)
-                if scope is not None:
-                    scope.__exit__(None, None, None)
+                scope.__exit__(None, None, None)
                 # checkpoint + migration run OUTSIDE the step scope: with
                 # async_checkpoint they cost one fast ref round-trip here
                 # and the residue shows up as the NEXT step's host_gap —

@@ -1,8 +1,8 @@
 """Per-step time/FLOP attribution: where does a train/decode step go?
 
 ROADMAP item 5 has `train_step_mfu` stuck at 0.564 with zero in-runtime
-visibility into where step time is spent; the offline harness
-(reports/mfu_ablate.py) answers it once per ablation run, not live. The
+visibility into where step time is spent (a figure from before PR 21;
+perfbench/ measures `mfu` on the chip now, once a run, not live). The
 step-level attribution that both the Gemma-on-TPU serving study (arXiv
 2605.25645) and the MPMD pipeline work (arXiv 2412.14374) lean on before
 optimizing is exactly: FLOPs from the compiled program

@@ -1,4 +1,5 @@
-"""Cluster serving-edge probe (bench.py `serve_million_sessions`).
+"""Cluster serving-edge probe (`serve_million_sessions`): hermetic, on a
+virtual clock; `tests/test_serve_edge.py` runs it scaled down.
 
 Three segments, one RESULT entry (ROADMAP item 2):
 

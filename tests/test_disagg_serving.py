@@ -364,8 +364,8 @@ def test_remote_prefill_greedy_bit_identical_and_compile_once(tiny):
     st = decode.stats()
     assert st["decode_compile_count"] == 1
     assert st["remote_prefix_tokens"] == 16
-    assert decode._import_span_fn._cache_size() == 1
-    assert prefill._export_span_fn._cache_size() == 1
+    assert decode._blocks._import_fn._cache_size() == 1
+    assert prefill._blocks._export_fn._cache_size() == 1
     # a redundant import of already-cached chunks is a no-op
     assert decode.import_kv_blocks(prompt[:covered],
                                    unpack_kv_spans(payload)) == 0

@@ -212,12 +212,12 @@ def test_pool_is_updated_in_place_not_rebuilt(tiny, program):
     eng = _engine(model, params, n_slots=3)
     chunk = eng._prefill_tiles[-1]
     if program == "decode":
-        fn, pools = eng._decode_fn, (eng._pool_k, eng._pool_v)
+        fn, pools = eng._decode_fn, (eng._slots.k, eng._slots.v)
         args = (eng.params, *pools, eng._lengths, eng._last_tok,
                 eng._rng, eng._temps)
         rows, new_len = 3, 1
     else:
-        shape = eng._scratch_shape
+        shape = eng._slots.scratch_shape
         fn, pools = eng._prefill_fn, (jnp.zeros(shape, jnp.float32),
                                       jnp.ones(shape, jnp.float32))
         args = (eng.params, *pools, jnp.zeros((1, chunk), jnp.int32),
@@ -371,8 +371,8 @@ def _prefill_then_decode(eng, prompt, n_new):
     h = eng.submit(prompt, max_new_tokens=n_new)
     assert _run_until(eng, lambda: h.first_token_t is not None, 40)
     n = len(prompt)
-    kv = (np.asarray(eng._pool_k[:, 0, :n]),
-          np.asarray(eng._pool_v[:, 0, :n]))
+    kv = (np.asarray(eng._slots.k[:, 0, :n]),
+          np.asarray(eng._slots.v[:, 0, :n]))
     assert _run_until(eng, lambda: h.finish_reason is not None)
     return h.tokens(), kv
 
@@ -495,8 +495,8 @@ def _tiles_and_tokens(eng, fillers, prompt, n_new=4):
         eng._run_prefill, eng._prefill_fn = run, fn
     n, slot = len(prompt), cur["st"].slot
     assert cur["st"].handle is h       # the last prefill before its token
-    kv = (np.asarray(eng._pool_k[:, slot, :n]),
-          np.asarray(eng._pool_v[:, slot, :n]))
+    kv = (np.asarray(eng._slots.k[:, slot, :n]),
+          np.asarray(eng._slots.v[:, slot, :n]))
     assert _run_until(eng, lambda: all(
         x.finish_reason for x in (h, *hs)))
     assert sum(n_real for _, _, n_real in own) == n
@@ -611,8 +611,8 @@ def test_spec_draft_pool_after_tiled_prefill_equals_chunked(tiny):
         assert eng.draft_prefill_compile_count == len(eng._prefill_tiles)
         h = eng.submit(prompt, max_new_tokens=9)
         assert _run_until(eng, lambda: h.first_token_t is not None, 40)
-        pools.append((np.asarray(eng._dpool_k[:, 0, :27]),
-                      np.asarray(eng._dpool_v[:, 0, :27])))
+        pools.append((np.asarray(eng._draft_slots.k[:, 0, :27]),
+                      np.asarray(eng._draft_slots.v[:, 0, :27])))
         assert _run_until(eng, lambda: h.finish_reason is not None)
         toks.append(h.tokens())
         assert eng.draft_prefill_compile_count == len(eng._prefill_tiles)

@@ -143,21 +143,6 @@ def test_engine_decode_emits_mfu_and_span_attribution():
     snap = _snap("runtime_decode_step")
     assert snap["runtime_decode_step_mfu"]["samples"][0][1] > 0
 
-    # step_profile=False keeps the old behavior (the bench baseline)
-    eng2 = InferenceEngine(model, params,
-                           EngineConfig(n_slots=2, max_len=32,
-                                        prefill_chunk=8,
-                                        prefill_budget=16,
-                                        step_profile=False))
-    h2 = eng2.submit([1, 2, 3], max_new_tokens=2)
-    while eng2.step():
-        pass
-    assert len(h2.tokens()) == 2
-    assert eng2.profiler is None
-    decs2 = [r for r in events.drain()
-             if r.get("state") == "RUNNING"
-             and r["name"] == "engine.decode"]
-    assert decs2 and all("mfu" not in d["attrs"] for d in decs2)
 
 
 def test_rl_learner_emits_update_mfu():

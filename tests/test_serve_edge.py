@@ -509,22 +509,16 @@ def test_batched_export_single_flight_coalesces(tiny):
 
 
 # ==========================================================================
-# serve_million_sessions smoke (scaled down; full scale lives in bench.py)
+# serve_million_sessions smoke (scaled down: 1k of the probe's 100k sessions)
 # ==========================================================================
 
 @pytest.mark.slow
 def test_serve_million_sessions_smoke():
     """O(1k)-session edge_probe pass through 2 real proxies: exercises
-    the full wiring of the serve_million_sessions bench entry (quota
-    leases + revocation, KV fabric vs local-only baseline, coalesced
-    batched export) without the 100k-session figure run."""
-    import os
-    import sys
-    reports = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "reports")
-    if reports not in sys.path:
-        sys.path.insert(0, reports)
-    import edge_probe
+    the full wiring of the serving edge (quota leases + revocation,
+    KV fabric vs local-only baseline, coalesced batched export) without
+    the 100k-session figure run."""
+    from tests import edge_probe
     # cluster rate scales down with the session count so the buckets
     # actually constrain (at the default 2000/s a 1k run never sheds
     # and the raw zipf draw leaks past the fairness bound) and so the
@@ -565,7 +559,7 @@ def test_rtlint_clean_on_edge_modules():
     targets = [os.path.join(repo, *p.split("/")) for p in (
         "ray_tpu/serve/fleet.py", "ray_tpu/serve/proxy.py",
         "ray_tpu/serve/disagg.py", "ray_tpu/serve/slo.py",
-        "ray_tpu/util/chaos.py", "reports/edge_probe.py")]
+        "ray_tpu/util/chaos.py", "tests/edge_probe.py")]
     r = run_lint(targets, config=LintConfig(root=repo),
                  use_baseline=False)
     assert r.findings == [], [str(f) for f in r.findings]
