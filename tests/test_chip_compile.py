@@ -67,8 +67,7 @@ def test_flash_core_compiles_for_v5e(one_chip, no_compile_cache, shape,
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     def core(q, k, v):
-        return _flash_core(True, 256, 256, shape[-1] ** -0.5, False,
-                           q, k, v)
+        return _flash_core(True, None, shape[-1] ** -0.5, False, q, k, v)
 
     fn = core if mode == "fwd" else jax.grad(
         lambda q, k, v: core(q, k, v).astype(jnp.float32).sum(),
