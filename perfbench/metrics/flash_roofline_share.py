@@ -9,7 +9,7 @@ so the share understates the kernels. Where no such op is found the metric
 is left out (PERF.md, list for `tracing`)."""
 import re
 
-from perfbench import yardstick
+from perfbench import spec, yardstick
 
 KERNEL = re.compile(r"^attn\.\d+( |$)")
 
@@ -22,8 +22,8 @@ def read(run):
             if KERNEL.search(k))
     if not steps or t <= 0:
         return None
-    flops = steps * yardstick.causal_attention_flops(
-        run["config"], run["mix"]["batch"], run["mix"]["seq_len"],
-        backward=True)
+    cfg = run["config"]
+    flops = steps * spec.family_of(cfg).causal_attention_flops(
+        cfg, run["mix"]["batch"], run["mix"]["seq_len"], backward=True)
     peak = yardstick.peaks(run["device"]["kind"])["flops_per_s"]
     return flops / peak / t * 100.0

@@ -22,14 +22,15 @@ class BenchLLMDeployment(LLMDeployment):
     def __init__(self, model_kwargs: dict, config: dict, seed: int,
                  **engine):
         from perfbench import spec, weights
-        from ray_tpu.models import TransformerLM
+        family = spec.family_of(config)
         self._bench_config = config
         self._bench_timings = {}
         self._bench_lock = threading.Lock()
-        model = TransformerLM(spec.build_transformer_config(model_kwargs))
+        model = family.build_model(model_kwargs)
         t0 = time.monotonic()
         super().__init__(model, seed=seed,
-                         params_fn=lambda: weights.seeded_params(model, seed),
+                         params_fn=lambda: weights.seeded_params(
+                             model, seed, family.weight_rule),
                          weights_key=None, **engine)
         self._bench_init_s = time.monotonic() - t0
         from perfbench.runtime import watch_clock
@@ -98,10 +99,11 @@ class BenchLLMDeployment(LLMDeployment):
     def bench_reference(self, cases) -> dict:
         """Teacher-forced check on this replica's own weights: for each
         (prompt, generated) the reference's gap per generated token."""
-        from perfbench import reference
+        from perfbench import spec
+        gaps_of = spec.family_of(self._bench_config).teacher_forced_gaps
         pad = max(len(p) + len(g) for p, g in cases)
         pad = -(-pad // 128) * 128
-        out = [reference.teacher_forced_gaps(
+        out = [gaps_of(
             self.engine.params, self._bench_config, p, g, pad_to=pad,
             with_spread=True) for p, g in cases]
         return {"gaps": [g for g, _ in out],

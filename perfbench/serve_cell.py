@@ -26,7 +26,8 @@ DRAIN_S = 20.0          # a request still unfinished this long after the
                         # window counts as failed, and enters the tails here
 PROBE_PROMPT = 300      # three prefill chunks of 128, two full ones saved
 PROBE_NEW = 16
-REFERENCE_CASES = ((200, 24), (700, 24))    # (prompt, generated) tokens
+REFERENCE_CASES = ((200, 24), (700, 24))    # (prompt, generated) tokens,
+                        # where the mix states no `reference_cases`
 
 
 def log(msg: str) -> None:
@@ -189,14 +190,15 @@ def run(args, cell, cfg, mix, t_start, checks: Checks) -> dict:
     vocab = cfg["vocab_size"]
     engine = dict(cfg["engine"])
     max_ongoing = engine.pop("max_ongoing_requests", 64)
-    model_kwargs = spec.transformer_kwargs(cfg)
+    model_kwargs = spec.family_of(cfg).model_kwargs(cfg)
     reqs = traffic.schedule(mix, args.seed, args.seconds, vocab,
                             engine["max_len"])
     rng = np.random.default_rng([int(args.seed), 99])
     probe_prompt = rng.integers(1, vocab, size=min(
         PROBE_PROMPT, engine["max_len"] // 2)).tolist()
     ref_cases = [(rng.integers(1, vocab, size=min(
-        p, engine["max_len"] // 2)).tolist(), g) for p, g in REFERENCE_CASES]
+        p, engine["max_len"] // 2)).tolist(), g)
+        for p, g in mix.get("reference_cases", REFERENCE_CASES)]
     trace_dir = None
     if args.trace:
         trace_dir = os.path.join(args.out_dir, "trace", cell["name"])

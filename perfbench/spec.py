@@ -2,18 +2,26 @@
 
 A cell names a configuration and a traffic mix; a metric names itself.
 Everything else is looked up here: `<path>/traffic/<traffic>.json`,
-the configuration's `file`, `<path>/metrics/<metric>.py`, for each
-directory in `paths`. A later PR adds files and entries and edits none.
+the configuration's `file`, `<path>/metrics/<metric>.py`, and the
+`<path>/families/<family>.py` a configuration's `"family"` key names (what
+the harness knows of a model: families/mistral.py says what that is), for
+each directory in `paths`. A later PR adds files and entries and edits none.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
+import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TRAFFIC_SUFFIXES = (".json",)
+FAMILY_STATES = ("model_kwargs", "build_model", "weight_rule",
+                 "teacher_forced_gaps", "batch_loss", "stored_param_bytes",
+                 "decode_step_bytes", "train_step_flops",
+                 "causal_attention_flops")
 
 
 class SpecError(Exception):
@@ -42,10 +50,19 @@ def workload(bench: dict, name: str) -> dict:
 
 
 def load_config(bench: dict, name: str, root: str = ROOT) -> dict:
+    """The configuration's file, with its entry and the file of its
+    family: what `family_of` needs, in this process or in a worker."""
     entry = _by_name(bench["configs"], name, "config")
-    with open(os.path.join(root, entry["file"])) as f:
+    path = os.path.join(root, entry["file"])
+    with open(path) as f:
         cfg = json.load(f)
+    if "family" not in cfg:
+        raise SpecError(f'{path} has no "family": the name of a '
+                        f'<path>/families/<name>.py')
     cfg["_entry"] = entry
+    cfg["_family_file"] = _find(bench, root, "families", cfg["family"],
+                                (".py",))
+    family_of(cfg)                       # a faulty file is refused here
     return cfg
 
 
@@ -83,65 +100,32 @@ def load_reader(bench: dict, metric: str, root: str = ROOT):
         if "." not in metric:
             raise
         path = _find(bench, root, "metrics", metric.split(".")[0], (".py",))
-    spec = importlib.util.spec_from_file_location(
-        "perfbench_metric_" + metric.replace(".", "_").replace("-", "_"),
-        path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    mod = _load_module("perfbench_metric_" + metric, path)
     if not callable(getattr(mod, "read", None)):
         raise SpecError(f"{path} defines no read(run)")
     return mod.read
 
 
-# ------------------------------------------------ configuration -> program
-# published key -> TransformerConfig field (models/transformer.py)
-_MODEL_KEYS = {
-    "vocab_size": "vocab_size", "hidden_size": "d_model",
-    "num_hidden_layers": "n_layers", "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads", "intermediate_size": "d_ff",
-    "max_position_embeddings": "max_seq_len", "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings",
-    "num_local_experts": "n_experts", "num_experts_per_tok": "expert_top_k",
-}
+def family_of(cfg: dict):
+    """The module of a loaded configuration's family, loaded once a
+    process."""
+    return _family_at(cfg["_family_file"], cfg["family"])
 
 
-def transformer_kwargs(cfg: dict) -> dict:
-    """The configuration file as keyword arguments of TransformerConfig
-    (dtypes as strings; the process that owns JAX turns them into dtypes).
-    Refuses what the program cannot state: another activation, a head size
-    that is not hidden/heads, a sliding window shorter than the engine's
-    slots (the program has no window, so it must be inert)."""
-    if cfg.get("hidden_act", "silu") != "silu":
-        raise SpecError(f"hidden_act {cfg['hidden_act']!r}: the program's "
-                        f"MLP is SwiGLU")
-    hd = cfg["hidden_size"] // cfg["num_attention_heads"]
-    if cfg.get("head_dim", hd) != hd:
-        raise SpecError("head_dim is not hidden_size / num_attention_heads")
-    kw = {dst: cfg[src] for src, dst in _MODEL_KEYS.items() if src in cfg}
-    longest = max((cfg.get("engine") or {}).get("max_len", 0),
-                  (cfg.get("train") or {}).get("seq_len", 0))
-    window = cfg.get("sliding_window")
-    if window is not None and longest > window:
-        raise SpecError(f"sequences of {longest} pass the sliding window "
-                        f"{window}, which the program does not implement")
-    if longest > cfg["max_position_embeddings"]:
-        raise SpecError(f"sequences of {longest} pass "
-                        f"max_position_embeddings")
-    kw["dtype"] = "bfloat16"
-    kw["param_dtype"] = cfg.get("param_dtype", cfg.get("torch_dtype",
-                                                       "bfloat16"))
-    for key in ("capacity_factor", "remat_policy", "attention_impl"):
-        if key in (cfg.get("program") or {}):
-            kw[key] = cfg["program"][key]
-    return kw
+@functools.lru_cache(maxsize=None)
+def _family_at(path: str, name: str):
+    mod = _load_module("perfbench_family_" + name, path)
+    missing = [n for n in FAMILY_STATES
+               if not callable(getattr(mod, n, None))]
+    if missing:
+        raise SpecError(f"{path} defines no {', '.join(missing)}")
+    return mod
 
 
-def build_transformer_config(kw: dict):
-    """In a process that may import JAX: kwargs -> TransformerConfig."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models.transformer import TransformerConfig
-    kw = dict(kw)
-    for key in ("dtype", "param_dtype"):
-        kw[key] = jnp.dtype(kw[key])
-    return TransformerConfig(**kw)
+def _load_module(name: str, path: str):
+    name = name.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod       # a dataclass (a flax module) looks here
+    spec.loader.exec_module(mod)
+    return mod

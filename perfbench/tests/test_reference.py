@@ -1,6 +1,6 @@
 """The plain reference against the program's TransformerLM at a tiny
 Mistral-shaped and Mixtral-shaped size: logits, loss and gradients."""
-import json
+import hashlib
 import os
 
 import jax
@@ -10,23 +10,24 @@ import pytest
 from flax.core import meta
 
 from perfbench import reference, spec, weights
-from ray_tpu.models import TransformerLM
 from ray_tpu.parallel.train_step import cross_entropy_loss
 
-FIX = os.path.join(os.path.dirname(__file__), "fixture_root", "bench",
-                   "configs")
+FIXTURE = os.path.join(os.path.dirname(__file__), "fixture_root")
+FIXBENCH = spec.load_benchmark(FIXTURE)
 
 
-def _build(name, capacity_factor=None):
-    with open(os.path.join(FIX, name + ".json")) as f:
-        cfg = json.load(f)
-    cfg["param_dtype"] = "float32"
+def _build(name, capacity_factor=None, dtype="float32", seed=3):
+    cfg = spec.load_config(FIXBENCH, name, FIXTURE)
+    family = spec.family_of(cfg)
+    if dtype is not None:              # the parity is of the mathematics
+        cfg["param_dtype"] = dtype
     if capacity_factor is not None:
         cfg["program"] = {"capacity_factor": capacity_factor}
-    kw = spec.transformer_kwargs(cfg)
-    kw["dtype"] = "float32"            # the parity is of the mathematics
-    model = TransformerLM(spec.build_transformer_config(kw))
-    return cfg, model, weights.seeded_params(model, 3)
+    kw = family.model_kwargs(cfg)
+    if dtype is not None:
+        kw["dtype"] = dtype
+    model = family.build_model(kw)
+    return cfg, model, weights.seeded_params(model, seed, family.weight_rule)
 
 
 def _tokens(n, vocab=256, seed=0):
@@ -100,8 +101,9 @@ def test_teacher_forced_gaps_and_padding():
 
 def test_seeded_weights_are_seeded_and_typed():
     cfg, model, params = _build("tiny-mixtral", 2.0)
-    again = weights.seeded_params(model, 3)
-    other = weights.seeded_params(model, 4)
+    rule = spec.family_of(cfg).weight_rule
+    again = weights.seeded_params(model, 3, rule)
+    other = weights.seeded_params(model, 4, rule)
     init = meta.unbox(jax.eval_shape(
         lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32))["params"],
         jax.random.PRNGKey(0)))
@@ -111,3 +113,42 @@ def test_seeded_weights_are_seeded_and_typed():
         assert bool((a == b).all())
     assert not bool((params["embed"] == other["embed"]).all())
     assert float(params["final_norm"]["scale"].min()) == 1.0
+
+
+# sha256 over the leaves (in tree order, as float32 bytes) of the tree that
+# the parent's weights.py drew on the CPU, before the rule moved into the
+# family's file (written down from commit 22e994d, PR 31)
+PARENT_TREES = {
+    ("tiny-mistral", 3):
+        "bb3096a8216a8d0ecaaa86f3ee32908898b4d01ec9a9f6bc9c0cc0d1aabcb2a2",
+    ("tiny-mistral", 3000000001):
+        "95018e262ff019d1569cdbf6227a1fc08f6ceb3146f014f3df0c890d05cb9c3d",
+    ("tiny-mixtral", 3):
+        "80a380b77e00a49eae8312fac26e3c606e6b4952347a5241a209b7f4d82373eb",
+    ("tiny-mixtral", 3000000001):
+        "4dbc1cf5140ee939922e2a779f73071ae1f18d940ddda75140f5336b636d2734",
+}
+
+
+@pytest.mark.parametrize("name,seed", sorted(PARENT_TREES))
+def test_seeded_weights_are_the_parents_bit_for_bit(name, seed):
+    _, _, params = _build(name, dtype=None, seed=seed)
+    h = hashlib.sha256()
+    for a in jax.tree.leaves(params):
+        h.update(np.asarray(a.astype(jnp.float32)).tobytes())
+    assert h.hexdigest() == PARENT_TREES[(name, seed)]
+
+
+def test_an_unknown_leaf_raises_and_names_the_familys_file():
+    cfg, model, _ = _build("tiny-mistral")
+    rule = spec.family_of(cfg).weight_rule
+
+    def narrower(names, shape):              # a family that knows no MLP
+        if "mlp" in names:
+            raise KeyError(names[-1])
+        return rule(names, shape)
+
+    with pytest.raises(KeyError) as e:
+        weights.seeded_params(model, 3, narrower)
+    assert os.path.basename(__file__) in str(e.value)
+    assert "mlp" in str(e.value)

@@ -7,6 +7,7 @@ import subprocess
 import sys
 
 import pytest
+from later_pr import add_later_pr, snapshot
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -33,6 +34,33 @@ def test_rehearsal_runs_the_cell_and_prints_no_metric(cell):
     assert last["correct"] is True, last
     assert last["attempted"] > 0 and last["failed"] == 0
     assert "device: cpu" in p.stderr
+
+
+def test_a_later_prs_family_rehearses_from_new_files_alone(tmp_path):
+    """A family the harness has never seen (another model class, a leaf and
+    key names that `mistral` refuses, its own reference) serves a cell to
+    `correct`; the bias on its logits (standard deviation 1) is known only
+    to its own reference, which is what scored the cell's tokens at the
+    lengths the mix states; a `mistral` cell beside it still runs."""
+    root = str(tmp_path / "root")
+    before = add_later_pr(root)
+    after = snapshot(root)
+    lasts = {}
+    for cell in ("tiny-other.burst", "tiny-mistral.open"):
+        p = _run("--root", root, "--rehearse", "--workload", cell,
+                 "--seed", "3000000002", "--seconds", "3", "--trace", "0")
+        assert p.returncode == 0, p.stderr[-3000:]
+        lasts[cell] = json.loads(p.stdout.strip().splitlines()[-1])
+        assert lasts[cell]["correct"] is True, lasts[cell]
+        assert lasts[cell]["attempted"] > 0 and lasts[cell]["failed"] == 0
+    ref = lasts["tiny-other.burst"]["reference"]
+    assert ref["n_tokens"] == 8 + 6 and ref["logit_std"] > 0.5
+    assert ref["share_within_gap"] >= 0.9
+    ref = lasts["tiny-mistral.open"]["reference"]
+    assert ref["n_tokens"] == 48 and ref["logit_std"] < 0.5
+    assert snapshot(root) == after           # a run writes nothing there
+    assert all(after[p] == data for p, data in before.items()
+               if not p.endswith("BENCHMARK.json"))
 
 
 def test_the_measuring_path_fails_without_a_chip():

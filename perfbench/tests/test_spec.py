@@ -4,15 +4,14 @@ entries only."""
 import json
 import os
 import re
-import shutil
 
 import pytest
+from later_pr import add_later_pr
 
 from perfbench import spec
 
 BENCH = spec.load_benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-FIXTURE = os.path.join(os.path.dirname(__file__), "fixture_root")
 
 
 def test_contract_shape():
@@ -40,7 +39,7 @@ def test_every_config_and_mix_loads_and_maps_to_the_program():
     for w in BENCH["workloads"]:
         cfg = spec.load_config(BENCH, w["config"])
         mix = spec.load_traffic(BENCH, w["traffic"])
-        kw = spec.transformer_kwargs(cfg)
+        kw = spec.family_of(cfg).model_kwargs(cfg)
         assert kw["d_model"] == 4096 and kw["d_ff"] == 14336   # no width cut
         assert kw["n_heads"] == 32 and kw["n_kv_heads"] == 8
         assert mix["driver"] in ("open", "closed", "train")
@@ -55,7 +54,7 @@ def test_a_passed_sliding_window_is_refused():
     cfg = spec.load_config(BENCH, "mistral-7b")
     cfg["engine"] = dict(cfg["engine"], max_len=8192)
     with pytest.raises(spec.SpecError):
-        spec.transformer_kwargs(cfg)
+        spec.family_of(cfg).model_kwargs(cfg)
 
 
 def test_every_metric_has_a_reader_and_moves_what_its_cells_report():
@@ -81,46 +80,12 @@ def test_every_metric_has_a_reader_and_moves_what_its_cells_report():
 
 def test_a_later_pr_adds_files_and_entries_only(tmp_path):
     root = str(tmp_path / "root")
-    shutil.copytree(FIXTURE, root)
-    before = {}
-    for d, _, files in os.walk(root):
-        for f in files:
-            p = os.path.join(d, f)
-            before[p] = open(p, "rb").read()
-    bench = json.load(open(os.path.join(root, "BENCHMARK.json")))
-    # new files: a second directory of the later PR's own
-    new = os.path.join(root, "bench2")
-    for sub in ("configs", "traffic", "metrics"):
-        os.makedirs(os.path.join(new, sub))
-    cfg = json.load(open(os.path.join(
-        root, "bench", "configs", "tiny-mistral.json")))
-    cfg.update(name="tiny-wide", intermediate_size=256)
-    json.dump(cfg, open(os.path.join(new, "configs", "tiny-wide.json"), "w"))
-    mix = json.load(open(os.path.join(root, "bench", "traffic", "open.json")))
-    mix["arrival"] = {"process": "gamma", "cv": 2.5, "rate_per_s": 6.0}
-    json.dump(mix, open(os.path.join(new, "traffic", "burst.json"), "w"))
-    with open(os.path.join(new, "metrics", "queue_depth_end.py"), "w") as f:
-        f.write("def read(run):\n"
-                "    c = run.get('counters') or {}\n"
-                "    return c['t1']['queue_depth'] if 't1' in c else None\n")
-    # new entries
-    bench["paths"].append("bench2")
-    bench["configs"].append({
-        "name": "tiny-wide", "source": "test", "reduced": [], "why": "t",
-        "file": "bench2/configs/tiny-wide.json"})
-    bench["workloads"].append({
-        "name": "tiny-wide.burst", "config": "tiny-wide",
-        "traffic": "burst", "chips": 1, "why": "t"})
-    bench["per_layer"].append({
-        "name": "queue_depth_end", "unit": "requests", "better": "lower",
-        "source": "program_counter", "layer": "Engine", "moves": "setup_s",
-        "workloads": ["tiny-wide.burst"]})
-    json.dump(bench, open(os.path.join(root, "BENCHMARK.json"), "w"))
-    # the harness finds all three by name
+    before = add_later_pr(root)
+    # the harness finds a configuration, a mix and a reader by name
     b = spec.load_benchmark(root)
     cell = spec.workload(b, "tiny-wide.burst")
-    assert spec.load_config(b, cell["config"], root)["intermediate_size"] \
-        == 256
+    wide = spec.load_config(b, cell["config"], root)
+    assert wide["intermediate_size"] == 256
     assert spec.load_traffic(b, cell["traffic"], root)["arrival"]["cv"] \
         == 2.5
     (m,) = spec.metrics_of(b, "tiny-wide.burst", "per_layer")
@@ -128,7 +93,56 @@ def test_a_later_pr_adds_files_and_entries_only(tmp_path):
     assert read({"counters": {"t1": {"queue_depth": 3}}}) == 3
     assert read({}) is None
     assert spec.metrics_of(b, "tiny-mistral.open", "per_layer") == []
+    # and a family: the new one under the later PR's directory, the old
+    # one where it was, each with its own mapping and its own counts
+    other = spec.load_config(b, "tiny-other", root)
+    fam = spec.family_of(other)
+    assert fam.__file__ == os.path.join(root, "bench2", "families",
+                                        "other.py")
+    assert fam.model_kwargs(other)["d_model"] == 64
+    assert spec.family_of(wide).__file__ == os.path.join(
+        root, "bench", "families", "mistral.py")
+    with pytest.raises(KeyError):            # other key names
+        spec.family_of(wide).model_kwargs(other)
+    with pytest.raises(KeyError):            # and a leaf it refuses
+        spec.family_of(wide).weight_rule(["logit_bias"], (256,))
+    assert fam.weight_rule(["logit_bias"], (256,)) == (1.0, False)
+    assert fam.stored_param_bytes(other, 2.0) == 2.0 * (
+        2 * (2 * 64 * 6 * 16 + 3 * 64 * 128) + 2 * 256 * 64)
+    # what travels to a worker is enough to find the same file there
+    travels = json.loads(json.dumps(
+        {k: v for k, v in other.items() if k != "_entry"}))
+    assert spec.family_of(travels) is fam
     # and no file that was there changed, but BENCHMARK.json
     for p, data in before.items():
         if not p.endswith("BENCHMARK.json"):
             assert open(p, "rb").read() == data
+
+
+def test_a_configuration_without_a_family_names_its_file(tmp_path):
+    root = str(tmp_path / "root")
+    add_later_pr(root)
+    path = os.path.join(root, "bench2", "configs", "tiny-wide.json")
+    cfg = json.load(open(path))
+    del cfg["family"]
+    json.dump(cfg, open(path, "w"))
+    with pytest.raises(spec.SpecError, match="tiny-wide.json"):
+        spec.load_config(spec.load_benchmark(root), "tiny-wide", root)
+    cfg["family"] = "nobodys"
+    json.dump(cfg, open(path, "w"))
+    with pytest.raises(spec.SpecError, match="families/nobodys.py"):
+        spec.load_config(spec.load_benchmark(root), "tiny-wide", root)
+
+
+@pytest.mark.parametrize("missing", spec.FAMILY_STATES)
+def test_a_family_that_leaves_a_statement_out_names_its_file(tmp_path,
+                                                              missing):
+    root = str(tmp_path / "root")
+    add_later_pr(root)
+    path = os.path.join(root, "bench2", "families", "other.py")
+    text = open(path).read()
+    assert f"def {missing}(" in text
+    open(path, "w").write(text.replace(f"def {missing}(",
+                                       f"def _{missing}("))
+    with pytest.raises(spec.SpecError, match=f"other.py.*{missing}"):
+        spec.load_config(spec.load_benchmark(root), "tiny-other", root)

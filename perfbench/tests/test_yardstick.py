@@ -18,11 +18,11 @@ def test_unknown_device_kind_raises():
 def test_train_step_flops_dense_by_hand():
     m = _cfg("mistral-7b-train")
     layer = 4096 * 4096 * 2 + 4096 * 1024 * 2 + 3 * 4096 * 14336
-    assert yardstick.layer_params(m, True) == layer == 218_103_808
+    assert spec.family_of(m).layer_params(m, True) == layer == 218_103_808
     tokens = 2 * 4096
     matmul = 6 * (4 * layer + 4096 * 32000) * tokens
     attn = 4 * 4 * 2 * 4096 * 4096 * 4096 / 2 * 3
-    assert yardstick.train_step_flops(m, 2, 4096) == matmul + attn
+    assert spec.family_of(m).train_step_flops(m, 2, 4096) == matmul + attn
     assert 5.2e13 < matmul + attn < 5.3e13        # the issue's 5.26e13
 
 
@@ -30,20 +30,20 @@ def test_moe_counts_active_experts_for_flops_all_for_bytes():
     m = _cfg("mixtral-8x7b")
     expert = 3 * 4096 * 14336
     attn = 4096 * 4096 * 2 + 4096 * 1024 * 2
-    assert yardstick.layer_params(m, True) == attn + 2 * expert + 4096 * 8
-    assert yardstick.layer_params(m, False) == attn + 8 * expert + 4096 * 8
-    stored = yardstick.stored_param_bytes(m, 2.0)
+    assert spec.family_of(m).layer_params(m, True) == attn + 2 * expert + 4096 * 8
+    assert spec.family_of(m).layer_params(m, False) == attn + 8 * expert + 4096 * 8
+    stored = spec.family_of(m).stored_param_bytes(m, 2.0)
     assert 12.0e9 < stored < 12.2e9               # the issue's 12.1 GB
     dense = _cfg("mistral-7b")
-    assert 9.2e9 < yardstick.stored_param_bytes(dense, 2.0) < 9.3e9
+    assert 9.2e9 < spec.family_of(dense).stored_param_bytes(dense, 2.0) < 9.3e9
 
 
 def test_decode_step_bytes_adds_live_kv():
     m = _cfg("mistral-7b")
-    base = yardstick.decode_step_bytes(m, [], 2.0, 2.0)
-    one = yardstick.decode_step_bytes(m, [1000], 2.0, 2.0)
+    base = spec.family_of(m).decode_step_bytes(m, [], 2.0, 2.0)
+    one = spec.family_of(m).decode_step_bytes(m, [1000], 2.0, 2.0)
     assert one - base == 2 * 20 * 1000 * 8 * 128 * 2
-    assert base == yardstick.stored_param_bytes(m, 2.0) - 32000 * 4096 * 2
+    assert base == spec.family_of(m).stored_param_bytes(m, 2.0) - 32000 * 4096 * 2
 
 
 def test_percentile_and_spread():

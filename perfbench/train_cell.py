@@ -31,14 +31,13 @@ def train_loop(config):
     import jax
     import optax
 
-    from perfbench import reference, trace_reduce, traffic
+    from perfbench import trace_reduce, traffic
     from ray_tpu import train
-    from ray_tpu.models import TransformerLM
     from ray_tpu.parallel import MeshConfig, make_mesh
     from ray_tpu.parallel.train_step import make_train_fns
 
     cfg, mix = config["config"], config["mix"]
-    tc = spec.build_transformer_config(config["model_kwargs"])
+    family = spec.family_of(cfg)
     B, L = mix["batch"], mix["seq_len"]
     d = jax.devices()
     device = {"platform": d[0].platform, "kind": d[0].device_kind,
@@ -49,8 +48,8 @@ def train_loop(config):
     opt = {"adafactor": optax.adafactor}[cfg["train"]["optimizer"]](
         cfg["train"]["learning_rate"])
     init_fn, step_fn, _ = make_train_fns(
-        TransformerLM(tc), opt, mesh, batch_shape=(B, L + 1),
-        loss_chunk=cfg["train"].get("loss_chunk"))
+        family.build_model(config["model_kwargs"]), opt, mesh,
+        batch_shape=(B, L + 1), loss_chunk=cfg["train"].get("loss_chunk"))
     state = init_fn(jax.random.PRNGKey(config["seed"] % (2 ** 31)))
 
     def batch(step):
@@ -60,7 +59,7 @@ def train_loop(config):
     first = batch(0)
     lowered = step_fn.lower(state, first).as_text()
     from flax.core import meta
-    ref_loss = reference.batch_loss(meta.unbox(state.params), cfg, first)
+    ref_loss = family.batch_loss(meta.unbox(state.params), cfg, first)
     losses = []
     for step in range(WARM_STEPS):
         state, metrics = step_fn(state, batch(step))
@@ -107,9 +106,7 @@ def run(args, cell, cfg, mix, t_start, checks: Checks) -> dict:
     from perfbench.runtime import shutdown_and_verify
     from ray_tpu.train import JaxTrainer, ScalingConfig
 
-    model_kwargs = spec.transformer_kwargs(cfg)
-    if args.rehearse:
-        model_kwargs["attention_impl"] = "auto"
+    model_kwargs = spec.family_of(cfg).model_kwargs(cfg)
     trace_dir = None
     if args.trace:
         trace_dir = os.path.join(args.out_dir, "trace", cell["name"])

@@ -1,36 +1,22 @@
 """Seeded weights, made on the device in one jitted call.
 
-The tree has the shapes and dtypes of the program's own
-`TransformerLM.init` (taken from `jax.eval_shape`, so nothing is
-computed), and each leaf is drawn in the type it is served in: a stacked
-leaf one layer at a time (`lax.map` over the layers axis), so that set-up
-never holds a whole-tree float32 intermediate. Scales follow the
-program's initialisers (normal 0.02 for the tables, 1/sqrt(fan_in) for the
-matmuls, ones for the norms), so activations stay O(1) through the depth
-and the reference check has logits of a realistic spread.
+The tree has the shapes and dtypes of the model's own `init` (taken from
+`jax.eval_shape`, so nothing is computed), and each leaf is drawn in the
+type it is served in: a stacked leaf one layer at a time (`lax.map` over
+the layers axis), so that set-up never holds a whole-tree float32
+intermediate. The scales are the family's rule (`weight_rule(names,
+shape)`: None for ones, or a standard deviation and whether the first axis
+is a stack), which follows the program's initialisers, so activations stay
+O(1) through the depth and the reference check has logits of a realistic
+spread.
 """
 
 from __future__ import annotations
 
-import math
+import inspect
 
 
-def _fan_in(names, shape) -> int:
-    """Fan-in of a matmul leaf by its name in the program's tree; an
-    unknown name raises, so a change of the tree is noticed here."""
-    leaf = names[-1] if names[-1] != "kernel" else names[-2]
-    stacked = shape[1:]                      # without the layers axis
-    if leaf in ("q", "k", "v", "router"):
-        return stacked[0]                    # [d_model, ...]
-    if leaf == "o":
-        return stacked[0] * stacked[1]       # [heads, head_dim, d_model]
-    if leaf in ("gate", "up", "down"):
-        return stacked[-2]                   # [(experts,) in, out]
-    raise KeyError(f"weights.py knows no initialiser for leaf "
-                   f"{'/'.join(names)} of shape {shape}")
-
-
-def seeded_params(model, seed: int):
+def seeded_params(model, seed: int, rule):
     """The model's parameter tree, seeded, on the default device."""
     import jax
     import jax.numpy as jnp
@@ -50,12 +36,19 @@ def seeded_params(model, seed: int):
         for i, (path, a) in enumerate(paths):
             names = [p.key for p in path]
             k = jax.random.fold_in(key, i)
-            if names[-1] == "scale":
+            try:
+                how = rule(names, a.shape)
+            except KeyError as e:
+                raise KeyError(
+                    f"{inspect.getsourcefile(rule)} states no initialiser "
+                    f"for leaf {'/'.join(names)} of shape {a.shape}") from e
+            if how is None:
                 leaves.append(jnp.ones(a.shape, a.dtype))
-            elif names[-1] in ("embed", "unembed"):
-                leaves.append(draw(k, a.shape, a.dtype, 0.02))
+                continue
+            std, stacked = how
+            if not stacked:
+                leaves.append(draw(k, a.shape, a.dtype, std))
             else:
-                std = 1.0 / math.sqrt(_fan_in(names, a.shape))
                 leaves.append(jax.lax.map(
                     lambda kk, a=a, std=std: draw(kk, a.shape[1:], a.dtype,
                                                   std),
