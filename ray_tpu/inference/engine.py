@@ -5,9 +5,12 @@ Architecture (the TPU-serving shape — cf. slot-based continuous
 batching in the Gemma-on-TPU serving stack):
 
 - The engine owns ``n_slots`` KV-cache slots, allocated once as two
-  pools (K and V) of ``[n_layers, n_slots, max_len, Hkv, D]``
+  pools (K and V) of ``[n_layers, n_slots, max_len, Hkv, D]`` and, for
+  a model with a sparse-attention indexer, a third of its keys,
+  ``[n_layers, n_slots, 1, DI, max_len]``
   (kv_cache.py ``SlotPool``; the prefix blocks and their fp/int8 format
-  are its ``BlockStore``) and donated through every step. The cached
+  are its ``BlockStore``, which holds K and V only: such a model runs
+  without a prefix cache) and donated through every step. The cached
   forward's layer loop only reads a pool; one write after the loop adds
   the step's new rows of all layers to it (models/transformer.py
   ``_decode``, ``_cache_write``), so the program's output pool IS the donated input
@@ -18,7 +21,8 @@ batching in the Gemma-on-TPU serving stack):
   prefill* under a per-step prefill-token budget — a long prompt is
   split across steps, and the tokens one step gives one request run as
   ONE fixed-shape tile through the cached-attention path
-  (``chunked_prefill=True``) into a scratch cache, so the weights
+  (``chunked_prefill=True``; the forward is told which of the tile's
+  rows the request owns, its padded tail is zeros) into a scratch cache, so the weights
   stream once a request a step and admission never stalls in-flight
   decodes for more than ``prefill_budget`` tokens of work; the tile is
   chosen from the prompt's length, so every token of a prompt passes
@@ -161,6 +165,13 @@ class InferenceEngine:
                 raise ValueError(
                     f"draft max_seq_len={self._draft_model.cfg.max_seq_len}"
                     f" < max_len + k = {pool_len}")
+        if mcfg.index_heads and (cfg.prefix_cache_slots > 0
+                                 or self._spec is not None):
+            raise ValueError(
+                "a model with a sparse-attention indexer keeps a third "
+                "cache (its indexer keys), which prefix blocks and a "
+                "speculative draft's verify step do not carry: run it with "
+                "prefix_cache_slots=0 and no spec")
         dtype = cfg.cache_dtype or mcfg.dtype
         self._kv_quant = kv_cache.check_format(cfg.kv_quant)
         self._lock = threading.RLock()
@@ -233,6 +244,23 @@ class InferenceEngine:
         self.spec_tokens_accepted = 0
         self.steps = 0
         self.tokens_generated = 0
+        # sparse attention (a model with an indexer): K/V rows a decode
+        # row read (the selected ones) and rows live, summed over the
+        # decode rows of every step; host arithmetic on the lengths
+        self._topk = mcfg.index_topk if mcfg.index_heads else 0
+        self.dsa_rows_read = 0
+        self.dsa_rows_live = 0
+        # an expert layer that holds a share of its experts: [rows the
+        # expert matmuls computed, picks of real rows that landed on a held
+        # expert], summed over layers and calls (models/moe.py sows them).
+        # Each program hands its pair back beside its other outputs; the
+        # pairs wait in `_moe_pending` and are folded in where the step
+        # reads a program's output anyway. With every expert held both
+        # counts are the shapes' (E x rows, K x rows): nothing to read,
+        # and those programs get no output more
+        self._count_moe = mcfg.n_experts > 0 and bool(mcfg.experts_held)
+        self._moe_counts = np.zeros((2,), np.int64)
+        self._moe_pending: List[Any] = []
         # disagg hand-off accounting (serve/disagg.py)
         self.kv_exports = 0
         self.kv_imports = 0
@@ -275,40 +303,64 @@ class InferenceEngine:
         # so the CPU tests exercise the same rebinding the chip runs
         # (a read of a donated buffer after its call raises there too)
 
-        def prefill(params, sk, sv, tokens, pos0, n_real, rng, temp):
-            # one request's share of a step's prompt budget, a [1, T]
+        names = tuple(self._slots.shapes)
+        count_moe = self._count_moe
+
+        def forward(params, tokens, pools, idx, real=None, **kw):
+            """-> (logits, the pools and, where counted, the call's
+            expert-layer counts summed over the layers). `real` [B, L]
+            bool: the rows a request owns, where not all."""
+            cache = dict(zip(names, pools), idx=idx)
+            if real is not None:
+                cache["real"] = real
+            out = model.apply({"params": params}, tokens, cache=cache, **kw,
+                              mutable=["counters"] if count_moe else False)
+            if not count_moe:
+                return out[0], tuple(out[1][n] for n in names)
+            (logits, new), counted = out
+            # one pair a layer (stacked where the layers are scanned)
+            pair = sum(c.reshape(-1, 2).sum(0)
+                       for c in jax.tree.leaves(counted))
+            return logits, tuple(new[n] for n in names) + (pair,)
+
+        def prefill(params, *args):
+            # (params, *scratch, tokens, pos0, n_real, rng, temp): one
+            # request's share of a step's prompt budget, a [1, T]
             # tile, through the cached path; samples the would-be next
             # token (used only on the prompt's last tile, where it is
             # the request's first generated token)
             self.prefill_compile_count += 1    # traces once a tile
-            cache = {"k": sk, "v": sv, "idx": pos0}
-            logits, new = model.apply({"params": params}, tokens,
-                                      cache=cache, chunked_prefill=True)
+            scratch = args[:len(names)]
+            tokens, pos0, n_real, rng, temp = args[len(names):]
+            # the tile's padded tail is rows no request owns
+            real = (jnp.arange(tokens.shape[1]) < n_real)[None, :]
+            logits, new = forward(params, tokens, scratch, pos0,
+                                  real=real, chunked_prefill=True)
             last = jax.lax.dynamic_index_in_dim(logits, n_real - 1,
                                                 axis=1, keepdims=False)
             tok = sample_logits_dynamic(last, rng, temp[None],
                                         top_k=top_k, top_p=top_p)
-            return tok[0].astype(jnp.int32), new["k"], new["v"]
+            return (tok[0].astype(jnp.int32),) + new
 
-        def decode(params, pk, pv, lengths, toks, rng, temps):
+        def decode(params, *args):
+            # (params, *pools, lengths, toks, rng, temps).
             # ONE program for the life of the engine: fixed [n_slots]
             # shapes, per-slot idx vector. Python side effect below runs
             # only at trace time — it counts XLA cache misses. The key
             # splits INSIDE the program (returned for the next step) so
             # the host does exactly one dispatch per decoded token.
             self.decode_compile_count += 1
+            pools = args[:len(names)]
+            lengths, toks, rng, temps = args[len(names):]
             rng, sub = jax.random.split(rng)
-            cache = {"k": pk, "v": pv, "idx": lengths}
-            logits, new = model.apply({"params": params}, toks[:, None],
-                                      cache=cache)
+            logits, new = forward(params, toks[:, None], pools, lengths)
             tok = sample_logits_dynamic(logits[:, -1, :], sub, temps,
                                         top_k=top_k, top_p=top_p)
-            return tok.astype(jnp.int32), new["k"], new["v"], rng
+            return (tok.astype(jnp.int32),) + new + (rng,)
 
-        self._prefill_fn = jax.jit(
-            prefill, donate_argnums=(1, 2))
-        self._decode_fn = jax.jit(
-            decode, donate_argnums=(1, 2))
+        donated = tuple(range(1, 1 + len(names)))     # the pools
+        self._prefill_fn = jax.jit(prefill, donate_argnums=donated)
+        self._decode_fn = jax.jit(decode, donate_argnums=donated)
 
         self._spec_step_fn = None
         self._draft_prefill_fn = None
@@ -357,11 +409,12 @@ class InferenceEngine:
         with self._mesh_ctx():
             for tile in self._prefill_tiles:
                 tokens = jnp.zeros((1, tile), jnp.int32)
-                sk, sv = self._slots.new_scratch()
+                scratch = self._slots.new_scratch()
                 for _ in range(2):
-                    _, sk, sv = self._prefill_fn(
-                        self.params, sk, sv, tokens, np.int32(0),
-                        np.int32(tile), key, np.float32(0.0))
+                    scratch = self._prefill_fn(
+                        self.params, *scratch, tokens, np.int32(0),
+                        np.int32(tile), key,
+                        np.float32(0.0))[1:1 + len(scratch)]
                 if self._spec is not None:
                     dk, dv = self._draft_slots.new_scratch()
                     for _ in range(2):
@@ -519,10 +572,12 @@ class InferenceEngine:
                     acc_host = np.asarray(acc)
                 else:
                     with self._mesh_ctx():
-                        toks, pool.k, pool.v, self._rng = self._decode_fn(
-                            self.params, pool.k, pool.v, self._lengths,
+                        toks, *new, self._rng = self._decode_fn(
+                            self.params, *pool.pools(), self._lengths,
                             self._last_tok, self._rng, self._temps)
+                    pool.rebind(new)
                     toks_host = np.asarray(toks)
+                    self._fold_moe_counts(new)
                 t_dec1 = time.perf_counter()
                 # capture before decode_emit: an evicted state's slot is
                 # None by the time the profiler reads it
@@ -554,6 +609,10 @@ class InferenceEngine:
                     for st in active:
                         slot = st.slot
                         self._lengths[slot] += 1
+                        if self._topk:      # the row attended itself too
+                            live = int(self._lengths[slot])
+                            self.dsa_rows_live += live
+                            self.dsa_rows_read += min(live, self._topk)
                         self._last_tok[slot] = toks_host[slot]
                         self.tokens_generated += 1
                         n_emitted += 1
@@ -654,15 +713,14 @@ class InferenceEngine:
                 prompt_tokens=len(st.request.tokens),
                 queue_wait_ms=round(
                     (now - st.handle.submitted_t) * 1e3, 3))
-        sk_sv = self._slots.scratch.get(st.rid)
-        if sk_sv is None:
-            sk_sv = self._slots.new_scratch()
+        scratch = self._slots.scratch.get(st.rid)
+        if scratch is None:
+            scratch = self._slots.new_scratch()
             if st.prefix_nodes:
                 # radix hit: the matched span's KV comes out of the
                 # block store as device-side copies — no forward pass
                 # runs over [0, prefix_matched)
-                sk_sv = self._restore_prefix(st, sk_sv)
-        sk, sv = sk_sv
+                scratch = self._restore_prefix(st, scratch)
         dk_dv = None
         if self._spec is not None:
             dk_dv = self._draft_slots.scratch.get(st.rid)
@@ -684,15 +742,18 @@ class InferenceEngine:
             trace_id=st.span.trace_id, parent_span_id=st.span.span_id,
             rid=st.rid, slot=st.slot, offset=ch.start, length=ch.length,
             tile=tile, is_last=ch.is_last,
+            live=ch.start + ch.length,    # positions the tile attended
             slots_occupied=self.sched.occupancy())
         compiles0 = self.prefill_compile_count
         self.prefill_dispatches += 1
         self.prefill_tokens += ch.length
         with self._mesh_ctx():
-            tok, sk, sv = self._prefill_fn(
-                self.params, sk, sv, tokens,
+            tok, *out = self._prefill_fn(
+                self.params, *scratch, tokens,
                 np.int32(ch.start), np.int32(ch.length), k,
                 np.float32(st.temperature))
+        scratch = tuple(out[:len(scratch)])
+        self._fold_moe_counts(out, wait=False)
         if self.prefill_compile_count > compiles0:
             events.record_instant(
                 "engine.compile", category="engine",
@@ -709,24 +770,42 @@ class InferenceEngine:
         if ch.is_last:
             slot = st.slot
             if self.prefix_cache is not None:
-                self._populate_prefix(st, (sk, sv))
-            self._slots.insert((sk, sv), slot)
+                self._populate_prefix(st, scratch)
+            self._slots.insert(scratch, slot)
             if self._spec is not None:
                 self._draft_slots.insert(dk_dv, slot)
             for pool in self._pools:
                 pool.scratch.pop(st.rid, None)
             self._lengths[slot] = len(prompt)
             first = int(tok)
+            self._fold_moe_counts()
             self._last_tok[slot] = first
             self._temps[slot] = st.temperature
             self.sched.prefill_done(st, first, time.monotonic())
         else:
             if self._write_through:
-                sk, sv = self._publish_chunk(st, (sk, sv), ch)
-            self._slots.scratch[st.rid] = (sk, sv)
+                scratch = self._publish_chunk(st, scratch, ch)
+            self._slots.scratch[st.rid] = scratch
             if self._spec is not None:
                 self._draft_slots.scratch[st.rid] = dk_dv
             self.sched.advance_prefill(st, ch.length)
+
+    def _fold_moe_counts(self, outputs=None, wait=True):
+        """Note the expert-layer counts at the end of a program's
+        `outputs`, and (`wait`) add every pair noted to the host's
+        totals. Folded only where the step has just read an output of
+        the latest program, so every pair is already computed and no
+        wait is added; a prefill tile that is not a prompt's last is
+        read by nobody and only notes its pair."""
+        if not self._count_moe:
+            return
+        if outputs is not None:
+            self._moe_pending.append(outputs[-1])
+        if not wait:
+            return
+        for pair in self._moe_pending:
+            self._moe_counts += np.asarray(pair)
+        self._moe_pending.clear()
 
     # ------------------------------------------------------- prefix cache
     def _restore_prefix(self, st, scratch):
@@ -879,4 +958,11 @@ class InferenceEngine:
                 else 0.0)
         out.update(kv_cache.format_stats(
             self._kv_quant, self.model.cfg.head_dim, self._kv_itemsize))
+        out["kv_pool_bytes"] = sum(p.nbytes() for p in self._pools)
+        if self._topk:
+            out["dsa_rows_read"] = self.dsa_rows_read
+            out["dsa_rows_live"] = self.dsa_rows_live
+        if self._count_moe:
+            out["moe_rows_computed"] = int(self._moe_counts[0])
+            out["moe_local_picks"] = int(self._moe_counts[1])
         return out

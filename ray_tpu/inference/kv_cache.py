@@ -2,13 +2,16 @@
 description.
 
 The LAYOUT ``[n_layers, rows, length, Hkv, D]`` is the model's
-(``models/transformer.py``: ``kv_cache_shape``, ``kv_cache_sharding``;
-the cached forward is what reads it). This module owns what the engine
-keeps in that layout and nothing else knows how:
+(``models/transformer.py``: ``kv_cache_shape``, ``kv_cache_sharding``,
+and for a model with a sparse-attention indexer ``index_cache_shape``, a
+third cache of one head of indexer keys a position; ``cache_shapes``
+names them all; the cached forward is what reads them). This module owns
+what the engine keeps in that layout and nothing else knows how:
 
-- ``SlotPool``: K and V of one model's decode slots, the scratch a
-  prompt prefills into, and the program that makes a finished scratch a
-  slot. Built twice: for the target and for a speculative draft.
+- ``SlotPool``: K and V (and indexer keys) of one model's decode slots,
+  the scratch a prompt prefills into, and the program that makes a
+  finished scratch a slot. Built twice: for the target and for a
+  speculative draft.
 - ``BlockStore``: the prefix cache's blocks. A block's FORMAT is the
   tuple of arrays that hold it, which is also its wire form between
   replicas: ``"none"`` = (k, v) in the cache dtype; ``"int8"`` =
@@ -66,49 +69,71 @@ def replicated(mesh):
 
 class SlotPool:
     """``k``, ``v``: ``[n_layers, n_slots, length, Hkv, D]`` of one
-    model, sharded as the layout says (pruned against THIS model's
-    shape: a draft's KV heads may not divide the tensor axis). The step
-    programs take them donated and the engine rebinds them after each
-    call. ``scratch`` holds the (k, v) of prompts still prefilling, by
-    request id: one row of ``scratch_len`` positions, replicated."""
+    model, and ``ki`` ``[n_layers, n_slots, 1, DI, length]`` where the
+    model has an indexer (None where not), sharded as the layout says
+    (pruned against THIS model's shape: a draft's KV heads may not
+    divide the tensor axis). The step programs take them donated, as the
+    tuple ``pools()``, and the engine ``rebind``s them after each call.
+    ``scratch`` holds the same tuple of one row of ``scratch_len``
+    positions, replicated, for each prompt still prefilling, by request
+    id."""
 
     def __init__(self, mcfg, n_slots: int, length: int, slot_len: int,
                  scratch_len: int, dtype, mesh=None, rules=None):
         import jax
 
-        from ray_tpu.models.transformer import (kv_cache_shape,
+        from ray_tpu.models.transformer import (CACHE_POS_AXIS,
+                                                cache_shapes,
                                                 kv_cache_sharding)
         self.dtype = dtype
-        self.shape = kv_cache_shape(mcfg, n_slots, length)
-        self.scratch_shape = kv_cache_shape(mcfg, 1, scratch_len)
+        self.shapes = cache_shapes(mcfg, n_slots, length)
+        self.scratch_shapes = cache_shapes(mcfg, 1, scratch_len)
+        # K's (and V's) own, as they were named before there was a third
+        self.shape = self.shapes["k"]
+        self.scratch_shape = self.scratch_shapes["k"]
         self.sharding = (kv_cache_sharding(self.shape, mesh, rules)
                          if mesh is not None else None)
         self._scratch_sharding = replicated(mesh)
-        self.k = zeros(self.shape, dtype, self.sharding)
-        self.v = zeros(self.shape, dtype, self.sharding)
-        self.scratch: Dict[int, Tuple[Any, Any]] = {}
+        self.k = self.v = self.ki = None
+        self.rebind(tuple(
+            zeros(shape, dtype, kv_cache_sharding(shape, mesh, rules, name)
+                  if mesh is not None else None)
+            for name, shape in self.shapes.items()))
+        self.scratch: Dict[int, Tuple[Any, ...]] = {}
 
-        def insert(pk, pv, sk, sv, slot):
+        def insert(pools, scratch, slot):
             # scratch carries the largest tile of padding tail; the slot
             # takes the first slot_len entries
-            sk = sk[:, :, :slot_len]
-            sv = sv[:, :, :slot_len]
-            pk = jax.lax.dynamic_update_slice(pk, sk, (0, slot, 0, 0, 0))
-            pv = jax.lax.dynamic_update_slice(pv, sv, (0, slot, 0, 0, 0))
-            return pk, pv
+            return tuple(
+                jax.lax.dynamic_update_slice(
+                    p, jax.lax.slice_in_dim(s, 0, slot_len,
+                                            axis=CACHE_POS_AXIS[name]),
+                    (0, slot, 0, 0, 0))
+                for name, p, s in zip(self.shapes, pools, scratch))
 
-        self._insert_fn = jax.jit(insert, donate_argnums=(0, 1))
+        self._insert_fn = jax.jit(insert, donate_argnums=(0,))
+
+    def pools(self) -> Tuple[Any, ...]:
+        """(k, v) or (k, v, ki): the order of ``cache_shapes``."""
+        return tuple(getattr(self, n) for n in self.shapes)
+
+    def rebind(self, pools) -> None:
+        for name, pool in zip(self.shapes, pools):
+            setattr(self, name, pool)
+
+    def nbytes(self) -> int:
+        """Bytes of the slots' pools (not of the scratches)."""
+        return sum(int(np.prod(shape)) for shape in self.shapes.values()) \
+            * np.dtype(self.dtype).itemsize
 
     def new_scratch(self):
-        return (zeros(self.scratch_shape, self.dtype,
-                      self._scratch_sharding),
-                zeros(self.scratch_shape, self.dtype,
-                      self._scratch_sharding))
+        return tuple(zeros(shape, self.dtype, self._scratch_sharding)
+                     for shape in self.scratch_shapes.values())
 
     def insert(self, scratch, slot: int):
         """A finished prompt's scratch becomes slot ``slot``."""
-        self.k, self.v = self._insert_fn(self.k, self.v, *scratch,
-                                         np.int32(slot))
+        self.rebind(self._insert_fn(self.pools(), tuple(scratch),
+                                    np.int32(slot)))
 
 
 def span_format(span) -> str:

@@ -23,7 +23,7 @@ from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.sampling import sample_logits
-from ray_tpu.models.transformer import (init_cache, kv_cache_shape,
+from ray_tpu.models.transformer import (cache_shapes, init_cache,
                                         kv_cache_sharding)
 from ray_tpu.parallel import sharding as sharding_lib
 from ray_tpu.parallel.mesh import use_mesh
@@ -55,9 +55,9 @@ def make_generate_fn(model: nn.Module, mesh: Mesh, rules=None,
     param_sh = state_shardings(abstract, mesh, rules)
     init_fn = jax.jit(init_params, out_shardings=param_sh)
 
-    kv_sh = kv_cache_sharding(kv_cache_shape(cfg, batch, max_len), mesh,
-                              rules)
-    cache_sh = {"k": kv_sh, "v": kv_sh, "idx": NamedSharding(mesh, P())}
+    cache_sh = {name: kv_cache_sharding(shape, mesh, rules, name)
+                for name, shape in cache_shapes(cfg, batch, max_len).items()}
+    cache_sh["idx"] = NamedSharding(mesh, P())
 
     def _pick(logits, rng):
         # shared with the inference engine (models/sampling.py); static

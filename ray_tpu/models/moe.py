@@ -10,10 +10,41 @@ expert's matmul runs at full tile size. No counterpart in the reference
 as "absent — must be built natively").
 
 Routing (per batch row as the dispatch group):
-- softmax router in fp32, top-k experts per token, gates renormalized;
-- per-expert capacity C = ceil(capacity_factor * L * k / E); tokens over
-  capacity are dropped (standard Switch behavior, keeps shapes static);
+- softmax router in fp32 over ALL `n_experts`, top-k experts per token,
+  gates renormalized;
+- per-expert capacity C = ceil(capacity_factor * L * k / E). Training
+  drops the tokens over capacity (standard Switch behavior, keeps shapes
+  static); serving (`exact`: the cached forward) drops nothing: a pick
+  past its expert's capacity is computed by the overflow route below;
 - aux load-balancing loss (Switch eq. 4): E * Σ_e frac_tokens_e · mean_prob_e.
+
+What the layer holds: all `n_experts`, or the contiguous range
+`experts_held` = (first, count) of them — one rank's share of a layer that
+several chips divide. The router, the top-k and the gates are the whole
+layer's; the dispatch, the expert weights and the result are the held
+experts' alone: what the absent experts would add to a token is left out
+(no code stands in for the other ranks or their exchange), so the shares
+of all ranks add up to the whole layer's result.
+
+The cost of the expert matmuls is held-experts x groups x C rows, and the
+form is chosen from the shapes alone, here: where C is the group's length
+(few wide experts, Mixtral's 8 with top-2, at capacity_factor = E / k;
+any layer at one row a group) nothing can overflow and the dispatch is
+all there is; where C < L (many narrow experts: 128, top-8, 16 held would
+compute E / k = 16 times the rows really routed at C = L, and take a C
+about twice the expected load) the serving forward adds the overflow
+route: the picks past an expert's capacity, rare, go through every held
+expert over the whole group under a `lax.cond` that runs only in a step
+where some pick overflowed.
+
+Rows no request owns (the padded tail of a prefill tile; `real` False) are
+routed nowhere where that matters: their picks are taken out before the
+capacity is counted, so they fill no expert's capacity and are not counted.
+
+Counters: where the caller makes the "counters" collection mutable
+(`apply(..., mutable=["counters"])`, the engine for a layer that holds a
+share of its experts) the layer sows int32[2]: the rows its expert matmuls
+computed and the picks of real rows that landed on a held expert.
 """
 
 from __future__ import annotations
@@ -36,11 +67,15 @@ class MoEMLP(nn.Module):
     cfg: Any
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, real=None, exact: bool = False):
+        """-> (out, aux loss). `real` [B, L] bool: the rows a request
+        owns (None: all). `exact`: the serving forward, which drops no
+        pick (the module's docstring)."""
         cfg = self.cfg
         B, L, D = x.shape
         E, K = cfg.n_experts, cfg.expert_top_k
-        C = max(1, math.ceil(cfg.capacity_factor * L * K / E))
+        first, held = cfg.experts_held or (0, E)
+        C = min(L, max(1, math.ceil(cfg.capacity_factor * L * K / E)))
 
         router = self.param(
             "router", _p(nn.initializers.lecun_normal(), "embed", "experts"),
@@ -56,7 +91,13 @@ class MoEMLP(nn.Module):
         # in expert e's buffer is the number of earlier (token, slot) picks
         # of e, counting slots in priority order (slot 0 of every token
         # first — standard top-k dispatch priority)
-        sel = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)   # [B,L,K,E]
+        sel_all = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # [B,L,K,E]
+        counting = self.is_mutable_collection("counters")
+        if real is not None and (C < L or counting):
+            sel_all = sel_all * real[:, :, None, None]
+        # the held experts' columns; E below is their count from here on
+        sel = sel_all if held == E else sel_all[..., first:first + held]
+        E = held
         flat = sel.transpose(0, 2, 1, 3).reshape(B, K * L, E)  # slot-major
         pos_flat = jnp.cumsum(flat, axis=1) - flat             # [B,K*L,E]
         pos = pos_flat.reshape(B, K, L, E).transpose(0, 2, 1, 3)  # [B,L,K,E]
@@ -92,20 +133,44 @@ class MoEMLP(nn.Module):
             "down", _p(nn.initializers.lecun_normal(),
                        "experts", "mlp", "embed"),
             (E, cfg.d_ff, D), cfg.param_dtype)
-        h = jnp.einsum("ebcd,edf->ebcf", expert_in,
-                       w_gate.astype(cfg.dtype))
-        u = jnp.einsum("ebcd,edf->ebcf", expert_in, w_up.astype(cfg.dtype))
-        y = nn.silu(h) * u
-        expert_out = jnp.einsum("ebcf,efd->ebcd", y,
-                                w_down.astype(cfg.dtype))
+        def experts(rows):                       # [E, .., D] -> [E, .., D]
+            h = jnp.einsum("ebcd,edf->ebcf", rows,
+                           w_gate.astype(cfg.dtype))
+            u = jnp.einsum("ebcd,edf->ebcf", rows, w_up.astype(cfg.dtype))
+            y = nn.silu(h) * u
+            return jnp.einsum("ebcf,efd->ebcd", y,
+                              w_down.astype(cfg.dtype))
+
+        with jax.named_scope("moe_experts"):
+            expert_out = experts(expert_in)
         expert_out = constrain(expert_out,
                                ("experts", None, None, "embed"))
 
         out = jnp.einsum("blec,ebcd->bld",
                          combine.astype(x.dtype), expert_out)
 
+        rows = E * B * C
+        if exact and C < L:
+            # the exact overflow route: a pick past its expert's capacity
+            # is computed by running every held expert over the whole
+            # group, weighted by the overflowed picks' gates alone
+            spill = jnp.einsum("blk,blke->ble", gate_vals * (1.0 - keep),
+                               sel)                            # [B,L,E]
+            spilled = jnp.any(spill > 0)
+            out = out + jax.lax.cond(
+                spilled,
+                lambda: jnp.einsum(
+                    "ble,ebld->bld", spill.astype(x.dtype),
+                    experts(jnp.broadcast_to(x, (E,) + x.shape))),
+                lambda: jnp.zeros_like(out))
+            rows = rows + spilled.astype(jnp.int32) * (E * B * L)
+        if counting:
+            # what `moe_rows_per_pick` divides
+            self.sow("counters", "rows_and_picks", jnp.stack(
+                [jnp.asarray(rows, jnp.int32), sel.sum().astype(jnp.int32)]))
+
         # Switch load-balance loss: encourages uniform routing
-        frac_tokens = sel.sum((1, 2)) / (L * K)                # [B,E]
+        frac_tokens = sel_all.sum((1, 2)) / (L * K)            # [B,E]
         mean_probs = probs.mean(1)                             # [B,E]
-        aux = E * (frac_tokens * mean_probs).sum(-1).mean()
+        aux = cfg.n_experts * (frac_tokens * mean_probs).sum(-1).mean()
         return out, aux

@@ -1,0 +1,284 @@
+"""Learned sparse attention: an indexer chooses, for every query, the
+positions its attention heads attend (DeepSeek-V3.2-Exp's published
+equations 1 and 2, beside grouped-query attention).
+
+With `qI_t` [J, DI] the indexer's query heads at position t, `kI_s` [DI]
+the ONE indexer key of position s and `w_t` [J] the head weights:
+
+    I(t, s) = sum_j w_t[j] * relu(qI_t[j] . kI_s)        for s <= t
+    S_t     = the min(topk, t + 1) positions s <= t of largest I(t, s),
+              ties to the lower position
+    o_t[h]  = sum_{s in S_t} softmax_s(q_t[h] . k_s[g(h)] / sqrt(D)) v_s[g(h)]
+
+Three forms of the same mathematics, chosen by the caller from what it
+holds (models/transformer.py `Attention`):
+
+- `sparse_attention`: the plainest, a sequence against itself, the index
+  scores and the mask whole. The uncached forward (training, the one-shot
+  prefill of `make_generate_fn`).
+- `sparse_prefill_attention`: a tile of S rows against a cache that holds
+  them. Blocked over the keys, and only over the blocks that hold a live
+  position: the index scores are accumulated over the indexer's heads (no
+  [.., J, M] array), the topk-th score of each row is found exactly by a
+  radix search over the scores' bits (no sort), and the masked attention
+  runs block by block with a running softmax, so no [.., S, M] score of
+  the attention heads exists.
+- `sparse_decode_attention`: one row a slot against the slot's cache.
+  An exact sort of the live index scores, then a GATHER of the selected
+  rows of K and V: the cache's other rows are not read.
+
+Selection is exact in all three (no approximate top-k, no block-level
+stand-in); at t + 1 <= topk it is every live position.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+_NEG = -1e30
+_MAX_BLOCK = 512        # keys a block of the blocked forms holds
+_ONE_PASS = 1 << 20     # index scores of up to this many are one pass
+_SELECT_BUCKETS = 4     # prefixes of the cache a tile's selection may run on
+
+
+def _block_of(m: int) -> int:
+    """The largest power of two up to _MAX_BLOCK that divides M."""
+    b = _MAX_BLOCK
+    while m % b:
+        b //= 2
+    return b
+
+
+def _head_scores(qi, w, ki):
+    """sum_j w[.., j] relu(qi[.., j, :] . ki[m]) -> [B, S, M] float32.
+    ki [B, 1, DI, M]: the indexer has one KV head, positions last. A tile
+    of queries takes one indexer head at a time, so the per-head scores
+    never exist together ([S, J, M] float32 is 1.2 GB at 1024 x 18k); one
+    row a slot takes the heads in one matmul (16 matmuls of one row each
+    cost a decode step 0.18 ms a layer, my chip run, PR 34)."""
+    ki = ki[:, 0]
+    w = w.astype(jnp.float32)
+    if qi.shape[1] == 1:
+        s = jnp.einsum("bsjd,bdm->bsjm", qi, ki,
+                       preferred_element_type=jnp.float32)
+        return jnp.sum(w[..., None] * jax.nn.relu(s), axis=2)
+    acc = None
+    for j in range(qi.shape[2]):
+        s = jnp.einsum("bsd,bdm->bsm", qi[:, :, j], ki,
+                       preferred_element_type=jnp.float32)
+        s = w[:, :, j, None] * jax.nn.relu(s)
+        acc = s if acc is None else acc + s
+    return acc
+
+
+def index_scores(qi, w, ki, qpos, layer=None):
+    """I [B, S, M] float32 of queries at absolute positions `qpos` [B, S]
+    against the indexer keys `ki` [B, 1, DI, M] of positions 0..M-1 (or,
+    with `layer`, that layer of a pool [n_layers, B, 1, DI, M]); -inf
+    where the key lies after the query. A tile of queries is blocked over
+    the keys, and a block past the last query's position is not read; a
+    few rows (one a slot, in decode) score the slot in one pass: a block
+    of [B, 512] is too little work to pay for a loop's step."""
+    B, S = qpos.shape
+    if layer is None:
+        ki, layer = ki[None], 0
+    DI, M = ki.shape[3], ki.shape[4]
+    block = M if B * S * M <= _ONE_PASS else _block_of(M)
+    n_live = jnp.minimum((jnp.max(qpos) + block) // block, M // block)
+
+    def scores_of(i):
+        kb = jax.lax.dynamic_slice(ki, (layer, 0, 0, 0, i * block),
+                                   (1, B, 1, DI, block))[0]
+        kpos = i * block + jnp.arange(block)
+        return jnp.where(kpos[None, None, :] <= qpos[:, :, None],
+                         _head_scores(qi, w, kb), -jnp.inf)
+
+    with jax.named_scope("dsa_indexer"):
+        if block == M:
+            return scores_of(0)
+        return jax.lax.fori_loop(
+            0, n_live,
+            lambda i, out: jax.lax.dynamic_update_slice_in_dim(
+                out, scores_of(i), i * block, 2),
+            jnp.full((B, S, M), -jnp.inf, jnp.float32))
+
+
+def _sortable(x):
+    """float32 -> uint32 whose unsigned order is the floats' order. Only
+    a NaN maps to 0, so 0 is free to mean 'no such position'; -inf maps
+    above it."""
+    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
+
+
+def kth_largest(keys, k: int, bits: int = 2):
+    """Per row of `keys` [.., M] uint32: the largest t with
+    count(keys >= t) >= k, which is the k-th largest key (0 where the row
+    has fewer than k keys above 0). A radix search from the top bits
+    down, `bits` at a time: 32 / bits passes over the row, each counting
+    against 2**bits - 1 candidates (2 bits: 1.4 ms for [1024, 18432]
+    against 2.0 ms at 4 and a sort's 23, my chip run, PR 34). Exact."""
+    prefix = jnp.zeros(keys.shape[:-1], jnp.uint32)
+    digits = jnp.arange(1, 1 << bits, dtype=jnp.uint32)
+    for p in range(32 // bits):
+        shift = 32 - bits * (p + 1)
+        cands = prefix[..., None] | (digits << shift)        # [.., n]
+        cnt = jnp.sum(keys[..., None, :] >= cands[..., :, None],
+                      axis=-1, dtype=jnp.int32)
+        digit = jnp.sum(cnt >= k, axis=-1).astype(jnp.uint32)
+        prefix = prefix | (digit << shift)
+    return prefix
+
+
+def select(scores, topk: int):
+    """The selected set as a mask [.., M] over `scores` [.., M] (-inf
+    where a position is not live): the topk largest, every live position
+    where there are no more than topk, ties at the topk-th score to the
+    lower position."""
+    with jax.named_scope("dsa_select"):
+        keys = jnp.where(scores > -jnp.inf, _sortable(scores),
+                         jnp.uint32(0))
+        thr = jnp.maximum(kth_largest(keys, topk), jnp.uint32(1))[..., None]
+        above = keys > thr
+        tied = keys == thr
+        room = topk - jnp.sum(above, axis=-1, keepdims=True,
+                              dtype=jnp.int32)
+        # nearly always the tied are one position a row and all fit; the
+        # ranking by position is computed only where some row's do not
+        fits = jax.lax.cond(
+            jnp.any(jnp.sum(tied, axis=-1, keepdims=True,
+                            dtype=jnp.int32) > room),
+            lambda: jnp.cumsum(tied, axis=-1, dtype=jnp.int32) <= room,
+            lambda: jnp.ones(tied.shape, bool))
+        return above | (tied & fits)
+
+
+def select_live(scores, topk: int, n_live):
+    """`select` for a tile against a long cache of which `n_live`
+    positions (traced) can be live: the search's passes run over the
+    shortest of a few static prefixes of the cache that holds them, and
+    not at all where every live position is selected (n_live <= topk).
+    The result is `select`'s."""
+    M = scores.shape[-1]
+    step = -(-M // _SELECT_BUCKETS)
+    prefixes = [m for m in range(step, M, step) if m > topk] + [M]
+    if M <= topk:
+        return scores > -jnp.inf
+
+    def on_prefix(m):
+        return lambda: jnp.pad(
+            select(scores[..., :m], topk),
+            [(0, 0)] * (scores.ndim - 1) + [(0, M - m)])
+
+    branches = [lambda: scores > -jnp.inf] + [on_prefix(m)
+                                              for m in prefixes]
+    bounds = jnp.asarray([topk] + prefixes[:-1], jnp.int32)
+    return jax.lax.switch(jnp.sum(n_live > bounds), branches)
+
+
+def masked_attention(q, k, v, mask):
+    """softmax over the masked positions, whole: q [B, S, H, D], k and v
+    [B, M, Hkv, D], mask [B, S, M]. Grouped-query: each KV head serves
+    H / Hkv query heads, against the unexpanded k and v."""
+    B, S, H, D = q.shape
+    Hkv = k.shape[2]
+    qg = q.reshape(B, S, Hkv, H // Hkv, D)
+    s = jnp.einsum("bshgd,bmhd->bhgsm", qg, k,
+                   preferred_element_type=jnp.float32) * D ** -0.5
+    s = jnp.where(mask[:, None, None], s, _NEG)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhgsm,bmhd->bshgd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, S, H, D).astype(q.dtype)
+
+
+def sparse_attention(q, k, v, qi, w, ki, topk: int):
+    """A sequence against itself: positions 0..L-1 of every row."""
+    B, L = q.shape[:2]
+    qpos = jnp.broadcast_to(jnp.arange(L)[None, :], (B, L))
+    mask = select(index_scores(qi, w, ki, qpos), topk)
+    with jax.named_scope("dsa_attend"):
+        return masked_attention(q, k, v, mask)
+
+
+def sparse_prefill_attention(q, k_cache, v_cache, qi, w, ki_cache, pos0,
+                             topk: int):
+    """A tile q [B, S, H, D] at absolute positions pos0 + 0..S-1 (pos0 a
+    scalar or [B]) against caches [B, M, ..] that already hold the tile's
+    own rows. The attention is blocked over the keys with a running
+    softmax, over the blocks up to the tile's last position only."""
+    B, S, H, D = q.shape
+    M, Hkv = k_cache.shape[1], k_cache.shape[2]
+    G = H // Hkv
+    qpos = jnp.reshape(pos0, (-1, 1)) + jnp.arange(S)[None, :]
+    qpos = jnp.broadcast_to(qpos, (B, S))
+    sel = select_live(index_scores(qi, w, ki_cache, qpos), topk,
+                      jnp.max(qpos) + 1)
+    block = _block_of(M)
+    n_live = jnp.minimum((jnp.max(qpos) + block) // block, M // block)
+    qg = q.reshape(B, S, Hkv, G, D)
+
+    def body(i, carry):
+        m, l, acc = carry
+        kb = jax.lax.dynamic_slice_in_dim(k_cache, i * block, block, 1)
+        vb = jax.lax.dynamic_slice_in_dim(v_cache, i * block, block, 1)
+        mb = jax.lax.dynamic_slice_in_dim(sel, i * block, block, 2)
+        mb = mb[:, None, None]                              # [B,1,1,S,blk]
+        s = jnp.einsum("bshgd,bmhd->bhgsm", qg, kb,
+                       preferred_element_type=jnp.float32) * D ** -0.5
+        s = jnp.where(mb, s, _NEG)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.where(mb, jnp.exp(s - m_new[..., None]), 0.0)
+        scale = jnp.exp(m - m_new)
+        l = l * scale + jnp.sum(p, axis=-1)
+        acc = acc * scale[..., None] + jnp.einsum(
+            "bhgsm,bmhd->bhgsd", p.astype(vb.dtype), vb,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    with jax.named_scope("dsa_attend"):
+        m0 = jnp.full((B, Hkv, G, S), _NEG, jnp.float32)
+        _, l, acc = jax.lax.fori_loop(
+            0, n_live, body,
+            (m0, jnp.zeros_like(m0), jnp.zeros((B, Hkv, G, S, D),
+                                               jnp.float32)))
+        out = acc / jnp.maximum(l, 1e-30)[..., None]
+        return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, D).astype(
+            q.dtype)
+
+
+def sparse_decode_attention(q, k_new, v_new, qi, w, ki_new, k_cache,
+                            v_cache, ki_cache, lens, topk: int, layer=None):
+    """One row a slot: q [B, 1, H, D] at position lens[b] (a scalar or
+    [B]); the caches [B, M, ..] (or, with `layer`, that layer of the
+    pools [n_layers, B, M, ..]) hold positions below lens[b] and are only
+    read: the row's own k, v and indexer key come beside them and stand
+    as position M of the selection. Of K and V only the selected rows are
+    read, by ONE gather of min(topk, M + 1) rows a slot straight out of
+    the pool (a layer sliced out of it first would be copied whole)."""
+    B = q.shape[0]
+    if layer is None:
+        k_cache, v_cache, ki_cache, layer = (k_cache[None], v_cache[None],
+                                             ki_cache[None], 0)
+    M = k_cache.shape[2]
+    lens = jnp.broadcast_to(jnp.reshape(lens, (-1,)), (B,))
+    past = index_scores(qi, w, ki_cache, lens[:, None] - 1, layer)[:, 0]
+    with jax.named_scope("dsa_indexer"):
+        own = _head_scores(qi, w, ki_new)[:, 0]                    # [B,1]
+    with jax.named_scope("dsa_select"):
+        # exact: a stable sort by falling score, whose first topk are the
+        # set with ties to the lower position (the row itself, the
+        # highest position, stands last)
+        scores = jnp.concatenate([past, own], axis=-1)           # [B,M+1]
+        idx = jnp.argsort(-scores, axis=-1, stable=True)[
+            :, :min(topk, M + 1)]
+        # the live positions sort first: lens[b] in the cache and the row
+        live = jnp.arange(idx.shape[1])[None, :] <= lens[:, None]
+        is_own = (idx == M)[:, :, None, None]
+        at = jnp.minimum(idx, M - 1)
+    with jax.named_scope("dsa_attend"):
+        rows = jnp.arange(B)[:, None]
+        kg = jnp.where(is_own, k_new, k_cache[layer, rows, at])
+        vg = jnp.where(is_own, v_new, v_cache[layer, rows, at])
+        return masked_attention(q, kg, vg, live[:, None, :])

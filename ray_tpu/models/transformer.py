@@ -60,10 +60,29 @@ class TransformerConfig:
     expert_top_k: int = 2
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    # the experts this program holds of a layer's n_experts, as (first,
+    # count): a contiguous range, one rank's share of a layer that several
+    # chips divide. The router keeps all n_experts outputs; the layer
+    # computes the held experts' part of the result (models/moe.py). None:
+    # every expert
+    experts_held: Optional[Tuple[int, int]] = None
+    # the size of an attention head; 0 (unstated) = d_model // n_heads
+    head_dim: int = 0
+    # RMSNorm on each head of q and k, before the rotary
+    qk_norm: bool = False
+    # learned sparse attention (models/sparse_attention.py): in every layer
+    # an indexer of `index_heads` query heads of `index_head_dim` against
+    # ONE indexer key a position scores the earlier positions, and the
+    # attention heads attend the `index_topk` best of them. 0 heads: the
+    # model has no indexer and attends every earlier position
+    index_heads: int = 0
+    index_head_dim: int = 64
+    index_topk: int = 2048
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim",
+                               self.d_model // self.n_heads)
 
 
 _PARTITION_OFF = __import__("threading").local()
@@ -94,10 +113,11 @@ class unpartitioned_params:
 class RMSNorm(nn.Module):
     eps: float
     dtype: Any
+    axis: str = "embed"     # the logical axis of the normed dimension
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", _p(nn.initializers.ones, "embed"),
+        scale = self.param("scale", _p(nn.initializers.ones, self.axis),
                            (x.shape[-1],), jnp.float32)
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True)
@@ -147,7 +167,7 @@ def _cached_attention(q, k_cache, v_cache, q_pos0):
     return out.reshape(B, S, H, D).astype(q.dtype)
 
 
-def _cache_write(cache, new, idx):
+def _cache_write(cache, new, idx, pos_axis: int = -3):
     """Write `new` [..., B, L, Hkv, D] into `cache` [..., B, M, Hkv, D]
     at position `idx` of every row: a scalar (all rows share one write
     offset) or a [B] vector (per-slot offsets — each row lands at its
@@ -156,13 +176,27 @@ def _cache_write(cache, new, idx):
     layers' new rows in ONE write). Only the new rows move: the update
     operand is `new`, never [.., M, ..], so a donated pool is updated in
     place. Out-of-range starts are clamped, so a full/free slot writes
-    at M-L harmlessly."""
+    at M-L harmlessly. `pos_axis` is where the positions lie (the
+    indexer's keys keep them last: `index_cache_shape`)."""
     new = new.astype(cache.dtype)
     b_axis = cache.ndim - 4
+    p_axis = cache.ndim + pos_axis
     if jnp.ndim(idx) == 0:
         start = [0] * cache.ndim
-        start[b_axis + 1] = idx
+        start[p_axis] = idx
         return jax.lax.dynamic_update_slice(cache, new, start)
+    if p_axis == cache.ndim - 1:
+        # positions in the lanes (the indexer's keys): a scatter there
+        # makes XLA relay the whole pool for the write and back (2.7 ms a
+        # step at 8 slots x 17k, my chip run, PR 34); a row at a time is
+        # written in place
+        for b in range(cache.shape[b_axis]):
+            start = [0] * cache.ndim
+            start[b_axis], start[p_axis] = b, idx[b]
+            cache = jax.lax.dynamic_update_slice(
+                cache, jax.lax.slice_in_dim(new, b, b + 1, axis=b_axis),
+                start)
+        return cache
     # one scatter batched over the rows (so a cache sharded over rows
     # stays local to its shard): row b's window [..., L, Hkv, D] lands
     # at position idx[b]
@@ -172,16 +206,34 @@ def _cache_write(cache, new, idx):
             update_window_dims=tuple(
                 a for a in range(cache.ndim) if a != b_axis),
             inserted_window_dims=(),
-            scatter_dims_to_operand_dims=(b_axis + 1,),
+            scatter_dims_to_operand_dims=(p_axis,),
             operand_batching_dims=(b_axis,),
             scatter_indices_batching_dims=(0,)),
         indices_are_sorted=True, unique_indices=True,
         mode=jax.lax.GatherScatterMode.CLIP)
 
 
+class LayerNorm(nn.Module):
+    """Mean and variance over the last axis in float32, scale and bias."""
+    eps: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", _p(nn.initializers.ones, None),
+                           (x.shape[-1],), jnp.float32)
+        bias = self.param("bias", _p(nn.initializers.zeros, None),
+                          (x.shape[-1],), jnp.float32)
+        x32 = x.astype(jnp.float32)
+        x32 = x32 - jnp.mean(x32, -1, keepdims=True)
+        y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True)
+                                + self.eps)
+        return (y * scale + bias).astype(self.dtype)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
-    # static: route L>1 cache writes through _cached_attention (prefill
+    # static: route L>1 cache writes through the cached attention (prefill
     # CONTINUES an occupied cache — chunked prefill) instead of assuming
     # an empty cache and using the fused kernel
     chunked: bool = False
@@ -189,12 +241,13 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, positions, cache=None):
         """cache=None: training/prefill forward (flash/ring dispatch),
-        returns out. cache=(k_layer, v_layer, idx): serving decode —
-        this layer's K and V [B,M,Hkv,D] as read out of the pool; the
-        call's own K/V rows are placed in that read-out at [idx, idx+L)
-        (idx scalar or per-slot [B] vector) for attention to see, and
-        returned as (out, (k_rows, v_rows)) [B,L,Hkv,D] for the caller
-        to add to the pool: the pool itself is not written here."""
+        returns out. cache=(rows, idx): serving decode — `rows` is this
+        layer's K and V [B,M,Hkv,D] as read out of the pool (and, where
+        the model has an indexer, its indexer keys [B,M,DI]); the call's
+        own rows are placed in that read-out at [idx, idx+L) (idx scalar
+        or per-slot [B] vector) for attention to see, and returned as
+        (out, new_rows) [B,L,..], in the pools' order, for the caller to
+        add to the pools: the pools themselves are not written here."""
         cfg = self.cfg
         B, L, E = x.shape
         H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -205,6 +258,11 @@ class Attention(nn.Module):
         q = dense((H, D), ("embed", "heads", "head_dim"), "q")(x)
         k = dense((Hkv, D), ("embed", "kv_heads", "head_dim"), "k")(x)
         v = dense((Hkv, D), ("embed", "kv_heads", "head_dim"), "v")(x)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.norm_eps, cfg.dtype, "head_dim",
+                        name="q_norm")(q)
+            k = RMSNorm(cfg.norm_eps, cfg.dtype, "head_dim",
+                        name="k_norm")(k)
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
         proj = nn.DenseGeneral(
@@ -212,11 +270,13 @@ class Attention(nn.Module):
             param_dtype=cfg.param_dtype, name="o",
             kernel_init=_p(nn.initializers.lecun_normal(),
                            "heads", "head_dim", "embed"))
+        if cfg.index_heads:
+            return self._sparse(x, positions, cache, q, k, v, proj, dense)
         if cache is None:
             out = attention_dispatch(q, k, v, causal=True,
                                      impl=cfg.attention_impl)
             return proj(out)
-        k_layer, v_layer, idx = cache
+        (k_layer, v_layer), idx = cache
         if L > 1 and not self.chunked:
             # one-shot prefill (L is static): the block attends only
             # within itself, so the fused flash/ring kernel computes it
@@ -231,6 +291,45 @@ class Attention(nn.Module):
             out = _cached_attention(q, _cache_write(k_layer, k, idx),
                                     _cache_write(v_layer, v, idx), idx)
         return proj(out), (k, v)
+
+    def _sparse(self, x, positions, cache, q, k, v, proj, dense):
+        """The model with an indexer (models/sparse_attention.py): the
+        indexer's queries, its one key a position and its head weights,
+        then the form of the selection and attention that fits what the
+        call holds: a sequence alone, a tile against a cache, or one row
+        a slot."""
+        from ray_tpu.models import sparse_attention as sa
+        cfg = self.cfg
+        L = x.shape[1]
+        J, DI, topk = cfg.index_heads, cfg.index_head_dim, cfg.index_topk
+        with jax.named_scope("dsa_indexer"):
+            qi = rope(dense((J, DI), ("embed", None, None), "index_q")(x),
+                      positions, cfg.rope_theta)
+            ki = LayerNorm(cfg.norm_eps, cfg.dtype, name="index_k_norm")(
+                dense((DI,), ("embed", None), "index_k")(x))
+            # [B,1,DI,L]: the indexer's ONE KV head, positions last as the
+            # third cache keeps them (index_cache_shape)
+            ki = rope(ki[:, :, None, :], positions,
+                      cfg.rope_theta).transpose(0, 2, 3, 1)
+            w = dense((J,), ("embed", None), "index_w")(x)
+        if cache is None:
+            return proj(sa.sparse_attention(q, k, v, qi, w, ki, topk))
+        (k_layer, v_layer, ki_layer), idx, *layer = cache
+        if L > 1 and not self.chunked:
+            # one-shot prefill from an EMPTY cache, as above
+            out = sa.sparse_attention(q, k, v, qi, w, ki, topk)
+        elif L > 1:
+            out = sa.sparse_prefill_attention(
+                q, _cache_write(k_layer, k, idx),
+                _cache_write(v_layer, v, idx), qi, w,
+                _cache_write(ki_layer, ki, idx, CACHE_POS_AXIS["ki"]),
+                idx, topk)
+        else:
+            # `layer` given: the three are the whole pools, read in place
+            out = sa.sparse_decode_attention(
+                q, k, v, qi, w, ki, k_layer, v_layer, ki_layer, idx, topk,
+                *layer)
+        return proj(out), (k, v, ki)
 
 
 class MLP(nn.Module):
@@ -254,7 +353,7 @@ class Block(nn.Module):
     chunked: bool = False
 
     @nn.compact
-    def __call__(self, x, positions, cache=None):
+    def __call__(self, x, positions, cache=None, real=None):
         cfg = self.cfg
         att = Attention(cfg, self.chunked, name="attn")(
             RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x),
@@ -266,7 +365,9 @@ class Block(nn.Module):
         normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(h)
         if cfg.n_experts > 0:
             from ray_tpu.models.moe import MoEMLP
-            y, aux = MoEMLP(cfg, name="moe")(normed)
+            # serving drops no pick; `real`: the rows a request owns
+            y, aux = MoEMLP(cfg, name="moe")(normed, real,
+                                             exact=cache is not None)
         else:
             y, aux = MLP(cfg, name="mlp")(normed), jnp.zeros((), jnp.float32)
         if cache is not None:
@@ -294,20 +395,21 @@ class ScanBlock(nn.Module):
 
 
 class DecodeScanBlock(nn.Module):
-    """Scan body for the serving decode path: the layer's K and V ride
-    in as a scanned input (axis 0 of the pools = layers), READ-ONLY, and
-    only the call's new rows [B,L,Hkv,D] come back in the ys — never the
-    layer, so no pool is stacked up again. Param names mirror ScanBlock
-    ('block' under the scan) so the SAME trained/stacked params apply."""
+    """Scan body for the serving decode path: the layer's K and V (and
+    indexer keys) ride in as a scanned input (axis 0 of the pools =
+    layers), READ-ONLY, and only the call's new rows [B,L,Hkv,D] come
+    back in the ys — never the layer, so no pool is stacked up again.
+    Param names mirror ScanBlock ('block' under the scan) so the SAME
+    trained/stacked params apply."""
     cfg: TransformerConfig
     chunked: bool = False
 
     @nn.compact
-    def __call__(self, carry, cache_kv):
-        x, positions, idx = carry
+    def __call__(self, carry, layer_rows, *layer):
+        x, positions, idx, real = carry
         out, _aux, new_rows = Block(self.cfg, self.chunked, name="block")(
-            x, positions, (cache_kv[0], cache_kv[1], idx))
-        return (out, positions, idx), new_rows
+            x, positions, (layer_rows, idx, *layer), real)
+        return (out, positions, idx, real), new_rows
 
 
 def kv_cache_shape(cfg: TransformerConfig, batch: int,
@@ -320,30 +422,62 @@ def kv_cache_shape(cfg: TransformerConfig, batch: int,
     return (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
 
 
-def kv_cache_sharding(shape, mesh, rules=None):
+def index_cache_shape(cfg: TransformerConfig, batch: int,
+                      max_len: int) -> Tuple[int, ...]:
+    """The layout of the third cache, the indexer's keys,
+    [n_layers, B, 1, DI, max_len]: the indexer's ONE KV head of
+    `index_head_dim`, and the positions LAST. A head of 64 is half a
+    vector register's lanes, so a [.., max_len, 1, 64] array is stored
+    padded or relaid at every use; with the positions in the lanes it is
+    stored dense, and it is the operand the index-score matmul wants
+    (it contracts DI against the queries)."""
+    return (cfg.n_layers, batch, 1, cfg.index_head_dim, max_len)
+
+
+# where the positions lie in each pool's layout, counted from its end (so
+# it holds for a pool and for one layer of it); also the order every tuple
+# of a model's pools keeps
+CACHE_POS_AXIS = {"k": -3, "v": -3, "ki": -1}
+
+
+def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int):
+    """{name: shape} of the pools a cache of this model carries: K, V
+    and, where the model has an indexer, its keys."""
+    kv = kv_cache_shape(cfg, batch, max_len)
+    shapes = {"k": kv, "v": kv}
+    if cfg.index_heads:
+        shapes["ki"] = index_cache_shape(cfg, batch, max_len)
+    return shapes
+
+
+def kv_cache_sharding(shape, mesh, rules=None, name: str = "k"):
     """That layout on a mesh: batch over the data axes, KV heads over
     `tensor` (the split the k/v projection weights carry), an axis the
-    shape does not divide left replicated."""
+    shape does not divide left replicated. The indexer's keys
+    (`name="ki"`) have one head: batch over the data axes alone."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ray_tpu.parallel import sharding as sharding_lib
     from ray_tpu.parallel.train_step import (_prune_indivisible,
                                              logical_pspec_to_mesh)
-    spec = logical_pspec_to_mesh(P(None, "batch", None, "kv_heads", None),
-                                 rules or sharding_lib.DEFAULT_RULES)
+    spec = logical_pspec_to_mesh(
+        P(None, "batch", None, "kv_heads" if name != "ki" else None, None),
+        rules or sharding_lib.DEFAULT_RULES)
     return NamedSharding(mesh, _prune_indivisible(spec, shape, mesh))
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                dtype=None):
-    """Fresh KV cache pytree: {'k','v': kv_cache_shape(...),
-    'idx': next write position (scalar int32)}. Each of 'k' and 'v' is
-    ONE pool for all layers; the cached forward returns the same pool
-    with this call's rows added in place (see TransformerLM._decode)."""
+    """Fresh KV cache pytree: {'k','v': kv_cache_shape(...), 'ki':
+    index_cache_shape(...) where the model has an indexer, 'idx': next
+    write position (scalar int32)}. Each of them is ONE pool for all
+    layers; the cached forward returns the same pool with this call's
+    rows added in place (see TransformerLM._decode)."""
     dtype = dtype or cfg.dtype
-    shape = kv_cache_shape(cfg, batch, max_len)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-            "idx": jnp.zeros((), jnp.int32)}
+    cache = {name: jnp.zeros(shape, dtype)
+             for name, shape in cache_shapes(cfg, batch, max_len).items()}
+    cache["idx"] = jnp.zeros((), jnp.int32)
+    return cache
 
 
 class TransformerLM(nn.Module):
@@ -461,8 +595,9 @@ class TransformerLM(nn.Module):
     def _decode(self, x, positions, cache, embed, return_hidden,
                 chunked_prefill=False):
         """Serving decode forward: applies every layer against the KV
-        cache and returns (logits|hidden, new_cache). The two pools
-        cache["k"], cache["v"] [n_layers,B,M,Hkv,D] are only READ by the
+        cache and returns (logits|hidden, new_cache). The pools
+        cache["k"], cache["v"] [n_layers,B,M,Hkv,D] (and cache["ki"], the
+        indexer's keys, where the model has them) are only READ by the
         layer loop: layer i reads pool[i], places its new [B,L,Hkv,D]
         rows in that read-out for its attention, and hands the rows
         back; after the loop ONE write per pool adds all layers' rows
@@ -475,28 +610,39 @@ class TransformerLM(nn.Module):
         cfg = self.cfg
         L = x.shape[1]
         idx = cache["idx"]
+        names = tuple(n for n in CACHE_POS_AXIS if n in cache)
+        pools = tuple(cache[n] for n in names)
+        # [B, L] bool, the rows a request owns (a prefill tile's padded
+        # tail is not; absent: all): the expert layer routes no other
+        real = cache.get("real") if cfg.n_experts > 0 else None
+        # one row a slot of a model with an indexer GATHERS its K and V:
+        # the layers' loop is handed the whole pools and the layer's
+        # number, not the layer sliced out (which would be copied whole
+        # to be gathered from)
+        whole = bool(cfg.index_heads) and L == 1
         if cfg.scan_layers:
             stack = nn.scan(
                 DecodeScanBlock,
-                variable_axes={"params": 0},
+                variable_axes={"params": 0, "counters": 0},
                 split_rngs={"params": True},
-                in_axes=0,
+                in_axes=(nn.broadcast, 0) if whole else 0,
                 length=cfg.n_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, chunked_prefill, name="layers")
-            (x, _, _), (k_rows, v_rows) = stack(
-                (x, positions, idx), (cache["k"], cache["v"]))
+            (x, _, _, _), rows = stack(
+                (x, positions, idx, real), pools,
+                *((jnp.arange(cfg.n_layers),) if whole else ()))
         else:
             rows = []
             for i in range(cfg.n_layers):
                 x, _aux, new_rows = Block(
                     cfg, chunked_prefill, name=f"layer_{i}")(
-                    x, positions, (cache["k"][i], cache["v"][i], idx))
+                    x, positions, (tuple(p[i] for p in pools), idx), real)
                 rows.append(new_rows)
-            k_rows, v_rows = (jnp.stack(r) for r in zip(*rows))
-        k_new = _cache_write(cache["k"], k_rows, idx)
-        v_new = _cache_write(cache["v"], v_rows, idx)
-        new_cache = {"k": k_new, "v": v_new, "idx": idx + L}
+            rows = tuple(jnp.stack(r) for r in zip(*rows))
+        new_cache = {n: _cache_write(p, r, idx, CACHE_POS_AXIS[n])
+                     for n, p, r in zip(names, pools, rows)}
+        new_cache["idx"] = idx + L
         x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
         if return_hidden:
             return x, new_cache
