@@ -22,25 +22,33 @@ batching in the Gemma-on-TPU serving stack):
   split across steps, and the tokens one step gives one request run as
   ONE fixed-shape tile through the cached-attention path
   (``chunked_prefill=True``; the forward is told which of the tile's
-  rows the request owns, its padded tail is zeros) into a scratch cache, so the weights
-  stream once a request a step and admission never stalls in-flight
-  decodes for more than ``prefill_budget`` tokens of work; the tile is
-  chosen from the prompt's length, so every token of a prompt passes
-  through one program whatever shared its steps — and (b)
-  advances EVERY occupied slot one token in a single batched decode
-  step (per-slot ``idx`` vector: each row attends and writes at its own
-  length).
+  rows the request owns, its padded tail is zeros) into a scratch cache,
+  so admission never stalls in-flight decodes for more than
+  ``prefill_budget`` tokens of work; the tile is chosen from the
+  prompt's length, so every token of a prompt passes through one
+  program whatever shared its steps — and (b) advances EVERY occupied
+  slot one token (per-slot ``idx`` vector: each row attends and writes
+  at its own length). In a step that carries prompt, (a) and (b) are ONE
+  program (``prefill``): the sequence is the tile's rows and behind them
+  one decode row a slot, everything but attention runs once over all of
+  them, so every weight streams once a step; a step with no prompt runs
+  the decode program (``decode``), and the two are the only step
+  programs. The tile's K/V never depend on the rows behind it. (Two
+  engines keep two calls a step: with a speculative draft, whose step
+  replaces decode, and with a model that has an indexer, whose decode
+  row is not bound by the weights' stream; ``_build_fns``.)
 - Tokens stream out per request through ``RequestHandle`` queues;
   slots are evicted (and immediately reusable) on EOS, max-tokens,
   slot-capacity, cancellation, or deadline.
 
 Shapes are static everywhere — tokens [n_slots], lengths [n_slots],
-prompt tiles [1, T] with T ``prefill_budget`` and, where that is four
-chunks or more, a handful of shorter lengths (``prefill_tiles``), every
-one compiled when the engine is built — so XLA compiles the prefill tiles,
-the slot insert and the decode step, and nothing ever recompiles across
-admissions/evictions. ``decode_compile_count`` counts decode retraces;
-tests assert it stays at 1.
+prompt tiles [1, T] (with the slots' rows, [1, T + n_slots]) with T
+``prefill_budget`` and, where that is four chunks or more, a handful of
+shorter lengths (``prefill_tiles``), every one compiled when the engine
+is built — so XLA compiles the tile programs, the slot insert and the
+decode step, and nothing ever recompiles across admissions/evictions.
+``decode_compile_count`` counts decode retraces; tests assert it stays
+at 1.
 
 Sampling is shared with ``make_generate_fn`` via models/sampling.py:
 greedy engine output is bit-identical to the one-program generator.
@@ -63,9 +71,11 @@ from ray_tpu.inference.scheduler import (FINISH_LENGTH, PrefillChunk,
 
 
 def prefill_tiles(chunk: int, budget: int) -> tuple:
-    """The static lengths T of a prefill call, ascending: the budget in
-    whole chunks, and below it a quarter of the tile above (in whole
-    chunks) while that is a chunk or more. A handful however large the
+    """The static lengths T of a prefill tile, ascending (the program of
+    a step that carries one has T + n_slots rows: the slots' decode rows
+    ride behind the tile): the budget in whole chunks, and below it a
+    quarter of the tile above (in whole chunks) while that is a chunk or
+    more. A handful however large the
     ratio ({32, 128, 512, 2048} at 16 / 2048), ONE member where the
     budget is under four chunks ({256} at 128 / 256; the budget <= chunk
     defaults): a tile costs a trace and an executable's load at every
@@ -92,11 +102,12 @@ class EngineConfig:
     prefill_budget: max prompt tokens admitted per engine step, and the
         largest tile: what a step gives one request runs as one call,
         in the tile of the prompt's length (a prompt of the budget's
-        length or more: this one).
+        length or more: this one), and the step's decode rows ride in
+        that call.
         The knob that trades TTFT (higher = prompts land faster, and a
         call's weight reads are shared by more tokens) against
-        inter-token latency of in-flight decodes (lower = decode steps
-        between prefill work come sooner).
+        inter-token latency of in-flight decodes (lower = a step that
+        carries prompt is shorter).
     eos_id: default EOS (<0 disables); per-request override on Request.
     temperature/top_k/top_p: default sampling (temperature has a
         per-request override; top_k/top_p are compiled in).
@@ -243,6 +254,10 @@ class InferenceEngine:
         self.spec_tokens_proposed = 0
         self.spec_tokens_accepted = 0
         self.steps = 0
+        # steps in which a prefill span and live decode rows ran as ONE
+        # program (of `steps`; the rest are decode-only, prefill with no
+        # slot live, or idle)
+        self.fused_steps = 0
         self.tokens_generated = 0
         # sparse attention (a model with an indexer): K/V rows a decode
         # row read (the selected ones) and rows live, summed over the
@@ -305,42 +320,82 @@ class InferenceEngine:
 
         names = tuple(self._slots.shapes)
         count_moe = self._count_moe
+        # the slots' decode rows ride in the program of a step's prefill
+        # tile, and the weights stream once for both. Two engines keep
+        # the tile program without them and two calls a step: one with a
+        # speculative draft, whose own step replaces decode; and one whose
+        # model has an indexer: its decode row's time is its own sort and
+        # gathers (8.8 of 15.55 ms), not the weights' stream, and riding
+        # saved nothing (one program 57.2-59.4 ms against 41.7 + 15.55)
+        # while a first token waited for the rows' work (my chip runs,
+        # PR 35; PERF.md section 6)
+        ride = self._ride = self._spec is None and not model.cfg.index_heads
 
-        def forward(params, tokens, pools, idx, real=None, **kw):
-            """-> (logits, the pools and, where counted, the call's
-            expert-layer counts summed over the layers). `real` [B, L]
-            bool: the rows a request owns, where not all."""
+        def forward(params, tokens, pools, idx, real=None, slots=None,
+                    **kw):
+            """-> (logits, the pools (then the slots' pools) and, where
+            counted, the call's expert-layer counts summed over the
+            layers). `real` [B, L] bool: the rows a request owns, where
+            not all. `slots`: the second cache of a tile's call, whose
+            decode rows end the sequence (TransformerLM._decode)."""
             cache = dict(zip(names, pools), idx=idx)
             if real is not None:
                 cache["real"] = real
+            if slots is not None:
+                cache["slots"] = slots
             out = model.apply({"params": params}, tokens, cache=cache, **kw,
                               mutable=["counters"] if count_moe else False)
+            (logits, new), counted = out if count_moe else (out, None)
+            new = tuple(c[n] for c in (new, new.get("slots"))
+                        if c is not None for n in names)
             if not count_moe:
-                return out[0], tuple(out[1][n] for n in names)
-            (logits, new), counted = out
+                return logits, new
             # one pair a layer (stacked where the layers are scanned)
             pair = sum(c.reshape(-1, 2).sum(0)
                        for c in jax.tree.leaves(counted))
-            return logits, tuple(new[n] for n in names) + (pair,)
+            return logits, new + (pair,)
 
         def prefill(params, *args):
-            # (params, *scratch, tokens, pos0, n_real, rng, temp): one
-            # request's share of a step's prompt budget, a [1, T]
-            # tile, through the cached path; samples the would-be next
-            # token (used only on the prompt's last tile, where it is
-            # the request's first generated token)
+            # (params, *scratch, [*pools,] tokens, pos0, n_real, rng, temp
+            # [, lengths, toks, temps, live]): the program of a step
+            # that carries prompt. One request's share of the step's
+            # budget, a [1, T] tile, through the cached path into its
+            # scratch, and behind it in the same sequence one decode row
+            # a slot against the pools, so every weight streams once for
+            # both; samples the tile's would-be next token (used only on
+            # the prompt's last tile, where it is the request's first
+            # generated token) and the slots' next tokens. `live` [n_slots]
+            # bool: the slots that decode; none (a step's further spans,
+            # an idle engine), and the rows' attention and pool write
+            # are skipped, never another shape
             self.prefill_compile_count += 1    # traces once a tile
-            scratch = args[:len(names)]
-            tokens, pos0, n_real, rng, temp = args[len(names):]
+            n = len(names)
+            scratch, args = args[:n], args[n:]
+            pools, args = (args[:n], args[n:]) if ride else ((), args)
+            tokens, pos0, n_real, rng, temp, *rows = args
+            tile = tokens.shape[1]
             # the tile's padded tail is rows no request owns
-            real = (jnp.arange(tokens.shape[1]) < n_real)[None, :]
-            logits, new = forward(params, tokens, scratch, pos0,
-                                  real=real, chunked_prefill=True)
+            real = (jnp.arange(tile) < n_real)[None, :]
+            slots = None
+            if ride:
+                lengths, toks, temps, live = rows
+                tokens = jnp.concatenate([tokens, toks[None, :]], axis=1)
+                real = jnp.concatenate([real, live[None, :]], axis=1)
+                slots = dict(zip(names, pools), idx=lengths,
+                             on=jnp.any(live))
+                rng, sub = jax.random.split(rng)
+            logits, new = forward(params, tokens, scratch, pos0, real=real,
+                                  slots=slots, chunked_prefill=True)
             last = jax.lax.dynamic_index_in_dim(logits, n_real - 1,
                                                 axis=1, keepdims=False)
             tok = sample_logits_dynamic(last, rng, temp[None],
                                         top_k=top_k, top_p=top_p)
-            return (tok[0].astype(jnp.int32),) + new
+            out = (tok[0].astype(jnp.int32),)
+            if ride:
+                out += (sample_logits_dynamic(
+                    logits[0, tile:], sub, temps, top_k=top_k,
+                    top_p=top_p).astype(jnp.int32),)
+            return out + new
 
         def decode(params, *args):
             # (params, *pools, lengths, toks, rng, temps).
@@ -359,7 +414,9 @@ class InferenceEngine:
             return (tok.astype(jnp.int32),) + new + (rng,)
 
         donated = tuple(range(1, 1 + len(names)))     # the pools
-        self._prefill_fn = jax.jit(prefill, donate_argnums=donated)
+        self._prefill_fn = jax.jit(
+            prefill, donate_argnums=tuple(
+                range(1, 1 + len(names) * (2 if ride else 1))))
         self._decode_fn = jax.jit(decode, donate_argnums=donated)
 
         self._spec_step_fn = None
@@ -402,6 +459,7 @@ class InferenceEngine:
         never moves again. Each tile runs twice, on a new scratch and on
         the one it handed back, as a prompt's first and later spans do:
         on a mesh the two differ in sharding and XLA compiles each. The
+        slots' pools pass through with no slot live, untouched. The
         engine's key is not advanced."""
         import jax
         import jax.numpy as jnp
@@ -411,10 +469,8 @@ class InferenceEngine:
                 tokens = jnp.zeros((1, tile), jnp.int32)
                 scratch = self._slots.new_scratch()
                 for _ in range(2):
-                    scratch = self._prefill_fn(
-                        self.params, *scratch, tokens, np.int32(0),
-                        np.int32(tile), key,
-                        np.float32(0.0))[1:1 + len(scratch)]
+                    scratch = self._call_prefill(
+                        scratch, tokens, 0, tile, key, 0.0, [])[2]
                 if self._spec is not None:
                     dk, dv = self._draft_slots.new_scratch()
                     for _ in range(2):
@@ -425,6 +481,7 @@ class InferenceEngine:
                     "engine.compile", category="engine",
                     trace_id=self._trace_id, fn="prefill", tile=tile,
                     compile_count=self.prefill_compile_count)
+        self._moe_pending.clear()       # the warm-up's rows are nobody's
 
     # -------------------------------------------------------------- intake
     def submit(self, tokens, max_new_tokens: int = 64,
@@ -511,10 +568,17 @@ class InferenceEngine:
 
     # --------------------------------------------------------------- step
     def step(self) -> bool:
-        """One engine iteration: reap cancels/deadlines, run the step's
-        budgeted prefill (admission; one dispatch a request), advance
-        every occupied slot one token. Returns True if any device work
-        ran."""
+        """One engine iteration: reap cancels/deadlines, then ONE program
+        where the step's plan holds one span: the span's prefill tile
+        and, behind it in the same sequence, a decode row for every
+        occupied slot (admission and decode share each weight's read;
+        a step's further spans run the same program with no slot live).
+        A step with no prompt to prefill runs the decode program. A
+        request whose prompt ends in this step decodes from the next.
+        (With a speculative draft or a model with an indexer: the tile
+        programs, then the draft's step or the decode program for every
+        occupied slot, the new request among them.)
+        Returns True if any device work ran."""
         import jax
 
         with self._lock:
@@ -523,18 +587,26 @@ class InferenceEngine:
             for st in self.sched.reap(now):
                 for pool in self._pools:
                     pool.scratch.pop(st.rid, None)
-            did = False
-            for span in self._prefill_spans(self.sched.plan_prefill()):
-                self._run_prefill(span, now)
-                did = True
-            t_admit = time.perf_counter()
-
             # capacity eviction BEFORE the step: a full slot has nowhere
             # to write its next token
             for st in self.sched.active_states():
                 if self._lengths[st.slot] >= self.config.max_len:
                     self.sched.evict(st, FINISH_LENGTH)
             active = self.sched.active_states()
+            spans = self._prefill_spans(self.sched.plan_prefill())
+            # the decode rows ride in the step's FIRST tile program
+            ride = self._ride and bool(spans)
+            rode = [self._run_prefill(span, now,
+                                      active if ride and i == 0 else ())
+                    for i, span in enumerate(spans)]
+            did = bool(spans)
+            if ride:
+                self.fused_steps += bool(active)
+                t_admit = t_iter0      # admission shares decode's program
+            else:
+                t_admit = time.perf_counter()
+                # a prompt that ended in this step decodes in it
+                active = self.sched.active_states()
             if active:
                 # decode is a BATCH phase: when one request occupies the
                 # engine its span adopts that request's trace (the
@@ -558,9 +630,12 @@ class InferenceEngine:
                     slots_occupied=self.sched.occupancy(),
                     queue_depth=self.sched.queue_depth())
                 compiles0 = self.decode_compile_count
-                t_dec0 = time.perf_counter()
+                t_dec0 = t_iter0 if ride else time.perf_counter()
                 pool, dpool = self._slots, self._draft_slots
-                if self._spec is not None:
+                if ride:
+                    toks_host = np.asarray(rode[0])
+                    self._fold_moe_counts()
+                elif self._spec is not None:
                     with self._mesh_ctx():
                         (out, acc, pool.k, pool.v, dpool.k, dpool.v,
                          self._rng) = self._spec_step_fn(
@@ -693,7 +768,33 @@ class InferenceEngine:
         tiles = self._prefill_tiles
         return next(t for t in tiles if t >= min(prompt_len, tiles[-1]))
 
-    def _run_prefill(self, ch: PrefillChunk, now: float):
+    def _call_prefill(self, scratch, tokens, pos0, n_real, key, temp,
+                      live):
+        """One call of the tile program -> (the tile's token, the slots'
+        tokens, the scratch). `live`: the slots whose decode rows ride
+        behind the tile; their pools are rebound here. (An engine whose
+        decode rows do not ride: no rows, no slots' tokens.)"""
+        n = len(scratch)
+        pools, rows = (), ()
+        if self._ride:
+            mask = np.zeros((self.config.n_slots,), bool)
+            mask[live] = True
+            pools = self._slots.pools()
+            rows = (self._lengths, self._last_tok, self._temps, mask)
+        tok, *out = self._prefill_fn(
+            self.params, *scratch, *pools, tokens, np.int32(pos0),
+            np.int32(n_real), key, np.float32(temp), *rows)
+        toks = None
+        if self._ride:
+            toks, *out = out
+            self._slots.rebind(out[n:2 * n])
+        self._fold_moe_counts(out, wait=False)
+        return tok, toks, tuple(out[:n])
+
+    def _run_prefill(self, ch: PrefillChunk, now: float, active=()):
+        """Run one span of a prompt in its tile, with the decode rows of
+        the `active` states' slots behind it -> the slots' tokens (on
+        the device; None where decode rows do not ride)."""
         import jax
         import jax.numpy as jnp
 
@@ -743,17 +844,15 @@ class InferenceEngine:
             rid=st.rid, slot=st.slot, offset=ch.start, length=ch.length,
             tile=tile, is_last=ch.is_last,
             live=ch.start + ch.length,    # positions the tile attended
+            decode_rows=len(active),      # slots advanced in its program
             slots_occupied=self.sched.occupancy())
         compiles0 = self.prefill_compile_count
         self.prefill_dispatches += 1
         self.prefill_tokens += ch.length
         with self._mesh_ctx():
-            tok, *out = self._prefill_fn(
-                self.params, *scratch, tokens,
-                np.int32(ch.start), np.int32(ch.length), k,
-                np.float32(st.temperature))
-        scratch = tuple(out[:len(scratch)])
-        self._fold_moe_counts(out, wait=False)
+            tok, toks, scratch = self._call_prefill(
+                scratch, tokens, ch.start, ch.length, k, st.temperature,
+                [a.slot for a in active])
         if self.prefill_compile_count > compiles0:
             events.record_instant(
                 "engine.compile", category="engine",
@@ -789,6 +888,7 @@ class InferenceEngine:
             if self._spec is not None:
                 self._draft_slots.scratch[st.rid] = dk_dv
             self.sched.advance_prefill(st, ch.length)
+        return toks
 
     def _fold_moe_counts(self, outputs=None, wait=True):
         """Note the expert-layer counts at the end of a program's
@@ -935,6 +1035,7 @@ class InferenceEngine:
             "queue_depth": self.sched.queue_depth(),
             "active": len(self.sched.active_slots()),
             "steps": self.steps,
+            "fused_steps": self.fused_steps,
             "tokens_generated": self.tokens_generated,
             "prefill_dispatches": self.prefill_dispatches,
             "prefill_tokens": self.prefill_tokens,

@@ -9,7 +9,9 @@ expert's matmul runs at full tile size. No counterpart in the reference
 (it orchestrates torch processes and ships no MoE, SURVEY §2.4: EP listed
 as "absent — must be built natively").
 
-Routing (per batch row as the dispatch group):
+Routing (a batch row is the dispatch group: a sequence in training, a
+prefill tile, one decode row, or in the engine's step a tile with the
+slots' decode rows behind it):
 - softmax router in fp32 over ALL `n_experts`, top-k experts per token,
   gates renormalized;
 - per-expert capacity C = ceil(capacity_factor * L * k / E). Training
@@ -37,9 +39,17 @@ route: the picks past an expert's capacity, rare, go through every held
 expert over the whole group under a `lax.cond` that runs only in a step
 where some pick overflowed.
 
-Rows no request owns (the padded tail of a prefill tile; `real` False) are
-routed nowhere where that matters: their picks are taken out before the
-capacity is counted, so they fill no expert's capacity and are not counted.
+Rows no request owns (the padded tail of a prefill tile, an idle slot's
+decode row; `real` False) are routed nowhere where that matters: their
+picks are taken out before the capacity is counted, so they fill no
+expert's capacity and are not counted.
+
+The last `tail` rows of a group (the slots' decode rows behind a prefill
+tile) take their places in an expert's capacity after EVERY pick of the
+rows before them. So where a tile's pick sits in its expert's matmul and
+whether it fits, and with them every bit of what the tile computes, are
+the same whatever rides behind it (a row's result may depend on its place
+in a matmul in a last bit: it does on the CPU backend).
 
 Counters: where the caller makes the "counters" collection mutable
 (`apply(..., mutable=["counters"])`, the engine for a layer that holds a
@@ -67,10 +77,11 @@ class MoEMLP(nn.Module):
     cfg: Any
 
     @nn.compact
-    def __call__(self, x, real=None, exact: bool = False):
+    def __call__(self, x, real=None, exact: bool = False, tail: int = 0):
         """-> (out, aux loss). `real` [B, L] bool: the rows a request
         owns (None: all). `exact`: the serving forward, which drops no
-        pick (the module's docstring)."""
+        pick. `tail`: the group's last rows that are counted after the
+        others (the module's docstring)."""
         cfg = self.cfg
         B, L, D = x.shape
         E, K = cfg.n_experts, cfg.expert_top_k
@@ -98,9 +109,19 @@ class MoEMLP(nn.Module):
         # the held experts' columns; E below is their count from here on
         sel = sel_all if held == E else sel_all[..., first:first + held]
         E = held
-        flat = sel.transpose(0, 2, 1, 3).reshape(B, K * L, E)  # slot-major
-        pos_flat = jnp.cumsum(flat, axis=1) - flat             # [B,K*L,E]
-        pos = pos_flat.reshape(B, K, L, E).transpose(0, 2, 1, 3)  # [B,L,K,E]
+        def places(sel, taken=0.0):
+            n = sel.shape[1]
+            flat = sel.transpose(0, 2, 1, 3).reshape(B, K * n, E)
+            before = jnp.cumsum(flat, axis=1) - flat + taken   # slot-major
+            return before.reshape(B, K, n, E).transpose(0, 2, 1, 3)
+
+        if tail:
+            head = sel[:, :L - tail]
+            pos = jnp.concatenate(
+                [places(head), places(sel[:, L - tail:],
+                                      head.sum((1, 2))[:, None])], axis=1)
+        else:
+            pos = places(sel)                                  # [B,L,K,E]
         pos = (pos * sel).sum(-1)                              # [B,L,K]
         keep = (pos < C).astype(gate_vals.dtype)
 

@@ -18,6 +18,7 @@ workload and benchmark subject.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -213,6 +214,18 @@ def _cache_write(cache, new, idx, pos_axis: int = -3):
         mode=jax.lax.GatherScatterMode.CLIP)
 
 
+def _split_rows(a, n: int):
+    """A sequence [1, T + n, ..] that ends in one decode row for each of
+    n slots -> (the tile [1, T, ..], the slots' rows [n, 1, ..])."""
+    t = a.shape[1] - n
+    return a[:, :t], jnp.swapaxes(a[:, t:], 0, 1)
+
+
+def _join_rows(tile, rows):
+    """The inverse of `_split_rows`."""
+    return jnp.concatenate([tile, jnp.swapaxes(rows, 0, 1)], axis=1)
+
+
 class LayerNorm(nn.Module):
     """Mean and variance over the last axis in float32, scale and bias."""
     eps: float
@@ -239,7 +252,7 @@ class Attention(nn.Module):
     chunked: bool = False
 
     @nn.compact
-    def __call__(self, x, positions, cache=None):
+    def __call__(self, x, positions, cache=None, slots=None):
         """cache=None: training/prefill forward (flash/ring dispatch),
         returns out. cache=(rows, idx): serving decode — `rows` is this
         layer's K and V [B,M,Hkv,D] as read out of the pool (and, where
@@ -247,7 +260,15 @@ class Attention(nn.Module):
         own rows are placed in that read-out at [idx, idx+L) (idx scalar
         or per-slot [B] vector) for attention to see, and returned as
         (out, new_rows) [B,L,..], in the pools' order, for the caller to
-        add to the pools: the pools themselves are not written here."""
+        add to the pools: the pools themselves are not written here.
+
+        slots=(rows, lengths, on) (a model without an indexer): the
+        sequence [1, T + S] is a prefill tile of T rows against `cache`
+        followed by one decode row for each of S slots against `slots`
+        (row b at position lengths[b] of slot b; `on` False: no slot is
+        live, their attention is skipped). The projections run once
+        over all T + S rows; only the attention splits them. new_rows
+        is then the pair (the tile's [1,T,..], the slots' [S,1,..])."""
         cfg = self.cfg
         B, L, E = x.shape
         H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -277,6 +298,19 @@ class Attention(nn.Module):
                                      impl=cfg.attention_impl)
             return proj(out)
         (k_layer, v_layer), idx = cache
+        if slots is not None:
+            (k_pool, v_pool), lens, on = slots
+            (q, qr), (k, kr), (v, vr) = (
+                _split_rows(a, len(lens)) for a in (q, k, v))
+            out = _join_rows(
+                _cached_attention(q, _cache_write(k_layer, k, idx),
+                                  _cache_write(v_layer, v, idx), idx),
+                jax.lax.cond(
+                    on, lambda: _cached_attention(
+                        qr, _cache_write(k_pool, kr, lens),
+                        _cache_write(v_pool, vr, lens), lens),
+                    lambda: jnp.zeros_like(qr)))
+            return proj(out), ((k, v), (kr, vr))
         if L > 1 and not self.chunked:
             # one-shot prefill (L is static): the block attends only
             # within itself, so the fused flash/ring kernel computes it
@@ -353,11 +387,11 @@ class Block(nn.Module):
     chunked: bool = False
 
     @nn.compact
-    def __call__(self, x, positions, cache=None, real=None):
+    def __call__(self, x, positions, cache=None, real=None, slots=None):
         cfg = self.cfg
         att = Attention(cfg, self.chunked, name="attn")(
             RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x),
-            positions, cache)
+            positions, cache, slots)
         new_rows = None
         if cache is not None:
             att, new_rows = att
@@ -365,9 +399,11 @@ class Block(nn.Module):
         normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(h)
         if cfg.n_experts > 0:
             from ray_tpu.models.moe import MoEMLP
-            # serving drops no pick; `real`: the rows a request owns
-            y, aux = MoEMLP(cfg, name="moe")(normed, real,
-                                             exact=cache is not None)
+            # serving drops no pick; `real`: the rows a request owns;
+            # the slots' decode rows behind a tile are counted after it
+            y, aux = MoEMLP(cfg, name="moe")(
+                normed, real, exact=cache is not None,
+                tail=0 if slots is None else len(slots[1]))
         else:
             y, aux = MLP(cfg, name="mlp")(normed), jnp.zeros((), jnp.float32)
         if cache is not None:
@@ -399,17 +435,19 @@ class DecodeScanBlock(nn.Module):
     indexer keys) ride in as a scanned input (axis 0 of the pools =
     layers), READ-ONLY, and only the call's new rows [B,L,Hkv,D] come
     back in the ys — never the layer, so no pool is stacked up again.
-    Param names mirror ScanBlock ('block' under the scan) so the SAME
-    trained/stacked params apply."""
+    `slot_rows`: the same layer of the slots' pools, where decode rows
+    ride behind a prefill tile. Param names mirror ScanBlock ('block'
+    under the scan) so the SAME trained/stacked params apply."""
     cfg: TransformerConfig
     chunked: bool = False
 
     @nn.compact
-    def __call__(self, carry, layer_rows, *layer):
-        x, positions, idx, real = carry
+    def __call__(self, carry, layer_rows, slot_rows, *layer):
+        x, positions, idx, real, slots = carry
         out, _aux, new_rows = Block(self.cfg, self.chunked, name="block")(
-            x, positions, (layer_rows, idx, *layer), real)
-        return (out, positions, idx, real), new_rows
+            x, positions, (layer_rows, idx, *layer), real,
+            slots and (slot_rows, *slots))
+        return (out, positions, idx, real, slots), new_rows
 
 
 def kv_cache_shape(cfg: TransformerConfig, batch: int,
@@ -460,10 +498,15 @@ def kv_cache_sharding(shape, mesh, rules=None, name: str = "k"):
     from ray_tpu.parallel import sharding as sharding_lib
     from ray_tpu.parallel.train_step import (_prune_indivisible,
                                              logical_pspec_to_mesh)
-    spec = logical_pspec_to_mesh(
+    spec = _prune_indivisible(logical_pspec_to_mesh(
         P(None, "batch", None, "kv_heads" if name != "ki" else None, None),
-        rules or sharding_lib.DEFAULT_RULES)
-    return NamedSharding(mesh, _prune_indivisible(spec, shape, mesh))
+        rules or sharding_lib.DEFAULT_RULES), shape, mesh)
+    # no trailing None: the spec a program hands a pool back with, so a
+    # new pool and a donated one are one sharding to jit's cache (the
+    # tile programs take the slots' pools from the engine's first call)
+    while len(spec) and spec[-1] is None:
+        spec = P(*spec[:-1])
+    return NamedSharding(mesh, spec)
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
@@ -496,16 +539,26 @@ class TransformerLM(nn.Module):
         cache with the causal offset cache["idx"] instead of assuming
         idx==0 (the inference engine's budgeted prompt chunks).
         cache["idx"] may be a scalar or a per-row [B] vector (slot pool:
-        every row decodes at its own length)."""
+        every row decodes at its own length). cache["slots"] (with
+        chunked_prefill, B = 1): tokens [1, T + S] is a tile of T rows
+        followed by one decode row for each of the S slots of a second
+        cache, {"k", "v": its pools, "idx": [S] the slots' lengths,
+        "on": whether any slot is live}; see `_decode`."""
         cfg = self.cfg
         B, L = tokens.shape
         if positions is None:
             if cache is not None:
                 # decode: tokens continue at the cache's write position
-                # (scalar idx, or [B] per-slot write positions)
+                # (scalar idx, or [B] per-slot write positions); the
+                # slots' decode rows behind a tile each at their slot's
+                lens = cache["slots"]["idx"] if "slots" in cache else ()
                 positions = jnp.broadcast_to(
                     jnp.reshape(cache["idx"], (-1, 1))
-                    + jnp.arange(L)[None, :], (B, L))
+                    + jnp.arange(L - len(lens))[None, :],
+                    (B, L - len(lens)))
+                if "slots" in cache:
+                    positions = jnp.concatenate([positions, lens[None, :]],
+                                                axis=1)
             else:
                 positions = jnp.broadcast_to(jnp.arange(L)[None, :],
                                              (B, L))
@@ -606,43 +659,80 @@ class TransformerLM(nn.Module):
         buffers back; nothing pool-shaped is copied or stacked).
         Shares the training param tree — the decode scan mirrors
         ScanBlock's naming ('layers'/'block'); the unscanned layout
-        reads and writes the same way."""
+        reads and writes the same way.
+
+        With cache["slots"] (a model without an indexer) ONE pass serves
+        a prefill tile and the slots' decode rows behind it: norms,
+        projections, MLP or experts and the unembedding run over all
+        T + S rows, so every weight is read once; each layer's attention
+        takes the tile against `cache` and the rows against the slots'
+        pools, and after the loop the tile's new rows are written to
+        `cache` and the slots' to theirs (new_cache["slots"]). With "on"
+        False the slots' attention and write are skipped and their pools
+        come back untouched; what the tile computes does not depend on
+        it."""
         cfg = self.cfg
-        L = x.shape[1]
         idx = cache["idx"]
         names = tuple(n for n in CACHE_POS_AXIS if n in cache)
         pools = tuple(cache[n] for n in names)
+        slots = cache.get("slots")
+        if slots and cfg.index_heads:
+            raise ValueError(
+                "decode rows behind a prefill tile: not with an indexer, "
+                "whose decode row's time is its own sort and gathers and "
+                "not the weights' stream (PERF.md section 6, PR 35)")
+        slot_pools = slots and tuple(slots[n] for n in names)
+        L = x.shape[1] - (len(slots["idx"]) if slots else 0)
         # [B, L] bool, the rows a request owns (a prefill tile's padded
-        # tail is not; absent: all): the expert layer routes no other
+        # tail and an idle slot's row are not; absent: all): the expert
+        # layer routes no other
         real = cache.get("real") if cfg.n_experts > 0 else None
         # one row a slot of a model with an indexer GATHERS its K and V:
         # the layers' loop is handed the whole pools and the layer's
         # number, not the layer sliced out (which would be copied whole
         # to be gathered from)
         whole = bool(cfg.index_heads) and L == 1
+        carry = (x, positions, idx, real,
+                 slots and (slots["idx"], slots["on"]))
         if cfg.scan_layers:
             stack = nn.scan(
                 DecodeScanBlock,
                 variable_axes={"params": 0, "counters": 0},
                 split_rngs={"params": True},
-                in_axes=(nn.broadcast, 0) if whole else 0,
+                in_axes=(nn.broadcast, 0, 0) if whole else 0,
                 length=cfg.n_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, chunked_prefill, name="layers")
-            (x, _, _, _), rows = stack(
-                (x, positions, idx, real), pools,
+            (x, *_), rows = stack(
+                carry, pools, slot_pools,
                 *((jnp.arange(cfg.n_layers),) if whole else ()))
         else:
             rows = []
             for i in range(cfg.n_layers):
                 x, _aux, new_rows = Block(
                     cfg, chunked_prefill, name=f"layer_{i}")(
-                    x, positions, (tuple(p[i] for p in pools), idx), real)
+                    x, positions, (tuple(p[i] for p in pools), idx), real,
+                    slots and (tuple(p[i] for p in slot_pools),
+                               *carry[-1]))
                 rows.append(new_rows)
-            rows = tuple(jnp.stack(r) for r in zip(*rows))
-        new_cache = {n: _cache_write(p, r, idx, CACHE_POS_AXIS[n])
+            rows = jax.tree.map(lambda *r: jnp.stack(r), *rows)
+
+        def write(n, pool, rows, idx):
+            return _cache_write(pool, rows, idx, CACHE_POS_AXIS[n])
+
+        rows, slot_rows = rows if slots else (rows, None)
+        new_cache = {n: write(n, p, r, idx)
                      for n, p, r in zip(names, pools, rows)}
         new_cache["idx"] = idx + L
+        if slots:
+            # a `cond` a pool: one around both cost the tile's program
+            # 3.6 ms at 20 layers (28.17 against 24.58 ms, my chip runs,
+            # PR 35)
+            new_cache["slots"] = {
+                n: jax.lax.cond(
+                    slots["on"], functools.partial(write, n),
+                    lambda pool, *_: pool, p, r, slots["idx"])
+                for n, p, r in zip(names, slot_pools, slot_rows)}
         x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
         if return_hidden:
             return x, new_cache

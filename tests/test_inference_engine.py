@@ -197,7 +197,7 @@ def _walk_eqns(jaxpr):
             yield from _walk_eqns(sub)
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("program", ["decode", "prefill", "prefill-rows"])
 def test_pool_is_updated_in_place_not_rebuilt(tiny, program):
     """The step programs pass each KV pool through as ONE buffer: the
     layer loop only reads it (it is never a scan output, carried or
@@ -206,7 +206,9 @@ def test_pool_is_updated_in_place_not_rebuilt(tiny, program):
     and the donated input buffer is the output buffer. Fails if the
     cached forward goes back to slicing a layer out, rewriting it and
     stacking the layers into a new pool (2.68 GB moved about every
-    decode step at 7B)."""
+    decode step at 7B). The program of a step that carries a tile holds
+    two kinds of pool: the tile's scratch ("prefill") and the slots',
+    whose decode rows ride behind the tile ("prefill-rows")."""
     import jax.numpy as jnp
     mcfg, model, params = tiny
     eng = _engine(model, params, n_slots=3)
@@ -215,15 +217,21 @@ def test_pool_is_updated_in_place_not_rebuilt(tiny, program):
         fn, pools = eng._decode_fn, (eng._slots.k, eng._slots.v)
         args = (eng.params, *pools, eng._lengths, eng._last_tok,
                 eng._rng, eng._temps)
-        rows, new_len = 3, 1
+        rows, new_len, first = 3, 1, 1
     else:
         shape = eng._slots.scratch_shape
-        fn, pools = eng._prefill_fn, (jnp.zeros(shape, jnp.float32),
-                                      jnp.ones(shape, jnp.float32))
-        args = (eng.params, *pools, jnp.zeros((1, chunk), jnp.int32),
-                jnp.int32(8), jnp.int32(chunk), eng._rng,
-                jnp.float32(0.0))
-        rows, new_len = 1, chunk
+        scratch = (jnp.zeros(shape, jnp.float32),
+                   jnp.ones(shape, jnp.float32))
+        slots = (eng._slots.k, eng._slots.v)
+        fn = eng._prefill_fn
+        args = (eng.params, *scratch, *slots,
+                jnp.zeros((1, chunk), jnp.int32), jnp.int32(8),
+                jnp.int32(chunk), eng._rng, jnp.float32(0.0),
+                eng._lengths, eng._last_tok, eng._temps,
+                np.ones((3,), bool))
+        pools, first = ((scratch, 2) if program == "prefill"
+                        else (slots, 4))
+        rows, new_len = (1, chunk) if program == "prefill" else (3, 1)
     pool_shape = pools[0].shape
     new_rows = rows * new_len * mcfg.n_kv_heads * mcfg.head_dim
     traced = fn.trace(*args)
@@ -254,8 +262,8 @@ def test_pool_is_updated_in_place_not_rebuilt(tiny, program):
     # ... and the buffers that come back are the ones that went in
     before = [p.unsafe_buffer_pointer() for p in pools]
     out = fn(*args)
-    assert [out[1].unsafe_buffer_pointer(),
-            out[2].unsafe_buffer_pointer()] == before
+    assert [out[first].unsafe_buffer_pointer(),
+            out[first + 1].unsafe_buffer_pointer()] == before
     assert all(p.is_deleted() for p in pools)
 
 
@@ -476,14 +484,14 @@ def _tiles_and_tokens(eng, fillers, prompt, n_new=4):
     own, cur = [], {}
     run, fn = eng._run_prefill, eng._prefill_fn
 
-    def spy_run(ch, now):
+    def spy_run(ch, *rest):
         cur["st"] = ch.state
-        return run(ch, now)
+        return run(ch, *rest)
 
-    def spy_fn(params, sk, sv, tokens, pos0, n_real, *rest):
+    def spy_fn(params, sk, sv, pk, pv, tokens, pos0, n_real, *rest):
         if cur["st"].handle is h:
             own.append((tokens.shape[1], int(pos0), int(n_real)))
-        return fn(params, sk, sv, tokens, pos0, n_real, *rest)
+        return fn(params, sk, sv, pk, pv, tokens, pos0, n_real, *rest)
     rng = np.random.RandomState(len(prompt))
     hs = [eng.submit(rng.randint(0, 128, n), max_new_tokens=1)
           for n in fillers]
