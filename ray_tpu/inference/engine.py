@@ -8,10 +8,13 @@ batching in the Gemma-on-TPU serving stack):
   pools (K and V) of ``[n_layers, n_slots, max_len, Hkv, D]`` and, for
   a model with a sparse-attention indexer, a third of its keys,
   ``[n_layers, n_slots, 1, DI, max_len]``
-  (kv_cache.py ``SlotPool``; the prefix blocks and their fp/int8 format
-  are its ``BlockStore``, which holds K and V only: such a model runs
-  without a prefix cache) and donated through every step. The cached
-  forward's layer loop only reads a pool; one write after the loop adds
+  (kv_cache.py ``SlotPool``; a model with layers of several kinds keeps
+  each kind's pools over that kind's layers: K, V and pooled keys for its
+  block-sparse layers, a float32 state with no position axis for its
+  linear ones; the prefix blocks and their fp/int8 format are its
+  ``BlockStore``, which holds K and V only: a model with any cache beyond
+  those runs without a prefix cache) and donated through every step. The
+  cached forward's layer loop only reads a pool; one write after the loop adds
   the step's new rows of all layers to it (models/transformer.py
   ``_decode``, ``_cache_write``), so the program's output pool IS the donated input
   buffer: the decode step compiles exactly ONCE and then mutates the
@@ -176,13 +179,15 @@ class InferenceEngine:
                 raise ValueError(
                     f"draft max_seq_len={self._draft_model.cfg.max_seq_len}"
                     f" < max_len + k = {pool_len}")
-        if mcfg.index_heads and (cfg.prefix_cache_slots > 0
-                                 or self._spec is not None):
+        from ray_tpu.models.transformer import cache_shapes
+        beyond = sorted(set(cache_shapes(mcfg, 1, 1)) - {"k", "v"})
+        if beyond and (cfg.prefix_cache_slots > 0 or self._spec is not None):
             raise ValueError(
-                "a model with a sparse-attention indexer keeps a third "
-                "cache (its indexer keys), which prefix blocks and a "
-                "speculative draft's verify step do not carry: run it with "
-                "prefix_cache_slots=0 and no spec")
+                f"the model keeps caches beyond K and V ({', '.join(beyond)}"
+                f": an indexer's keys, pooled keys, a recurrent state), "
+                f"which prefix blocks and a speculative draft's verify step "
+                f"do not carry: run it with prefix_cache_slots=0 and no "
+                f"spec")
         dtype = cfg.cache_dtype or mcfg.dtype
         self._kv_quant = kv_cache.check_format(cfg.kv_quant)
         self._lock = threading.RLock()
@@ -265,6 +270,16 @@ class InferenceEngine:
         self._topk = mcfg.index_topk if mcfg.index_heads else 0
         self.dsa_rows_read = 0
         self.dsa_rows_live = 0
+        # selection by block (a model with "blk" layers): the positions the
+        # selection leaves a decode row to attend (those up to its own of
+        # the blocks it selects) and the positions live, summed alike. Host
+        # arithmetic too, and the LEAST a kernel could read, not what the
+        # chip reads: block_decode_attention passes over the slot's whole
+        # length and masks what was not selected
+        self._blk = (mcfg.blk_size, mcfg.blk_topk) \
+            if "blk" in (mcfg.mixer_kinds or ()) else None
+        self.blk_rows_read = 0
+        self.blk_rows_live = 0
         # an expert layer that holds a share of its experts: [rows the
         # expert matmuls computed, picks of real rows that landed on a held
         # expert], summed over layers and calls (models/moe.py sows them).
@@ -688,6 +703,14 @@ class InferenceEngine:
                             live = int(self._lengths[slot])
                             self.dsa_rows_live += live
                             self.dsa_rows_read += min(live, self._topk)
+                        if self._blk:
+                            # the row's own block is among the selected
+                            # and holds the positions up to the row's only
+                            live = int(self._lengths[slot])
+                            size, topk = self._blk
+                            self.blk_rows_live += live
+                            self.blk_rows_read += min(
+                                live, size * topk - (-live % size))
                         self._last_tok[slot] = toks_host[slot]
                         self.tokens_generated += 1
                         n_emitted += 1
@@ -1060,9 +1083,14 @@ class InferenceEngine:
         out.update(kv_cache.format_stats(
             self._kv_quant, self.model.cfg.head_dim, self._kv_itemsize))
         out["kv_pool_bytes"] = sum(p.nbytes() for p in self._pools)
+        if "s" in self._slots.shapes:
+            out["state_pool_bytes"] = self._slots.nbytes(("s",))
         if self._topk:
             out["dsa_rows_read"] = self.dsa_rows_read
             out["dsa_rows_live"] = self.dsa_rows_live
+        if self._blk:
+            out["blk_rows_read"] = self.blk_rows_read
+            out["blk_rows_live"] = self.blk_rows_live
         if self._count_moe:
             out["moe_rows_computed"] = int(self._moe_counts[0])
             out["moe_local_picks"] = int(self._moe_counts[1])

@@ -8,9 +8,15 @@ third cache of one head of indexer keys a position; ``cache_shapes``
 names them all; the cached forward is what reads them). This module owns
 what the engine keeps in that layout and nothing else knows how:
 
-- ``SlotPool``: K and V (and indexer keys) of one model's decode slots,
-  the scratch a prompt prefills into, and the program that makes a
-  finished scratch a slot. Built twice: for the target and for a
+- ``SlotPool``: every pool of one model's decode slots (K and V; an
+  indexer's keys; for a model with layers of several kinds the pooled
+  keys of its block-sparse layers and the float32 states of its linear
+  layers, each over its own kind's layers), the scratch a prompt prefills
+  into, and the program that makes a finished scratch a slot. Pools are
+  of two natures (``CACHE_POS_AXIS``): with a position axis, of which a
+  slot takes the scratch's first ``slot_len`` positions; without (a
+  state), of which it takes the scratch's whole entry, so a slot never
+  inherits its last owner's. Built twice: for the target and for a
   speculative draft.
 - ``BlockStore``: the prefix cache's blocks. A block's FORMAT is the
   tuple of arrays that hold it, which is also its wire form between
@@ -70,7 +76,9 @@ def replicated(mesh):
 class SlotPool:
     """``k``, ``v``: ``[n_layers, n_slots, length, Hkv, D]`` of one
     model, and ``ki`` ``[n_layers, n_slots, 1, DI, length]`` where the
-    model has an indexer (None where not), sharded as the layout says
+    model has an indexer (None where not), or whatever else
+    ``cache_shapes`` names (each an attribute of its name, in the type
+    ``cache_dtype`` gives it), sharded as the layout says
     (pruned against THIS model's shape: a draft's KV heads may not
     divide the tensor axis). The step programs take them donated, as the
     tuple ``pools()``, and the engine ``rebind``s them after each call.
@@ -82,53 +90,58 @@ class SlotPool:
                  scratch_len: int, dtype, mesh=None, rules=None):
         import jax
 
-        from ray_tpu.models.transformer import (CACHE_POS_AXIS,
-                                                cache_shapes,
+        from ray_tpu.models.transformer import (cache_dtype, cache_shapes,
                                                 kv_cache_sharding)
         self.dtype = dtype
         self.shapes = cache_shapes(mcfg, n_slots, length)
         self.scratch_shapes = cache_shapes(mcfg, 1, scratch_len)
+        self.dtypes = {n: cache_dtype(n, dtype) for n in self.shapes}
         # K's (and V's) own, as they were named before there was a third
-        self.shape = self.shapes["k"]
-        self.scratch_shape = self.scratch_shapes["k"]
+        self.shape = self.shapes.get("k")
+        self.scratch_shape = self.scratch_shapes.get("k")
         self.sharding = (kv_cache_sharding(self.shape, mesh, rules)
-                         if mesh is not None else None)
+                         if mesh is not None and self.shape else None)
         self._scratch_sharding = replicated(mesh)
         self.k = self.v = self.ki = None
         self.rebind(tuple(
-            zeros(shape, dtype, kv_cache_sharding(shape, mesh, rules, name)
+            zeros(shape, self.dtypes[name],
+                  kv_cache_sharding(shape, mesh, rules, name)
                   if mesh is not None else None)
             for name, shape in self.shapes.items()))
         self.scratch: Dict[int, Tuple[Any, ...]] = {}
+        # what a slot takes of a scratch: its first slot_len positions
+        # (the scratch carries the largest tile of padding tail), or, of
+        # a pool that has no position, its whole entry
+        taken = cache_shapes(mcfg, 1, slot_len)
 
         def insert(pools, scratch, slot):
-            # scratch carries the largest tile of padding tail; the slot
-            # takes the first slot_len entries
             return tuple(
                 jax.lax.dynamic_update_slice(
-                    p, jax.lax.slice_in_dim(s, 0, slot_len,
-                                            axis=CACHE_POS_AXIS[name]),
+                    p, jax.lax.slice(s, (0,) * s.ndim, taken[name]),
                     (0, slot, 0, 0, 0))
                 for name, p, s in zip(self.shapes, pools, scratch))
 
         self._insert_fn = jax.jit(insert, donate_argnums=(0,))
 
     def pools(self) -> Tuple[Any, ...]:
-        """(k, v) or (k, v, ki): the order of ``cache_shapes``."""
+        """(k, v), (k, v, ki) or (k, v, kp, s): the order of
+        ``cache_shapes``."""
         return tuple(getattr(self, n) for n in self.shapes)
 
     def rebind(self, pools) -> None:
         for name, pool in zip(self.shapes, pools):
             setattr(self, name, pool)
 
-    def nbytes(self) -> int:
-        """Bytes of the slots' pools (not of the scratches)."""
-        return sum(int(np.prod(shape)) for shape in self.shapes.values()) \
-            * np.dtype(self.dtype).itemsize
+    def nbytes(self, names=None) -> int:
+        """Bytes of the slots' pools (not of the scratches): of all, or of
+        those named."""
+        return sum(int(np.prod(shape)) * np.dtype(self.dtypes[n]).itemsize
+                   for n, shape in self.shapes.items()
+                   if names is None or n in names)
 
     def new_scratch(self):
-        return tuple(zeros(shape, self.dtype, self._scratch_sharding)
-                     for shape in self.scratch_shapes.values())
+        return tuple(zeros(shape, self.dtypes[n], self._scratch_sharding)
+                     for n, shape in self.scratch_shapes.items())
 
     def insert(self, scratch, slot: int):
         """A finished prompt's scratch becomes slot ``slot``."""

@@ -29,9 +29,15 @@ holds (models/transformer.py `Attention`):
 
 Selection is exact in all three (no approximate top-k, no block-level
 stand-in); at t + 1 <= topk it is every live position.
+
+Below them, a second published mechanism: selection by BLOCK from
+mean-pooled keys (`block_select` and the forms that attend its blocks).
 """
 
 from __future__ import annotations
+
+import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -202,6 +208,40 @@ def sparse_attention(q, k, v, qi, w, ki, topk: int):
         return masked_attention(q, k, v, mask)
 
 
+def _softmax_step(carry, s, mb, vb):
+    """One block of keys into a running softmax: `carry` (the largest
+    score so far, the sum, the weighted values), the block's scores s
+    [B, Hkv, G, S, blk] with its mask `mb` (broadcast against them) and
+    its values vb [B, blk, Hkv, D]."""
+    m, l, acc = carry
+    s = jnp.where(mb, s, _NEG)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+    p = jnp.where(mb, jnp.exp(s - m_new[..., None]), 0.0)
+    scale = jnp.exp(m - m_new)
+    l = l * scale + jnp.sum(p, axis=-1)
+    acc = acc * scale[..., None] + jnp.einsum(
+        "bhgsm,bmhd->bhgsd", p.astype(vb.dtype), vb,
+        preferred_element_type=jnp.float32)
+    return m_new, l, acc
+
+
+def _blocked_softmax(q, Hkv: int, n_live, block_of):
+    """The running softmax over key blocks 0 .. n_live - 1 (traced) of a
+    tile q [B, S, H, D] against Hkv KV heads -> [B, S, H, D] in q's type.
+    `block_of(i, qg)`, with qg the queries grouped [B, S, Hkv, G, D],
+    gives block i's scores [B, Hkv, G, S, blk], their mask and its values
+    [B, blk, Hkv, D]."""
+    B, S, H, D = q.shape
+    G = H // Hkv
+    qg = q.reshape(B, S, Hkv, G, D)
+    m0 = jnp.full((B, Hkv, G, S), _NEG, jnp.float32)
+    _, l, acc = jax.lax.fori_loop(
+        0, n_live, lambda i, carry: _softmax_step(carry, *block_of(i, qg)),
+        (m0, jnp.zeros_like(m0), jnp.zeros((B, Hkv, G, S, D), jnp.float32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, D).astype(q.dtype)
+
+
 def sparse_prefill_attention(q, k_cache, v_cache, qi, w, ki_cache, pos0,
                              topk: int):
     """A tile q [B, S, H, D] at absolute positions pos0 + 0..S-1 (pos0 a
@@ -210,42 +250,24 @@ def sparse_prefill_attention(q, k_cache, v_cache, qi, w, ki_cache, pos0,
     softmax, over the blocks up to the tile's last position only."""
     B, S, H, D = q.shape
     M, Hkv = k_cache.shape[1], k_cache.shape[2]
-    G = H // Hkv
     qpos = jnp.reshape(pos0, (-1, 1)) + jnp.arange(S)[None, :]
     qpos = jnp.broadcast_to(qpos, (B, S))
     sel = select_live(index_scores(qi, w, ki_cache, qpos), topk,
                       jnp.max(qpos) + 1)
     block = _block_of(M)
     n_live = jnp.minimum((jnp.max(qpos) + block) // block, M // block)
-    qg = q.reshape(B, S, Hkv, G, D)
 
-    def body(i, carry):
-        m, l, acc = carry
+    def block_of(i, qg):
         kb = jax.lax.dynamic_slice_in_dim(k_cache, i * block, block, 1)
         vb = jax.lax.dynamic_slice_in_dim(v_cache, i * block, block, 1)
         mb = jax.lax.dynamic_slice_in_dim(sel, i * block, block, 2)
         mb = mb[:, None, None]                              # [B,1,1,S,blk]
         s = jnp.einsum("bshgd,bmhd->bhgsm", qg, kb,
                        preferred_element_type=jnp.float32) * D ** -0.5
-        s = jnp.where(mb, s, _NEG)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.where(mb, jnp.exp(s - m_new[..., None]), 0.0)
-        scale = jnp.exp(m - m_new)
-        l = l * scale + jnp.sum(p, axis=-1)
-        acc = acc * scale[..., None] + jnp.einsum(
-            "bhgsm,bmhd->bhgsd", p.astype(vb.dtype), vb,
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        return s, mb, vb
 
     with jax.named_scope("dsa_attend"):
-        m0 = jnp.full((B, Hkv, G, S), _NEG, jnp.float32)
-        _, l, acc = jax.lax.fori_loop(
-            0, n_live, body,
-            (m0, jnp.zeros_like(m0), jnp.zeros((B, Hkv, G, S, D),
-                                               jnp.float32)))
-        out = acc / jnp.maximum(l, 1e-30)[..., None]
-        return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, D).astype(
-            q.dtype)
+        return _blocked_softmax(q, Hkv, n_live, block_of)
 
 
 def sparse_decode_attention(q, k_new, v_new, qi, w, ki_new, k_cache,
@@ -282,3 +304,199 @@ def sparse_decode_attention(q, k_new, v_new, qi, w, ki_new, k_cache,
         kg = jnp.where(is_own, k_new, k_cache[layer, rows, at])
         vg = jnp.where(is_own, v_new, v_cache[layer, rows, at])
         return masked_attention(q, kg, vg, live[:, None, :])
+
+
+# ---------------------------------------------------------------------------
+# Selection by BLOCK from mean-pooled keys (MiniCPM4 / InfLLM-V2), beside
+# the per-token indexer above. With `c_j` the mean of the keys of one KV
+# group over the kernel [stride j, stride j + kernel), a query at position t
+# scores the kernels that end at or before t,
+#
+#     p[h, j] = softmax_j(q_h . c_j / sqrt(D)),  r_j = sum_{h in group} p[h, j]
+#     R_b     = max r_j over the kernels that touch block b
+#
+# and attends, one selection a KV group, every position <= t of `topk`
+# blocks: the first `init` blocks and the blocks that hold the last `window`
+# positions always, the rest the best by R_b (ties to the lower block). With
+# no more than `topk` blocks visible that is every position <= t. Two forms
+# (models/transformer.py `Attention`): a tile against a cache that holds it,
+# blocked over the keys with the running softmax of the per-token form; one
+# row a slot against the slot's cache, in one pass, the row's own key and
+# value beside it.
+
+BlockGeometry = collections.namedtuple(
+    "BlockGeometry", "block kernel stride init window topk")
+
+
+def pool_keys(k_rows, n: int, geo: BlockGeometry):
+    """`n` pooled keys [B, n, Hkv, D] float32 of `k_rows` [B, R, Hkv, D],
+    whose first row is the first row of the first kernel:
+    R >= stride (n - 1) + kernel. A kernel is kernel / stride segments of
+    `stride` rows, summed once."""
+    B, _, Hkv, D = k_rows.shape
+    m = geo.kernel // geo.stride
+    with jax.named_scope("blk_pool"):
+        seg = k_rows[:, :geo.stride * (n + m - 1)].astype(jnp.float32) \
+            .reshape(B, n + m - 1, geo.stride, Hkv, D).sum(axis=2)
+        return sum(seg[:, i:i + n] for i in range(m)) / geo.kernel
+
+
+def pooled_at(pos, geo: BlockGeometry, n_rows=None):
+    """The row of a pooled-key cache that a call at position `pos` writes
+    from. A tile (pos a scalar; `n_rows` None): the first kernel it may
+    end. One decode row a slot (pos [B]) of a cache of `n_rows` rows: the
+    kernel the row ends, or, where it ends none, the cache's last row,
+    whose kernel would end past the cache and is seen by no query."""
+    if n_rows is None:
+        return jnp.maximum(
+            pos // geo.stride - geo.kernel // geo.stride + 1, 0)
+    ends = (pos + 1 >= geo.kernel) & ((pos + 1 - geo.kernel)
+                                      % geo.stride == 0)
+    return jnp.where(ends, (pos + 1 - geo.kernel) // geo.stride, n_rows - 1)
+
+
+def tile_pooled_keys(k_cache, pos0, tile: int, geo: BlockGeometry):
+    """The pooled keys a tile of `tile` rows at position `pos0` (traced)
+    writes at row `pooled_at(pos0, geo)`, out of k_cache
+    [B, M, Hkv, D] that holds the tile's rows -> [B, tile / stride, Hkv,
+    D] float32. They are every kernel that ENDS inside the tile and,
+    where the tile starts on no stride, one that ends past it: that one is
+    written again, by the tile or decode row that ends it, before a query
+    can see it."""
+    n = max(tile // geo.stride, 1)
+    first = pooled_at(pos0, geo)
+    # the last kernel may read past the cache's end: zeros, never seen
+    rows = jax.lax.dynamic_slice_in_dim(
+        jnp.pad(k_cache, ((0, 0), (0, geo.kernel), (0, 0), (0, 0))),
+        first * geo.stride, geo.stride * (n - 1) + geo.kernel, 1)
+    return pool_keys(rows, n, geo)
+
+
+def row_pooled_key(k_cache, k_new, lens, geo: BlockGeometry):
+    """The pooled key one decode row a slot may end (written at row
+    `pooled_at(lens, geo, rows)`): k_new [B, 1, Hkv, D] at position
+    lens[b], beside k_cache [B, M, Hkv, D] that holds the positions below
+    -> [B, 1, Hkv, D] float32."""
+    M = k_cache.shape[1]
+    start = jnp.clip(lens + 1 - geo.kernel, 0, M - geo.kernel)
+    # a slice a slot: batched, XLA gathers them out of the whole pool's
+    # layer (0.4 ms for 16 x 32 rows, my chip run, PR 39)
+    win = jnp.stack([jax.lax.dynamic_slice_in_dim(
+        k_cache[b], start[b], geo.kernel, 0)
+        for b in range(k_cache.shape[0])])
+    own = (jnp.arange(geo.kernel)[None, :] == (lens - start)[:, None])
+    win = jnp.where(own[:, :, None, None], k_new, win)
+    return pool_keys(win, 1, geo)
+
+
+def block_select(q, kp, qpos, geo: BlockGeometry):
+    """The selected blocks [B, Hkv, S, M / block] bool of queries q
+    [B, S, H, D] at absolute positions `qpos` [B, S] against the pooled
+    keys kp [B, NK, Hkv, D] (kernel j at row j)."""
+    B, S, H, D = q.shape
+    NK, Hkv = kp.shape[1], kp.shape[2]
+    r, m = geo.block // geo.stride, geo.kernel // geo.stride
+    NB = NK // r
+    with jax.named_scope("blk_select"):
+        qg = q.reshape(B, S, Hkv, H // Hkv, D)
+        s = jnp.einsum("bshgd,bjhd->bhgsj", qg, kp.astype(q.dtype),
+                       preferred_element_type=jnp.float32) * D ** -0.5
+        ends = jnp.arange(NK) * geo.stride + geo.kernel
+        seen = (ends[None, None, :] <= qpos[:, :, None] + 1)[:, None, None]
+        s = jnp.where(seen, s, -jnp.inf)
+        top = jnp.max(s, axis=-1, keepdims=True)
+        e = jnp.where(seen, jnp.exp(s - jnp.where(top > -jnp.inf, top, 0.0)),
+                      0.0)
+        p = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+        rj = jnp.sum(p, axis=2)                              # [B,Hkv,S,NK]
+        # block b is touched by kernels r b - m + 1 .. r b + r - 1: a
+        # max-pool of width r + m - 1, stride r, padding m - 1
+        rj = jnp.pad(rj, ((0, 0),) * 3 + ((m - 1, 0),),
+                     constant_values=-jnp.inf)
+        R = functools.reduce(jnp.maximum, (
+            rj[..., i:i + r * NB:r] for i in range(r + m - 1)))
+        b0 = jnp.arange(NB) * geo.block
+        t = qpos[:, None, :, None]                           # [B,1,S,1]
+        forced = (b0 < geo.init * geo.block) | (
+            b0 + geo.block > t - geo.window + 1)
+        score = jnp.where(forced, jnp.inf, R)
+        score = jnp.where(b0 <= t, score, -jnp.inf)
+        return select(score, geo.topk)
+
+
+def _positions_mask(sel, qpos, first: int, n: int, block: int):
+    """[B, Hkv, 1, S, n]: the selected blocks `sel` [B, Hkv, S, n / block]
+    spread over positions first .. first + n - 1, and causal."""
+    kpos = first + jnp.arange(n)
+    return (jnp.repeat(sel, block, axis=-1)
+            & (kpos <= qpos[:, None, :, None]))[:, :, None]
+
+
+def block_prefill_attention(q, k_cache, v_cache, kp_cache, pos0,
+                            geo: BlockGeometry):
+    """A tile q [B, S, H, D] at absolute positions pos0 + 0..S-1 (pos0 a
+    scalar or [B]) against caches [B, M, Hkv, D] that already hold the
+    tile's own rows, and pooled keys [B, M / stride, Hkv, D] that hold the
+    kernels the tile ends. Blocked over the keys with a running softmax,
+    over the blocks up to the tile's last position only."""
+    B, S, H, D = q.shape
+    M, Hkv = k_cache.shape[1], k_cache.shape[2]
+    qpos = jnp.broadcast_to(
+        jnp.reshape(pos0, (-1, 1)) + jnp.arange(S)[None, :], (B, S))
+    sel = block_select(q, kp_cache, qpos, geo)
+    kb = _block_of(M)
+    n_live = jnp.minimum((jnp.max(qpos) + kb) // kb, M // kb)
+
+    def block_of(i, qg):
+        kblk = jax.lax.dynamic_slice_in_dim(k_cache, i * kb, kb, 1)
+        vblk = jax.lax.dynamic_slice_in_dim(v_cache, i * kb, kb, 1)
+        mb = _positions_mask(
+            jax.lax.dynamic_slice_in_dim(sel, i * (kb // geo.block),
+                                         kb // geo.block, 3),
+            qpos, i * kb, kb, geo.block)
+        s = jnp.einsum("bshgd,bmhd->bhgsm", qg, kblk,
+                       preferred_element_type=jnp.float32) * D ** -0.5
+        return s, mb, vblk
+
+    with jax.named_scope("blk_attend"):
+        return _blocked_softmax(q, Hkv, n_live, block_of)
+
+
+def block_decode_attention(q, k_new, v_new, k_cache, v_cache, kp_cache, lens,
+                           geo: BlockGeometry):
+    """One row a slot: q [B, 1, H, D] at position lens[b]; the caches
+    [B, M, Hkv, D] hold the positions below lens[b] and are only read: the
+    row's own key and value come beside them (its block is always among
+    the selected). kp_cache already holds the kernel the row ends, if it
+    ends one. One pass over the slot: XLA's gather of the selected blocks
+    is no faster at these lengths than the read (PERF.md section 6)."""
+    B, _, H, D = q.shape
+    M, Hkv = k_cache.shape[1], k_cache.shape[2]
+    lens = jnp.broadcast_to(jnp.reshape(lens, (-1,)), (B,))
+    sel = block_select(q, kp_cache, lens[:, None], geo)
+    with jax.named_scope("blk_attend"):
+        qg = q.reshape(B, 1, Hkv, H // Hkv, D)
+        mask = _positions_mask(sel, lens[:, None] - 1, 0, M, geo.block)
+        s = jnp.einsum("bshgd,bmhd->bhgsm", qg, k_cache,
+                       preferred_element_type=jnp.float32) * D ** -0.5
+        own = jnp.einsum("bshgd,bshd->bhgs", qg, k_new,
+                         preferred_element_type=jnp.float32) * D ** -0.5
+        s = jnp.concatenate([jnp.where(mask, s, _NEG), own[..., None]], -1)
+        p = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("bhgsm,bmhd->bshgd", p[..., :M].astype(
+            v_cache.dtype), v_cache, preferred_element_type=jnp.float32) \
+            + p[..., M].transpose(0, 3, 1, 2)[..., None] \
+            * v_new.astype(jnp.float32)[:, :, :, None, :]
+        return out.reshape(B, 1, H, D).astype(q.dtype)
+
+
+def block_attention(q, k, v, geo: BlockGeometry):
+    """A sequence against itself, positions 0..L-1 of every row: the tile
+    form on the sequence as its own cache, padded to whole blocks."""
+    L = q.shape[1]
+    pad = -L % max(geo.block, geo.kernel)
+    kc, vc = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (k, v))
+    n = (L + pad) // geo.stride
+    kp = pool_keys(jnp.pad(kc, ((0, 0), (0, geo.kernel), (0, 0), (0, 0))),
+                   n, geo)
+    return block_prefill_attention(q, kc, vc, kp.astype(k.dtype), 0, geo)

@@ -79,12 +79,58 @@ class TransformerConfig:
     index_heads: int = 0
     index_head_dim: int = 64
     index_topk: int = 2048
+    # layers of more than one kind: the kind of each layer's mixer, in
+    # order and of any order (data, not a period). "blk": grouped-query
+    # attention over blocks selected from mean-pooled keys (the `blk_*`
+    # sizes; models/sparse_attention.py `block_select`); "lin": lightning
+    # linear attention, `n_heads` heads of `head_dim` with a recurrent
+    # state in float32 and no cache of keys (models/linear_attention.py).
+    # Each kind's layers keep caches of their own (`cache_shapes`), stacked
+    # over that kind's layers, and the layers are not scanned. None: every
+    # layer is the attention above
+    mixer_kinds: Optional[Tuple[str, ...]] = None
+    blk_size: int = 64          # positions a selectable block holds
+    blk_kernel: int = 32        # keys a pooled key is the mean of
+    blk_stride: int = 16        # positions from one kernel to the next
+    blk_init: int = 1           # leading blocks always attended
+    blk_window: int = 2048      # trailing positions always attended
+    blk_topk: int = 64          # blocks attended in all
+    # rotary on the attention's q and k ("lin" layers always have it)
+    attn_rope: bool = True
+    # a sigmoid gate on the mixer's output, from the mixer's input, before
+    # the output projection
+    out_gate: bool = False
+    # muP scalings: the embedding's rows times `scale_emb`, each residual
+    # branch times `residual_scale`, the final hidden times `logit_scale`
+    # before the unembedding
+    scale_emb: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
 
     def __post_init__(self):
         if not self.head_dim:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.n_heads)
+        kinds = self.mixer_kinds
+        if kinds is not None:
+            object.__setattr__(self, "mixer_kinds", tuple(kinds))
+            if (len(kinds) != self.n_layers or set(kinds) - set(KIND_CACHES)
+                    or self.scan_layers or self.index_heads):
+                raise ValueError(
+                    f"mixer_kinds {kinds}: one of {sorted(KIND_CACHES)} for "
+                    f"each of the {self.n_layers} layers, with "
+                    f"scan_layers=False and no indexer")
+            if (self.blk_kernel % self.blk_stride
+                    or self.blk_size % self.blk_stride
+                    or self.blk_size & (self.blk_size - 1)):
+                raise ValueError(
+                    "blk_kernel and blk_size are whole strides, blk_size a "
+                    "power of two")
 
+
+# the caches a layer of each kind keeps, in the order every tuple of them
+# keeps (`cache_shapes` says their shapes, CACHE_POS_AXIS their nature)
+KIND_CACHES = {"blk": ("k", "v", "kp"), "lin": ("s",)}
 
 _PARTITION_OFF = __import__("threading").local()
 
@@ -244,12 +290,21 @@ class LayerNorm(nn.Module):
         return (y * scale + bias).astype(self.dtype)
 
 
+def _block_geometry(cfg: TransformerConfig):
+    from ray_tpu.models.sparse_attention import BlockGeometry
+    return BlockGeometry(cfg.blk_size, cfg.blk_kernel, cfg.blk_stride,
+                         cfg.blk_init, cfg.blk_window, cfg.blk_topk)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
     # static: route L>1 cache writes through the cached attention (prefill
     # CONTINUES an occupied cache — chunked prefill) instead of assuming
     # an empty cache and using the fused kernel
     chunked: bool = False
+    # "blk": this layer attends blocks selected from pooled keys
+    # (`_block_sparse`); None: every earlier position, or the indexer's
+    kind: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, positions, cache=None, slots=None):
@@ -284,8 +339,9 @@ class Attention(nn.Module):
                         name="q_norm")(q)
             k = RMSNorm(cfg.norm_eps, cfg.dtype, "head_dim",
                         name="k_norm")(k)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        if cfg.attn_rope:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
         proj = nn.DenseGeneral(
             E, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="o",
@@ -293,6 +349,8 @@ class Attention(nn.Module):
                            "heads", "head_dim", "embed"))
         if cfg.index_heads:
             return self._sparse(x, positions, cache, q, k, v, proj, dense)
+        if self.kind == "blk":
+            return self._block_sparse(x, cache, slots, q, k, v, proj, dense)
         if cache is None:
             out = attention_dispatch(q, k, v, causal=True,
                                      impl=cfg.attention_impl)
@@ -365,6 +423,121 @@ class Attention(nn.Module):
                 *layer)
         return proj(out), (k, v, ki)
 
+    def _block_sparse(self, x, cache, slots, q, k, v, proj, dense):
+        """A "blk" layer (models/sparse_attention.py, selection by block):
+        K and V as above and a third cache of pooled keys, a row for every
+        `blk_stride` positions, written as K is: a tile writes the kernels
+        it ends, a decode row the one it ends, if any."""
+        from ray_tpu.models import sparse_attention as sa
+        cfg = self.cfg
+        H, D = cfg.n_heads, cfg.head_dim
+        geo = _block_geometry(cfg)
+        if cfg.out_gate:
+            gate = nn.sigmoid(dense((H, D), ("embed", "heads", "head_dim"),
+                                    "gate")(x))
+            out_of = lambda o: proj(o * gate)                # noqa: E731
+        else:
+            out_of = proj
+        if cache is None:
+            return out_of(sa.block_attention(q, k, v, geo))
+
+        def tile(q, k, v, layer, idx):
+            kc, vc, kpc = layer
+            kc, vc = _cache_write(kc, k, idx), _cache_write(vc, v, idx)
+            kp = sa.tile_pooled_keys(kc, idx, q.shape[1], geo).astype(
+                kpc.dtype)
+            at = sa.pooled_at(idx, geo)
+            return sa.block_prefill_attention(
+                q, kc, vc, _cache_write(kpc, kp, at), idx, geo), kp
+
+        def rows(q, k, v, layer, lens):
+            kc, vc, kpc = layer
+            lens = jnp.broadcast_to(jnp.reshape(lens, (-1,)), q.shape[:1])
+            kp = sa.row_pooled_key(kc, k, lens, geo).astype(kpc.dtype)
+            at = sa.pooled_at(lens, geo, kpc.shape[1])
+            return sa.block_decode_attention(
+                q, k, v, kc, vc, _cache_write(kpc, kp, at), lens, geo), kp
+
+        layer, idx = cache
+        if slots is not None:
+            pools, lens, _ = slots
+            (q, qr), (k, kr), (v, vr) = (
+                _split_rows(a, len(lens)) for a in (q, k, v))
+            out, kp = tile(q, k, v, layer, idx)
+            # not under a `cond` on `on`: handing a branch the slots'
+            # layers makes XLA copy them (K and V of a layer, 285 MB; my
+            # chip run, PR 39); with no slot live the rows are computed
+            # and `_decode` writes none of them
+            out_r, kpr = rows(qr, kr, vr, pools, lens)
+            return out_of(_join_rows(out, out_r)), ((k, v, kp),
+                                                    (kr, vr, kpr))
+        if q.shape[1] > 1:
+            out, kp = tile(q, k, v, layer, idx)
+        else:
+            out, kp = rows(q, k, v, layer, idx)
+        return out_of(out), (k, v, kp)
+
+
+class LightningAttention(nn.Module):
+    """A "lin" layer (models/linear_attention.py): `n_heads` heads of
+    `head_dim` for q, k and v alike, RMSNorm on each head of q and k,
+    rotary, the recurrence with a fixed decay a head, RMSNorm on each head
+    of the output, the sigmoid gate, the output projection. Its cache is
+    the state [B, H, D, D] in float32, which has no position: a call takes
+    the state in and hands the new one back WHOLE (`Attention` hands back
+    rows). `real` [B, L] bool: the rows a request owns; a tile's padded
+    tail must not reach the state."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions, cache=None, slots=None, real=None):
+        from ray_tpu.models import linear_attention as la
+        cfg = self.cfg
+        B, L, E = x.shape
+        H, D = cfg.n_heads, cfg.head_dim
+        dense = lambda name: nn.DenseGeneral(  # noqa: E731
+            (H, D), axis=-1, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name=name,
+            kernel_init=_p(nn.initializers.lecun_normal(),
+                           "embed", "heads", "head_dim"))
+        norm = lambda name: RMSNorm(  # noqa: E731
+            cfg.norm_eps, cfg.dtype, "head_dim", name=name)
+        q, k, v = dense("q")(x), dense("k")(x), dense("v")(x)
+        if cfg.qk_norm:
+            q, k = norm("q_norm")(q), norm("k_norm")(k)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+        gate = nn.sigmoid(dense("gate")(x)) if cfg.out_gate else None
+        proj = nn.DenseGeneral(
+            E, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name="o",
+            kernel_init=_p(nn.initializers.lecun_normal(),
+                           "heads", "head_dim", "embed"))
+
+        def out_of(o):
+            o = norm("o_norm")(o)
+            return proj(o if gate is None else o * gate)
+
+        if cache is None:
+            zero = jnp.zeros((B, H, D, D), jnp.float32)
+            return out_of(la.lightning_scan(q, k, v, zero, real)[0])
+        (state,), _ = cache
+        if slots is not None:
+            (states,), lens, _ = slots
+            n = len(lens)
+            (q, qr), (k, kr), (v, vr) = (
+                _split_rows(a, n) for a in (q, k, v))
+            out, new = la.lightning_scan(
+                q, k, v, state, None if real is None else real[:, :L - n])
+            # always computed (no `cond` on `on`, as in `_block_sparse`)
+            out_r, new_r = la.lightning_step(qr, kr, vr, states)
+            return out_of(_join_rows(out, out_r)), ((new,), (new_r,))
+        if L > 1:
+            out, new = la.lightning_scan(q, k, v, state, real)
+        else:
+            out, new = la.lightning_step(q, k, v, state)
+        return out_of(out), (new,)
+
 
 class MLP(nn.Module):
     cfg: TransformerConfig
@@ -385,16 +558,23 @@ class MLP(nn.Module):
 class Block(nn.Module):
     cfg: TransformerConfig
     chunked: bool = False
+    kind: Optional[str] = None      # of cfg.mixer_kinds, where it has them
 
     @nn.compact
     def __call__(self, x, positions, cache=None, real=None, slots=None):
         cfg = self.cfg
-        att = Attention(cfg, self.chunked, name="attn")(
-            RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x),
-            positions, cache, slots)
+        normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x)
+        if self.kind == "lin":
+            att = LightningAttention(cfg, name="attn")(
+                normed, positions, cache, slots, real)
+        else:
+            att = Attention(cfg, self.chunked, self.kind, name="attn")(
+                normed, positions, cache, slots)
         new_rows = None
         if cache is not None:
             att, new_rows = att
+        if cfg.residual_scale != 1.0:       # muP's depth scaling
+            att = cfg.residual_scale * att
         h = x + att
         normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(h)
         if cfg.n_experts > 0:
@@ -406,6 +586,8 @@ class Block(nn.Module):
                 tail=0 if slots is None else len(slots[1]))
         else:
             y, aux = MLP(cfg, name="mlp")(normed), jnp.zeros((), jnp.float32)
+        if cfg.residual_scale != 1.0:
+            y = cfg.residual_scale * y
         if cache is not None:
             return h + y, aux, new_rows
         return h + y, aux
@@ -474,18 +656,42 @@ def index_cache_shape(cfg: TransformerConfig, batch: int,
 
 # where the positions lie in each pool's layout, counted from its end (so
 # it holds for a pool and for one layer of it); also the order every tuple
-# of a model's pools keeps
-CACHE_POS_AXIS = {"k": -3, "v": -3, "ki": -1}
+# of a model's pools keeps. Two natures: a pool with a position axis is
+# written at a position, a call's new rows beside what it holds ("kp", the
+# pooled keys of a "blk" layer, one row every `blk_stride` positions);
+# None: the pool has no position (the state of a "lin" layer) and a call
+# replaces a row's entry whole
+CACHE_POS_AXIS = {"k": -3, "v": -3, "ki": -1, "kp": -3, "s": None}
 
 
 def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int):
     """{name: shape} of the pools a cache of this model carries: K, V
-    and, where the model has an indexer, its keys."""
+    and, where the model has an indexer, its keys. A model with layers of
+    several kinds: each kind's caches (KIND_CACHES) over THAT kind's
+    layers, K, V and the pooled keys of the "blk" layers and the states
+    of the "lin" layers."""
+    if cfg.mixer_kinds:
+        n_blk = cfg.mixer_kinds.count("blk")
+        n_lin = cfg.mixer_kinds.count("lin")
+        kv = (n_blk,) + kv_cache_shape(cfg, batch, max_len)[1:]
+        shapes = {"k": kv, "v": kv, "kp": (
+            n_blk, batch, max_len // cfg.blk_stride) + kv[3:]} \
+            if n_blk else {}
+        if n_lin:
+            shapes["s"] = (n_lin, batch, cfg.n_heads, cfg.head_dim,
+                           cfg.head_dim)
+        return shapes
     kv = kv_cache_shape(cfg, batch, max_len)
     shapes = {"k": kv, "v": kv}
     if cfg.index_heads:
         shapes["ki"] = index_cache_shape(cfg, batch, max_len)
     return shapes
+
+
+def cache_dtype(name: str, dtype):
+    """The type a pool is kept in: the cache's, but float32 for a pool
+    with no position (a state, which every step reads and rewrites)."""
+    return jnp.float32 if CACHE_POS_AXIS[name] is None else dtype
 
 
 def kv_cache_sharding(shape, mesh, rules=None, name: str = "k"):
@@ -499,7 +705,8 @@ def kv_cache_sharding(shape, mesh, rules=None, name: str = "k"):
     from ray_tpu.parallel.train_step import (_prune_indivisible,
                                              logical_pspec_to_mesh)
     spec = _prune_indivisible(logical_pspec_to_mesh(
-        P(None, "batch", None, "kv_heads" if name != "ki" else None, None),
+        P(None, "batch", None,
+          "kv_heads" if name not in ("ki", "s") else None, None),
         rules or sharding_lib.DEFAULT_RULES), shape, mesh)
     # no trailing None: the spec a program hands a pool back with, so a
     # new pool and a donated one are one sharding to jit's cache (the
@@ -517,7 +724,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     layers; the cached forward returns the same pool with this call's
     rows added in place (see TransformerLM._decode)."""
     dtype = dtype or cfg.dtype
-    cache = {name: jnp.zeros(shape, dtype)
+    cache = {name: jnp.zeros(shape, cache_dtype(name, dtype))
              for name, shape in cache_shapes(cfg, batch, max_len).items()}
     cache["idx"] = jnp.zeros((), jnp.int32)
     return cache
@@ -567,6 +774,8 @@ class TransformerLM(nn.Module):
             _p(nn.initializers.normal(0.02), "vocab", "embed_lookup"),
             (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
         x = embed.astype(cfg.dtype)[tokens]
+        if cfg.scale_emb != 1.0:
+            x = cfg.scale_emb * x
         # canonical activation layout from the very first op: the embed
         # table's own layout (vocab@tensor, d@fsdp) must not leak into x
         # — fsdp is already spent on the batch dim, and GSPMD bridges the
@@ -612,7 +821,9 @@ class TransformerLM(nn.Module):
                     Block, prevent_cse=False, policy=remat_policy)
             aux_total = jnp.zeros((), jnp.float32)
             for i in range(cfg.n_layers):
-                x, aux_i = block(cfg, name=f"layer_{i}")(x, positions)
+                kind = cfg.mixer_kinds[i] if cfg.mixer_kinds else None
+                x, aux_i = block(cfg, kind=kind, name=f"layer_{i}")(
+                    x, positions)
                 aux_total = aux_total + aux_i
         if cfg.n_experts > 0:
             # surfaced to the train step via mutable=["losses"]; a no-op
@@ -638,6 +849,8 @@ class TransformerLM(nn.Module):
         """Shared output head (training/prefill AND decode): final-norm
         hidden -> vocab logits, honoring tie_embeddings/logits_fp32."""
         cfg = self.cfg
+        if cfg.logit_scale != 1.0:
+            x = cfg.logit_scale * x
         if cfg.tie_embeddings:
             logits = jnp.einsum("bld,vd->blv", x, embed.astype(cfg.dtype))
         else:
@@ -686,7 +899,8 @@ class TransformerLM(nn.Module):
         # [B, L] bool, the rows a request owns (a prefill tile's padded
         # tail and an idle slot's row are not; absent: all): the expert
         # layer routes no other
-        real = cache.get("real") if cfg.n_experts > 0 else None
+        real = cache.get("real") if cfg.n_experts > 0 or cfg.mixer_kinds \
+            else None
         # one row a slot of a model with an indexer GATHERS its K and V:
         # the layers' loop is handed the whole pools and the layer's
         # number, not the layer sliced out (which would be copied whole
@@ -707,21 +921,47 @@ class TransformerLM(nn.Module):
                 carry, pools, slot_pools,
                 *((jnp.arange(cfg.n_layers),) if whole else ()))
         else:
-            rows = []
+            # layer i reads entry j of ITS kind's pools (every pool's,
+            # where the layers are of one kind) and hands its rows back
+            got = ({n: [] for n in names}, {n: [] for n in names})
+            seen: dict = {}
             for i in range(cfg.n_layers):
+                kind = cfg.mixer_kinds[i] if cfg.mixer_kinds else None
+                of = KIND_CACHES.get(kind, names)
+                j = seen[kind] = seen.get(kind, -1) + 1
                 x, _aux, new_rows = Block(
-                    cfg, chunked_prefill, name=f"layer_{i}")(
-                    x, positions, (tuple(p[i] for p in pools), idx), real,
-                    slots and (tuple(p[i] for p in slot_pools),
+                    cfg, chunked_prefill, kind, name=f"layer_{i}")(
+                    x, positions,
+                    (tuple(cache[n][j] for n in of), idx), real,
+                    slots and (tuple(slots[n][j] for n in of),
                                *carry[-1]))
-                rows.append(new_rows)
-            rows = jax.tree.map(lambda *r: jnp.stack(r), *rows)
+                for into, new in zip(got, new_rows if slots
+                                     else (new_rows,)):
+                    for n, r in zip(of, new):
+                        into[n].append(r)
+            # a state is not stacked: its layers are written one by one
+            rows = tuple(
+                tuple(jnp.stack(into[n]) if CACHE_POS_AXIS[n] is not None
+                      else tuple(into[n]) for n in names)
+                if into[names[0]] else None for into in got)
+            rows = rows if slots else rows[0]
 
-        def write(n, pool, rows, idx):
+        def write(n, pool, rows, idx, tile=False):
+            if CACHE_POS_AXIS[n] is None:
+                # a state: each layer's replaced whole, in place (the
+                # layers stacked and handed back cost two more passes
+                # over the pool, 1.5 ms a step; my chip run, PR 39)
+                for j, new in enumerate(rows):
+                    pool = pool.at[j].set(new.astype(pool.dtype))
+                return pool
+            if n == "kp":                   # at the kernels the call ends
+                from ray_tpu.models import sparse_attention as sa
+                idx = sa.pooled_at(idx, _block_geometry(cfg),
+                                   None if tile else pool.shape[2])
             return _cache_write(pool, rows, idx, CACHE_POS_AXIS[n])
 
         rows, slot_rows = rows if slots else (rows, None)
-        new_cache = {n: write(n, p, r, idx)
+        new_cache = {n: write(n, p, r, idx, L > 1)
                      for n, p, r in zip(names, pools, rows)}
         new_cache["idx"] = idx + L
         if slots:
