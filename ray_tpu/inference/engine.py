@@ -60,6 +60,7 @@ greedy engine output is bit-identical to the one-program generator.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -264,12 +265,25 @@ class InferenceEngine:
         # slot live, or idle)
         self.fused_steps = 0
         self.tokens_generated = 0
-        # sparse attention (a model with an indexer): K/V rows a decode
-        # row read (the selected ones) and rows live, summed over the
-        # decode rows of every step; host arithmetic on the lengths
+        # sparse attention (a model with an indexer), summed over the
+        # decode rows of every step, all host arithmetic on the lengths:
+        # the positions live (the row's own among them); the positions
+        # the selection leaves a row to attend, min(live, topk): the
+        # selection's arithmetic, NOT the chip's reads; and the positions
+        # of K and V the decode attention passes over for it, which is
+        # what the chip reads of the cache (sparse_attention.
+        # decode_positions_read: whole key blocks, each row's own where
+        # the kernel runs, the longest live row's for every row where the
+        # XLA loop does)
         self._topk = mcfg.index_topk if mcfg.index_heads else 0
         self.dsa_rows_read = 0
         self.dsa_rows_live = 0
+        self.dsa_rows_streamed = 0
+        if self._topk:
+            from ray_tpu.models import sparse_attention
+            self._dsa_streamed = functools.partial(
+                sparse_attention.decode_positions_read,
+                M=cfg.max_len, Hkv=mcfg.n_kv_heads, D=mcfg.head_dim)
         # selection by block (a model with "blk" layers): the positions the
         # selection leaves a decode row to attend (those up to its own of
         # the blocks it selects) and the positions live, summed alike. Host
@@ -339,11 +353,12 @@ class InferenceEngine:
         # tile, and the weights stream once for both. Two engines keep
         # the tile program without them and two calls a step: one with a
         # speculative draft, whose own step replaces decode; and one whose
-        # model has an indexer: its decode row's time is its own sort and
-        # gathers (8.8 of 15.55 ms), not the weights' stream, and riding
-        # saved nothing (one program 57.2-59.4 ms against 41.7 + 15.55)
-        # while a first token waited for the rows' work (my chip runs,
-        # PR 35; PERF.md section 6)
+        # model has an indexer: while its decode row sorted and gathered
+        # (8.8 of 15.55 ms) riding saved nothing (one program 57.2-59.4
+        # ms against 41.7 + 15.55) and a first token waited for the rows'
+        # work (my chip runs, PR 35; PERF.md section 6). The row reads
+        # its cache in place since PR 41 and this was not measured again
+        # (section 7)
         ride = self._ride = self._spec is None and not model.cfg.index_heads
 
         def forward(params, tokens, pools, idx, real=None, slots=None,
@@ -696,6 +711,9 @@ class InferenceEngine:
                             if st.slot is None:
                                 break    # finished (EOS / max tokens)
                 else:
+                    if self._topk and active:   # before the rows' own
+                        self.dsa_rows_streamed += self._dsa_streamed(
+                            [int(self._lengths[st.slot]) for st in active])
                     for st in active:
                         slot = st.slot
                         self._lengths[slot] += 1
@@ -1088,6 +1106,7 @@ class InferenceEngine:
         if self._topk:
             out["dsa_rows_read"] = self.dsa_rows_read
             out["dsa_rows_live"] = self.dsa_rows_live
+            out["dsa_rows_streamed"] = self.dsa_rows_streamed
         if self._blk:
             out["blk_rows_read"] = self.blk_rows_read
             out["blk_rows_live"] = self.blk_rows_live

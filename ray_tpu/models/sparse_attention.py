@@ -24,8 +24,12 @@ holds (models/transformer.py `Attention`):
   runs block by block with a running softmax, so no [.., S, M] score of
   the attention heads exists.
 - `sparse_decode_attention`: one row a slot against the slot's cache.
-  An exact sort of the live index scores, then a GATHER of the selected
-  rows of K and V: the cache's other rows are not read.
+  The same radix threshold over the slot's live index scores and the
+  row's own gives the set as a MASK (no sort), and K and V are attended
+  where they lie in the pools, block by block over the live positions
+  under that mask, with a running softmax (no gather): on a TPU by the
+  kernel of `ops/decode_attention.py`, each slot's blocks up to its own
+  last live one; elsewhere by an XLA loop over the same blocks.
 
 Selection is exact in all three (no approximate top-k, no block-level
 stand-in); at t + 1 <= topk it is every live position.
@@ -42,6 +46,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import decode_attention
+
 _NEG = -1e30
 _MAX_BLOCK = 512        # keys a block of the blocked forms holds
 _ONE_PASS = 1 << 20     # index scores of up to this many are one pass
@@ -50,10 +56,7 @@ _SELECT_BUCKETS = 4     # prefixes of the cache a tile's selection may run on
 
 def _block_of(m: int) -> int:
     """The largest power of two up to _MAX_BLOCK that divides M."""
-    b = _MAX_BLOCK
-    while m % b:
-        b //= 2
-    return b
+    return decode_attention.block_of(m, _MAX_BLOCK)
 
 
 def _head_scores(qi, w, ki):
@@ -124,7 +127,10 @@ def kth_largest(keys, k: int, bits: int = 2):
     has fewer than k keys above 0). A radix search from the top bits
     down, `bits` at a time: 32 / bits passes over the row, each counting
     against 2**bits - 1 candidates (2 bits: 1.4 ms for [1024, 18432]
-    against 2.0 ms at 4 and a sort's 23, my chip run, PR 34). Exact."""
+    against 2.0 ms at 4 and a sort's 23, my chip run, PR 34; for a decode
+    step's [8, 8705] 2 and 4 bits both 3 us against 95 us at 8 and a
+    sort's 166, my chip run, PR 41: a pass is under a microsecond, so a
+    few rows want no wider digit either). Exact."""
     prefix = jnp.zeros(keys.shape[:-1], jnp.uint32)
     digits = jnp.arange(1, 1 << bits, dtype=jnp.uint32)
     for p in range(32 // bits):
@@ -225,6 +231,15 @@ def _softmax_step(carry, s, mb, vb):
     return m_new, l, acc
 
 
+def _softmax_out(carry, q):
+    """A running softmax's carry (`_softmax_step`), divided -> [B, S, H, D]
+    in q's type."""
+    B, S, H, D = q.shape
+    _, l, acc = carry
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, D).astype(q.dtype)
+
+
 def _blocked_softmax(q, Hkv: int, n_live, block_of):
     """The running softmax over key blocks 0 .. n_live - 1 (traced) of a
     tile q [B, S, H, D] against Hkv KV heads -> [B, S, H, D] in q's type.
@@ -235,11 +250,10 @@ def _blocked_softmax(q, Hkv: int, n_live, block_of):
     G = H // Hkv
     qg = q.reshape(B, S, Hkv, G, D)
     m0 = jnp.full((B, Hkv, G, S), _NEG, jnp.float32)
-    _, l, acc = jax.lax.fori_loop(
+    return _softmax_out(jax.lax.fori_loop(
         0, n_live, lambda i, carry: _softmax_step(carry, *block_of(i, qg)),
-        (m0, jnp.zeros_like(m0), jnp.zeros((B, Hkv, G, S, D), jnp.float32)))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, D).astype(q.dtype)
+        (m0, jnp.zeros_like(m0), jnp.zeros((B, Hkv, G, S, D), jnp.float32))),
+        q)
 
 
 def sparse_prefill_attention(q, k_cache, v_cache, qi, w, ki_cache, pos0,
@@ -270,40 +284,79 @@ def sparse_prefill_attention(q, k_cache, v_cache, qi, w, ki_cache, pos0,
         return _blocked_softmax(q, Hkv, n_live, block_of)
 
 
+def _kernel_reads(M: int, Hkv: int, D: int) -> bool:
+    """Whether the decode row reads the pools through the Pallas kernel
+    (ops/decode_attention.py): on a TPU, where the pools' shape fits it.
+    Elsewhere the same blocks are read by an XLA loop."""
+    return jax.default_backend() == "tpu" and decode_attention.fits(
+        M, Hkv, D)
+
+
+def decode_positions_read(lens, M: int, Hkv: int, D: int) -> int:
+    """Host arithmetic: the positions of K and V that
+    `sparse_decode_attention` passes over for decode rows whose slots hold
+    `lens` positions of caches [.., M, Hkv, D], each row's own counted
+    too. Whole key blocks: up to each row's own last live one where the
+    kernel reads, up to the longest row's, for every row, where the XLA
+    loop does."""
+    block = decode_attention.block_of(M)
+    if not _kernel_reads(M, Hkv, D):
+        lens = [max(lens)] * len(lens)
+    return sum(-(-n // block) * block + 1 for n in lens)
+
+
 def sparse_decode_attention(q, k_new, v_new, qi, w, ki_new, k_cache,
                             v_cache, ki_cache, lens, topk: int, layer=None):
     """One row a slot: q [B, 1, H, D] at position lens[b] (a scalar or
     [B]); the caches [B, M, ..] (or, with `layer`, that layer of the
     pools [n_layers, B, M, ..]) hold positions below lens[b] and are only
     read: the row's own k, v and indexer key come beside them and stand
-    as position M of the selection. Of K and V only the selected rows are
-    read, by ONE gather of min(topk, M + 1) rows a slot straight out of
-    the pool (a layer sliced out of it first would be copied whole)."""
-    B = q.shape[0]
+    as position M of the selection. The selected set is a MASK over the
+    slot's positions (the exact radix threshold of the tile's form, no
+    sort), and K and V are attended where they lie in the pools, block by
+    block over the live positions under that mask, with a running softmax
+    into which the row's own key goes last: nothing is gathered, and a
+    layer is never sliced out of the pool (it would be copied whole). On
+    a TPU the blocks are read by `ops.decode_attention`'s kernel, each
+    slot's up to ITS last live block; elsewhere (and where the pools'
+    shape does not fit the kernel) by an XLA loop over the blocks up to
+    the LONGEST live slot's."""
+    B, _, H, D = q.shape
     if layer is None:
         k_cache, v_cache, ki_cache, layer = (k_cache[None], v_cache[None],
                                              ki_cache[None], 0)
-    M = k_cache.shape[2]
+    M, Hkv = k_cache.shape[2], k_cache.shape[3]
     lens = jnp.broadcast_to(jnp.reshape(lens, (-1,)), (B,))
     past = index_scores(qi, w, ki_cache, lens[:, None] - 1, layer)[:, 0]
     with jax.named_scope("dsa_indexer"):
         own = _head_scores(qi, w, ki_new)[:, 0]                    # [B,1]
-    with jax.named_scope("dsa_select"):
-        # exact: a stable sort by falling score, whose first topk are the
-        # set with ties to the lower position (the row itself, the
-        # highest position, stands last)
-        scores = jnp.concatenate([past, own], axis=-1)           # [B,M+1]
-        idx = jnp.argsort(-scores, axis=-1, stable=True)[
-            :, :min(topk, M + 1)]
-        # the live positions sort first: lens[b] in the cache and the row
-        live = jnp.arange(idx.shape[1])[None, :] <= lens[:, None]
-        is_own = (idx == M)[:, :, None, None]
-        at = jnp.minimum(idx, M - 1)
+    # exact, ties to the lower position: the row itself, the highest,
+    # stands last and loses them. A score of -0.0 (every head's relu zero
+    # under negative weights) ties with 0.0, as a sort has it; the
+    # search's order of bits would put it below
+    scores = jnp.concatenate([past, own], axis=-1)              # [B,M+1]
+    # one search over the slot whole: on a prefix that holds the live
+    # positions (`select_live`) it is 1-2 us a layer faster and four more
+    # searches to trace, 3.8 s of a replica's warm-up (8.9 against 5.1 s,
+    # the parent's 4.0; my chip runs, PR 41)
+    sel = select(jnp.where(scores == 0, 0.0, scores), topk)
+    attend = decode_attention.pool_decode_attention \
+        if _kernel_reads(M, Hkv, D) else decode_attention.pool_decode_reference
     with jax.named_scope("dsa_attend"):
-        rows = jnp.arange(B)[:, None]
-        kg = jnp.where(is_own, k_new, k_cache[layer, rows, at])
-        vg = jnp.where(is_own, v_new, v_cache[layer, rows, at])
-        return masked_attention(q, kg, vg, live[:, None, :])
+        m, l, acc = attend(q[:, 0], k_cache, v_cache, layer, lens,
+                           sel[:, :M])
+        # the row's own key and value, as the pools' rows are taken: one
+        # row a KV head, met by all H query heads and counted by its own
+        of_q = jnp.arange(H) // (H // Hkv)
+        ok = sel[:, M:, None] & (jnp.arange(Hkv)[None, None, :]
+                                 == of_q[None, :, None])       # [B,H,Hkv]
+        s = jnp.einsum("bhd,bnd->bhn", q[:, 0], k_new[:, 0],
+                       preferred_element_type=jnp.float32) * D ** -0.5
+        carry = _softmax_step(
+            (m[:, None, :, None], l[:, None, :, None],
+             acc[:, None, :, None]),
+            s[:, None, :, None], ok[:, None, :, None], v_new[:, 0, :, None])
+        return _softmax_out(carry, q)
 
 
 # ---------------------------------------------------------------------------

@@ -891,9 +891,10 @@ class TransformerLM(nn.Module):
         slots = cache.get("slots")
         if slots and cfg.index_heads:
             raise ValueError(
-                "decode rows behind a prefill tile: not with an indexer, "
-                "whose decode row's time is its own sort and gathers and "
-                "not the weights' stream (PERF.md section 6, PR 35)")
+                "decode rows behind a prefill tile: not with an indexer "
+                "(riding gained nothing while its decode row sorted and "
+                "gathered, PERF.md section 6, PR 35; not measured again "
+                "since the row reads its cache in place, section 7)")
         slot_pools = slots and tuple(slots[n] for n in names)
         L = x.shape[1] - (len(slots["idx"]) if slots else 0)
         # [B, L] bool, the rows a request owns (a prefill tile's padded
@@ -901,10 +902,10 @@ class TransformerLM(nn.Module):
         # layer routes no other
         real = cache.get("real") if cfg.n_experts > 0 or cfg.mixer_kinds \
             else None
-        # one row a slot of a model with an indexer GATHERS its K and V:
-        # the layers' loop is handed the whole pools and the layer's
-        # number, not the layer sliced out (which would be copied whole
-        # to be gathered from)
+        # one row a slot of a model with an indexer reads its K and V in
+        # the pools, blocks of its live positions only: the layers' loop
+        # is handed the whole pools and the layer's number, not the layer
+        # sliced out (which would be copied whole to be read from)
         whole = bool(cfg.index_heads) and L == 1
         carry = (x, positions, idx, real,
                  slots and (slots["idx"], slots["on"]))

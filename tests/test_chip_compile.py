@@ -113,6 +113,30 @@ def test_flash_by_name_shards_over_fsdp2_tensor2(topo, no_compile_cache):
         _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
 
 
+@pytest.mark.parametrize("masked", [False, True])
+def test_pool_decode_kernel_takes_the_pool_as_it_lies(one_chip,
+                                                      no_compile_cache,
+                                                      masked):
+    """ops/decode_attention.py at the shapes of Keye-VL-2.0's cell (8
+    slots of 17,408 positions, 4 KV heads of 128, 16 layers): the kernel
+    compiles, and its view of a pool as [.., M * Hkv, D] rows is a bitcast
+    of the parameter, never a copy of a pool (285 MB a layer)."""
+    import re
+
+    from ray_tpu.ops import decode_attention as da
+    assert da.fits(17408, 4, 128)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = s((16, 8, 17408, 4, 128), jnp.bfloat16)
+    args = [s((8, 32, 128), jnp.bfloat16), pool, pool, s((), jnp.int32),
+            s((8,), jnp.int32)] + [s((8, 17408), jnp.bool_)] * masked
+    text = _compile(da.pool_decode_attention, *args).as_text()
+    assert not re.search(r"= bf16\[16,8,[\d,]+\]\S* copy\(", text)
+    assert re.search(r"bf16\[16,8,69632,128\]\S* bitcast\(", text)
+
+
 def test_flash_by_name_never_returns_the_reference():
     """A length the kernel cannot tile raises; it is "auto" that chooses
     by platform and shape (here, on the CPU: the reference)."""

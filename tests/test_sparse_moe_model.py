@@ -29,6 +29,7 @@ from ray_tpu.inference.engine import EngineConfig, InferenceEngine
 from ray_tpu.models import MODEL_REGISTRY, TransformerLM
 from ray_tpu.models import sparse_attention as sa
 from ray_tpu.models.transformer import TransformerConfig, init_cache
+from ray_tpu.ops import decode_attention
 
 TOPK = 16
 
@@ -197,13 +198,13 @@ def _indexer_inputs(L, ties=False, seed=5):
     return qi, ki, w
 
 
-def _reference_set(qi, ki, w):
+def _reference_set(qi, ki, w, topk=TOPK):
     L = qi.shape[1]
     per_head = jax.nn.relu(jnp.einsum("qjk,kl->qjl", qi[0], ki[0, 0]))
     scores = jnp.einsum("qj,qjl->ql", w[0], per_head)
     causal = jnp.arange(L)[None, :] <= jnp.arange(L)[:, None]
     return np.asarray(ref.selected(jnp.where(causal, scores, -jnp.inf),
-                                   TOPK))
+                                   topk))
 
 
 @pytest.mark.parametrize("ties", [False, True])
@@ -221,9 +222,8 @@ def test_selection_is_the_references_set(L, ties):
 
 @pytest.mark.parametrize("L", [8, 16, 17, 96])
 def test_decode_attends_the_references_set(L):
-    """The gather form: the last row's attention over the rows it
-    selected out of the caches equals masked attention over the
-    reference's set."""
+    """The last row's attention over the caches, in place under the mask
+    it selected, equals masked attention over the reference's set."""
     qi, ki, w = _indexer_inputs(L)
     ks = jax.random.split(jax.random.PRNGKey(6), 3)
     q = jax.random.normal(ks[0], (1, L, 4, 32))
@@ -239,6 +239,167 @@ def test_decode_attends_the_references_set(L):
         jnp.pad(ki[..., :-1], ((0, 0), (0, 0), (0, 0), (0, pad))),
         jnp.int32(L - 1), TOPK)[:, 0]
     np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+# slots' lengths (the decode row is a sequence's last; its cache holds the
+# rows before it) and `topk`: below, at, one past and far above `topk`;
+# slots of different lengths in one call, one of them idle at length 0;
+# and a `topk` past M + 1, which is every live position
+DECODE_CASES = {
+    "below": ([8], TOPK), "at": ([16], TOPK), "one_past": ([17], TOPK),
+    "far_above": ([96], TOPK), "mixed": ([96, 8, 1, 17, 40], TOPK),
+    "every_live": ([96, 17], 10 ** 6)}
+CACHE_LEN = 128
+
+
+def _decode_case(Ls, ties, topk, seed=11):
+    """Slots whose sequences have lengths `Ls`: the arguments of
+    `sparse_decode_attention` in the [B, M, ..] form (the caches' dead
+    rows hold finite noise) and what masked attention over the
+    reference's set gives the last row of each."""
+    rows, want = [], []
+    for b, L in enumerate(Ls):
+        qi, ki, w = _indexer_inputs(L, ties, seed=seed + b)
+        ks = jax.random.split(jax.random.PRNGKey(seed + 100 + b), 4)
+        q = jax.random.normal(ks[0], (1, L, 4, 32))
+        k = jax.random.normal(ks[1], (1, L, 2, 32))
+        v = jax.random.normal(ks[2], (1, L, 2, 32))
+        mask = jnp.asarray(_reference_set(qi, ki, w, topk))[None]
+        want.append(sa.masked_attention(q, k, v, mask)[:, -1])
+        pad = CACHE_LEN - (L - 1)
+        noise = jax.random.normal(ks[3], (3, 1, pad, 2, 32))
+        rows.append((
+            q[:, -1:], k[:, -1:], v[:, -1:], qi[:, -1:], w[:, -1:],
+            ki[..., -1:], jnp.concatenate([k[:, :-1], noise[0]], 1),
+            jnp.concatenate([v[:, :-1], noise[1]], 1),
+            jnp.concatenate([ki[..., :-1], noise[2, :, :, 0, :16].transpose(
+                0, 2, 1)[:, None]], -1)))
+    args = [jnp.concatenate(a) for a in zip(*rows)]
+    return args, jnp.asarray([L - 1 for L in Ls], jnp.int32), \
+        jnp.concatenate(want)
+
+
+@pytest.fixture
+def kernel_interpreted(monkeypatch):
+    """`sparse_decode_attention` takes the Pallas kernel, interpreted."""
+    monkeypatch.setattr(sa, "_kernel_reads", lambda *shape: True)
+    monkeypatch.setattr(
+        decode_attention, "pool_decode_attention", functools.partial(
+            decode_attention.pool_decode_attention, interpret=True))
+
+
+@pytest.mark.parametrize("form", ["rows", "pool", "kernel"])
+@pytest.mark.parametrize("ties", [False, True])
+@pytest.mark.parametrize("case", list(DECODE_CASES))
+def test_decode_row_attends_the_references_set_in_place(case, ties, form,
+                                                        request):
+    """Every slot's row equals masked attention over the reference's set:
+    in the [B, M, ..] form, in the pool-with-`layer` form at a layer other
+    than 0 (the other layers hold noise), and through the kernel,
+    interpreted, in place of the XLA loop; with ties at the topk-th score
+    the row's own key, the highest position, loses them."""
+    Ls, topk = DECODE_CASES[case]
+    args, lens, want = _decode_case(Ls, ties, topk)
+    layer = ()
+    if form != "rows":
+        if form == "kernel":
+            request.getfixturevalue("kernel_interpreted")
+        noise = jax.random.PRNGKey(3)
+        args[6:] = [jnp.stack([jax.random.normal(noise, a.shape), a,
+                               -jax.random.normal(noise, a.shape)])
+                    for a in args[6:]]
+        layer = (jnp.int32(1),)
+    got = jax.jit(lambda *a: sa.sparse_decode_attention(
+        *a[:9], a[9], topk, *a[10:]))(*args, lens, *layer)[:, 0]
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("ties", [False, True])
+@pytest.mark.parametrize("lens", [[0, 5, 15, 16], [17, 40, 3, 31],
+                                  [90, 127, 64, 0]])
+def test_decode_rows_mask_is_the_stable_sorts_set(lens, ties):
+    """The mask over a slot's positions and the row's own equals the first
+    `topk` of a stable sort by falling score (the form the decode row had:
+    kept here as the oracle), slots of several lengths in one call, the
+    dead positions at -inf. The search has one digit width whatever the
+    shape (2 bits: a wider one gained nothing for a few rows, PERF.md
+    section 6, PR 41)."""
+    M = CACHE_LEN
+    lens = jnp.asarray(lens)
+    scores = jax.random.normal(jax.random.PRNGKey(int(lens[0])), (4, M + 1))
+    # few distinct scores: many ties at the topk-th (0.0, not -0.0: the
+    # decode row hands the search no negative zero)
+    scores = jnp.round(scores) if ties else scores
+    scores = jnp.where(scores == 0, 0.0, scores)
+    scores = jnp.where(jnp.arange(M + 1)[None] < lens[:, None], scores,
+                       -jnp.inf).at[:, M].set(scores[:, M])
+    idx = jnp.argsort(-scores, axis=-1, stable=True)[:, :TOPK]
+    want = np.zeros((4, M + 1), bool)
+    np.put_along_axis(want, np.asarray(idx), True, axis=1)
+    want &= np.asarray(scores) > -np.inf
+    got = sa.select(scores, TOPK)
+    assert (np.asarray(got) == want).all()
+    assert (want.sum(-1) == np.minimum(np.asarray(lens) + 1, TOPK)).all()
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("lens", [[0, 0, 0], [1, 33, 128], [64, 0, 97]])
+def test_pool_kernel_interpreted_is_the_xla_loop(lens, masked):
+    """ops/decode_attention.py: the kernel (interpreted) and the XLA loop
+    give the same running softmax of a pool's layer, slots at their own
+    lengths (an idle one at 0), with a mask and without, over blocks
+    smaller than the cache so that a slot's dead blocks are met."""
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    q = jax.random.normal(ks[0], (3, 4, 32))
+    kp = jax.random.normal(ks[1], (3, 3, CACHE_LEN, 2, 32))
+    vp = jax.random.normal(ks[2], (3, 3, CACHE_LEN, 2, 32))
+    mask = jax.random.bernoulli(ks[3], 0.4, (3, CACHE_LEN)) if masked \
+        else None
+    args = (q, kp, vp, jnp.int32(2), jnp.asarray(lens, jnp.int32), mask)
+    want = decode_attention.pool_decode_reference(*args, max_block=32)
+    got = decode_attention.pool_decode_attention(*args, max_block=32,
+                                                 interpret=True)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-5, rtol=1e-5)
+    if not masked and max(lens) == 0:
+        assert float(jnp.abs(got[2]).max()) == 0.0   # nothing attended
+
+
+def _tile_program():
+    model = build(config(4))
+    S = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: meta.unbox(model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    cache = dict(jax.eval_shape(lambda: init_cache(model.cfg, 1, 112)),
+                 idx=S((), jnp.int32), real=S((1, 16), jnp.bool_))
+    return jax.jit(lambda p, toks, c: model.apply(
+        {"params": p}, toks, cache=c, chunked_prefill=True)).lower(
+        params, S((1, 16), jnp.int32), cache)
+
+
+def _block_select_program(B, S):
+    # MiniCPM-SALA's geometry against a 17,408-position cache's kernels
+    geo = sa.BlockGeometry(64, 32, 16, 1, 2048, 64)
+    s = jax.ShapeDtypeStruct
+    return jax.jit(lambda q, kp, qpos: sa.block_select(
+        q, kp, qpos, geo)).lower(
+        s((B, S, 4, 32), jnp.float32), s((B, 1088, 2, 32), jnp.float32),
+        s((B, S), jnp.int32))
+
+
+@pytest.mark.parametrize("program,digest", [
+    (_tile_program, "eba88afd506ec9e5"),
+    (functools.partial(_block_select_program, 1, 8), "9077eaa2cb26a582"),
+    (functools.partial(_block_select_program, 4, 1), "b075abdd0ab7d65c")])
+def test_programs_the_decode_row_shares_code_with_are_the_parents(program,
+                                                                  digest):
+    """The lowered text of the indexer model's TILE program and of the
+    selection by block (a tile's and the decode rows') as read on the
+    commit before the decode row's selection took a digit of its own
+    (PR 40's tree): the radix search and the blocked softmax they share
+    with it reach their old defaults, line for line."""
+    text = program().as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
 
 
 # ------------------------------------------------------ shares of a layer
@@ -340,6 +501,16 @@ def test_engine_tokens_are_the_references_and_counters_count(small, engine):
     live = sum(range(71, 80))       # nine decode rows, at lengths 71..79
     assert st["dsa_rows_live"] - before["dsa_rows_live"] == live
     assert st["dsa_rows_read"] - before["dsa_rows_read"] == 9 * TOPK
+    # what the decode attention passes over: the cache's 70..78 positions
+    # in whole key blocks (32 divides the slot's 96), and the row's own
+    block = decode_attention.block_of(96)
+    assert block == 32
+    assert st["dsa_rows_streamed"] - before["dsa_rows_streamed"] == sum(
+        -(-n // block) * block + 1 for n in range(70, 79))
+    # rows of several lengths: each its own blocks where the kernel
+    # reads, the longest one's for every row where the XLA loop does
+    assert sa.decode_positions_read([5, 40, 70], 96, 2, 32) == 3 * 97
+    assert sa.decode_positions_read([0], 96, 2, 32) == 1
     picks = st["moe_local_picks"] - before["moe_local_picks"]
     rows = st["moe_rows_computed"] - before["moe_rows_computed"]
     assert 0 < picks <= rows
