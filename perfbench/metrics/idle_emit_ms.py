@@ -1,0 +1,12 @@
+"""Engine: milliseconds a step in which no program ran on the device while
+the engine's thread was emitting
+(the walk over the slots, the counters, the recorder, on_step),
+over the whole `engine.step` spans of the traced slice
+(perfbench/host_spans.py: the stretches between module executions, split
+among the `engine.emit` spans they overlap). None where the trace holds no
+`engine.step`."""
+from perfbench import host_spans
+
+
+def read(run):
+    return host_spans.idle_ms(run, "emit")

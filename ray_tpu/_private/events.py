@@ -50,6 +50,7 @@ __all__ = [
     "Span", "current_context", "trace_context", "new_trace_id",
     "new_span_id", "enabled", "set_enabled", "flush", "drain", "stats",
     "configure", "set_sink", "set_identity", "set_tap", "peek",
+    "annotate",
 ]
 
 _lock = threading.Lock()
@@ -255,6 +256,24 @@ def record_complete(name: str, start: float, end: float,
              "trace_id": trace_id or new_trace_id(),
              "span_id": new_span_id(), "parent_span_id": parent_span_id,
              "start": start, "end": max(end, start), "attrs": attrs})
+
+
+# ------------------------------------------------ the profiler's own trace
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotate(name: str, **attrs):
+    """A context manager that puts the span `name`, with `attrs`, on the
+    calling thread's line of the JAX profiler's trace, where the device's
+    programs are on the same clock (`jax.profiler.TraceAnnotation`: under
+    a microsecond with no trace running). It writes nothing to the ring.
+    In a process that has not imported JAX it is nothing at all, and this
+    module never imports JAX: the load generator imports it and must
+    open no backend."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_ANNOTATION
+    return jax.profiler.TraceAnnotation(name, **attrs)
 
 
 # -------------------------------------------------------------- ring + flush

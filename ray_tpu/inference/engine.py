@@ -94,6 +94,79 @@ def prefill_tiles(chunk: int, budget: int) -> tuple:
     return tuple(tiles)
 
 
+PHASES = ("plan", "dispatch", "read", "emit")
+
+
+class _StepPhases:
+    """The marks of an engine step, taken once and read three ways: the
+    profiler's trace (`engine.step` with a span a phase under it, on the
+    engine's thread, beside the device's programs on one clock), the
+    step's one flight-recorder record (`plan_ms` ... `between_ms` on
+    `engine.decode`) and `stats()` (a request's wait and its prefill
+    span are counted from the `t_mono` of the step of its first span).
+
+    A step is tiled by four phases, each entered as often as the step
+    needs it (`enter` ends the phase before at the same clock read):
+    plan (reap, evictions, the prefill plan), dispatch (a program's host
+    arguments and its call until the call returns, and the device-side
+    copies a prompt's end or a prefix hit issues), read (wherever the
+    thread blocks on a device value) and emit (from the tokens' arrival
+    on: the walk over the slots, the counters, the recorder, `on_step`).
+    `engine.step` carries `step`, `t_mono` (time.monotonic()) and `t_wall`
+    (time.time()): the pair by which a reader lays the harness's
+    monotonic stamps and the recorder's wall-clock spans on the trace."""
+
+    __slots__ = ("ms", "between_ms", "t_wall", "_t0", "_name", "_t", "_ann",
+                 "_step_ann", "_t_end")
+
+    def __init__(self):
+        self._name = self._t_end = None     # no step open, none ended yet
+
+    def begin(self, step: int, t_mono: float):
+        self.t_wall = time.time()
+        self._t0 = t = time.perf_counter()
+        # this step's start less the last step's end: the loop between
+        # two steps, its lock and its wait for work
+        self.between_ms = 0.0 if self._t_end is None \
+            else (t - self._t_end) * 1e3
+        self.ms = dict.fromkeys(PHASES, 0.0)
+        self._step_ann = events.annotate(
+            "engine.step", step=step, t_mono=t_mono, t_wall=self.t_wall)
+        self._step_ann.__enter__()
+        self._open("plan", t)
+
+    def _open(self, name: str, t: float):
+        self._name, self._t = name, t
+        self._ann = events.annotate("engine." + name)
+        self._ann.__enter__()
+
+    def enter(self, name: str):
+        """End the phase in force and begin `name`: one clock read."""
+        if name == self._name:
+            return
+        t = time.perf_counter()
+        self.ms[self._name] += (t - self._t) * 1e3
+        self._ann.__exit__(None, None, None)
+        self._open(name, t)
+
+    def end(self, span=None, **attrs):
+        """The step's end: `span` (its `engine.decode`, where rows
+        decoded) is ended at this mark with the phases on it, the
+        annotations close. A second call does nothing: an exception's way
+        out of a step."""
+        if self._name is None:
+            return
+        self._t_end = time.perf_counter()
+        self.ms[self._name] += (self._t_end - self._t) * 1e3
+        if span is not None:
+            span.end(end=self.t_wall + (self._t_end - self._t0),
+                     between_ms=round(self.between_ms, 4), **attrs,
+                     **{k + "_ms": round(v, 4) for k, v in self.ms.items()})
+        self._ann.__exit__(None, None, None)
+        self._step_ann.__exit__(None, None, None)
+        self._name = None
+
+
 @dataclasses.dataclass
 class EngineConfig:
     """Knobs of the slot pool and admission policy.
@@ -313,17 +386,17 @@ class InferenceEngine:
         # flight-recorder root for engine-owned work that belongs to no
         # single request (multi-request decode batches)
         self._trace_id = events.new_trace_id()
-        # step attribution: decode FLOPs are computed analytically
-        # (re-lowering the decode program for cost_analysis would trip
-        # the compile-once invariant the tests assert on)
-        from ray_tpu.util import profiling
-        leaves = jax.tree_util.tree_leaves(params)
-        self._n_params = int(sum(x.size for x in leaves))
-        self._param_bytes = float(sum(
-            x.size * getattr(x.dtype, "itemsize", 4) for x in leaves))
         self._kv_itemsize = int(jnp.dtype(dtype).itemsize)
-        self.profiler = profiling.StepProfiler(
-            "decode_step", emit_span=False)
+        self._phases = _StepPhases()
+        # a request's wait, counted where it happens: requests whose
+        # first span ran and the seconds they had waited for it since
+        # submit (the `queue_wait_ms` of their `engine.slot` spans);
+        # first tokens, and the seconds from a request's first span's
+        # step to its first token. TTFT at the replica is their sum
+        self.admitted = 0
+        self.queue_wait_s = 0.0
+        self.first_tokens = 0
+        self.prefill_span_s = 0.0
         self._build_fns()
         self._compile_prefill_tiles()
 
@@ -609,173 +682,156 @@ class InferenceEngine:
         programs, then the draft's step or the decode program for every
         occupied slot, the new request among them.)
         Returns True if any device work ran."""
-        import jax
-
         with self._lock:
-            t_iter0 = time.perf_counter()
+            ph = self._phases
             now = time.monotonic()
-            for st in self.sched.reap(now):
-                for pool in self._pools:
-                    pool.scratch.pop(st.rid, None)
-            # capacity eviction BEFORE the step: a full slot has nowhere
-            # to write its next token
-            for st in self.sched.active_states():
-                if self._lengths[st.slot] >= self.config.max_len:
-                    self.sched.evict(st, FINISH_LENGTH)
+            ph.begin(self.steps, now)           # plan
+            try:
+                return self._step(ph, now)
+            finally:
+                ph.end()
+
+    def _step(self, ph: _StepPhases, now: float) -> bool:
+        for st in self.sched.reap(now):
+            for pool in self._pools:
+                pool.scratch.pop(st.rid, None)
+        # capacity eviction BEFORE the step: a full slot has nowhere
+        # to write its next token
+        for st in self.sched.active_states():
+            if self._lengths[st.slot] >= self.config.max_len:
+                self.sched.evict(st, FINISH_LENGTH)
+        active = self.sched.active_states()
+        spans = self._prefill_spans(self.sched.plan_prefill())
+        # the decode rows ride in the step's FIRST tile program
+        ride = self._ride and bool(spans)
+        rode = [self._run_prefill(span, now,
+                                  active if ride and i == 0 else ())
+                for i, span in enumerate(spans)]
+        did = bool(spans)
+        if ride:
+            self.fused_steps += bool(active)
+        else:
+            # a prompt that ended in this step decodes in it
             active = self.sched.active_states()
-            spans = self._prefill_spans(self.sched.plan_prefill())
-            # the decode rows ride in the step's FIRST tile program
-            ride = self._ride and bool(spans)
-            rode = [self._run_prefill(span, now,
-                                      active if ride and i == 0 else ())
-                    for i, span in enumerate(spans)]
-            did = bool(spans)
-            if ride:
-                self.fused_steps += bool(active)
-                t_admit = t_iter0      # admission shares decode's program
+        dspan, n_emitted = None, 0
+        if active:
+            # decode is a BATCH phase: when one request occupies the
+            # engine its span adopts that request's trace (the
+            # acceptance path — one Serve call renders its decode
+            # windows inline); with several co-resident traces the
+            # span records under the engine's own root trace with
+            # slot attribution instead of picking a favorite. It is
+            # the step's one record: it starts where the step did and
+            # ends with the step's phases on it
+            traces = {st.span.trace_id for st in active
+                      if st.span is not None}
+            if len(active) == 1 and active[0].span is not None:
+                d_trace = active[0].span.trace_id
+                d_parent = active[0].span.span_id
+            elif len(traces) == 1:
+                d_trace, d_parent = next(iter(traces)), None
             else:
-                t_admit = time.perf_counter()
-                # a prompt that ended in this step decodes in it
-                active = self.sched.active_states()
-            if active:
-                # decode is a BATCH phase: when one request occupies the
-                # engine its span adopts that request's trace (the
-                # acceptance path — one Serve call renders its decode
-                # windows inline); with several co-resident traces the
-                # span records under the engine's own root trace with
-                # slot attribution instead of picking a favorite
-                traces = {st.span.trace_id for st in active
-                          if st.span is not None}
-                if len(active) == 1 and active[0].span is not None:
-                    d_trace = active[0].span.trace_id
-                    d_parent = active[0].span.span_id
-                elif len(traces) == 1:
-                    d_trace, d_parent = next(iter(traces)), None
-                else:
-                    d_trace, d_parent = self._trace_id, None
-                dspan = events.start_span(
-                    "engine.decode", category="engine",
-                    trace_id=d_trace, parent_span_id=d_parent,
-                    step=self.steps, slots_active=len(active),
-                    slots_occupied=self.sched.occupancy(),
-                    queue_depth=self.sched.queue_depth())
-                compiles0 = self.decode_compile_count
-                t_dec0 = t_iter0 if ride else time.perf_counter()
-                pool, dpool = self._slots, self._draft_slots
-                if ride:
-                    toks_host = np.asarray(rode[0])
-                    self._fold_moe_counts()
-                elif self._spec is not None:
-                    with self._mesh_ctx():
-                        (out, acc, pool.k, pool.v, dpool.k, dpool.v,
-                         self._rng) = self._spec_step_fn(
-                            self.params, self._draft_params,
-                            pool.k, pool.v, dpool.k, dpool.v,
-                            self._lengths, self._last_tok,
-                            self._rng, self._temps)
-                    out_host = np.asarray(out)
-                    acc_host = np.asarray(acc)
-                else:
-                    with self._mesh_ctx():
-                        toks, *new, self._rng = self._decode_fn(
-                            self.params, *pool.pools(), self._lengths,
-                            self._last_tok, self._rng, self._temps)
-                    pool.rebind(new)
-                    toks_host = np.asarray(toks)
-                    self._fold_moe_counts(new)
-                t_dec1 = time.perf_counter()
-                # capture before decode_emit: an evicted state's slot is
-                # None by the time the profiler reads it
-                slots = [st.slot for st in active]
-                now = time.monotonic()
-                n_emitted = 0
-                if self._spec is not None:
-                    # accepted prefix + one bonus token per slot. ALL
-                    # accept-count control flow happens HERE, on
-                    # materialized numpy values — a Python branch on the
-                    # traced count inside the program is the classic
-                    # retrace bug (rtlint RT002 fixture).
-                    for st in active:
-                        slot = st.slot
-                        accepted = int(acc_host[slot])
-                        if self._temps[slot] <= 0.0:
-                            self.spec_tokens_proposed += self._spec_k
-                            self.spec_tokens_accepted += accepted
-                        for j in range(accepted + 1):
-                            self._lengths[slot] += 1
-                            tok = int(out_host[slot, j])
-                            self._last_tok[slot] = tok
-                            self.tokens_generated += 1
-                            n_emitted += 1
-                            self.sched.decode_emit(st, tok, now)
-                            if st.slot is None:
-                                break    # finished (EOS / max tokens)
-                else:
-                    if self._topk and active:   # before the rows' own
-                        self.dsa_rows_streamed += self._dsa_streamed(
-                            [int(self._lengths[st.slot]) for st in active])
-                    for st in active:
-                        slot = st.slot
+                d_trace, d_parent = self._trace_id, None
+            dspan = events.start_span(
+                "engine.decode", category="engine",
+                trace_id=d_trace, parent_span_id=d_parent,
+                start=ph.t_wall,
+                step=self.steps, slots_active=len(active),
+                slots_occupied=self.sched.occupancy(),
+                queue_depth=self.sched.queue_depth())
+            compiles0 = self.decode_compile_count
+            pool, dpool = self._slots, self._draft_slots
+            if ride:
+                ph.enter("read")
+                toks_host = np.asarray(rode[0])
+                self._fold_moe_counts()
+            elif self._spec is not None:
+                ph.enter("dispatch")
+                with self._mesh_ctx():
+                    (out, acc, pool.k, pool.v, dpool.k, dpool.v,
+                     self._rng) = self._spec_step_fn(
+                        self.params, self._draft_params,
+                        pool.k, pool.v, dpool.k, dpool.v,
+                        self._lengths, self._last_tok,
+                        self._rng, self._temps)
+                ph.enter("read")
+                out_host = np.asarray(out)
+                acc_host = np.asarray(acc)
+            else:
+                ph.enter("dispatch")
+                with self._mesh_ctx():
+                    toks, *new, self._rng = self._decode_fn(
+                        self.params, *pool.pools(), self._lengths,
+                        self._last_tok, self._rng, self._temps)
+                pool.rebind(new)
+                ph.enter("read")
+                toks_host = np.asarray(toks)
+                self._fold_moe_counts(new)
+            ph.enter("emit")
+            now = time.monotonic()
+            if self._spec is not None:
+                # accepted prefix + one bonus token per slot. ALL
+                # accept-count control flow happens HERE, on
+                # materialized numpy values — a Python branch on the
+                # traced count inside the program is the classic
+                # retrace bug (rtlint RT002 fixture).
+                for st in active:
+                    slot = st.slot
+                    accepted = int(acc_host[slot])
+                    if self._temps[slot] <= 0.0:
+                        self.spec_tokens_proposed += self._spec_k
+                        self.spec_tokens_accepted += accepted
+                    for j in range(accepted + 1):
                         self._lengths[slot] += 1
-                        if self._topk:      # the row attended itself too
-                            live = int(self._lengths[slot])
-                            self.dsa_rows_live += live
-                            self.dsa_rows_read += min(live, self._topk)
-                        if self._blk:
-                            # the row's own block is among the selected
-                            # and holds the positions up to the row's only
-                            live = int(self._lengths[slot])
-                            size, topk = self._blk
-                            self.blk_rows_live += live
-                            self.blk_rows_read += min(
-                                live, size * topk - (-live % size))
-                        self._last_tok[slot] = toks_host[slot]
+                        tok = int(out_host[slot, j])
+                        self._last_tok[slot] = tok
                         self.tokens_generated += 1
                         n_emitted += 1
-                        self.sched.decode_emit(st, int(toks_host[slot]),
-                                               now)
-                if self.decode_compile_count > compiles0:
-                    # a decode retrace is THE perf cliff this engine is
-                    # built to avoid — make every occurrence a first-class
-                    # timeline event (tests assert the count stays at 1)
-                    events.record_instant(
-                        "engine.compile", category="engine",
-                        trace_id=d_trace, parent_span_id=dspan.span_id,
-                        fn="decode", compile_count=self.decode_compile_count)
-                attribution = self._profile_decode(
-                    [int(self._lengths[s]) for s in slots],
-                    t_iter0, t_admit, t_dec0, t_dec1)
-                dspan.end(tokens=n_emitted, **attribution)
-                did = True
-            self.steps += 1
-            if self.on_step is not None:
-                try:
-                    self.on_step(self.stats())
-                except Exception:
-                    pass
-            return did
-
-    def _profile_decode(self, kv_lens, t_iter0, t_admit, t_dec0, t_dec1):
-        """Per-step attribution: decode compute vs prefill/admission work
-        ("data wait" — tokens can't advance while it runs) vs host gap
-        (scheduler bookkeeping + idle between steps). Returns the attrs
-        attached to the engine.decode span (mfu + phase ms) so the
-        timeline answers the stuck-MFU question inline."""
-        from ray_tpu.util import profiling
-        mcfg = self.model.cfg
-        flops = profiling.decode_step_flops(
-            self._n_params, mcfg.n_layers, mcfg.n_heads, mcfg.head_dim,
-            kv_lens)
-        nbytes = profiling.decode_step_bytes(
-            self._param_bytes, mcfg.n_layers, mcfg.n_kv_heads,
-            mcfg.head_dim, kv_lens, self._kv_itemsize)
-        rec = self.profiler.observe(
-            compute_s=t_dec1 - t_dec0, data_s=t_admit - t_iter0,
-            begin_t=t_iter0, end_t=t_dec1, tokens=len(kv_lens),
-            flops=flops, bytes_accessed=nbytes)
-        return {k: rec[k] for k in ("mfu", "mfu_compute", "compute_ms",
-                                    "host_gap_ms", "data_wait_ms",
-                                    "roofline_bound") if k in rec}
+                        self.sched.decode_emit(st, tok, now)
+                        if st.slot is None:
+                            break    # finished (EOS / max tokens)
+            else:
+                if self._topk and active:   # before the rows' own
+                    self.dsa_rows_streamed += self._dsa_streamed(
+                        [int(self._lengths[st.slot]) for st in active])
+                for st in active:
+                    slot = st.slot
+                    self._lengths[slot] += 1
+                    if self._topk:      # the row attended itself too
+                        live = int(self._lengths[slot])
+                        self.dsa_rows_live += live
+                        self.dsa_rows_read += min(live, self._topk)
+                    if self._blk:
+                        # the row's own block is among the selected
+                        # and holds the positions up to the row's only
+                        live = int(self._lengths[slot])
+                        size, topk = self._blk
+                        self.blk_rows_live += live
+                        self.blk_rows_read += min(
+                            live, size * topk - (-live % size))
+                    self._last_tok[slot] = toks_host[slot]
+                    self.tokens_generated += 1
+                    n_emitted += 1
+                    self.sched.decode_emit(st, int(toks_host[slot]),
+                                           now)
+            if self.decode_compile_count > compiles0:
+                # a decode retrace is THE perf cliff this engine is
+                # built to avoid — make every occurrence a first-class
+                # timeline event (tests assert the count stays at 1)
+                events.record_instant(
+                    "engine.compile", category="engine",
+                    trace_id=d_trace, parent_span_id=dspan.span_id,
+                    fn="decode", compile_count=self.decode_compile_count)
+            did = True
+        self.steps += 1
+        if self.on_step is not None:
+            try:
+                self.on_step({"slots_occupied": self.sched.occupancy(),
+                              "queue_depth": self.sched.queue_depth()})
+            except Exception:
+                pass
+        ph.end(dspan, tokens=n_emitted)
+        return did
 
     def _prefill_spans(self, chunks: List[PrefillChunk]):
         """The step's plan, one piece a dispatch: the consecutive chunks
@@ -835,26 +891,33 @@ class InferenceEngine:
     def _run_prefill(self, ch: PrefillChunk, now: float, active=()):
         """Run one span of a prompt in its tile, with the decode rows of
         the `active` states' slots behind it -> the slots' tokens (on
-        the device; None where decode rows do not ride)."""
+        the device; None where decode rows do not ride). `now`: the
+        step's start (time.monotonic())."""
         import jax
         import jax.numpy as jnp
 
+        ph = self._phases
+        ph.enter("dispatch")
         st = ch.state
         if st.span is None:
-            # first span == admission: open the engine-slot span. It
+            # first span == admission: open the engine-slot span, at the
+            # step's start, where `now` was read. It
             # parents under the submitting request's propagated context
             # (Serve path) or roots its own trace (direct engine use),
             # and carries the queue-wait the built-in scheduler-latency
             # metric is derived from.
             ctx = st.request.trace_ctx
+            st.admitted_t = now
+            wait_s = now - st.handle.submitted_t
+            self.admitted += 1
+            self.queue_wait_s += wait_s
             st.span = events.start_span(
                 "engine.slot", category="engine",
                 trace_id=ctx[0] if ctx else None,
                 parent_span_id=ctx[1] if ctx else None,
-                rid=st.rid, slot=st.slot,
+                start=ph.t_wall, rid=st.rid, slot=st.slot,
                 prompt_tokens=len(st.request.tokens),
-                queue_wait_ms=round(
-                    (now - st.handle.submitted_t) * 1e3, 3))
+                queue_wait_ms=round(wait_s * 1e3, 3))
         scratch = self._slots.scratch.get(st.rid)
         if scratch is None:
             scratch = self._slots.new_scratch()
@@ -917,14 +980,20 @@ class InferenceEngine:
             for pool in self._pools:
                 pool.scratch.pop(st.rid, None)
             self._lengths[slot] = len(prompt)
+            ph.enter("read")
             first = int(tok)
             self._fold_moe_counts()
+            ph.enter("emit")
             self._last_tok[slot] = first
             self._temps[slot] = st.temperature
-            self.sched.prefill_done(st, first, time.monotonic())
+            t_first = time.monotonic()
+            self.first_tokens += 1
+            self.prefill_span_s += t_first - st.admitted_t
+            self.sched.prefill_done(st, first, t_first)
         else:
             if self._write_through:
                 scratch = self._publish_chunk(st, scratch, ch)
+            ph.enter("emit")
             self._slots.scratch[st.rid] = scratch
             if self._spec is not None:
                 self._draft_slots.scratch[st.rid] = dk_dv
@@ -1080,6 +1149,10 @@ class InferenceEngine:
             "tokens_generated": self.tokens_generated,
             "prefill_dispatches": self.prefill_dispatches,
             "prefill_tokens": self.prefill_tokens,
+            "admitted": self.admitted,
+            "queue_wait_s": self.queue_wait_s,
+            "first_tokens": self.first_tokens,
+            "prefill_span_s": self.prefill_span_s,
             "decode_compile_count": self.decode_compile_count,
             "draining": self.sched.draining,
         }

@@ -167,6 +167,7 @@ class RequestState:
     generated: int = 0
     last_token: int = 0
     span: Optional[Any] = None    # flight-recorder engine.slot span
+    admitted_t: float = 0.0       # monotonic: the step of its first span
     # radix-cache admission state: matched prefix length (its prefill
     # is skipped — the engine copies the blocks instead) and the pinned
     # trie nodes backing it (released once the copy lands in scratch)

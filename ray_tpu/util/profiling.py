@@ -1,4 +1,4 @@
-"""Per-step time/FLOP attribution: where does a train/decode step go?
+"""Per-step time/FLOP attribution: where does a training step go?
 
 ROADMAP item 5 has `train_step_mfu` stuck at 0.564 with zero in-runtime
 visibility into where step time is spent (a figure from before PR 21;
@@ -37,9 +37,12 @@ timeline instead of requiring the offline harness.
 Cost sources, in order of preference: ``wrap_jit`` (AOT lower+compile
 once per input shape — cost_analysis comes free and the compiled
 executable is reused, no double compile), ``observe_compiled`` (caller
-already has an AOT executable), ``set_cost`` (analytic formulas — the
-inference engine's decode step uses ``decode_step_flops`` because
-re-lowering its decode program would trip the compile-once invariant).
+already has an AOT executable), ``set_cost`` (analytic formulas).
+
+The inference engine is not among its users: a serving step is told by
+phase on the profiler's own clock (inference/engine.py ``_StepPhases``),
+and what a step must compute or move is the model family's to say
+(perfbench/families/), not a dense model's arithmetic.
 """
 
 from __future__ import annotations
@@ -134,28 +137,6 @@ def cost_of_compiled(compiled) -> Dict[str, float]:
     nbytes = sum(float(d.get("bytes accessed", 0.0) or 0.0)
                  for d in ca or [])
     return {"flops": flops, "bytes_accessed": nbytes}
-
-
-def decode_step_flops(n_params: int, n_layers: int, n_heads: int,
-                      head_dim: int, kv_lens) -> float:
-    """Analytic per-decode-step FLOPs for a transformer slot batch:
-    2 FLOPs/param/token for the dense path plus QK^T and AV against each
-    slot's live KV length (the engine can't re-lower its decode program
-    for cost_analysis without tripping the compile-once invariant)."""
-    total = 0.0
-    for kv in kv_lens:
-        total += 2.0 * n_params \
-            + 4.0 * n_layers * float(kv) * n_heads * head_dim
-    return total
-
-
-def decode_step_bytes(param_bytes: float, n_layers: int, n_kv_heads: int,
-                      head_dim: int, kv_lens, elt_bytes: float) -> float:
-    """Decode is memory-bound: every step re-reads the params plus each
-    slot's K and V history."""
-    kv_read = sum(2.0 * n_layers * float(kv) * n_kv_heads * head_dim
-                  * elt_bytes for kv in kv_lens)
-    return float(param_bytes) + kv_read
 
 
 def _shape_key(tree) -> tuple:
