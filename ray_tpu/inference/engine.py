@@ -40,12 +40,20 @@ batching in the Gemma-on-TPU serving stack):
   engines keep two calls a step: with a speculative draft, whose step
   replaces decode, and with a model that has an indexer, whose decode
   row is not bound by the weights' stream; ``_build_fns``.)
+- Between two step programs the engine's thread does only what the next
+  program needs (``step``: read, decide, plan, dispatch, deliver). The
+  slots' CARRY (lengths, last tokens, temperatures, the key) lives on
+  the device and only the programs write it; the host hands each program
+  ONE packed array (the live mask, a tile's tokens and place) and keeps a
+  mirror of the lengths. A step's programs are issued back to back and
+  read once; what the read decides is delivered to consumers behind the
+  NEXT step's dispatch, under the program then running.
 - Tokens stream out per request through ``RequestHandle`` queues;
   slots are evicted (and immediately reusable) on EOS, max-tokens,
   slot-capacity, cancellation, or deadline.
 
-Shapes are static everywhere — tokens [n_slots], lengths [n_slots],
-prompt tiles [1, T] (with the slots' rows, [1, T + n_slots]) with T
+Shapes are static everywhere — the carry's vectors [n_slots], prompt
+tiles [1, T] (with the slots' rows, [1, T + n_slots]) with T
 ``prefill_budget`` and, where that is four chunks or more, a handful of
 shorter lengths (``prefill_tiles``), every one compiled when the engine
 is built — so XLA compiles the tile programs, the slot insert and the
@@ -95,6 +103,9 @@ def prefill_tiles(chunk: int, budget: int) -> tuple:
 
 
 PHASES = ("plan", "dispatch", "read", "emit")
+# the scalars between the live mask and the tokens of the array a tile
+# program takes from the host (`InferenceEngine._tile_args`)
+_TILE_HEAD = 6
 
 
 class _StepPhases:
@@ -106,12 +117,19 @@ class _StepPhases:
     span are counted from the `t_mono` of the step of its first span).
 
     A step is tiled by four phases, each entered as often as the step
-    needs it (`enter` ends the phase before at the same clock read):
-    plan (reap, evictions, the prefill plan), dispatch (a program's host
-    arguments and its call until the call returns, and the device-side
-    copies a prompt's end or a prefix hit issues), read (wherever the
-    thread blocks on a device value) and emit (from the tokens' arrival
-    on: the walk over the slots, the counters, the recorder, `on_step`).
+    needs it (`enter` ends the phase before at the same clock read), in
+    the order of `InferenceEngine.step`: plan (reap, evictions, the
+    prefill plan), dispatch (a program's one host array and its call
+    until the call returns, and the device-side copies a prompt's end or
+    a prefix hit issues; all the step's programs back to back), emit
+    (DELIVERY, under the programs now running: the tokens and finishes
+    the step BEFORE decided go to their consumers, its record and the
+    spans' ends to the recorder; then this step's counters), read (the
+    thread blocks on the last program's output, once) and emit again
+    (the decision, from the tokens' arrival on: the walk over the rows,
+    finishes, freed slots, `on_step`; nothing is handed to a consumer
+    here unless no work follows). A step's record therefore carries in
+    `emit_ms` the delivery of the step before it and its own decisions.
     `engine.step` carries `step`, `t_mono` (time.monotonic()) and `t_wall`
     (time.time()): the pair by which a reader lays the harness's
     monotonic stamps and the recorder's wall-clock spans on the trace."""
@@ -149,19 +167,21 @@ class _StepPhases:
         self._ann.__exit__(None, None, None)
         self._open(name, t)
 
-    def end(self, span=None, **attrs):
+    def end(self, span=None, after=None, **attrs):
         """The step's end: `span` (its `engine.decode`, where rows
-        decoded) is ended at this mark with the phases on it, the
-        annotations close. A second call does nothing: an exception's way
-        out of a step."""
+        decoded) ends at this mark with the phases on it, which the
+        recorder is told with the next delivery (appended to `after`:
+        (function, keywords)); the annotations close. A second call does
+        nothing: an exception's way out of a step."""
         if self._name is None:
             return
         self._t_end = time.perf_counter()
         self.ms[self._name] += (self._t_end - self._t) * 1e3
         if span is not None:
-            span.end(end=self.t_wall + (self._t_end - self._t0),
-                     between_ms=round(self.between_ms, 4), **attrs,
-                     **{k + "_ms": round(v, 4) for k, v in self.ms.items()})
+            after.append((span.end, dict(
+                end=self.t_wall + (self._t_end - self._t0),
+                between_ms=round(self.between_ms, 4), **attrs,
+                **{k + "_ms": round(v, 4) for k, v in self.ms.items()})))
         self._ann.__exit__(None, None, None)
         self._step_ann.__exit__(None, None, None)
         self._name = None
@@ -268,13 +288,6 @@ class InferenceEngine:
         self._work = threading.Condition(self._lock)
         self._stop = False
         self._thread: Optional[threading.Thread] = None
-        self._rng = jax.random.PRNGKey(seed)
-        if mesh is not None:
-            # the key lives on the mesh from the start: the step program
-            # hands it back replicated there, and a first call that saw
-            # it on one device would make the second one retrace
-            self._rng = jax.jit(lambda: jax.random.PRNGKey(seed),
-                                out_shardings=kv_cache.replicated(mesh))()
 
         # the tiles a prefill call may take. A store that is written
         # through chunk by chunk (_publish_chunk: a chunk attends what
@@ -317,10 +330,10 @@ class InferenceEngine:
                                chunk_size=cfg.prefill_chunk,
                                prefix_cache=self.prefix_cache)
 
-        # host-side slot state (fixed width, mirrors the device arrays)
+        # the host's mirror of the carry's lengths, for its own
+        # bookkeeping (`max_len` evictions, the dsa_* / blk_* counters):
+        # it knows them without reading
         self._lengths = np.zeros((cfg.n_slots,), np.int32)
-        self._last_tok = np.zeros((cfg.n_slots,), np.int32)
-        self._temps = np.zeros((cfg.n_slots,), np.float32)
 
         self.decode_compile_count = 0
         self.prefill_compile_count = 0
@@ -337,6 +350,10 @@ class InferenceEngine:
         # program (of `steps`; the rest are decode-only, prefill with no
         # slot live, or idle)
         self.fused_steps = 0
+        # steps whose first program was issued while what the step before
+        # decided was still undelivered (of `steps`): its tokens reached
+        # their consumers under this step's program
+        self.issued_ahead = 0
         self.tokens_generated = 0
         # sparse attention (a model with an indexer), summed over the
         # decode rows of every step, all host arithmetic on the lengths:
@@ -370,14 +387,14 @@ class InferenceEngine:
         # an expert layer that holds a share of its experts: [rows the
         # expert matmuls computed, picks of real rows that landed on a held
         # expert], summed over layers and calls (models/moe.py sows them).
-        # Each program hands its pair back beside its other outputs; the
-        # pairs wait in `_moe_pending` and are folded in where the step
-        # reads a program's output anyway. With every expert held both
-        # counts are the shapes' (E x rows, K x rows): nothing to read,
-        # and those programs get no output more
+        # Each program adds its pair to a running sum in the carry, which
+        # comes back in the array the step reads anyway (it wraps at 32
+        # bits; `_read` adds the difference since the last one read).
+        # With every expert held both counts are the shapes' (E x rows,
+        # K x rows): nothing to read, and the carry holds no sum
         self._count_moe = mcfg.n_experts > 0 and bool(mcfg.experts_held)
         self._moe_counts = np.zeros((2,), np.int64)
-        self._moe_pending: List[Any] = []
+        self._moe_seen = np.zeros((2,), np.uint32)
         # disagg hand-off accounting (serve/disagg.py)
         self.kv_exports = 0
         self.kv_imports = 0
@@ -397,7 +414,11 @@ class InferenceEngine:
         self.queue_wait_s = 0.0
         self.first_tokens = 0
         self.prefill_span_s = 0.0
+        # the recorder's work of a step (a span's end: (function,
+        # keywords)), made with the tokens' delivery (`_deliver`)
+        self._after: List[tuple] = []
         self._build_fns()
+        self._carry = self._new_carry(seed)
         self._compile_prefill_tiles()
 
     # ------------------------------------------------------------ device fns
@@ -421,6 +442,8 @@ class InferenceEngine:
         # (a read of a donated buffer after its call raises there too)
 
         names = tuple(self._slots.shapes)
+        n = len(names)
+        S = cfg.n_slots
         count_moe = self._count_moe
         # the slots' decode rows ride in the program of a step's prefill
         # tile, and the weights stream once for both. Two engines keep
@@ -436,11 +459,12 @@ class InferenceEngine:
 
         def forward(params, tokens, pools, idx, real=None, slots=None,
                     **kw):
-            """-> (logits, the pools (then the slots' pools) and, where
-            counted, the call's expert-layer counts summed over the
-            layers). `real` [B, L] bool: the rows a request owns, where
-            not all. `slots`: the second cache of a tile's call, whose
-            decode rows end the sequence (TransformerLM._decode)."""
+            """-> (logits, the pools (then the slots' pools), the call's
+            expert-layer counts summed over the layers: int32[2], None
+            where not counted). `real` [B, L] bool: the rows a request
+            owns, where not all. `slots`: the second cache of a tile's
+            call, whose decode rows end the sequence
+            (TransformerLM._decode)."""
             cache = dict(zip(names, pools), idx=idx)
             if real is not None:
                 cache["real"] = real
@@ -449,78 +473,145 @@ class InferenceEngine:
             out = model.apply({"params": params}, tokens, cache=cache, **kw,
                               mutable=["counters"] if count_moe else False)
             (logits, new), counted = out if count_moe else (out, None)
-            new = tuple(c[n] for c in (new, new.get("slots"))
-                        if c is not None for n in names)
+            new = tuple(c[name] for c in (new, new.get("slots"))
+                        if c is not None for name in names)
             if not count_moe:
-                return logits, new
+                return logits, new, None
             # one pair a layer (stacked where the layers are scanned)
-            pair = sum(c.reshape(-1, 2).sum(0)
-                       for c in jax.tree.leaves(counted))
-            return logits, new + (pair,)
+            return logits, new, sum(c.reshape(-1, 2).sum(0)
+                                    for c in jax.tree.leaves(counted))
+
+        # Every step program ends its arguments with the slots' CARRY and
+        # ONE packed int32 array from the host, and hands the carry back
+        # first, advanced. The carry is ONE int32 array too (`_new_carry`),
+        # which is also all the host reads of a step: the slots' lengths
+        # [S], their last tokens [S], their temperatures' bits [S], the
+        # tokens the decode program last consumed [S] (a prompt's first
+        # token, where its tile wrote it), the key's two words, and where
+        # expert counts are kept their running sum [2] (it wraps; the host
+        # takes differences). One array because a small output costs a
+        # call 75 us of the host's time on the chip unless it is donated,
+        # and four small donated inputs cost the compiler's memory-space
+        # assignment one of a layer's K / V copies (below).
+        # Only the programs write it: a live row's length + 1 and its
+        # sampled token, the key split (by decode), and by the tile that
+        # ends a prompt the new slot's length, temperature and first
+        # token. The host's array starts with the live mask [S]: how an
+        # eviction or a cancel reaches the carry (a slot not live is not
+        # advanced, and nothing reads its entries before a prompt's last
+        # tile has written them).
+        def unpack(state):
+            """-> lengths, toks, temps, fed, rng, moe (a tuple: int32[2]
+            where counted)"""
+            rows = [state[i * S:(i + 1) * S] for i in range(4)]
+            rows[2] = jax.lax.bitcast_convert_type(rows[2], jnp.float32)
+            rng = jax.lax.bitcast_convert_type(state[4 * S:4 * S + 2],
+                                               jnp.uint32)
+            return (*rows, rng, (state[4 * S + 2:],) * count_moe)
+
+        def pack(lengths, toks, temps, fed, rng, moe, pair=None):
+            return jnp.concatenate([
+                lengths, toks,
+                jax.lax.bitcast_convert_type(temps, jnp.int32), fed,
+                jax.lax.bitcast_convert_type(rng, jnp.int32),
+                *(m + pair for m in moe)])
 
         def prefill(params, *args):
-            # (params, *scratch, [*pools,] tokens, pos0, n_real, rng, temp
-            # [, lengths, toks, temps, live]): the program of a step
-            # that carries prompt. One request's share of the step's
-            # budget, a [1, T] tile, through the cached path into its
-            # scratch, and behind it in the same sequence one decode row
-            # a slot against the pools, so every weight streams once for
-            # both; samples the tile's would-be next token (used only on
-            # the prompt's last tile, where it is the request's first
-            # generated token) and the slots' next tokens. `live` [n_slots]
-            # bool: the slots that decode; none (a step's further spans,
-            # an idle engine), and the rows' attention and pool write
-            # are skipped, never another shape
+            # (params, *scratch, [*pools,] *carry, host): the program of
+            # a step that carries prompt. One request's share of the
+            # step's budget, a [1, T] tile, through the cached path into
+            # its scratch, and behind it in the same sequence one decode
+            # row a slot against the pools, so every weight streams once
+            # for both; samples the tile's would-be next token (kept only
+            # by the prompt's last tile, which writes it, the prompt's
+            # length and the temperature into the carry at its slot) and
+            # the slots' next tokens. host: live [S] (the slots that
+            # decode; none (a step's further spans, an idle engine), and
+            # the rows' attention and pool write are skipped, never
+            # another shape), pos0, n_real, slot, whether the span ends
+            # its prompt, the temperature's bits, how many tile programs
+            # were issued before this one, the tile's tokens [T].
+            # -> (the carry's tokens [+ counts], slot, *scratch,
+            # [*pools,] *carry)
             self.prefill_compile_count += 1    # traces once a tile
-            n = len(names)
             scratch, args = args[:n], args[n:]
             pools, args = (args[:n], args[n:]) if ride else ((), args)
-            tokens, pos0, n_real, rng, temp, *rows = args
+            state, host = args
+            live = host[:S] != 0
+            pos0, n_real, slot, is_last = (host[S + i] for i in range(4))
+            temp = jax.lax.bitcast_convert_type(host[S + 4], jnp.float32)
+            tokens = host[None, S + _TILE_HEAD:]
             tile = tokens.shape[1]
             # the tile's padded tail is rows no request owns
             real = (jnp.arange(tile) < n_real)[None, :]
+            # the call's key is the carry's with the host's count of tile
+            # programs folded in, and the carry's stays (decode splits it):
+            # no jitted split on the host, and none here either, since a
+            # tile program that handed back a key it had split lost one of
+            # a layer's K / V copies from the fast memory (the compiler's
+            # memory-space assignment, read off the program compiled for a
+            # described v5e; Mistral's tile 24.57 -> 28.19 ms, my chip run,
+            # PR 43; PERF.md section 6)
+            lengths, toks, temps, fed, rng, moe = unpack(state)
+            key = jax.random.fold_in(rng, host[S + 5])
             slots = None
             if ride:
-                lengths, toks, temps, live = rows
                 tokens = jnp.concatenate([tokens, toks[None, :]], axis=1)
                 real = jnp.concatenate([real, live[None, :]], axis=1)
                 slots = dict(zip(names, pools), idx=lengths,
                              on=jnp.any(live))
-                rng, sub = jax.random.split(rng)
-            logits, new = forward(params, tokens, scratch, pos0, real=real,
-                                  slots=slots, chunked_prefill=True)
+                key, sub = jax.random.split(key)
+            logits, new, pair = forward(params, tokens, scratch, pos0,
+                                        real=real, slots=slots,
+                                        chunked_prefill=True)
             last = jax.lax.dynamic_index_in_dim(logits, n_real - 1,
                                                 axis=1, keepdims=False)
-            tok = sample_logits_dynamic(last, rng, temp[None],
-                                        top_k=top_k, top_p=top_p)
-            out = (tok[0].astype(jnp.int32),)
+            tok = sample_logits_dynamic(last, key, temp[None], top_k=top_k,
+                                        top_p=top_p)[0].astype(jnp.int32)
             if ride:
-                out += (sample_logits_dynamic(
+                rows = sample_logits_dynamic(
                     logits[0, tile:], sub, temps, top_k=top_k,
-                    top_p=top_p).astype(jnp.int32),)
-            return out + new
+                    top_p=top_p).astype(jnp.int32)
+                lengths, toks = lengths + live, jnp.where(live, rows, toks)
+            ends = (jnp.arange(S) == slot) & (is_last != 0)
+            state = pack(jnp.where(ends, pos0 + n_real, lengths),
+                         jnp.where(ends, tok, toks),
+                         jnp.where(ends, temp, temps),
+                         jnp.where(ends, tok, fed), rng, moe, pair)
+            return (state, slot) + new
 
         def decode(params, *args):
-            # (params, *pools, lengths, toks, rng, temps).
-            # ONE program for the life of the engine: fixed [n_slots]
-            # shapes, per-slot idx vector. Python side effect below runs
-            # only at trace time — it counts XLA cache misses. The key
-            # splits INSIDE the program (returned for the next step) so
-            # the host does exactly one dispatch per decoded token.
+            # (params, *pools, carry, live [S] from the host) -> (carry,
+            # *pools). ONE program for the life of the engine: fixed
+            # [n_slots] shapes, per-slot idx vector. Python side effect
+            # below runs only at trace time — it counts XLA cache misses.
+            # The key splits INSIDE the program (the carry keeps its half)
+            # so the host does exactly one dispatch per decoded token.
             self.decode_compile_count += 1
-            pools = args[:len(names)]
-            lengths, toks, rng, temps = args[len(names):]
+            pools, (state, host) = args[:n], args[n:]
+            lengths, toks, temps, _, rng, moe = unpack(state)
+            live = host != 0
             rng, sub = jax.random.split(rng)
-            logits, new = forward(params, toks[:, None], pools, lengths)
+            logits, new, pair = forward(params, toks[:, None], pools,
+                                        lengths)
             tok = sample_logits_dynamic(logits[:, -1, :], sub, temps,
                                         top_k=top_k, top_p=top_p)
-            return (tok.astype(jnp.int32),) + new + (rng,)
+            state = pack(lengths + live,
+                         jnp.where(live, tok.astype(jnp.int32), toks),
+                         temps, toks, rng, moe, pair)
+            return (state,) + new
 
-        donated = tuple(range(1, 1 + len(names)))     # the pools
+        # the pools and the carry are donated. The carry is one array
+        # and not four: with four small arrays aliased XLA's memory-space
+        # assignment moved one of a layer's K / V copies out of the fast
+        # memory (Mistral's decode program 20.13 -> 23.27 ms, my chip run,
+        # PR 43; PERF.md section 6), and not aliased each cost the call
+        # 75 us; tests/test_chip_compile.py holds both copies there
         self._prefill_fn = jax.jit(
             prefill, donate_argnums=tuple(
-                range(1, 1 + len(names) * (2 if ride else 1))))
-        self._decode_fn = jax.jit(decode, donate_argnums=donated)
+                range(1, 2 + n * (2 if ride else 1))))
+        self._decode_fn = jax.jit(
+            decode, donate_argnums=tuple(range(1, 2 + n)))
 
         self._spec_step_fn = None
         self._draft_prefill_fn = None
@@ -535,11 +626,29 @@ class InferenceEngine:
                 self.decode_compile_count += 1
                 self.spec_verify_compile_count += 1
 
-            self._spec_step_fn = jax.jit(
-                build_spec_step(model, draft_model, self._spec.k,
-                                top_k, top_p,
-                                on_trace=_count_verify_trace),
-                donate_argnums=(2, 3, 4, 5))
+            spec_step = build_spec_step(model, draft_model, self._spec.k,
+                                        top_k, top_p,
+                                        on_trace=_count_verify_trace)
+
+            def spec(params, dparams, pk, pv, dk, dv, state, host):
+                # the draft's step on the same carry: a live row's length
+                # grows by what it accepted and one, its last token is
+                # the last it emits. -> (carry, every position's choice
+                # [S, K+1] and behind them the accepted counts [S], the
+                # four pools)
+                lengths, toks, temps, _, rng, moe = unpack(state)
+                live = host != 0
+                out, acc, pk, pv, dk, dv, rng = spec_step(
+                    params, dparams, pk, pv, dk, dv, lengths, toks, rng,
+                    temps)
+                last = jnp.take_along_axis(out, acc[:, None], axis=1)[:, 0]
+                state = pack(lengths + jnp.where(live, acc + 1, 0),
+                             jnp.where(live, last, toks), temps, toks, rng,
+                             moe, 0)
+                return (state, jnp.concatenate([out.reshape(-1), acc]),
+                        pk, pv, dk, dv)
+
+            self._spec_step_fn = jax.jit(spec, donate_argnums=(2, 3, 4, 5))
 
             def draft_prefill(dparams, sk, sv, tokens, pos0):
                 # prompt KV for the draft cache: same chunked path as
@@ -555,6 +664,42 @@ class InferenceEngine:
             self._draft_prefill_fn = jax.jit(
                 draft_prefill, donate_argnums=(1, 2))
 
+    def _new_carry(self, seed: int):
+        """The slots' carry with the key of `seed` (`_build_fns` lays it
+        out), made where it lives: on the device, replicated on a mesh
+        (the step programs hand it back so there, and a first call that
+        saw it on one device would make the second one retrace)."""
+        import jax
+        import jax.numpy as jnp
+        S, counts = self.config.n_slots, 2 * self._count_moe
+
+        def carry():
+            return jnp.concatenate([
+                jnp.zeros((4 * S,), jnp.int32),
+                jax.lax.bitcast_convert_type(jax.random.PRNGKey(seed),
+                                             jnp.int32),
+                jnp.zeros((counts,), jnp.int32)])
+        return jax.jit(carry,
+                       out_shardings=kv_cache.replicated(self.mesh))()
+
+    def _tile_args(self, tile: int, tokens, pos0: int, slot: int,
+                   is_last: bool, temp: float, live) -> np.ndarray:
+        """The one array a tile program takes from the host (`prefill`
+        in `_build_fns` reads it back): the slots `live` behind the tile,
+        the span's `tokens` (padded to `tile`) and where they start, its
+        request's slot and temperature, whether it ends the prompt, and
+        the count of tile programs issued, which the call's key is made
+        of."""
+        S = self.config.n_slots
+        host = np.zeros((S + _TILE_HEAD + tile,), np.int32)
+        host[live] = 1
+        host[S:S + _TILE_HEAD] = (
+            pos0, len(tokens), slot, is_last,
+            np.float32(temp).view(np.int32),
+            self.prefill_dispatches & 0x7FFFFFFF)
+        host[S + _TILE_HEAD:S + _TILE_HEAD + len(tokens)] = tokens
+        return host
+
     def _compile_prefill_tiles(self):
         """Run every prefill tile on a throwaway scratch, so no request
         is the first user of a shape: ``prefill_compile_count`` (and the
@@ -562,29 +707,30 @@ class InferenceEngine:
         never moves again. Each tile runs twice, on a new scratch and on
         the one it handed back, as a prompt's first and later spans do:
         on a mesh the two differ in sharding and XLA compiles each. The
-        slots' pools pass through with no slot live, untouched. The
-        engine's key is not advanced."""
-        import jax
-        import jax.numpy as jnp
-        _, key = jax.random.split(self._rng)    # as _run_prefill splits
+        slots' pools pass through with no slot live, and the carry with
+        no slot written and its key as it was (a tile program draws from
+        it and does not advance it); the warm-up's expert rows are
+        nobody's."""
         with self._mesh_ctx():
             for tile in self._prefill_tiles:
-                tokens = jnp.zeros((1, tile), jnp.int32)
+                host = self._tile_args(
+                    tile, np.zeros((tile,), np.int32), 0, 0, False, 0.0, [])
                 scratch = self._slots.new_scratch()
                 for _ in range(2):
-                    scratch = self._call_prefill(
-                        scratch, tokens, 0, tile, key, 0.0, [])[2]
+                    scratch = self._call_prefill(scratch, host)[1]
                 if self._spec is not None:
                     dk, dv = self._draft_slots.new_scratch()
                     for _ in range(2):
                         dk, dv = self._draft_prefill_fn(
-                            self._draft_params, dk, dv, tokens,
-                            np.int32(0))
+                            self._draft_params, dk, dv,
+                            np.zeros((1, tile), np.int32), np.int32(0))
                 events.record_instant(
                     "engine.compile", category="engine",
                     trace_id=self._trace_id, fn="prefill", tile=tile,
                     compile_count=self.prefill_compile_count)
-        self._moe_pending.clear()       # the warm-up's rows are nobody's
+        if self._count_moe:
+            self._read()
+            self._moe_counts[:] = 0
 
     # -------------------------------------------------------------- intake
     def submit(self, tokens, max_new_tokens: int = 64,
@@ -652,6 +798,7 @@ class InferenceEngine:
             self._thread = None
         with self._lock:
             self.sched.fail_all(RuntimeError("engine stopped"))
+            self._deliver()
 
     def _loop(self):
         while True:
@@ -668,19 +815,48 @@ class InferenceEngine:
             except Exception as e:           # engine must not die silently
                 with self._lock:
                     self.sched.fail_all(e)
+                    self._deliver()
 
     # --------------------------------------------------------------- step
     def step(self) -> bool:
-        """One engine iteration: reap cancels/deadlines, then ONE program
-        where the step's plan holds one span: the span's prefill tile
-        and, behind it in the same sequence, a decode row for every
-        occupied slot (admission and decode share each weight's read;
-        a step's further spans run the same program with no slot live).
-        A step with no prompt to prefill runs the decode program. A
-        request whose prompt ends in this step decodes from the next.
-        (With a speculative draft or a model with an indexer: the tile
-        programs, then the draft's step or the decode program for every
-        occupied slot, the new request among them.)
+        """One engine iteration. Between two step programs the engine's
+        thread does only what the next program needs, so a step runs in
+        this order:
+
+        - plan: reap cancels/deadlines, evict full slots, spend the
+          prefill budget. It is planned after the step before it has been
+          read, so an arrival joins the very next plan.
+        - dispatch: everything the step runs, back to back, no call
+          waiting for a value. ONE program where the plan holds one span:
+          the span's prefill tile and, behind it in the same sequence, a
+          decode row for every occupied slot (admission and decode share
+          each weight's read; a step's further spans run the same program
+          with no slot live). A step with no prompt to prefill runs the
+          decode program. A request whose prompt ends in this step
+          decodes from the next. (With a speculative draft or a model
+          with an indexer: the tile programs, then right behind them the
+          draft's step or the decode program for every occupied slot, the
+          new request among them.) The slots' CARRY (lengths, last
+          tokens, temperatures, the key; `_build_fns`) lives on the device
+          and only the programs write it; the host says what it alone
+          knows in one packed array a program (the live mask, which is
+          how an eviction or a cancel reaches the carry, and a tile's
+          tokens, place, slot and temperature) and keeps a mirror of the
+          lengths. With a temperature above zero the key's stream is the
+          carry's: the decode program splits it where it runs, a tile
+          program draws with the carry's key and the host's count of
+          tile programs folded in; no program of the key's runs on the
+          host's side of a step.
+        - deliver: under the programs now running, what the step BEFORE
+          decided is handed over: tokens to their consumers' queues,
+          finishes to the handles, the recorder's spans ended.
+        - read: once, the last program's output, which holds every token
+          the step sampled.
+        - decide: each live request's token is appended and its finish
+          decided (EOS, max_new_tokens; `max_len` at the next plan), slots
+          are freed. What was decided is held for the next step's deliver;
+          where no work follows it is delivered at once.
+
         Returns True if any device work ran."""
         with self._lock:
             ph = self._phases
@@ -692,29 +868,42 @@ class InferenceEngine:
                 ph.end()
 
     def _step(self, ph: _StepPhases, now: float) -> bool:
-        for st in self.sched.reap(now):
+        sched = self.sched
+        ahead = sched.holding()     # the step before left tokens held
+        for st in sched.reap(now):
             for pool in self._pools:
                 pool.scratch.pop(st.rid, None)
         # capacity eviction BEFORE the step: a full slot has nowhere
         # to write its next token
-        for st in self.sched.active_states():
+        for st in sched.active_states():
             if self._lengths[st.slot] >= self.config.max_len:
-                self.sched.evict(st, FINISH_LENGTH)
-        active = self.sched.active_states()
-        spans = self._prefill_spans(self.sched.plan_prefill())
+                sched.evict(st, FINISH_LENGTH)
+        active = sched.active_states()
+        spans = self._prefill_spans(sched.plan_prefill())
         # the decode rows ride in the step's FIRST tile program
         ride = self._ride and bool(spans)
-        rode = [self._run_prefill(span, now,
-                                  active if ride and i == 0 else ())
-                for i, span in enumerate(spans)]
-        did = bool(spans)
-        if ride:
-            self.fused_steps += bool(active)
-        else:
-            # a prompt that ended in this step decodes in it
-            active = self.sched.active_states()
-        dspan, n_emitted = None, 0
-        if active:
+        for i, span in enumerate(spans):
+            self._issue_prefill(span, now, active if ride and i == 0 else ())
+        ended = [span.state for span in spans if span.is_last]
+        rows = [(st, st.slot) for st in active]
+        drafted = None
+        if not ride:
+            # a prompt that ended in this step decodes in it: its first
+            # token is in the carry (unless one token is all it may have)
+            rows += [(st, st.slot) for st in ended
+                     if st.request.max_new_tokens > 1]
+        slots = [slot for _, slot in rows]
+        if rows and not ride:
+            drafted = self._issue_rows(slots)
+        did = bool(spans or rows)
+        self.issued_ahead += did and ahead
+        # under the programs now running: what the step before decided,
+        # then what of this step waits for no token
+        ph.enter("emit")
+        self._deliver()
+        dspan = None
+        if rows:
+            self.fused_steps += ride
             # decode is a BATCH phase: when one request occupies the
             # engine its span adopts that request's trace (the
             # acceptance path — one Serve call renders its decode
@@ -723,11 +912,11 @@ class InferenceEngine:
             # slot attribution instead of picking a favorite. It is
             # the step's one record: it starts where the step did and
             # ends with the step's phases on it
-            traces = {st.span.trace_id for st in active
+            traces = {st.span.trace_id for st, _ in rows
                       if st.span is not None}
-            if len(active) == 1 and active[0].span is not None:
-                d_trace = active[0].span.trace_id
-                d_parent = active[0].span.span_id
+            if len(rows) == 1 and rows[0][0].span is not None:
+                d_trace = rows[0][0].span.trace_id
+                d_parent = rows[0][0].span.span_id
             elif len(traces) == 1:
                 d_trace, d_parent = next(iter(traces)), None
             else:
@@ -736,102 +925,133 @@ class InferenceEngine:
                 "engine.decode", category="engine",
                 trace_id=d_trace, parent_span_id=d_parent,
                 start=ph.t_wall,
-                step=self.steps, slots_active=len(active),
-                slots_occupied=self.sched.occupancy(),
-                queue_depth=self.sched.queue_depth())
-            compiles0 = self.decode_compile_count
-            pool, dpool = self._slots, self._draft_slots
-            if ride:
-                ph.enter("read")
-                toks_host = np.asarray(rode[0])
-                self._fold_moe_counts()
-            elif self._spec is not None:
-                ph.enter("dispatch")
-                with self._mesh_ctx():
-                    (out, acc, pool.k, pool.v, dpool.k, dpool.v,
-                     self._rng) = self._spec_step_fn(
-                        self.params, self._draft_params,
-                        pool.k, pool.v, dpool.k, dpool.v,
-                        self._lengths, self._last_tok,
-                        self._rng, self._temps)
-                ph.enter("read")
-                out_host = np.asarray(out)
-                acc_host = np.asarray(acc)
-            else:
-                ph.enter("dispatch")
-                with self._mesh_ctx():
-                    toks, *new, self._rng = self._decode_fn(
-                        self.params, *pool.pools(), self._lengths,
-                        self._last_tok, self._rng, self._temps)
-                pool.rebind(new)
-                ph.enter("read")
-                toks_host = np.asarray(toks)
-                self._fold_moe_counts(new)
+                step=self.steps, slots_active=len(rows),
+                slots_occupied=sched.occupancy(),
+                queue_depth=sched.queue_depth())
+            if self._spec is None:
+                self._rows_ran(slots)
+        n_emitted = 0
+        if rows or ended:
+            ph.enter("read")
+            toks, fed = self._read()
             ph.enter("emit")
             now = time.monotonic()
-            if self._spec is not None:
+            for st in ended:
+                # the prompt's first token: where its tile wrote it, and
+                # what a decode program behind the tile consumed
+                self.first_tokens += 1
+                self.prefill_span_s += now - st.admitted_t
+                sched.prefill_done(st, int(fed[st.slot]), now)
+            if drafted is not None:
                 # accepted prefix + one bonus token per slot. ALL
                 # accept-count control flow happens HERE, on
                 # materialized numpy values — a Python branch on the
                 # traced count inside the program is the classic
                 # retrace bug (rtlint RT002 fixture).
-                for st in active:
-                    slot = st.slot
-                    accepted = int(acc_host[slot])
-                    if self._temps[slot] <= 0.0:
+                S = self.config.n_slots
+                drafted = np.asarray(drafted)
+                outs = drafted[:-S].reshape(S, -1)
+                for st, slot in rows:
+                    accepted = int(drafted[-S + slot])
+                    self._lengths[slot] += accepted + 1
+                    if st.temperature <= 0.0:
                         self.spec_tokens_proposed += self._spec_k
                         self.spec_tokens_accepted += accepted
                     for j in range(accepted + 1):
-                        self._lengths[slot] += 1
-                        tok = int(out_host[slot, j])
-                        self._last_tok[slot] = tok
-                        self.tokens_generated += 1
-                        n_emitted += 1
-                        self.sched.decode_emit(st, tok, now)
                         if st.slot is None:
                             break    # finished (EOS / max tokens)
+                        self.tokens_generated += 1
+                        n_emitted += 1
+                        sched.decode_emit(st, int(outs[slot, j]), now)
             else:
-                if self._topk and active:   # before the rows' own
-                    self.dsa_rows_streamed += self._dsa_streamed(
-                        [int(self._lengths[st.slot]) for st in active])
-                for st in active:
-                    slot = st.slot
-                    self._lengths[slot] += 1
-                    if self._topk:      # the row attended itself too
-                        live = int(self._lengths[slot])
-                        self.dsa_rows_live += live
-                        self.dsa_rows_read += min(live, self._topk)
-                    if self._blk:
-                        # the row's own block is among the selected
-                        # and holds the positions up to the row's only
-                        live = int(self._lengths[slot])
-                        size, topk = self._blk
-                        self.blk_rows_live += live
-                        self.blk_rows_read += min(
-                            live, size * topk - (-live % size))
-                    self._last_tok[slot] = toks_host[slot]
+                for st, slot in rows:
+                    if st.slot is None:
+                        continue     # its first token ended it
                     self.tokens_generated += 1
                     n_emitted += 1
-                    self.sched.decode_emit(st, int(toks_host[slot]),
-                                           now)
-            if self.decode_compile_count > compiles0:
-                # a decode retrace is THE perf cliff this engine is
-                # built to avoid — make every occurrence a first-class
-                # timeline event (tests assert the count stays at 1)
-                events.record_instant(
-                    "engine.compile", category="engine",
-                    trace_id=d_trace, parent_span_id=dspan.span_id,
-                    fn="decode", compile_count=self.decode_compile_count)
-            did = True
+                    sched.decode_emit(st, int(toks[slot]), now)
         self.steps += 1
         if self.on_step is not None:
             try:
-                self.on_step({"slots_occupied": self.sched.occupancy(),
-                              "queue_depth": self.sched.queue_depth()})
+                self.on_step({"slots_occupied": sched.occupancy(),
+                              "queue_depth": sched.queue_depth()})
             except Exception:
                 pass
-        ph.end(dspan, tokens=n_emitted)
+        ph.end(dspan, self._after, tokens=n_emitted)
+        if not sched.has_work():
+            self._deliver()         # nothing follows to deliver behind
         return did
+
+    def _deliver(self):
+        """Hand over what was decided and held: tokens and finishes
+        (`Scheduler.deliver`), then the recorder's spans."""
+        self.sched.deliver()
+        after, self._after = self._after, []
+        for fn, kw in after:
+            fn(**kw)
+
+    def _read(self):
+        """The step's one read, the carry as the last program left it ->
+        (the slots' last tokens, the tokens decode last consumed: a
+        prompt's first). The expert counts at its end are added to the
+        host's totals (the carry's sum wraps at 32 bits; so does the
+        difference)."""
+        host, S = np.asarray(self._carry), self.config.n_slots
+        if self._count_moe:
+            seen = host[-2:].view(np.uint32)
+            self._moe_counts += seen - self._moe_seen
+            self._moe_seen = seen
+        return host[S:2 * S], host[3 * S:4 * S]
+
+    def _rows_ran(self, slots):
+        """The decode rows of `slots` were issued: the mirror of their
+        lengths and what the counters make of them, host arithmetic."""
+        if self._topk:      # before the rows' own
+            self.dsa_rows_streamed += self._dsa_streamed(
+                [int(self._lengths[slot]) for slot in slots])
+        self._lengths[slots] += 1
+        for slot in slots:
+            live = int(self._lengths[slot])
+            if self._topk:      # the row attended itself too
+                self.dsa_rows_live += live
+                self.dsa_rows_read += min(live, self._topk)
+            if self._blk:
+                # the row's own block is among the selected
+                # and holds the positions up to the row's only
+                size, topk = self._blk
+                self.blk_rows_live += live
+                self.blk_rows_read += min(
+                    live, size * topk - (-live % size))
+
+    def _issue_rows(self, slots):
+        """Issue the decode program for the rows of `slots` (or, where
+        there is a draft, its step -> every position's choice and the
+        accepted counts, still on the device)."""
+        self._phases.enter("dispatch")
+        live = np.zeros((self.config.n_slots,), np.int32)
+        live[slots] = 1
+        compiles0 = self.decode_compile_count
+        pool, dpool = self._slots, self._draft_slots
+        out = None
+        with self._mesh_ctx():
+            if self._spec is not None:
+                (self._carry, out, pool.k, pool.v, dpool.k,
+                 dpool.v) = self._spec_step_fn(
+                    self.params, self._draft_params, pool.k, pool.v,
+                    dpool.k, dpool.v, self._carry, live)
+            else:
+                self._carry, *new = self._decode_fn(
+                    self.params, *pool.pools(), self._carry, live)
+                pool.rebind(new)
+        if self.decode_compile_count > compiles0:
+            # a decode retrace is THE perf cliff this engine is
+            # built to avoid — make every occurrence a first-class
+            # timeline event (tests assert the count stays at 1)
+            events.record_instant(
+                "engine.compile", category="engine",
+                trace_id=self._trace_id, fn="decode",
+                compile_count=self.decode_compile_count)
+        return out
 
     def _prefill_spans(self, chunks: List[PrefillChunk]):
         """The step's plan, one piece a dispatch: the consecutive chunks
@@ -865,37 +1085,25 @@ class InferenceEngine:
         tiles = self._prefill_tiles
         return next(t for t in tiles if t >= min(prompt_len, tiles[-1]))
 
-    def _call_prefill(self, scratch, tokens, pos0, n_real, key, temp,
-                      live):
-        """One call of the tile program -> (the tile's token, the slots'
-        tokens, the scratch). `live`: the slots whose decode rows ride
-        behind the tile; their pools are rebound here. (An engine whose
-        decode rows do not ride: no rows, no slots' tokens.)"""
+    def _call_prefill(self, scratch, host):
+        """One call of the tile program on the host's array `host`
+        (`_tile_args`) -> (the span's slot, the scratch), on the device.
+        The carry and, where decode rows ride behind the tile, their
+        pools are rebound here."""
         n = len(scratch)
-        pools, rows = (), ()
+        pools = self._slots.pools() if self._ride else ()
+        self._carry, slot, *new = self._prefill_fn(
+            self.params, *scratch, *pools, self._carry, host)
         if self._ride:
-            mask = np.zeros((self.config.n_slots,), bool)
-            mask[live] = True
-            pools = self._slots.pools()
-            rows = (self._lengths, self._last_tok, self._temps, mask)
-        tok, *out = self._prefill_fn(
-            self.params, *scratch, *pools, tokens, np.int32(pos0),
-            np.int32(n_real), key, np.float32(temp), *rows)
-        toks = None
-        if self._ride:
-            toks, *out = out
-            self._slots.rebind(out[n:2 * n])
-        self._fold_moe_counts(out, wait=False)
-        return tok, toks, tuple(out[:n])
+            self._slots.rebind(new[n:])
+        return slot, tuple(new[:n])
 
-    def _run_prefill(self, ch: PrefillChunk, now: float, active=()):
-        """Run one span of a prompt in its tile, with the decode rows of
-        the `active` states' slots behind it -> the slots' tokens (on
-        the device; None where decode rows do not ride). `now`: the
-        step's start (time.monotonic())."""
-        import jax
-        import jax.numpy as jnp
-
+    def _issue_prefill(self, ch: PrefillChunk, now: float, active=()):
+        """Issue one span of a prompt in its tile, with the decode rows of
+        the `active` states' slots behind it. Nothing here waits for a value: a
+        span that ends its prompt has its slot made (the scratch copied
+        in) behind its tile, and its first token is the carry's until the
+        step reads. `now`: the step's start (time.monotonic())."""
         ph = self._phases
         ph.enter("dispatch")
         st = ch.state
@@ -938,10 +1146,10 @@ class InferenceEngine:
                     dk_dv = self._draft_replay(st, *dk_dv)
         prompt = st.request.tokens
         tile = self._tile_of(len(prompt))
-        tokens = np.zeros((1, tile), np.int32)
-        tokens[0, :ch.length] = prompt[ch.start:ch.start + ch.length]
-        tokens = jnp.asarray(tokens)
-        self._rng, k = jax.random.split(self._rng)
+        slots = [a.slot for a in active]
+        host = self._tile_args(
+            tile, prompt[ch.start:ch.start + ch.length], ch.start, st.slot,
+            ch.is_last, st.temperature, slots)
         pspan = events.start_span(
             "engine.prefill", category="engine",
             trace_id=st.span.trace_id, parent_span_id=st.span.span_id,
@@ -954,24 +1162,21 @@ class InferenceEngine:
         self.prefill_dispatches += 1
         self.prefill_tokens += ch.length
         with self._mesh_ctx():
-            tok, toks, scratch = self._call_prefill(
-                scratch, tokens, ch.start, ch.length, k, st.temperature,
-                [a.slot for a in active])
+            slot, scratch = self._call_prefill(scratch, host)
         if self.prefill_compile_count > compiles0:
             events.record_instant(
                 "engine.compile", category="engine",
                 trace_id=st.span.trace_id,
                 parent_span_id=pspan.span_id, fn="prefill",
                 compile_count=self.prefill_compile_count)
-        pspan.end()
+        self._after.append((pspan.end, {"end": time.time()}))
         if self._spec is not None:
             with self._mesh_ctx():
-                ndk, ndv = self._draft_prefill_fn(
-                    self._draft_params, dk_dv[0], dk_dv[1],
-                    tokens, np.int32(ch.start))
-            dk_dv = (ndk, ndv)
+                dk_dv = self._draft_prefill_fn(
+                    self._draft_params, *dk_dv,
+                    host[None, self.config.n_slots + _TILE_HEAD:],
+                    np.int32(ch.start))
         if ch.is_last:
-            slot = st.slot
             if self.prefix_cache is not None:
                 self._populate_prefix(st, scratch)
             self._slots.insert(scratch, slot)
@@ -979,43 +1184,14 @@ class InferenceEngine:
                 self._draft_slots.insert(dk_dv, slot)
             for pool in self._pools:
                 pool.scratch.pop(st.rid, None)
-            self._lengths[slot] = len(prompt)
-            ph.enter("read")
-            first = int(tok)
-            self._fold_moe_counts()
-            ph.enter("emit")
-            self._last_tok[slot] = first
-            self._temps[slot] = st.temperature
-            t_first = time.monotonic()
-            self.first_tokens += 1
-            self.prefill_span_s += t_first - st.admitted_t
-            self.sched.prefill_done(st, first, t_first)
+            self._lengths[st.slot] = len(prompt)
         else:
             if self._write_through:
                 scratch = self._publish_chunk(st, scratch, ch)
-            ph.enter("emit")
             self._slots.scratch[st.rid] = scratch
             if self._spec is not None:
                 self._draft_slots.scratch[st.rid] = dk_dv
             self.sched.advance_prefill(st, ch.length)
-        return toks
-
-    def _fold_moe_counts(self, outputs=None, wait=True):
-        """Note the expert-layer counts at the end of a program's
-        `outputs`, and (`wait`) add every pair noted to the host's
-        totals. Folded only where the step has just read an output of
-        the latest program, so every pair is already computed and no
-        wait is added; a prefill tile that is not a prompt's last is
-        read by nobody and only notes its pair."""
-        if not self._count_moe:
-            return
-        if outputs is not None:
-            self._moe_pending.append(outputs[-1])
-        if not wait:
-            return
-        for pair in self._moe_pending:
-            self._moe_counts += np.asarray(pair)
-        self._moe_pending.clear()
 
     # ------------------------------------------------------- prefix cache
     def _restore_prefix(self, st, scratch):
@@ -1146,6 +1322,7 @@ class InferenceEngine:
             "active": len(self.sched.active_slots()),
             "steps": self.steps,
             "fused_steps": self.fused_steps,
+            "issued_ahead": self.issued_ahead,
             "tokens_generated": self.tokens_generated,
             "prefill_dispatches": self.prefill_dispatches,
             "prefill_tokens": self.prefill_tokens,

@@ -143,10 +143,13 @@ class SlotPool:
         return tuple(zeros(shape, self.dtypes[n], self._scratch_sharding)
                      for n, shape in self.scratch_shapes.items())
 
-    def insert(self, scratch, slot: int):
-        """A finished prompt's scratch becomes slot ``slot``."""
-        self.rebind(self._insert_fn(self.pools(), tuple(scratch),
-                                    np.int32(slot)))
+    def insert(self, scratch, slot):
+        """A finished prompt's scratch becomes slot ``slot``: an int, or
+        an int32 scalar already on the device (the engine's, which its
+        tile program hands back: the copy then waits for no transfer)."""
+        if isinstance(slot, int):
+            slot = np.int32(slot)
+        self.rebind(self._insert_fn(self.pools(), tuple(scratch), slot))
 
 
 def span_format(span) -> str:

@@ -9,6 +9,14 @@ what to evict, what to prefill, what is active — and reports back what
 happened; all device-side state (KV pool, scratch caches) stays in the
 engine.
 
+Decision and delivery are two moments. What decides the next plan (a
+token appended, a finish decided, a slot freed) happens where the engine
+reports it; what a consumer sees of it (the handle's queue, its
+``finish_reason``, the flight recorder's slot span) is HELD, in order, and
+handed over by ``deliver()``, which the engine calls behind its next
+dispatch: waking a consumer thread costs the engine's thread the GIL, and
+nothing the next program needs waits for it.
+
 Thread model: the engine serializes all scheduler calls under its own
 lock; request handles (the streaming consumer side) only touch their
 thread-safe token queue and the `cancelled` flag.
@@ -74,6 +82,11 @@ class RequestHandle:
 
     # ------------------------------------------------------ engine side
     def _emit(self, token: int, now: float):
+        """Hand one token to the consumer. Called from
+        ``Scheduler.deliver()``, on the engine's thread, behind the
+        dispatch of the step after the one that sampled the token (at once
+        where no work follows); `now` is when the engine read the token,
+        so ``ttft_s`` does not count the wait for the delivery."""
         if self.first_token_t is None:
             self.first_token_t = now
         self._q.put(int(token))
@@ -221,6 +234,9 @@ class Scheduler:
         # the caller re-routes them to a surviving replica — while
         # everything already queued/prefilling/active runs to completion
         self.draining = False
+        # decided, not yet delivered: (function, arguments) in the order
+        # decided, so a request's stream keeps its order (deliver())
+        self._held: List[tuple] = []
 
     # ----------------------------------------------------------- draining
     def begin_drain(self):
@@ -294,13 +310,11 @@ class Scheduler:
         keep = []
         for st in self._queue:
             if st.handle.cancelled:
-                st.status = "FINISHED"
-                st.handle._finish(FINISH_CANCELLED, now)
+                self._release(st, FINISH_CANCELLED, now)
                 reaped.append(st)
             elif (st.request.deadline_s is not None
                     and now > st.request.deadline_s):
-                st.status = "FINISHED"
-                st.handle._finish(FINISH_DEADLINE, now)
+                self._release(st, FINISH_DEADLINE, now)
                 reaped.append(st)
             else:
                 keep.append(st)
@@ -323,6 +337,9 @@ class Scheduler:
 
     def _release(self, st: RequestState, reason: str, now: float,
                  error: Optional[BaseException] = None):
+        """The decision: the request is over and its slot (if it held
+        one) is free for the next plan. The consumer and the recorder
+        hear of it at ``deliver()``."""
         st.status = "FINISHED"
         self.unpin_prefix(st)
         freed_slot = st.slot
@@ -331,20 +348,41 @@ class Scheduler:
             self._free_slots.append(st.slot)
             self._free_slots.sort()
             st.slot = None
-        st.handle._finish(reason, now, error)
-        if st.span is not None:
+        span, st.span = st.span, None
+        self._held.append((self._deliver_finish, (
+            st.handle, reason, now, error, span, freed_slot, st.generated,
+            time.time())))
+
+    @staticmethod
+    def _deliver_finish(handle, reason, now, error, span, freed_slot,
+                        generated, t_wall):
+        handle._finish(reason, now, error)
+        if span is not None:
             # the engine-slot span covers admission -> eviction; the
             # finish reason and token count ride as attributes, and an
             # eviction instant marks the exact slot-release point
             from ray_tpu._private import events
             events.record_instant(
                 "engine.evict", category="engine",
-                trace_id=st.span.trace_id,
-                parent_span_id=st.span.span_id,
-                slot=freed_slot, reason=reason)
-            st.span.end(finish_reason=reason,
-                        tokens_generated=st.generated)
-            st.span = None
+                trace_id=span.trace_id, parent_span_id=span.span_id,
+                ts=t_wall, slot=freed_slot, reason=reason)
+            span.end(end=t_wall, finish_reason=reason,
+                     tokens_generated=generated)
+
+    # ---------------------------------------------------------- delivery
+    def holding(self) -> bool:
+        """Whether anything decided has yet to be delivered."""
+        return bool(self._held)
+
+    def deliver(self):
+        """Hand everything held to its consumer, in the order decided:
+        tokens to their handles' queues, finishes to the handles and the
+        recorder. The engine calls it behind a step's dispatch (the
+        program then running needs none of it), and at a step's end where
+        no work follows."""
+        held, self._held = self._held, []
+        for fn, args in held:
+            fn(*args)
 
     # --------------------------------------------------------- admission
     def plan_prefill(self) -> List[PrefillChunk]:
@@ -418,7 +456,7 @@ class Scheduler:
         st.prefill_pos = len(st.request.tokens)
         st.last_token = int(first_token)
         st.generated = 1
-        st.handle._emit(first_token, now)
+        self._held.append((st.handle._emit, (first_token, now)))
         if self._is_finished(st, first_token):
             self._release(st, self._finish_reason(st, first_token), now)
         else:
@@ -429,11 +467,12 @@ class Scheduler:
 
     # ------------------------------------------------------------ decode
     def decode_emit(self, st: RequestState, token: int, now: float):
-        """One decoded token for an active slot: emit, then evict on
-        EOS/max-tokens (slot returns to the free list immediately)."""
+        """One decoded token for an active slot: append it (held for
+        ``deliver()``), then evict on EOS/max-tokens (slot returns to the
+        free list immediately)."""
         st.last_token = int(token)
         st.generated += 1
-        st.handle._emit(token, now)
+        self._held.append((st.handle._emit, (token, now)))
         if self._is_finished(st, token):
             self._release(st, self._finish_reason(st, token), now)
 
@@ -454,13 +493,15 @@ class Scheduler:
         self._release(st, reason, time.monotonic(), error)
 
     def fail_all(self, error: BaseException):
-        """Engine shutdown/crash: fail everything still in flight."""
+        """Engine shutdown/crash: fail everything still in flight, and
+        deliver at once (what was held first)."""
         now = time.monotonic()
         for st in (list(self._queue) + list(self._prefilling)
                    + list(self._active.values())):
             self._release(st, FINISH_CANCELLED, now, error)
         self._queue.clear()
         self._prefilling.clear()
+        self.deliver()
 
     def has_work(self) -> bool:
         """Actionable work only: a queue holding nothing but held

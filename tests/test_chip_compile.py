@@ -137,6 +137,61 @@ def test_pool_decode_kernel_takes_the_pool_as_it_lies(one_chip,
     assert re.search(r"bf16\[16,8,69632,128\]\S* bitcast\(", text)
 
 
+@pytest.mark.parametrize("program", ["decode", "tile"])
+def test_step_programs_keep_a_layers_kv_in_fast_memory(
+        one_chip, no_compile_cache, monkeypatch, program):
+    """The engine's step programs at `mistral-7b.chat-steady`'s size (20
+    layers, 16 slots of 2048): each layer's K and V are copied out of the
+    pool ([16, 2048, 8, 128], 67 MB) and attended, and the compiler keeps
+    BOTH copies in the fast memory (`S(1)` in the compiled text). That
+    assignment is a cliff no test on the CPU sees: with the slots' carry
+    donated, or with the tile program handing back a key it had split, one
+    of the two stayed in HBM and the attention's ops ran 1.6 times as long
+    (`decode_prog_ms` 20.13 -> 23.27, `prefill_prog_ms` 24.57 -> 28.19, my
+    chip run, PR 43; PERF.md section 6). The engine is built on shapes:
+    nothing is allocated and nothing runs."""
+    import json
+    import re
+
+    import numpy as np
+
+    from perfbench import spec
+    from perfbench.families import mistral
+    from ray_tpu.inference import kv_cache
+    from ray_tpu.inference.engine import EngineConfig, InferenceEngine
+    with open(os.path.join(spec.ROOT, "perfbench", "configs",
+                           "mistral-7b.json")) as f:
+        cfg = json.load(f)
+    model = mistral.build_model(mistral.model_kwargs(cfg))
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    monkeypatch.setattr(kv_cache, "zeros", lambda shape, dtype, sh=None:
+                        jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype)))
+    monkeypatch.setattr(InferenceEngine, "_compile_prefill_tiles",
+                        lambda self: None)
+    engine = dict(cfg["engine"], prefix_cache_slots=0)
+    del engine["max_ongoing_requests"]
+    eng = InferenceEngine(model, params, EngineConfig(**engine))
+    S, tile = eng.config.n_slots, eng._prefill_tiles[-1]
+    if program == "decode":
+        fn, args = eng._decode_fn, (
+            eng.params, *eng._slots.pools(), eng._carry,
+            np.zeros((S,), np.int32))
+    else:
+        fn, args = eng._prefill_fn, (
+            eng.params, *eng._slots.new_scratch(), *eng._slots.pools(),
+            eng._carry, eng._tile_args(
+                tile, np.zeros((tile,), np.int32), 0, 0, False, 0.0, []))
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        np.shape(a), a.dtype, sharding=one_chip), args)
+    text = fn.lower(*args).compile().as_text()
+    copies = re.findall(
+        r"%dynamic-slice\S* = bf16\[16,2048,8,128\]\{([^}]*)\} fusion\(",
+        text)
+    assert len(copies) == 2, copies             # a layer's K and its V
+    assert all("S(1)" in layout for layout in copies), copies
+
+
 def test_flash_by_name_never_returns_the_reference():
     """A length the kernel cannot tile raises; it is "auto" that chooses
     by platform and shape (here, on the CPU: the reference)."""

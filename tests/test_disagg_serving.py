@@ -130,6 +130,7 @@ def test_held_request_still_reaped_on_cancel_and_release_is_idempotent():
     held.cancel()
     reaped = s.reap()
     assert [st.rid for st in reaped] == [held.rid]
+    s.deliver()
     assert held.finish_reason == "cancelled"
     assert s.release_hold(held.rid) is False   # already gone
 

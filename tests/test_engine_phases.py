@@ -210,24 +210,18 @@ def test_on_step_is_handed_what_the_gauges_read():
 
 # ------------------------------------------------- the programs' own text
 def _decode_text(eng):
-    pool = eng._slots
     return eng._decode_fn.lower(
-        eng.params, *pool.pools(), eng._lengths, eng._last_tok, eng._rng,
-        eng._temps).as_text()
+        eng.params, *eng._slots.pools(), eng._carry,
+        np.zeros((eng.config.n_slots,), np.int32)).as_text()
 
 
 def _tile_text(eng):
     tile = eng._prefill_tiles[-1]
-    rows = ()
-    pools = ()
-    if eng._ride:
-        pools = eng._slots.pools()
-        rows = (eng._lengths, eng._last_tok, eng._temps,
-                np.zeros((eng.config.n_slots,), bool))
+    pools = eng._slots.pools() if eng._ride else ()
     return eng._prefill_fn.lower(
-        eng.params, *eng._slots.new_scratch(), *pools,
-        jnp.zeros((1, tile), jnp.int32), np.int32(0), np.int32(tile),
-        eng._rng, np.float32(0.0), *rows).as_text()
+        eng.params, *eng._slots.new_scratch(), *pools, eng._carry,
+        eng._tile_args(tile, np.zeros((tile,), np.int32), 0, 0, False, 0.0,
+                       [])).as_text()
 
 
 def _train_text(_):
@@ -251,19 +245,27 @@ INDEXER = dict(index_heads=2, index_head_dim=16, index_topk=16)
 
 
 @pytest.mark.parametrize("model,text,digest", [
-    ({}, _decode_text, "bed14eae24701b4e"),
-    ({}, _tile_text, "d028a44f77a5b8a4"),
-    (INDEXER, _decode_text, "9cde982ed21fc859"),
-    (INDEXER, _tile_text, "30a61f3e8e0aed7f"),
+    ({}, _decode_text, "13226c2622d8400b"),
+    ({}, _tile_text, "adb06cf3c66ec937"),
+    (INDEXER, _decode_text, "8702c029e6542675"),
+    (INDEXER, _tile_text, "bcd13d65728df53a"),
     (None, _train_text, "8aecbfdae32759c2")],
     ids=["dense_decode", "dense_tile_with_rows", "indexer_decode",
          "indexer_tile", "train_step"])
 def test_the_step_programs_are_the_parents(model, text, digest):
     """The lowered text of the decode program, of the tile program (with
     the riding rows, and an indexer model's without) and of a training
-    step, as read on the commit before the step was told by phase (PR 41's
-    tree, by this same function): the marks are the host's, no program
-    recompiles for them and set-up has no reason to move."""
+    step. The training step's digest is PR 41's tree's (read there by this
+    same function, before the step was told by phase): the marks are the
+    host's, no program recompiles for them and set-up has no reason to
+    move. The four engine programs' were re-pinned once, on PR 43's final
+    tree: that PR moved the slots' carry (lengths, last tokens,
+    temperatures, the key) onto the device as one array the programs take
+    and hand back advanced, packed the host's arguments into one array and
+    made the tile's key of the carry's and a count, so every engine
+    program's signature and last few ops changed; what they compute of the
+    model did not (tests/test_step_order.py holds their greedy tokens to
+    the parent's)."""
     eng = None if model is None else _engine(**model)
     got = hashlib.sha256(text(eng).encode()).hexdigest()[:16]
     assert got == digest

@@ -152,9 +152,10 @@ def _prompt_kv(kind, prompt, decoders, second):
         run_until(eng, lambda: all(h.first_token_t for h in others))
     spans, call = [], eng._call_prefill
 
-    def spy(scratch, tokens, pos0, n_real, key, temp, live):
-        spans.append((int(pos0), int(n_real), len(live)))
-        return call(scratch, tokens, pos0, n_real, key, temp, live)
+    def spy(scratch, host):
+        S = eng.config.n_slots      # the host's one array: _tile_args
+        spans.append((int(host[S]), int(host[S + 1]), int(host[:S].sum())))
+        return call(scratch, host)
     eng._call_prefill = spy
     if second:
         others.append(eng.submit(rng.randint(1, 128, 3), max_new_tokens=1))
@@ -200,7 +201,7 @@ def test_counters_and_spans_count_what_they_say():
     eng = engine_of("dense")
     a = eng.submit(np.arange(1, 4), max_new_tokens=6)
     eng.step()                      # a's prompt ends: no row was live
-    assert a.first_token_t is not None and eng.sched._active
+    assert eng.first_tokens == 1 and eng.sched._active
     assert eng.stats()["fused_steps"] == 0 and eng.tokens_generated == 0
     eng.step()                      # a decode-only step: the second token
     assert eng.tokens_generated == 1 and eng.stats()["fused_steps"] == 0
@@ -210,9 +211,10 @@ def test_counters_and_spans_count_what_they_say():
     st = eng.stats()
     assert st["fused_steps"] == 2 and st["steps"] == 4
     assert st["prefill_dispatches"] == 3 and eng.tokens_generated == 3
-    # b's prompt ended in the fourth step: its first token is out, its
-    # second comes with the next step's decode rows, a's among them
-    assert b.first_token_t is not None
+    # b's prompt ended in the fourth step: its first token is decided
+    # (and goes out behind the next dispatch), its second comes with the
+    # next step's decode rows, a's among them
+    assert eng.first_tokens == 2 and b.first_token_t is None
     (state,) = [s for s in eng.sched.active_states() if s.handle is b]
     assert state.generated == 1
     eng.step()

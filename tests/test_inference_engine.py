@@ -215,8 +215,7 @@ def test_pool_is_updated_in_place_not_rebuilt(tiny, program):
     chunk = eng._prefill_tiles[-1]
     if program == "decode":
         fn, pools = eng._decode_fn, (eng._slots.k, eng._slots.v)
-        args = (eng.params, *pools, eng._lengths, eng._last_tok,
-                eng._rng, eng._temps)
+        args = (eng.params, *pools, eng._carry, np.ones((3,), np.int32))
         rows, new_len, first = 3, 1, 1
     else:
         shape = eng._slots.scratch_shape
@@ -224,11 +223,9 @@ def test_pool_is_updated_in_place_not_rebuilt(tiny, program):
                    jnp.ones(shape, jnp.float32))
         slots = (eng._slots.k, eng._slots.v)
         fn = eng._prefill_fn
-        args = (eng.params, *scratch, *slots,
-                jnp.zeros((1, chunk), jnp.int32), jnp.int32(8),
-                jnp.int32(chunk), eng._rng, jnp.float32(0.0),
-                eng._lengths, eng._last_tok, eng._temps,
-                np.ones((3,), bool))
+        args = (eng.params, *scratch, *slots, eng._carry,
+                eng._tile_args(chunk, np.zeros((chunk,), np.int32), 8, 0,
+                               False, 0.0, [0, 1, 2]))
         pools, first = ((scratch, 2) if program == "prefill"
                         else (slots, 4))
         rows, new_len = (1, chunk) if program == "prefill" else (3, 1)
@@ -481,28 +478,32 @@ def _tiles_and_tokens(eng, fillers, prompt, n_new=4):
     """Tokens of `prompt` submitted behind `fillers` (which share its
     steps' budget), its K/V when its first token is out, and the
     (tile, offset, real tokens) of every prefill dispatch it got."""
+    from ray_tpu.inference.engine import _TILE_HEAD
     own, cur = [], {}
-    run, fn = eng._run_prefill, eng._prefill_fn
+    run, fn = eng._issue_prefill, eng._prefill_fn
+    S = eng.config.n_slots
 
     def spy_run(ch, *rest):
         cur["st"] = ch.state
         return run(ch, *rest)
 
-    def spy_fn(params, sk, sv, pk, pv, tokens, pos0, n_real, *rest):
+    def spy_fn(*args):
+        host = args[-1]             # the host's one array: _tile_args
         if cur["st"].handle is h:
-            own.append((tokens.shape[1], int(pos0), int(n_real)))
-        return fn(params, sk, sv, pk, pv, tokens, pos0, n_real, *rest)
+            cur["slot"] = cur["st"].slot
+            own.append((len(host) - S - _TILE_HEAD, int(host[S]),
+                        int(host[S + 1])))
+        return fn(*args)
     rng = np.random.RandomState(len(prompt))
     hs = [eng.submit(rng.randint(0, 128, n), max_new_tokens=1)
           for n in fillers]
     h = eng.submit(prompt, max_new_tokens=n_new)
-    eng._run_prefill, eng._prefill_fn = spy_run, spy_fn
+    eng._issue_prefill, eng._prefill_fn = spy_run, spy_fn
     try:
         assert _run_until(eng, lambda: h.first_token_t is not None, 60)
     finally:
-        eng._run_prefill, eng._prefill_fn = run, fn
-    n, slot = len(prompt), cur["st"].slot
-    assert cur["st"].handle is h       # the last prefill before its token
+        eng._issue_prefill, eng._prefill_fn = run, fn
+    n, slot = len(prompt), cur["slot"]
     kv = (np.asarray(eng._slots.k[:, slot, :n]),
           np.asarray(eng._slots.v[:, slot, :n]))
     assert _run_until(eng, lambda: all(

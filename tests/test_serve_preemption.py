@@ -502,6 +502,8 @@ def test_scheduler_drain_mode_refuses_new_finishes_queued():
     st = chunks[0].state
     for tok in (8, 9, 10):
         s.decode_emit(st, tok, time.monotonic())
+    assert h1.finish_reason is None         # decided, held for deliver()
+    s.deliver()
     assert h1.tokens() == [7, 8, 9, 10]
     assert s.drained()
 
