@@ -11,7 +11,9 @@ batching in the Gemma-on-TPU serving stack):
   (kv_cache.py ``SlotPool``; a model with layers of several kinds keeps
   each kind's pools over that kind's layers: K, V and pooled keys for its
   block-sparse layers, a float32 state with no position axis for its
-  linear ones; the prefix blocks and their fp/int8 format are its
+  linear ones, and for layers of attention heads beside a state-space
+  mixer K and V AND two such states, the mixer's and its convolution's
+  tail; the prefix blocks and their fp/int8 format are its
   ``BlockStore``, which holds K and V only: a model with any cache beyond
   those runs without a prefix cache) and donated through every step. The
   cached forward's layer loop only reads a pool; one write after the loop adds
@@ -278,7 +280,8 @@ class InferenceEngine:
         if beyond and (cfg.prefix_cache_slots > 0 or self._spec is not None):
             raise ValueError(
                 f"the model keeps caches beyond K and V ({', '.join(beyond)}"
-                f": an indexer's keys, pooled keys, a recurrent state), "
+                f": an indexer's keys, pooled keys, a recurrent state, a "
+                f"convolution's tail), "
                 f"which prefix blocks and a speculative draft's verify step "
                 f"do not carry: run it with prefix_cache_slots=0 and no "
                 f"spec")
@@ -1353,6 +1356,8 @@ class InferenceEngine:
         out["kv_pool_bytes"] = sum(p.nbytes() for p in self._pools)
         if "s" in self._slots.shapes:
             out["state_pool_bytes"] = self._slots.nbytes(("s",))
+        if "c" in self._slots.shapes:
+            out["conv_pool_bytes"] = self._slots.nbytes(("c",))
         if self._topk:
             out["dsa_rows_read"] = self.dsa_rows_read
             out["dsa_rows_live"] = self.dsa_rows_live

@@ -11,7 +11,10 @@ what the engine keeps in that layout and nothing else knows how:
 - ``SlotPool``: every pool of one model's decode slots (K and V; an
   indexer's keys; for a model with layers of several kinds the pooled
   keys of its block-sparse layers and the float32 states of its linear
-  layers, each over its own kind's layers), the scratch a prompt prefills
+  layers, each over its own kind's layers; for layers that run attention
+  heads and a state-space mixer side by side K and V AND two float32
+  states, the mixer's and its convolution's tail), the scratch a prompt
+  prefills
   into, and the program that makes a finished scratch a slot. Pools are
   of two natures (``CACHE_POS_AXIS``): with a position axis, of which a
   slot takes the scratch's first ``slot_len`` positions; without (a
@@ -118,14 +121,14 @@ class SlotPool:
             return tuple(
                 jax.lax.dynamic_update_slice(
                     p, jax.lax.slice(s, (0,) * s.ndim, taken[name]),
-                    (0, slot, 0, 0, 0))
+                    (0, slot) + (0,) * (s.ndim - 2))
                 for name, p, s in zip(self.shapes, pools, scratch))
 
         self._insert_fn = jax.jit(insert, donate_argnums=(0,))
 
     def pools(self) -> Tuple[Any, ...]:
-        """(k, v), (k, v, ki) or (k, v, kp, s): the order of
-        ``cache_shapes``."""
+        """(k, v), (k, v, ki), (k, v, kp, s) or (k, v, s, c): the order
+        of ``cache_shapes``."""
         return tuple(getattr(self, n) for n in self.shapes)
 
     def rebind(self, pools) -> None:

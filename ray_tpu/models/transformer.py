@@ -84,7 +84,10 @@ class TransformerConfig:
     # attention over blocks selected from mean-pooled keys (the `blk_*`
     # sizes; models/sparse_attention.py `block_select`); "lin": lightning
     # linear attention, `n_heads` heads of `head_dim` with a recurrent
-    # state in float32 and no cache of keys (models/linear_attention.py).
+    # state in float32 and no cache of keys (models/linear_attention.py);
+    # "hyb": the attention above (every earlier position) and a state-space
+    # mixer in parallel (the `ssm_*` sizes and `*_mult` scalings below), a
+    # layer that keeps K and V by position AND two states with none.
     # Each kind's layers keep caches of their own (`cache_shapes`), stacked
     # over that kind's layers, and the layers are not scanned. None: every
     # layer is the attention above
@@ -106,11 +109,35 @@ class TransformerConfig:
     scale_emb: float = 1.0
     residual_scale: float = 1.0
     logit_scale: float = 1.0
+    # a "hyb" layer (of `mixer_kinds`): the attention heads above and a
+    # state-space mixer (models/ssm.py) side by side on ONE normed input,
+    # their outputs summed. The mixer: `ssm_heads` heads of `ssm_head_dim`
+    # with a float32 state of `ssm_state` a head dimension, B and C shared
+    # by the heads of each of `ssm_groups` groups, behind a depthwise
+    # causal convolution of `ssm_conv` taps whose input's tail is a second
+    # state
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    # such a block's fixed scalings (muP, as published with the model):
+    # each branch's input and output, the keys, the in-projection's five
+    # segments (z, x, B, C, dt), the MLP's gate and output
+    attn_in_mult: float = 1.0
+    attn_out_mult: float = 1.0
+    key_mult: float = 1.0
+    ssm_in_mult: float = 1.0
+    ssm_out_mult: float = 1.0
+    ssm_mults: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_mults: Tuple[float, float] = (1.0, 1.0)
 
     def __post_init__(self):
         if not self.head_dim:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.n_heads)
+        object.__setattr__(self, "ssm_mults", tuple(self.ssm_mults))
+        object.__setattr__(self, "mlp_mults", tuple(self.mlp_mults))
         kinds = self.mixer_kinds
         if kinds is not None:
             object.__setattr__(self, "mixer_kinds", tuple(kinds))
@@ -120,6 +147,14 @@ class TransformerConfig:
                     f"mixer_kinds {kinds}: one of {sorted(KIND_CACHES)} for "
                     f"each of the {self.n_layers} layers, with "
                     f"scan_layers=False and no indexer")
+            if "hyb" in kinds and (
+                    "lin" in kinds or not self.ssm_heads
+                    or self.ssm_heads % self.ssm_groups):
+                raise ValueError(
+                    "\"hyb\" layers: ssm_heads in whole groups, and no "
+                    "\"lin\" layer beside them (both keep the pool \"s\", "
+                    "each in a shape of its own)")
+
             if (self.blk_kernel % self.blk_stride
                     or self.blk_size % self.blk_stride
                     or self.blk_size & (self.blk_size - 1)):
@@ -130,7 +165,8 @@ class TransformerConfig:
 
 # the caches a layer of each kind keeps, in the order every tuple of them
 # keeps (`cache_shapes` says their shapes, CACHE_POS_AXIS their nature)
-KIND_CACHES = {"blk": ("k", "v", "kp"), "lin": ("s",)}
+KIND_CACHES = {"blk": ("k", "v", "kp"), "lin": ("s",),
+               "hyb": ("k", "v", "s", "c")}
 
 _PARTITION_OFF = __import__("threading").local()
 
@@ -272,6 +308,67 @@ def _join_rows(tile, rows):
     return jnp.concatenate([tile, jnp.swapaxes(rows, 0, 1)], axis=1)
 
 
+def _tile_attention(q, k_cache, v_cache, pos0):
+    """A tile q [B, S, H, D] at absolute positions pos0 + 0..S-1 (pos0 a
+    scalar or [B]) against caches [B, M, Hkv, D] that already hold the
+    tile's own rows: causal over absolute positions, blocked over the keys
+    with a running softmax, over the blocks up to the tile's last position
+    only (`_cached_attention` scores every position of the cache at once:
+    [H, S, M] in float32, 0.75 GB a layer for a 1024-row tile against
+    9216 positions)."""
+    from ray_tpu.models import sparse_attention as sa
+    B, S, H, D = q.shape
+    M, Hkv = k_cache.shape[1], k_cache.shape[2]
+    qpos = jnp.broadcast_to(
+        jnp.reshape(pos0, (-1, 1)) + jnp.arange(S)[None, :], (B, S))
+    kb = sa._block_of(M)
+    n_live = jnp.minimum((jnp.max(qpos) + kb) // kb, M // kb)
+
+    def block_of(i, qg):
+        kblk = jax.lax.dynamic_slice_in_dim(k_cache, i * kb, kb, 1)
+        vblk = jax.lax.dynamic_slice_in_dim(v_cache, i * kb, kb, 1)
+        mb = (i * kb + jnp.arange(kb)[None, None, :]
+              <= qpos[:, :, None])[:, None, None]
+        s = jnp.einsum("bshgd,bmhd->bhgsm", qg, kblk,
+                       preferred_element_type=jnp.float32) * D ** -0.5
+        return s, mb, vblk
+
+    return sa._blocked_softmax(q, Hkv, n_live, block_of)
+
+
+def _row_attention(q, k_new, v_new, k_pool, v_pool, layer, lens):
+    """One row a slot: q [B, 1, H, D] at position lens[b] (a scalar: every
+    row's) against layer `layer` of the pools [n_layers, B, M, Hkv, D],
+    which hold the positions below lens[b] and are READ WHERE THEY LIE,
+    each slot's live key blocks only (ops/decode_attention.py: the Pallas
+    kernel on a TPU where the shape fits it, else the same blocks by an
+    XLA loop); the row's own key and value are folded into the running
+    softmax beside them, so the caller's one write after the layers is the
+    only one. (A layer sliced out of the pool to be attended is copied
+    whole, 0.27 GB a layer in and out a step at 16 slots of 8,192: read
+    off the program compiled for a described v5e, PERF.md section 6,
+    PR 44.)"""
+    from ray_tpu.models import sparse_attention as sa
+    from ray_tpu.ops import decode_attention
+    B, _, H, D = q.shape
+    M, Hkv = k_pool.shape[2:4]
+    lens = jnp.broadcast_to(jnp.reshape(lens, (-1,)), (B,))
+    attend = decode_attention.pool_decode_attention \
+        if sa._kernel_reads(M, Hkv, D) \
+        else decode_attention.pool_decode_reference
+    m, l, acc = attend(q[:, 0], k_pool, v_pool, layer, lens)
+    # the row's own: one key a KV head, met by all H query heads and
+    # counted by its own (as `sparse_decode_attention` folds it)
+    own = (jnp.arange(Hkv)[None, :]
+           == (jnp.arange(H) // (H // Hkv))[:, None])            # [H, Hkv]
+    s = jnp.einsum("bhd,bnd->bhn", q[:, 0], k_new[:, 0],
+                   preferred_element_type=jnp.float32) * D ** -0.5
+    carry = sa._softmax_step(
+        (m[:, None, :, None], l[:, None, :, None], acc[:, None, :, None]),
+        s[:, None, :, None], own[None, None, :, None], v_new[:, 0, :, None])
+    return sa._softmax_out(carry, q)
+
+
 class LayerNorm(nn.Module):
     """Mean and variance over the last axis in float32, scale and bias."""
     eps: float
@@ -339,6 +436,8 @@ class Attention(nn.Module):
                         name="q_norm")(q)
             k = RMSNorm(cfg.norm_eps, cfg.dtype, "head_dim",
                         name="k_norm")(k)
+        if cfg.key_mult != 1.0:
+            k = cfg.key_mult * k
         if cfg.attn_rope:
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
@@ -351,6 +450,8 @@ class Attention(nn.Module):
             return self._sparse(x, positions, cache, q, k, v, proj, dense)
         if self.kind == "blk":
             return self._block_sparse(x, cache, slots, q, k, v, proj, dense)
+        if self.kind == "hyb" and cache is not None:
+            return self._in_place(cache, slots, q, k, v, proj)
         if cache is None:
             out = attention_dispatch(q, k, v, causal=True,
                                      impl=cfg.attention_impl)
@@ -383,6 +484,31 @@ class Attention(nn.Module):
             out = _cached_attention(q, _cache_write(k_layer, k, idx),
                                     _cache_write(v_layer, v, idx), idx)
         return proj(out), (k, v)
+
+    def _in_place(self, cache, slots, q, k, v, proj):
+        """A "hyb" layer's heads against caches of thousands of positions:
+        a tile against ITS layer of the scratch, over the key blocks up to
+        its last position only, a running softmax (`_tile_attention`); a
+        decode row against the WHOLE pools and the layer's number, read
+        where they lie, its own key and value beside them
+        (`_row_attention`), and under no `cond` (as in `_block_sparse`)."""
+        (k_layer, v_layer), idx, layer = cache
+
+        def tile(q, k, v):
+            return _tile_attention(q, _cache_write(k_layer, k, idx),
+                                   _cache_write(v_layer, v, idx), idx)
+
+        if slots is not None:
+            (k_pool, v_pool), lens, _, layer = slots
+            (q, qr), (k, kr), (v, vr) = (
+                _split_rows(a, len(lens)) for a in (q, k, v))
+            out = _join_rows(tile(q, k, v), _row_attention(
+                qr, kr, vr, k_pool, v_pool, layer, lens))
+            return proj(out), ((k, v), (kr, vr))
+        if q.shape[1] > 1:
+            return proj(tile(q, k, v)), (k, v)
+        return proj(_row_attention(q, k, v, k_layer, v_layer, layer,
+                                   idx)), (k, v)
 
     def _sparse(self, x, positions, cache, q, k, v, proj, dense):
         """The model with an indexer (models/sparse_attention.py): the
@@ -539,6 +665,106 @@ class LightningAttention(nn.Module):
         return out_of(out), (new,)
 
 
+class Mamba2Mixer(nn.Module):
+    """The state-space branch of a "hyb" layer (models/ssm.py): the
+    in-projection's segments z, [x, B, C] and dt, each times its own
+    `ssm_mults`; a depthwise causal convolution with bias and SiLU over
+    [x, B, C]; dt = softplus(dt + dt_bias), A = -exp(A_log); the
+    recurrence; y * silu(z) under an RMSNorm a GROUP of heads; the output
+    projection. Its caches are two states with no position: "s"
+    [B, H, P, N] float32, and "c" [B, K - 1, channels] float32, the last
+    K - 1 real rows of the convolution's input. A call takes both in and
+    hands both back WHOLE. `real` [B, L] bool: the rows a request owns."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, cache=None, slots=None, real=None):
+        from ray_tpu.models import ssm
+        cfg = self.cfg
+        B, L, E = x.shape
+        H, P, N, G, K = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                         cfg.ssm_groups, cfg.ssm_conv)
+        inner, chans = H * P, H * P + 2 * G * N
+        mz, mx, mb, mc, mdt = cfg.ssm_mults
+        dense = lambda feats, name: nn.DenseGeneral(  # noqa: E731
+            feats, axis=-1, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name=name,
+            kernel_init=_p(nn.initializers.lecun_normal(), "embed", "mlp"))
+        vec = lambda name, init, n: self.param(  # noqa: E731
+            name, _p(init, None), (n,), cfg.param_dtype)
+        z = mz * dense(inner, "in_z")(x)
+        # one multiplier a channel of [x, B, C], in the activations' type
+        xbc = dense(chans, "in_xbc")(x) * jnp.asarray(
+            [mx] * inner + [mb] * (G * N) + [mc] * (G * N), cfg.dtype)
+        dt = jax.nn.softplus(
+            mdt * dense(H, "in_dt")(x).astype(jnp.float32)
+            + vec("dt_bias", nn.initializers.zeros, H).astype(jnp.float32))
+        conv_w = self.param("conv_w", _p(nn.initializers.lecun_normal(),
+                                         None, None), (K, chans),
+                            cfg.param_dtype)
+        conv_b = vec("conv_b", nn.initializers.zeros, chans)
+        A = -jnp.exp(vec("A_log", nn.initializers.zeros, H)
+                     .astype(jnp.float32))
+        D = vec("D", nn.initializers.ones, H)
+
+        def conv(xbc, tail, real):
+            y, tail = ssm.causal_conv(xbc, tail, conv_w, conv_b, real)
+            y = nn.silu(y).astype(cfg.dtype)
+            n = y.shape[:2]
+            return (y[..., :inner].reshape(n + (H, P)),
+                    y[..., inner:inner + G * N].reshape(n + (G, N)),
+                    y[..., inner + G * N:].reshape(n + (G, N)), tail)
+
+        def scan(xbc, dt, state, tail, real):
+            xs, bs, cs, tail = conv(xbc, tail, real)
+            y, state = ssm.ssd_scan(xs, dt, A, bs, cs, D, state, real)
+            return y, state, tail
+
+        def step(xbc, dt, state, tail, real):
+            xs, bs, cs, tail = conv(xbc, tail, real)
+            y, state = ssm.ssd_step(xs, dt, A, bs, cs, D, state,
+                                    None if real is None else real[:, 0])
+            return y, state, tail
+
+        if cache is None:
+            y, _, _ = scan(xbc, dt, jnp.zeros((B, H, P, N), jnp.float32),
+                           jnp.zeros((B, K - 1, chans), jnp.float32), real)
+            new = None
+        else:
+            (state, tail), _ = cache
+            if slots is not None:
+                (states, tails), lens, _ = slots
+                n = len(lens)
+                (xbc, xbc_r), (dt, dt_r) = (_split_rows(a, n)
+                                            for a in (xbc, dt))
+                tile_real, rows_real = (None, None) if real is None else (
+                    real[:, :L - n], real[0, L - n:, None])
+                y, state, tail = scan(xbc, dt, state, tail, tile_real)
+                # always computed (no `cond` on `on`, as in `_block_sparse`)
+                y_r, states, tails = step(xbc_r, dt_r, states, tails,
+                                          rows_real)
+                y = _join_rows(y, y_r)
+                new = ((state, tail), (states, tails))
+            else:
+                y, state, tail = (scan if L > 1 else step)(
+                    xbc, dt, state, tail, real)
+                new = (state, tail)
+        # gated, then normed a group of heads (norm_before_gate false)
+        y = y.reshape(B, L, inner).astype(jnp.float32) \
+            * nn.silu(z.astype(jnp.float32))
+        yg = y.reshape(B, L, G, inner // G)
+        yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True)
+                                + cfg.norm_eps)
+        scale = self.param("norm_scale", _p(nn.initializers.ones, None),
+                           (inner,), jnp.float32)
+        y = (yg.reshape(B, L, inner) * scale).astype(cfg.dtype)
+        out = nn.DenseGeneral(
+            E, axis=-1, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name="out",
+            kernel_init=_p(nn.initializers.lecun_normal(), "mlp", "embed"))(y)
+        return out if cache is None else (out, new)
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
 
@@ -551,8 +777,11 @@ class MLP(nn.Module):
             kernel_init=_p(nn.initializers.lecun_normal(), *axes))
         gate = dense(cfg.d_ff, ("embed", "mlp"), "gate")(x)
         up = dense(cfg.d_ff, ("embed", "mlp"), "up")(x)
-        y = nn.silu(gate) * up
-        return dense(cfg.d_model, ("mlp", "embed"), "down")(y)
+        m_gate, m_down = cfg.mlp_mults
+        if m_gate != 1.0:
+            gate = m_gate * gate
+        y = dense(cfg.d_model, ("mlp", "embed"), "down")(nn.silu(gate) * up)
+        return y if m_down == 1.0 else m_down * y
 
 
 class Block(nn.Module):
@@ -564,7 +793,9 @@ class Block(nn.Module):
     def __call__(self, x, positions, cache=None, real=None, slots=None):
         cfg = self.cfg
         normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x)
-        if self.kind == "lin":
+        if self.kind == "hyb":
+            att = self._hybrid(normed, positions, cache, slots, real)
+        elif self.kind == "lin":
             att = LightningAttention(cfg, name="attn")(
                 normed, positions, cache, slots, real)
         else:
@@ -591,6 +822,32 @@ class Block(nn.Module):
         if cache is not None:
             return h + y, aux, new_rows
         return h + y, aux
+
+    def _hybrid(self, normed, positions, cache, slots, real):
+        """A "hyb" layer's mixer: the attention heads and the state-space
+        mixer on the same normed input, each under its own scalings,
+        summed. Of the layer's four caches K and V are the heads', the
+        state and the convolution's tail the mixer's; the new rows come
+        back in the same order."""
+        cfg = self.cfg
+        scaled = lambda m: normed if m == 1.0 else m * normed  # noqa: E731
+        # (the heads take the layer's number behind the rest: their decode
+        # rows read K and V in the whole pools, `TransformerLM._decode`)
+        part = lambda c, a, b, n: c and (c[0][a:b], *c[1:n])   # noqa: E731
+        with jax.named_scope("hyb_attn"):
+            att = Attention(cfg, self.chunked, "hyb", name="attn")(
+                scaled(cfg.attn_in_mult), positions, part(cache, 0, 2, 3),
+                part(slots, 0, 2, 4))
+        with jax.named_scope("hyb_ssm"):
+            mix = Mamba2Mixer(cfg, name="ssm")(
+                scaled(cfg.ssm_in_mult), part(cache, 2, 4, 2),
+                part(slots, 2, 4, 3), real)
+        if cache is None:
+            return cfg.attn_out_mult * att + cfg.ssm_out_mult * mix
+        (att, kv), (mix, states) = att, mix
+        new = tuple(a + b for a, b in zip(kv, states)) if slots \
+            else kv + states
+        return cfg.attn_out_mult * att + cfg.ssm_out_mult * mix, new
 
 
 class ScanBlock(nn.Module):
@@ -659,28 +916,36 @@ def index_cache_shape(cfg: TransformerConfig, batch: int,
 # of a model's pools keeps. Two natures: a pool with a position axis is
 # written at a position, a call's new rows beside what it holds ("kp", the
 # pooled keys of a "blk" layer, one row every `blk_stride` positions);
-# None: the pool has no position (the state of a "lin" layer) and a call
-# replaces a row's entry whole
-CACHE_POS_AXIS = {"k": -3, "v": -3, "ki": -1, "kp": -3, "s": None}
+# None: the pool has no position (the state of a "lin" layer; the state
+# "s" and the convolution's tail "c" of a "hyb" layer) and a call replaces
+# a row's entry whole
+CACHE_POS_AXIS = {"k": -3, "v": -3, "ki": -1, "kp": -3, "s": None,
+                  "c": None}
 
 
 def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int):
     """{name: shape} of the pools a cache of this model carries: K, V
     and, where the model has an indexer, its keys. A model with layers of
-    several kinds: each kind's caches (KIND_CACHES) over THAT kind's
-    layers, K, V and the pooled keys of the "blk" layers and the states
-    of the "lin" layers."""
+    several kinds: each pool over the layers whose kind keeps it
+    (KIND_CACHES): K, V and the pooled keys of the "blk" layers, the
+    states of the "lin" layers; K, V, the state-space mixer's states
+    [n, rows, heads, d_head, d_state] and its convolution's tails
+    [n, rows, taps - 1, channels] of the "hyb" layers."""
     if cfg.mixer_kinds:
-        n_blk = cfg.mixer_kinds.count("blk")
-        n_lin = cfg.mixer_kinds.count("lin")
-        kv = (n_blk,) + kv_cache_shape(cfg, batch, max_len)[1:]
-        shapes = {"k": kv, "v": kv, "kp": (
-            n_blk, batch, max_len // cfg.blk_stride) + kv[3:]} \
-            if n_blk else {}
-        if n_lin:
-            shapes["s"] = (n_lin, batch, cfg.n_heads, cfg.head_dim,
-                           cfg.head_dim)
-        return shapes
+        kv = kv_cache_shape(cfg, batch, max_len)[1:]
+        hyb = "hyb" in cfg.mixer_kinds
+        entry = {"k": kv, "v": kv,
+                 "kp": (batch, max_len // cfg.blk_stride) + kv[2:],
+                 "s": (batch, cfg.ssm_heads, cfg.ssm_head_dim,
+                       cfg.ssm_state) if hyb
+                 else (batch, cfg.n_heads, cfg.head_dim, cfg.head_dim),
+                 "c": (batch, cfg.ssm_conv - 1, cfg.ssm_heads
+                       * cfg.ssm_head_dim + 2 * cfg.ssm_groups
+                       * cfg.ssm_state)}
+        layers = {n: sum(n in KIND_CACHES[k] for k in cfg.mixer_kinds)
+                  for n in CACHE_POS_AXIS if n in entry}
+        return {n: (count,) + entry[n] for n, count in layers.items()
+                if count}
     kv = kv_cache_shape(cfg, batch, max_len)
     shapes = {"k": kv, "v": kv}
     if cfg.index_heads:
@@ -705,8 +970,9 @@ def kv_cache_sharding(shape, mesh, rules=None, name: str = "k"):
     from ray_tpu.parallel.train_step import (_prune_indivisible,
                                              logical_pspec_to_mesh)
     spec = _prune_indivisible(logical_pspec_to_mesh(
-        P(None, "batch", None,
-          "kv_heads" if name not in ("ki", "s") else None, None),
+        P(*(None, "batch", None,
+            "kv_heads" if name not in ("ki", "s", "c") else None,
+            None)[:len(shape)]),
         rules or sharding_lib.DEFAULT_RULES), shape, mesh)
     # no trailing None: the spec a program hands a pool back with, so a
     # new pool and a donated one are one sharding to jit's cache (the
@@ -923,23 +1189,56 @@ class TransformerLM(nn.Module):
                 *((jnp.arange(cfg.n_layers),) if whole else ()))
         else:
             # layer i reads entry j of ITS kind's pools (every pool's,
-            # where the layers are of one kind) and hands its rows back
+            # where the layers are of one kind) and hands its rows back.
+            # A "hyb" layer's states are read out of the RUNNING pool and
+            # written back to it before the next layer: read from the
+            # pool as it came and written after the loop, every write but
+            # the first reads a pool another has already changed, and XLA
+            # copies the whole pool at both ends (0.4 GB each way a step
+            # at this size; read off the program compiled for a described
+            # v5e, PERF.md section 6). Its rows that no request owns leave
+            # their states as they were (models/ssm.py), so the slots'
+            # need no `cond`; a "lin" layer's do not, and keep it
             got = ({n: [] for n in names}, {n: [] for n in names})
+            running = [dict(zip(names, pools)),
+                       slots and dict(zip(names, slot_pools))]
             seen: dict = {}
             for i in range(cfg.n_layers):
                 kind = cfg.mixer_kinds[i] if cfg.mixer_kinds else None
                 of = KIND_CACHES.get(kind, names)
                 j = seen[kind] = seen.get(kind, -1) + 1
+                hyb = kind == "hyb"
+
+                def read(now, rows):
+                    # one row a slot of a "hyb" layer reads K and V in
+                    # the WHOLE pools, by the layer's number (a layer
+                    # sliced out to be attended is copied whole)
+                    return tuple(now[n] if hyb and rows and n in ("k", "v")
+                                 else now[n][j] for n in of)
                 x, _aux, new_rows = Block(
                     cfg, chunked_prefill, kind, name=f"layer_{i}")(
                     x, positions,
-                    (tuple(cache[n][j] for n in of), idx), real,
-                    slots and (tuple(slots[n][j] for n in of),
-                               *carry[-1]))
-                for into, new in zip(got, new_rows if slots
-                                     else (new_rows,)):
+                    (read(running[0], L == 1), idx) + (j,) * hyb, real,
+                    slots and (read(running[1], True), *carry[-1])
+                    + (j,) * hyb)
+                for into, now, new in zip(got, running, new_rows if slots
+                                          else (new_rows,)):
                     for n, r in zip(of, new):
-                        into[n].append(r)
+                        if not (hyb and CACHE_POS_AXIS[n] is None):
+                            into[n].append(r)
+                            continue
+                        # under the scope of the form that made it: XLA
+                        # fuses the recurrence's last product into this
+                        # write, and the trace files a fusion under its
+                        # root's scope (models/ssm.py names them)
+                        rows = now is running[1] or L == 1
+                        with jax.named_scope("hyb_ssm"), jax.named_scope(
+                                "ssm_conv" if n == "c" else
+                                "ssd_step" if rows else "ssd_scan"):
+                            now[n] = jax.lax.dynamic_update_index_in_dim(
+                                now[n], r.astype(now[n].dtype), j, 0)
+            pools = tuple(running[0][n] for n in names)
+            slot_pools = slots and tuple(running[1][n] for n in names)
             # a state is not stacked: its layers are written one by one
             rows = tuple(
                 tuple(jnp.stack(into[n]) if CACHE_POS_AXIS[n] is not None
@@ -970,7 +1269,7 @@ class TransformerLM(nn.Module):
             # 3.6 ms at 20 layers (28.17 against 24.58 ms, my chip runs,
             # PR 35)
             new_cache["slots"] = {
-                n: jax.lax.cond(
+                n: p if isinstance(r, tuple) and not r else jax.lax.cond(
                     slots["on"], functools.partial(write, n),
                     lambda pool, *_: pool, p, r, slots["idx"])
                 for n, p, r in zip(names, slot_pools, slot_rows)}

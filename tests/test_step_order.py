@@ -10,7 +10,8 @@ import pytest
 
 from tests.test_fused_step import model_of
 
-KINDS = ["dense", "all-experts", "indexer", "two-kinds"]
+KINDS = ["dense", "all-experts", "indexer", "two-kinds",
+         "side-by-side"]
 
 
 @functools.lru_cache(maxsize=None)
@@ -19,6 +20,10 @@ def _model(kind):
         from tests import test_hybrid_mixer_model as hybrid
         model = hybrid.build(hybrid.config())
         return model, hybrid.seeded(model)
+    if kind == "side-by-side":      # attention heads and a state-space mixer
+        from tests import test_falcon_h1_model as falcon
+        model = falcon.build(falcon.config())
+        return model, falcon.seeded(model)
     return model_of({"all-experts": "moe"}.get(kind, kind))
 
 
@@ -82,6 +87,7 @@ PINNED = {
     "all-experts": (9, [42, 7, 54, 16, 95, 7, 7], {'tiles': ([66, 40, 40, 40, 23, 23, 23, 23, 23, 23, 23, 23], 'length'), 'ends_beside_rows': ([92, 92, 92, 92, 92, 92], 'length'), 'span_one': ([105, 53, 34, 78, 100], 'length'), 'eos': ([40, 40, 40, 40, 40, 9], 'eos'), 'cancel': ([102, 76, 28], 'cancelled'), 'max_len': ([122, 110, 76, 76, 76, 76, 76, 76, 76, 76, 76, 76, 76, 76, 76, 76, 76, 76, 107, 107, 107, 107, 107, 112, 22], 'length'), 'prefix_hit': ([10, 38, 17, 66], 'length')}),
     "indexer": (60, [39, 0, 54, 18, 111, 7, 7], {'tiles': ([127, 69, 76, 114, 124, 23, 67, 114, 60, 124, 68, 75], 'length'), 'ends_beside_rows': ([45, 75, 80, 80, 28, 58], 'length'), 'span_one': ([25, 103, 103, 5, 103], 'length'), 'eos': ([80, 66, 60], 'eos'), 'cancel': ([119, 48, 96, 48, 119, 96], 'cancelled'), 'max_len': ([52, 124, 5, 4, 124, 8, 99, 60, 79, 93, 79, 60, 109, 109, 103, 105, 60, 60, 109, 29, 99, 91, 104, 105, 100], 'length'), 'prefix_hit': ([124, 81, 69, 89], 'length')}),
     "two-kinds": (94, [41, 11, 52, 18, 111, 7, 7], {'tiles': ([143, 41, 151, 88, 11, 14, 247, 64, 59, 232, 216, 93], 'length'), 'ends_beside_rows': ([57, 23, 10, 221, 254, 90], 'length'), 'span_one': ([105, 187, 42, 90, 87], 'length'), 'eos': ([47, 15, 94], 'eos'), 'cancel': ([112, 41, 2, 95], 'cancelled'), 'max_len': ([60, 99, 8, 185, 98, 216, 146, 147, 194, 73, 131, 85, 206, 71, 38, 201, 13, 64, 23, 10, 98, 64, 52, 30, 114], 'length'), 'prefix_hit': ([122, 65, 219, 16], 'length')}),
+    "side-by-side": (207, [41, 11, 52, 18, 111, 7, 7], {'tiles': ([184, 190, 65, 100, 153, 224, 18, 155, 72, 190, 237, 73], 'length'), 'ends_beside_rows': ([119, 72, 4, 162, 46, 67], 'length'), 'span_one': ([224, 191, 217, 9, 20], 'length'), 'eos': ([116, 236, 207], 'eos'), 'cancel': ([144, 69, 44, 70], 'cancelled'), 'max_len': ([219, 153, 5, 174, 199, 247, 21, 122, 73, 87, 225, 234, 32, 96, 122, 130, 135, 188, 134, 61, 93, 215, 15, 178, 59], 'length'), 'prefix_hit': ([115, 188, 145, 237], 'length')}),
 }
 
 
@@ -111,8 +117,10 @@ def _served(kind):
 @pytest.mark.parametrize("kind", KINDS)
 def test_greedy_tokens_are_the_parents(kind):
     """Pinned on the commit before the carry moved to the device (PR 42's
-    tree, by `python tests/test_step_order.py`): every program computes
-    what it computed, and the plans are the same plans."""
+    tree, by `python tests/test_step_order.py`; "side-by-side" on the tree
+    that brought its model, PR 44's, where every served token is also held
+    to the reference's argmax, tests/test_falcon_h1_model.py): every
+    program computes what it computed, and the plans are the same plans."""
     _, counted, want = PINNED[kind]
     got, stats, _ = _served(kind)
     assert got == want
