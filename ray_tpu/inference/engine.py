@@ -564,16 +564,20 @@ class InferenceEngine:
                 slots = dict(zip(names, pools), idx=lengths,
                              on=jnp.any(live))
                 key, sub = jax.random.split(key)
-            logits, new, pair = forward(params, tokens, scratch, pos0,
-                                        real=real, slots=slots,
-                                        chunked_prefill=True)
-            last = jax.lax.dynamic_index_in_dim(logits, n_real - 1,
-                                                axis=1, keepdims=False)
-            tok = sample_logits_dynamic(last, key, temp[None], top_k=top_k,
-                                        top_p=top_p)[0].astype(jnp.int32)
+            # the head runs over the rows sampled below and no other: the
+            # prompt's would-be next token and the slots' rows behind the
+            # tile, 1 + S of T + S
+            logits, new, pair = forward(
+                params, tokens, scratch, pos0, real=real, slots=slots,
+                chunked_prefill=True, logit_rows=jnp.concatenate(
+                    [(n_real - 1)[None],
+                     jnp.arange(tile, tokens.shape[1], dtype=jnp.int32)]))
+            tok = sample_logits_dynamic(
+                logits[:, 0], key, temp[None], top_k=top_k,
+                top_p=top_p)[0].astype(jnp.int32)
             if ride:
                 rows = sample_logits_dynamic(
-                    logits[0, tile:], sub, temps, top_k=top_k,
+                    logits[0, 1:], sub, temps, top_k=top_k,
                     top_p=top_p).astype(jnp.int32)
                 lengths, toks = lengths + live, jnp.where(live, rows, toks)
             ends = (jnp.arange(S) == slot) & (is_last != 0)

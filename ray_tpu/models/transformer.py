@@ -1001,7 +1001,7 @@ class TransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, positions=None, return_hidden=False,
-                 cache=None, chunked_prefill=False):
+                 cache=None, chunked_prefill=False, logit_rows=None):
         """return_hidden=True skips the unembed projection and returns the
         final-norm hidden states [B,L,d] — callers (train_step's chunked
         cross-entropy) then compute logits a block at a time so the
@@ -1016,9 +1016,16 @@ class TransformerLM(nn.Module):
         chunked_prefill, B = 1): tokens [1, T + S] is a tile of T rows
         followed by one decode row for each of the S slots of a second
         cache, {"k", "v": its pools, "idx": [S] the slots' lengths,
-        "on": whether any slot is live}; see `_decode`."""
+        "on": whether any slot is live}; see `_decode`.
+
+        logit_rows (needs cache): int32 [R], the positions of the sequence
+        whose logits (or hidden states) the caller will read; the final
+        norm and the head then run over those R rows alone and the result
+        is [B, R, ..]. Absent: every row."""
         cfg = self.cfg
         B, L = tokens.shape
+        if logit_rows is not None and cache is None:
+            raise ValueError("logit_rows: only the cached forward takes it")
         if positions is None:
             if cache is not None:
                 # decode: tokens continue at the cache's write position
@@ -1050,7 +1057,7 @@ class TransformerLM(nn.Module):
         x = constrain(x, ("batch", "seq", None))
         if cache is not None:
             return self._decode(x, positions, cache, embed, return_hidden,
-                                chunked_prefill)
+                                chunked_prefill, logit_rows)
 
         # (training/prefill path continues below)
 
@@ -1125,7 +1132,7 @@ class TransformerLM(nn.Module):
         return logits.astype(jnp.float32) if cfg.logits_fp32 else logits
 
     def _decode(self, x, positions, cache, embed, return_hidden,
-                chunked_prefill=False):
+                chunked_prefill=False, logit_rows=None):
         """Serving decode forward: applies every layer against the KV
         cache and returns (logits|hidden, new_cache). The pools
         cache["k"], cache["v"] [n_layers,B,M,Hkv,D] (and cache["ki"], the
@@ -1142,8 +1149,9 @@ class TransformerLM(nn.Module):
 
         With cache["slots"] (a model without an indexer) ONE pass serves
         a prefill tile and the slots' decode rows behind it: norms,
-        projections, MLP or experts and the unembedding run over all
-        T + S rows, so every weight is read once; each layer's attention
+        projections and MLP or experts run over all T + S rows and the
+        unembedding over those of them that `logit_rows` names, so every
+        weight is read once; each layer's attention
         takes the tile against `cache` and the rows against the slots'
         pools, and after the loop the tile's new rows are written to
         `cache` and the slots' to theirs (new_cache["slots"]). With "on"
@@ -1273,11 +1281,17 @@ class TransformerLM(nn.Module):
                     slots["on"], functools.partial(write, n),
                     lambda pool, *_: pool, p, r, slots["idx"])
                 for n, p, r in zip(names, slot_pools, slot_rows)}
-        x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
-        if return_hidden:
-            return x, new_cache
-        unembed = None if cfg.tie_embeddings else self._unembed_param()
-        return self._logits(x, embed, unembed), new_cache
+        if logit_rows is not None:
+            # before the norm and the head: a tile's caller samples 1 + S
+            # of its T + S rows, and the head over all of them was 14.6 of
+            # a 51.6 ms tile at a 261k vocabulary (PERF.md section 6, PR 45)
+            x = jnp.take(x, logit_rows, axis=1, mode="clip")
+        with jax.named_scope("lm_head"):
+            x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
+            if return_hidden:
+                return x, new_cache
+            unembed = None if cfg.tie_embeddings else self._unembed_param()
+            return self._logits(x, embed, unembed), new_cache
 
 
 def count_params(params) -> int:

@@ -20,6 +20,7 @@ from ray_tpu.inference.engine import PHASES, EngineConfig, InferenceEngine
 from ray_tpu.models import TransformerLM
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.util import metrics as metrics_mod
+from tests.test_fused_step import tile_text as _tile_text
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -31,16 +32,20 @@ def _clean_recorder():
     events.drain()
 
 
-def _engine(n_slots=2, max_len=64, d_model=32, n_layers=2, **model):
+def _engine(n_slots=2, max_len=64, d_model=32, n_layers=2, draft_k=0,
+            **model):
     cfg = TransformerConfig(vocab_size=64, d_model=d_model,
                             n_layers=n_layers, n_heads=2, n_kv_heads=2,
-                            d_ff=2 * d_model, max_seq_len=max_len, **model)
+                            d_ff=2 * d_model, max_seq_len=max_len + draft_k,
+                            **model)
     lm = TransformerLM(cfg)
     params = lm.init(jax.random.PRNGKey(0),
                      np.zeros((1, 8), np.int32))["params"]
     return InferenceEngine(lm, params, EngineConfig(
         n_slots=n_slots, max_len=max_len, prefill_chunk=8,
-        prefill_budget=16))
+        prefill_budget=16), spec={
+            "draft_model": lm, "draft_params_fn": lambda: params,
+            "k": draft_k} if draft_k else None)
 
 
 # ------------------------------------------------ the profiler's own trace
@@ -215,13 +220,11 @@ def _decode_text(eng):
         np.zeros((eng.config.n_slots,), np.int32)).as_text()
 
 
-def _tile_text(eng):
-    tile = eng._prefill_tiles[-1]
-    pools = eng._slots.pools() if eng._ride else ()
-    return eng._prefill_fn.lower(
-        eng.params, *eng._slots.new_scratch(), *pools, eng._carry,
-        eng._tile_args(tile, np.zeros((tile,), np.int32), 0, 0, False, 0.0,
-                       [])).as_text()
+def _verify_text(eng):
+    pool, dpool = eng._slots, eng._draft_slots
+    return eng._spec_step_fn.lower(
+        eng.params, eng._draft_params, pool.k, pool.v, dpool.k, dpool.v,
+        eng._carry, np.zeros((eng.config.n_slots,), np.int32)).as_text()
 
 
 def _train_text(_):
@@ -246,26 +249,36 @@ INDEXER = dict(index_heads=2, index_head_dim=16, index_topk=16)
 
 @pytest.mark.parametrize("model,text,digest", [
     ({}, _decode_text, "13226c2622d8400b"),
-    ({}, _tile_text, "adb06cf3c66ec937"),
+    ({}, _tile_text, "2bbc047cde5e404f"),
     (INDEXER, _decode_text, "8702c029e6542675"),
-    (INDEXER, _tile_text, "bcd13d65728df53a"),
+    (INDEXER, _tile_text, "2d7af74232ada8e8"),
+    (dict(draft_k=2), _verify_text, "0c4d3e949e620057"),
     (None, _train_text, "8aecbfdae32759c2")],
     ids=["dense_decode", "dense_tile_with_rows", "indexer_decode",
-         "indexer_tile", "train_step"])
+         "indexer_tile", "draft_verify", "train_step"])
 def test_the_step_programs_are_the_parents(model, text, digest):
     """The lowered text of the decode program, of the tile program (with
-    the riding rows, and an indexer model's without) and of a training
-    step. The training step's digest is PR 41's tree's (read there by this
-    same function, before the step was told by phase): the marks are the
-    host's, no program recompiles for them and set-up has no reason to
-    move. The four engine programs' were re-pinned once, on PR 43's final
-    tree: that PR moved the slots' carry (lengths, last tokens,
-    temperatures, the key) onto the device as one array the programs take
-    and hand back advanced, packed the host's arguments into one array and
-    made the tile's key of the carry's and a count, so every engine
-    program's signature and last few ops changed; what they compute of the
-    model did not (tests/test_step_order.py holds their greedy tokens to
-    the parent's)."""
+    the riding rows, and an indexer model's without), of a speculative
+    draft's verify step and of a training step. The training step's digest
+    is PR 41's tree's (read there by this same function, before the step
+    was told by phase): the marks are the host's, no program recompiles
+    for them and set-up has no reason to move. The four engine programs'
+    were re-pinned once, on PR 43's final tree: that PR moved the slots'
+    carry (lengths, last tokens, temperatures, the key) onto the device as
+    one array the programs take and hand back advanced, packed the host's
+    arguments into one array and made the tile's key of the carry's and a
+    count, so every engine program's signature and last few ops changed;
+    what they compute of the model did not (tests/test_step_order.py holds
+    their greedy tokens to the parent's). The two TILE programs' were
+    re-pinned on PR 45's final tree: the tile names the rows it samples
+    (the prompt's would-be next token and the riding rows) and the cached
+    forward gathers them before the final norm and the head, so the head's
+    dot has 1 + S rows (1 for the indexer's model) where it had T + S.
+    Both decode programs and the training step pass with the digests they
+    had, and the verify step with PR 44's tree's (read there by
+    `_verify_text`): a cached call that names no rows is the program it
+    was, and the `lm_head` scope is metadata the lowered text does not
+    print."""
     eng = None if model is None else _engine(**model)
     got = hashlib.sha256(text(eng).encode()).hexdigest()[:16]
     assert got == digest

@@ -257,6 +257,16 @@ def test_a_slot_reused_holds_its_requests_state_and_tail(small):
                                rtol=1e-4, atol=1e-5)
 
 
+def test_the_tile_program_unembeds_the_rows_it_samples(small):
+    """Every block's two branches, like every other model's tile
+    (tests/test_fused_step.py): the head runs over the prompt's row and
+    the two riding rows, never over the tile."""
+    from tests.test_fused_step import tile_head_rows
+    _, model, params, _ = small
+    eng = InferenceEngine(model, params, EngineConfig(**ENGINE))
+    assert tile_head_rows(eng) == ([1 + ENGINE["n_slots"]], 16)
+
+
 def test_engine_refuses_what_does_not_carry_the_caches(small):
     from ray_tpu.models.transformer import TransformerConfig
     m, model, params, _ = small

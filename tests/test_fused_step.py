@@ -14,9 +14,14 @@ programs a step: its decode row is not bound by the weights' stream).
 - the tile programs and the decode program compile when the engine is
   built / once, and never again; ``fused_steps`` and the prefill span's
   ``decode_rows`` count what they say; a prompt that ends in a step emits
-  its second token in the next.
+  its second token in the next;
+- the tile program's head runs over the rows it samples (the prompt's
+  would-be next token and the riding rows, 1 + S of T + S) and no dot of
+  it has a tile of vocabulary rows for a result; a cached call that names
+  no rows gets every row's logits, as the speculative verify step needs.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -194,6 +199,64 @@ def test_a_prompts_kv_are_the_same_bits_whatever_rides_behind(kind):
     # carries none even then
     assert seen == ({(0, 0, 0)} if kind == "indexer" else
                     {(2, 2, 2), (0, 0, 0), (0, 2, 2)})
+
+
+def tile_text(eng):
+    """The lowered text of the program of `eng`'s largest tile."""
+    tile = eng._prefill_tiles[-1]
+    pools = eng._slots.pools() if eng._ride else ()
+    return eng._prefill_fn.lower(
+        eng.params, *eng._slots.new_scratch(), *pools, eng._carry,
+        eng._tile_args(tile, np.zeros((tile,), np.int32), 0, 0, False, 0.0,
+                       [])).as_text()
+
+
+def tile_head_rows(eng):
+    """The rows ([.., rows, vocab] -> rows) of every dot in the lowered
+    program of `eng`'s largest tile whose result ends in the vocabulary,
+    and that tile's length."""
+    dots = re.findall(r"dot_general.*-> tensor<([\dx]+)x\w+>",
+                      tile_text(eng))
+    assert dots
+    shapes = [tuple(map(int, d.split("x"))) for d in dots]
+    vocab = eng.model.cfg.vocab_size
+    return ([s[-2] for s in shapes if s[-1] == vocab],
+            eng._prefill_tiles[-1])
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_tile_program_unembeds_the_rows_it_samples(kind):
+    eng = engine_of(kind)
+    S = eng.config.n_slots
+    rows, tile = tile_head_rows(eng)
+    # one head, over the prompt's row and the riding rows (an indexer's
+    # model has none behind its tile): never the tile's T (+ S)
+    assert tile == 8 and rows == [1 if kind == "indexer" else 1 + S]
+
+
+def test_a_cached_call_that_names_no_rows_gets_every_rows_logits():
+    """The speculative verify step scores all its k + 1 rows
+    (inference/spec_decode.py) and asks for none by name: the cached
+    forward then returns every row's logits, and the rows a caller does
+    name are those rows of it."""
+    from ray_tpu.models.transformer import init_cache
+    model, params = model_of("dense")
+    toks = jnp.asarray(np.random.RandomState(3).randint(1, 128, (2, 9)))
+    cache = init_cache(model.cfg, 2, 32, jnp.float32)
+    whole, new = model.apply({"params": params}, toks, cache=cache)
+    assert whole.shape == (2, 9, 128) and int(new["idx"]) == 9
+    # the one-shot forward (no cache) is the same model over the same rows
+    np.testing.assert_allclose(
+        whole, model.apply({"params": params}, toks), atol=2e-5)
+    rows = jnp.asarray([8, 0, 3], jnp.int32)
+    named, again = model.apply({"params": params}, toks, cache=cache,
+                               logit_rows=rows)
+    assert named.shape == (2, 3, 128)
+    np.testing.assert_allclose(named, whole[:, rows], atol=1e-6)
+    for n in ("k", "v"):
+        np.testing.assert_array_equal(again[n], new[n])
+    with pytest.raises(ValueError, match="cached forward"):
+        model.apply({"params": params}, toks, logit_rows=rows)
 
 
 def test_counters_and_spans_count_what_they_say():
