@@ -281,7 +281,7 @@ class InferenceEngine:
             raise ValueError(
                 f"the model keeps caches beyond K and V ({', '.join(beyond)}"
                 f": an indexer's keys, pooled keys, a recurrent state, a "
-                f"convolution's tail), "
+                f"convolution's tail, a sliding window's ring), "
                 f"which prefix blocks and a speculative draft's verify step "
                 f"do not carry: run it with prefix_cache_slots=0 and no "
                 f"spec")
@@ -387,6 +387,27 @@ class InferenceEngine:
             if "blk" in (mcfg.mixer_kinds or ()) else None
         self.blk_rows_read = 0
         self.blk_rows_live = 0
+        # a sliding window (a model with "win" layers), summed over the
+        # decode rows of every step, host arithmetic on the lengths too:
+        # the window's positions a row attends, min(length, window), its
+        # own among them; and the places of the ring its attention passes
+        # over (`decode_positions_read` of what the ring holds of the slot:
+        # all of it once the slot has filled it). A tile longer than the
+        # ring's slack would overwrite keys its own first rows attend
+        self._win = None
+        if "win" in (mcfg.mixer_kinds or ()):
+            from ray_tpu.models import sparse_attention
+            ring = mcfg.win_ring
+            if self._prefill_tiles[-1] + mcfg.window - 1 > ring:
+                raise ValueError(
+                    f"win_ring={ring} holds no tile of "
+                    f"{self._prefill_tiles[-1]} rows beside a window of "
+                    f"{mcfg.window}: it takes window + prefill budget")
+            self._win = (mcfg.window, ring, functools.partial(
+                sparse_attention.decode_positions_read,
+                M=ring, Hkv=mcfg.n_kv_heads, D=mcfg.head_dim))
+        self.win_rows_streamed = 0
+        self.win_rows_live = 0
         # an expert layer that holds a share of its experts: [rows the
         # expert matmuls computed, picks of real rows that landed on a held
         # expert], summed over layers and calls (models/moe.py sows them).
@@ -1016,12 +1037,19 @@ class InferenceEngine:
         if self._topk:      # before the rows' own
             self.dsa_rows_streamed += self._dsa_streamed(
                 [int(self._lengths[slot]) for slot in slots])
+        if self._win:       # (less each row's own, which no ring holds)
+            _, ring, streamed = self._win
+            self.win_rows_streamed += streamed(
+                [min(int(self._lengths[slot]), ring) for slot in slots]
+            ) - len(slots)
         self._lengths[slots] += 1
         for slot in slots:
             live = int(self._lengths[slot])
             if self._topk:      # the row attended itself too
                 self.dsa_rows_live += live
                 self.dsa_rows_read += min(live, self._topk)
+            if self._win:
+                self.win_rows_live += min(live, self._win[0])
             if self._blk:
                 # the row's own block is among the selected
                 # and holds the positions up to the row's only
@@ -1163,6 +1191,10 @@ class InferenceEngine:
             rid=st.rid, slot=st.slot, offset=ch.start, length=ch.length,
             tile=tile, is_last=ch.is_last,
             live=ch.start + ch.length,    # positions the tile attended
+            # of them, those its rows attend in a "win" layer
+            **({"win_live": min(ch.start + ch.length,
+                                ch.length + self._win[0] - 1)}
+               if self._win else {}),
             decode_rows=len(active),      # slots advanced in its program
             slots_occupied=self.sched.occupancy())
         compiles0 = self.prefill_compile_count
@@ -1362,6 +1394,10 @@ class InferenceEngine:
             out["state_pool_bytes"] = self._slots.nbytes(("s",))
         if "c" in self._slots.shapes:
             out["conv_pool_bytes"] = self._slots.nbytes(("c",))
+        if self._win:
+            out["win_pool_bytes"] = self._slots.nbytes(("wk", "wv"))
+            out["win_rows_streamed"] = self.win_rows_streamed
+            out["win_rows_live"] = self.win_rows_live
         if self._topk:
             out["dsa_rows_read"] = self.dsa_rows_read
             out["dsa_rows_live"] = self.dsa_rows_live

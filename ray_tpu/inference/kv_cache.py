@@ -13,14 +13,20 @@ what the engine keeps in that layout and nothing else knows how:
   keys of its block-sparse layers and the float32 states of its linear
   layers, each over its own kind's layers; for layers that run attention
   heads and a state-space mixer side by side K and V AND two float32
-  states, the mixer's and its convolution's tail), the scratch a prompt
-  prefills
-  into, and the program that makes a finished scratch a slot. Pools are
-  of two natures (``CACHE_POS_AXIS``): with a position axis, of which a
+  states, the mixer's and its convolution's tail; for sliding-window
+  layers beside full-attention ones K and V in RINGS beside K and V by
+  position), the scratch a prompt prefills into, and the program that
+  makes a finished scratch a slot. Pools are of three natures
+  (``CACHE_POS_AXIS``, ``CACHE_RINGS``): with a position axis, of which a
   slot takes the scratch's first ``slot_len`` positions; without (a
   state), of which it takes the scratch's whole entry, so a slot never
-  inherits its last owner's. Built twice: for the target and for a
-  speculative draft.
+  inherits its last owner's; and a ring (``wk``, ``wv``): a position
+  axis of ``win_ring`` places whatever ``length`` is, position p at
+  p mod ``win_ring``, in a slot and in a scratch alike, so a slot takes
+  the scratch's ring whole too (the places a short prompt never wrote
+  hold the fresh scratch's zeros, and the attention masks every place
+  whose position is not in the row's window). Built twice: for the
+  target and for a speculative draft.
 - ``BlockStore``: the prefix cache's blocks. A block's FORMAT is the
   tuple of arrays that hold it, which is also its wire form between
   replicas: ``"none"`` = (k, v) in the cache dtype; ``"int8"`` =
@@ -114,7 +120,7 @@ class SlotPool:
         self.scratch: Dict[int, Tuple[Any, ...]] = {}
         # what a slot takes of a scratch: its first slot_len positions
         # (the scratch carries the largest tile of padding tail), or, of
-        # a pool that has no position, its whole entry
+        # a pool that has no position or is a ring, its whole entry
         taken = cache_shapes(mcfg, 1, slot_len)
 
         def insert(pools, scratch, slot):
@@ -127,8 +133,8 @@ class SlotPool:
         self._insert_fn = jax.jit(insert, donate_argnums=(0,))
 
     def pools(self) -> Tuple[Any, ...]:
-        """(k, v), (k, v, ki), (k, v, kp, s) or (k, v, s, c): the order
-        of ``cache_shapes``."""
+        """(k, v), (k, v, ki), (k, v, kp, s), (k, v, s, c) or
+        (k, v, wk, wv): the order of ``cache_shapes``."""
         return tuple(getattr(self, n) for n in self.shapes)
 
     def rebind(self, pools) -> None:

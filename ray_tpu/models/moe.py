@@ -11,14 +11,24 @@ as "absent — must be built natively").
 
 Routing (a batch row is the dispatch group: a sequence in training, a
 prefill tile, one decode row, or in the engine's step a tile with the
-slots' decode rows behind it):
-- softmax router in fp32 over ALL `n_experts`, top-k experts per token,
-  gates renormalized;
+slots' decode rows behind it), by one of two routers (`cfg.router`):
+- "softmax": a softmax in fp32 over ALL `n_experts`, top-k experts per
+  token, gates renormalized;
+- "sigmoid" (`sigmoid_route`): a sigmoid score an expert in fp32; the
+  top-k of score + `router_bias` (a learned bias an expert that CHOOSES
+  and does not weigh) are taken and weighed by their scores, renormalized
+  where `route_norm`, times `route_scale`;
 - per-expert capacity C = ceil(capacity_factor * L * k / E). Training
   drops the tokens over capacity (standard Switch behavior, keeps shapes
   static); serving (`exact`: the cached forward) drops nothing: a pick
   past its expert's capacity is computed by the overflow route below;
 - aux load-balancing loss (Switch eq. 4): E * Σ_e frac_tokens_e · mean_prob_e.
+
+The experts are SwiGLUs of `expert_d_ff` (a width of their own beside the
+dense MLP's `d_ff`, which a model's leading `n_dense_layers` keep:
+`transformer.Block`). With `n_shared_experts` a further SwiGLU of that
+many experts' width takes every row whatever it picked, added to the
+routed experts' result; it is whole on every rank.
 
 What the layer holds: all `n_experts`, or the contiguous range
 `experts_held` = (first, count) of them — one rank's share of a layer that
@@ -70,9 +80,20 @@ from ray_tpu.models.transformer import _p
 from ray_tpu.parallel.sharding import constrain
 
 
+def sigmoid_route(x, router, bias, k: int):
+    """The sigmoid router: a score an expert in float32, the k experts of
+    largest score + bias, weighed by their SCORES (the bias chooses and
+    does not weigh). -> (scores [B, L, E], the taken experts' scores and
+    numbers [B, L, k])."""
+    scores = jax.nn.sigmoid(x.astype(jnp.float32) @ router)
+    _, taken = jax.lax.top_k(scores + bias, k)
+    return scores, jnp.take_along_axis(scores, taken, axis=-1), taken
+
+
 class MoEMLP(nn.Module):
     """Drop-in replacement for the dense MLP block (gate/up/down SwiGLU),
-    with `cfg.n_experts` experts and top-`cfg.expert_top_k` routing."""
+    with `cfg.n_experts` experts, top-`cfg.expert_top_k` routing by
+    `cfg.router`, and `cfg.n_shared_experts` shared ones."""
 
     cfg: Any
 
@@ -88,15 +109,26 @@ class MoEMLP(nn.Module):
         first, held = cfg.experts_held or (0, E)
         C = min(L, max(1, math.ceil(cfg.capacity_factor * L * K / E)))
 
+        F = cfg.expert_d_ff or cfg.d_ff
         router = self.param(
             "router", _p(nn.initializers.lecun_normal(), "embed", "experts"),
             (D, E), jnp.float32)
-        probs = jax.nn.softmax(
-            x.astype(jnp.float32) @ router, axis=-1)           # [B,L,E]
-
-        gate_vals, gate_idx = jax.lax.top_k(probs, K)          # [B,L,K]
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(-1, keepdims=True), 1e-9)
+        with jax.named_scope("moe_router"):
+            if cfg.router == "sigmoid":
+                bias = self.param("router_bias", _p(nn.initializers.zeros,
+                                                    "experts"), (E,),
+                                  jnp.float32)
+                probs, gate_vals, gate_idx = sigmoid_route(x, router, bias,
+                                                           K)
+            else:
+                probs = jax.nn.softmax(
+                    x.astype(jnp.float32) @ router, axis=-1)   # [B,L,E]
+                gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [B,L,K]
+            if cfg.route_norm:
+                gate_vals = gate_vals / jnp.maximum(
+                    gate_vals.sum(-1, keepdims=True), 1e-9)
+            if cfg.route_scale != 1.0:
+                gate_vals = cfg.route_scale * gate_vals
 
         # expert-choice position: for the j-th routing slot, a token's slot
         # in expert e's buffer is the number of earlier (token, slot) picks
@@ -145,15 +177,15 @@ class MoEMLP(nn.Module):
         w_gate = self.param(
             "gate", _p(nn.initializers.lecun_normal(),
                        "experts", "embed", "mlp"),
-            (E, D, cfg.d_ff), cfg.param_dtype)
+            (E, D, F), cfg.param_dtype)
         w_up = self.param(
             "up", _p(nn.initializers.lecun_normal(),
                      "experts", "embed", "mlp"),
-            (E, D, cfg.d_ff), cfg.param_dtype)
+            (E, D, F), cfg.param_dtype)
         w_down = self.param(
             "down", _p(nn.initializers.lecun_normal(),
                        "experts", "mlp", "embed"),
-            (E, cfg.d_ff, D), cfg.param_dtype)
+            (E, F, D), cfg.param_dtype)
         def experts(rows):                       # [E, .., D] -> [E, .., D]
             h = jnp.einsum("ebcd,edf->ebcf", rows,
                            w_gate.astype(cfg.dtype))
@@ -185,6 +217,14 @@ class MoEMLP(nn.Module):
                     experts(jnp.broadcast_to(x, (E,) + x.shape))),
                 lambda: jnp.zeros_like(out))
             rows = rows + spilled.astype(jnp.int32) * (E * B * L)
+        if cfg.n_shared_experts:
+            # the shared expert: every row passes it, whatever it picked
+            # (and whatever this rank holds: it is whole on each)
+            with jax.named_scope("moe_shared"):
+                wide = cfg.n_shared_experts * F
+                y = nn.silu(dense(wide, ("embed", "mlp"), "shared_gate")(x)) \
+                    * dense(wide, ("embed", "mlp"), "shared_up")(x)
+                out = out + dense(D, ("mlp", "embed"), "shared_down")(y)
         if counting:
             # what `moe_rows_per_pick` divides
             self.sow("counters", "rows_and_picks", jnp.stack(

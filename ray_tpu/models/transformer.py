@@ -17,6 +17,7 @@ workload and benchmark subject.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Optional, Tuple
@@ -67,6 +68,24 @@ class TransformerConfig:
     # computes the held experts' part of the result (models/moe.py). None:
     # every expert
     experts_held: Optional[Tuple[int, int]] = None
+    # the expert layer's further forms (models/moe.py). `router`: "softmax"
+    # over the experts, or "sigmoid": a score an expert, chosen by score +
+    # a learned bias that chooses and does not weigh. `route_norm`: the
+    # chosen gates divided by their sum; all gates times `route_scale`.
+    # `expert_d_ff`: an expert's width where it is not the dense MLP's
+    # (0: d_ff). `n_shared_experts`: a SwiGLU of that many experts' width
+    # that every token passes beside its routed ones. `n_dense_layers`: the
+    # leading layers that keep the dense MLP of d_ff (the layers are then
+    # not scanned)
+    router: str = "softmax"
+    route_norm: bool = True
+    route_scale: float = 1.0
+    expert_d_ff: int = 0
+    n_shared_experts: int = 0
+    n_dense_layers: int = 0
+    # a block with four norms: each branch normed at its output too,
+    # before it joins the residual
+    sandwich_norm: bool = False
     # the size of an attention head; 0 (unstated) = d_model // n_heads
     head_dim: int = 0
     # RMSNorm on each head of q and k, before the rotary
@@ -91,14 +110,24 @@ class TransformerConfig:
     # Each kind's layers keep caches of their own (`cache_shapes`), stacked
     # over that kind's layers, and the layers are not scanned. None: every
     # layer is the attention above
+    # "win": the attention above over the `window` NEWEST positions up to
+    # a row's own (its own among them), always rotary, K and V kept in a
+    # RING of `win_ring` positions (position p lies at p mod win_ring,
+    # whatever the cache's length; win_ring >= window + the longest tile a
+    # call takes, in whole key blocks); "att": the attention above over
+    # every earlier position as a kind among others (K and V by position,
+    # rotary where `attn_rope`). Both take `qk_norm` and `out_gate`.
     mixer_kinds: Optional[Tuple[str, ...]] = None
+    window: int = 0
+    win_ring: int = 0
     blk_size: int = 64          # positions a selectable block holds
     blk_kernel: int = 32        # keys a pooled key is the mean of
     blk_stride: int = 16        # positions from one kernel to the next
     blk_init: int = 1           # leading blocks always attended
     blk_window: int = 2048      # trailing positions always attended
     blk_topk: int = 64          # blocks attended in all
-    # rotary on the attention's q and k ("lin" layers always have it)
+    # rotary on the attention's q and k ("lin" and "win" layers always
+    # have it)
     attn_rope: bool = True
     # a sigmoid gate on the mixer's output, from the mixer's input, before
     # the output projection
@@ -136,6 +165,11 @@ class TransformerConfig:
         if not self.head_dim:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.n_heads)
+        if self.router not in ("softmax", "sigmoid") or (
+                self.n_dense_layers and self.scan_layers):
+            raise ValueError(
+                f"router {self.router!r}: \"softmax\" or \"sigmoid\"; "
+                f"n_dense_layers needs scan_layers=False")
         object.__setattr__(self, "ssm_mults", tuple(self.ssm_mults))
         object.__setattr__(self, "mlp_mults", tuple(self.mlp_mults))
         kinds = self.mixer_kinds
@@ -154,6 +188,14 @@ class TransformerConfig:
                     "\"hyb\" layers: ssm_heads in whole groups, and no "
                     "\"lin\" layer beside them (both keep the pool \"s\", "
                     "each in a shape of its own)")
+            if "att" in kinds and {"blk", "hyb"} & set(kinds):
+                raise ValueError(
+                    "\"att\" layers beside \"blk\" or \"hyb\": each "
+                    "counts its own layers of the pools \"k\" and \"v\"")
+            if "win" in kinds and not 0 < self.window <= self.win_ring:
+                raise ValueError(
+                    f"\"win\" layers: window {self.window} > 0 and "
+                    f"win_ring {self.win_ring} >= it")
 
             if (self.blk_kernel % self.blk_stride
                     or self.blk_size % self.blk_stride
@@ -166,7 +208,11 @@ class TransformerConfig:
 # the caches a layer of each kind keeps, in the order every tuple of them
 # keeps (`cache_shapes` says their shapes, CACHE_POS_AXIS their nature)
 KIND_CACHES = {"blk": ("k", "v", "kp"), "lin": ("s",),
-               "hyb": ("k", "v", "s", "c")}
+               "hyb": ("k", "v", "s", "c"), "win": ("wk", "wv"),
+               "att": ("k", "v")}
+# the kinds whose decode rows read K and V in the WHOLE pools, by the
+# layer's number (`Attention._in_place`)
+_IN_PLACE = ("hyb", "win", "att")
 
 _PARTITION_OFF = __import__("threading").local()
 
@@ -296,6 +342,31 @@ def _cache_write(cache, new, idx, pos_axis: int = -3):
         mode=jax.lax.GatherScatterMode.CLIP)
 
 
+def _ring_write(ring, new, pos0, pos_axis: int = -3):
+    """Write a tile `new` [..., B, T, Hkv, D] at positions pos0 + 0..T-1
+    (pos0 a scalar: every row's) into a RING [..., B, R, Hkv, D], position
+    p at p mod R. The tile may pass the ring's end: it is turned by the
+    rows that wrap, and two windows of T rows take it, the one that ends
+    where the tile or the ring does and the ring's first T rows, each
+    keeping what it held where the tile has no row for it. One decode row
+    a slot never wraps and goes through `_cache_write` at its p mod R."""
+    new = new.astype(ring.dtype)
+    axis = ring.ndim + pos_axis
+    R, T = ring.shape[axis], new.shape[axis]
+    if jnp.ndim(pos0) or T > R:
+        raise ValueError("a tile into a ring: one start for every row, "
+                         "and no longer than the ring")
+    s = pos0 % R
+    w = jnp.maximum(s + T - R, 0)            # rows that wrap
+    turned = jnp.roll(new, w, axis)          # the wrapped rows first
+    at = jnp.arange(T).reshape((T,) + (1,) * (ring.ndim - 1 - axis))
+    for start, mine in ((s - w, at >= w), (0, at < w)):
+        held = jax.lax.dynamic_slice_in_dim(ring, start, T, axis)
+        ring = jax.lax.dynamic_update_slice_in_dim(
+            ring, jnp.where(mine, turned, held), start, axis)
+    return ring
+
+
 def _split_rows(a, n: int):
     """A sequence [1, T + n, ..] that ends in one decode row for each of
     n slots -> (the tile [1, T, ..], the slots' rows [n, 1, ..])."""
@@ -308,20 +379,46 @@ def _join_rows(tile, rows):
     return jnp.concatenate([tile, jnp.swapaxes(rows, 0, 1)], axis=1)
 
 
-def _tile_attention(q, k_cache, v_cache, pos0):
+def _tile_attention(q, k_cache, v_cache, pos0, window: int = 0):
     """A tile q [B, S, H, D] at absolute positions pos0 + 0..S-1 (pos0 a
     scalar or [B]) against caches [B, M, Hkv, D] that already hold the
     tile's own rows: causal over absolute positions, blocked over the keys
     with a running softmax, over the blocks up to the tile's last position
     only (`_cached_attention` scores every position of the cache at once:
     [H, S, M] in float32, 0.75 GB a layer for a 1024-row tile against
-    9216 positions)."""
+    9216 positions). `window`: the caches are RINGS of M positions
+    (position p at p mod M) and a row attends the `window` newest
+    positions up to its own; the loop runs over the blocks of POSITIONS
+    from the first row's oldest key to the last row's own, each read at
+    its place in the ring (M is whole blocks, so a block of positions is
+    a block of the ring), and no other block is read or computed."""
     from ray_tpu.models import sparse_attention as sa
     B, S, H, D = q.shape
     M, Hkv = k_cache.shape[1], k_cache.shape[2]
     qpos = jnp.broadcast_to(
         jnp.reshape(pos0, (-1, 1)) + jnp.arange(S)[None, :], (B, S))
     kb = sa._block_of(M)
+    if window:
+        if S + window - 1 > M:
+            raise ValueError(
+                f"a tile of {S} rows attends {S + window - 1} positions: "
+                f"more than the ring's {M}")
+        first = jnp.maximum(jnp.min(qpos) - window + 1, 0) // kb
+
+        def ring_block_of(i, qg):
+            c = first + i                    # the block of positions
+            at = (c % (M // kb)) * kb        # where the ring holds it
+            kblk = jax.lax.dynamic_slice_in_dim(k_cache, at, kb, 1)
+            vblk = jax.lax.dynamic_slice_in_dim(v_cache, at, kb, 1)
+            kpos = c * kb + jnp.arange(kb)[None, None, :]
+            mb = ((kpos <= qpos[:, :, None])
+                  & (kpos > qpos[:, :, None] - window))[:, None, None]
+            s = jnp.einsum("bshgd,bmhd->bhgsm", qg, kblk,
+                           preferred_element_type=jnp.float32) * D ** -0.5
+            return s, mb, vblk
+
+        return sa._blocked_softmax(
+            q, Hkv, jnp.max(qpos) // kb - first + 1, ring_block_of)
     n_live = jnp.minimum((jnp.max(qpos) + kb) // kb, M // kb)
 
     def block_of(i, qg):
@@ -336,7 +433,8 @@ def _tile_attention(q, k_cache, v_cache, pos0):
     return sa._blocked_softmax(q, Hkv, n_live, block_of)
 
 
-def _row_attention(q, k_new, v_new, k_pool, v_pool, layer, lens):
+def _row_attention(q, k_new, v_new, k_pool, v_pool, layer, lens,
+                   window: int = 0):
     """One row a slot: q [B, 1, H, D] at position lens[b] (a scalar: every
     row's) against layer `layer` of the pools [n_layers, B, M, Hkv, D],
     which hold the positions below lens[b] and are READ WHERE THEY LIE,
@@ -347,7 +445,10 @@ def _row_attention(q, k_new, v_new, k_pool, v_pool, layer, lens):
     only one. (A layer sliced out of the pool to be attended is copied
     whole, 0.27 GB a layer in and out a step at 16 slots of 8,192: read
     off the program compiled for a described v5e, PERF.md section 6,
-    PR 44.)"""
+    PR 44.) `window`: the pools are RINGS of M positions; the row attends
+    the window - 1 newest of them beside its own, the ring read where it
+    lies (all of it once the slot has filled it) under a mask of the
+    places whose position is in the window."""
     from ray_tpu.models import sparse_attention as sa
     from ray_tpu.ops import decode_attention
     B, _, H, D = q.shape
@@ -356,7 +457,13 @@ def _row_attention(q, k_new, v_new, k_pool, v_pool, layer, lens):
     attend = decode_attention.pool_decode_attention \
         if sa._kernel_reads(M, Hkv, D) \
         else decode_attention.pool_decode_reference
-    m, l, acc = attend(q[:, 0], k_pool, v_pool, layer, lens)
+    mask = None
+    if window:
+        # how far behind the newest position kept (lens - 1) place j's is
+        back = (lens[:, None] - 1 - jnp.arange(M)[None, :]) % M
+        mask = (back <= window - 2) & (back < lens[:, None])
+        lens = jnp.minimum(lens, M)
+    m, l, acc = attend(q[:, 0], k_pool, v_pool, layer, lens, mask)
     # the row's own: one key a KV head, met by all H query heads and
     # counted by its own (as `sparse_decode_attention` folds it)
     own = (jnp.arange(Hkv)[None, :]
@@ -400,7 +507,10 @@ class Attention(nn.Module):
     # an empty cache and using the fused kernel
     chunked: bool = False
     # "blk": this layer attends blocks selected from pooled keys
-    # (`_block_sparse`); None: every earlier position, or the indexer's
+    # (`_block_sparse`); "win": the `window` newest positions, out of a
+    # ring; "att" (and a "hyb" layer's heads): every earlier position, the
+    # caches read in place (`_in_place`); None: every earlier position, or
+    # the indexer's
     kind: Optional[str] = None
 
     @nn.compact
@@ -438,7 +548,7 @@ class Attention(nn.Module):
                         name="k_norm")(k)
         if cfg.key_mult != 1.0:
             k = cfg.key_mult * k
-        if cfg.attn_rope:
+        if cfg.attn_rope or self.kind == "win":
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
         proj = nn.DenseGeneral(
@@ -452,6 +562,20 @@ class Attention(nn.Module):
             return self._block_sparse(x, cache, slots, q, k, v, proj, dense)
         if self.kind == "hyb" and cache is not None:
             return self._in_place(cache, slots, q, k, v, proj)
+        if self.kind in ("win", "att"):
+            if cfg.out_gate:
+                gate = nn.sigmoid(dense(
+                    (H, D), ("embed", "heads", "head_dim"), "gate")(x))
+                inner, proj = proj, lambda o: inner(o * gate)
+            if cache is not None:
+                return self._in_place(cache, slots, q, k, v, proj)
+            from ray_tpu.models import sparse_attention as sa
+            at = jnp.arange(L)
+            mask = at[None, :] <= at[:, None]
+            if self.kind == "win":
+                mask &= at[None, :] > at[:, None] - cfg.window
+            return proj(sa.masked_attention(
+                q, k, v, jnp.broadcast_to(mask, (B, L, L))))
         if cache is None:
             out = attention_dispatch(q, k, v, causal=True,
                                      impl=cfg.attention_impl)
@@ -486,29 +610,43 @@ class Attention(nn.Module):
         return proj(out), (k, v)
 
     def _in_place(self, cache, slots, q, k, v, proj):
-        """A "hyb" layer's heads against caches of thousands of positions:
-        a tile against ITS layer of the scratch, over the key blocks up to
-        its last position only, a running softmax (`_tile_attention`); a
-        decode row against the WHOLE pools and the layer's number, read
-        where they lie, its own key and value beside them
-        (`_row_attention`), and under no `cond` (as in `_block_sparse`)."""
+        """The heads of a "hyb", "att" or "win" layer against caches of
+        thousands of positions: a tile against ITS layer of the scratch,
+        over the key blocks up to its last position only, a running softmax
+        (`_tile_attention`); a decode row against the WHOLE pools and the
+        layer's number, read where they lie, its own key and value beside
+        them (`_row_attention`), and under no `cond` (as in
+        `_block_sparse`). A "win" layer's caches are rings: the tile is
+        written at its positions modulo the ring (`_ring_write`) and both
+        forms attend the window alone. "win" and "att" name their forms to
+        the trace (`win_attend` / `win_row`, `att_attend` / `att_row`)."""
         (k_layer, v_layer), idx, layer = cache
+        window = self.cfg.window if self.kind == "win" else 0
+        put = _ring_write if window else _cache_write
+        named = self.kind in ("win", "att")
+        scope = lambda form: jax.named_scope(  # noqa: E731
+            f"{self.kind}_{form}") if named else contextlib.nullcontext()
 
         def tile(q, k, v):
-            return _tile_attention(q, _cache_write(k_layer, k, idx),
-                                   _cache_write(v_layer, v, idx), idx)
+            with scope("attend"):
+                return _tile_attention(q, put(k_layer, k, idx),
+                                       put(v_layer, v, idx), idx, window)
+
+        def row(q, k, v, k_pool, v_pool, layer, lens):
+            with scope("row"):
+                return _row_attention(q, k, v, k_pool, v_pool, layer, lens,
+                                      window)
 
         if slots is not None:
             (k_pool, v_pool), lens, _, layer = slots
             (q, qr), (k, kr), (v, vr) = (
                 _split_rows(a, len(lens)) for a in (q, k, v))
-            out = _join_rows(tile(q, k, v), _row_attention(
-                qr, kr, vr, k_pool, v_pool, layer, lens))
+            out = _join_rows(tile(q, k, v),
+                             row(qr, kr, vr, k_pool, v_pool, layer, lens))
             return proj(out), ((k, v), (kr, vr))
         if q.shape[1] > 1:
             return proj(tile(q, k, v)), (k, v)
-        return proj(_row_attention(q, k, v, k_layer, v_layer, layer,
-                                   idx)), (k, v)
+        return proj(row(q, k, v, k_layer, v_layer, layer, idx)), (k, v)
 
     def _sparse(self, x, positions, cache, q, k, v, proj, dense):
         """The model with an indexer (models/sparse_attention.py): the
@@ -788,10 +926,16 @@ class Block(nn.Module):
     cfg: TransformerConfig
     chunked: bool = False
     kind: Optional[str] = None      # of cfg.mixer_kinds, where it has them
+    # one of a model with experts' leading layers that keep the dense MLP
+    dense_mlp: bool = False
 
     @nn.compact
     def __call__(self, x, positions, cache=None, real=None, slots=None):
         cfg = self.cfg
+        # (a sandwich block norms each branch at its output too)
+        after = lambda y, name: RMSNorm(  # noqa: E731
+            cfg.norm_eps, cfg.dtype, name=name)(y) if cfg.sandwich_norm \
+            else y
         normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x)
         if self.kind == "hyb":
             att = self._hybrid(normed, positions, cache, slots, real)
@@ -804,11 +948,12 @@ class Block(nn.Module):
         new_rows = None
         if cache is not None:
             att, new_rows = att
+        att = after(att, "post_attn_norm")
         if cfg.residual_scale != 1.0:       # muP's depth scaling
             att = cfg.residual_scale * att
         h = x + att
         normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(h)
-        if cfg.n_experts > 0:
+        if cfg.n_experts > 0 and not self.dense_mlp:
             from ray_tpu.models.moe import MoEMLP
             # serving drops no pick; `real`: the rows a request owns;
             # the slots' decode rows behind a tile are counted after it
@@ -817,6 +962,7 @@ class Block(nn.Module):
                 tail=0 if slots is None else len(slots[1]))
         else:
             y, aux = MLP(cfg, name="mlp")(normed), jnp.zeros((), jnp.float32)
+        y = after(y, "post_mlp_norm")
         if cfg.residual_scale != 1.0:
             y = cfg.residual_scale * y
         if cache is not None:
@@ -919,8 +1065,12 @@ def index_cache_shape(cfg: TransformerConfig, batch: int,
 # None: the pool has no position (the state of a "lin" layer; the state
 # "s" and the convolution's tail "c" of a "hyb" layer) and a call replaces
 # a row's entry whole
-CACHE_POS_AXIS = {"k": -3, "v": -3, "ki": -1, "kp": -3, "s": None,
-                  "c": None}
+CACHE_POS_AXIS = {"k": -3, "v": -3, "ki": -1, "kp": -3, "wk": -3,
+                  "wv": -3, "s": None, "c": None}
+# the third nature: a RING, K and V of the "win" layers. It has a position
+# axis of `win_ring` places whatever the cache's length, position p lies at
+# p mod win_ring, and a slot takes the scratch's ring whole
+CACHE_RINGS = ("wk", "wv")
 
 
 def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int):
@@ -930,11 +1080,14 @@ def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int):
     (KIND_CACHES): K, V and the pooled keys of the "blk" layers, the
     states of the "lin" layers; K, V, the state-space mixer's states
     [n, rows, heads, d_head, d_state] and its convolution's tails
-    [n, rows, taps - 1, channels] of the "hyb" layers."""
+    [n, rows, taps - 1, channels] of the "hyb" layers; K and V of the
+    "att" layers by position, and of the "win" layers in rings of
+    `win_ring` places, [n, rows, win_ring, Hkv, D], whatever `max_len`."""
     if cfg.mixer_kinds:
         kv = kv_cache_shape(cfg, batch, max_len)[1:]
         hyb = "hyb" in cfg.mixer_kinds
-        entry = {"k": kv, "v": kv,
+        ring = (batch, cfg.win_ring) + kv[2:]
+        entry = {"k": kv, "v": kv, "wk": ring, "wv": ring,
                  "kp": (batch, max_len // cfg.blk_stride) + kv[2:],
                  "s": (batch, cfg.ssm_heads, cfg.ssm_head_dim,
                        cfg.ssm_state) if hyb
@@ -1095,8 +1248,9 @@ class TransformerLM(nn.Module):
             aux_total = jnp.zeros((), jnp.float32)
             for i in range(cfg.n_layers):
                 kind = cfg.mixer_kinds[i] if cfg.mixer_kinds else None
-                x, aux_i = block(cfg, kind=kind, name=f"layer_{i}")(
-                    x, positions)
+                x, aux_i = block(cfg, kind=kind,
+                                 dense_mlp=i < cfg.n_dense_layers,
+                                 name=f"layer_{i}")(x, positions)
                 aux_total = aux_total + aux_i
         if cfg.n_experts > 0:
             # surfaced to the train step via mutable=["losses"]; a no-op
@@ -1216,19 +1370,22 @@ class TransformerLM(nn.Module):
                 of = KIND_CACHES.get(kind, names)
                 j = seen[kind] = seen.get(kind, -1) + 1
                 hyb = kind == "hyb"
+                whole = kind in _IN_PLACE
 
                 def read(now, rows):
-                    # one row a slot of a "hyb" layer reads K and V in
-                    # the WHOLE pools, by the layer's number (a layer
-                    # sliced out to be attended is copied whole)
-                    return tuple(now[n] if hyb and rows and n in ("k", "v")
+                    # one row a slot of a "hyb", "att" or "win" layer reads
+                    # K and V in the WHOLE pools, by the layer's number (a
+                    # layer sliced out to be attended is copied whole)
+                    return tuple(now[n] if whole and rows
+                                 and CACHE_POS_AXIS[n] is not None
                                  else now[n][j] for n in of)
                 x, _aux, new_rows = Block(
-                    cfg, chunked_prefill, kind, name=f"layer_{i}")(
+                    cfg, chunked_prefill, kind, i < cfg.n_dense_layers,
+                    name=f"layer_{i}")(
                     x, positions,
-                    (read(running[0], L == 1), idx) + (j,) * hyb, real,
+                    (read(running[0], L == 1), idx) + (j,) * whole, real,
                     slots and (read(running[1], True), *carry[-1])
-                    + (j,) * hyb)
+                    + (j,) * whole)
                 for into, now, new in zip(got, running, new_rows if slots
                                           else (new_rows,)):
                     for n, r in zip(of, new):
@@ -1266,6 +1423,12 @@ class TransformerLM(nn.Module):
                 from ray_tpu.models import sparse_attention as sa
                 idx = sa.pooled_at(idx, _block_geometry(cfg),
                                    None if tile else pool.shape[2])
+            if n in CACHE_RINGS:            # at the position modulo the ring
+                scope = "win_attend" if tile else "win_row"
+                with jax.named_scope(scope):
+                    if tile:
+                        return _ring_write(pool, rows, idx)
+                    return _cache_write(pool, rows, idx % pool.shape[2])
             return _cache_write(pool, rows, idx, CACHE_POS_AXIS[n])
 
         rows, slot_rows = rows if slots else (rows, None)
