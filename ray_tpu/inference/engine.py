@@ -408,6 +408,22 @@ class InferenceEngine:
                 M=ring, Hkv=mcfg.n_kv_heads, D=mcfg.head_dim))
         self.win_rows_streamed = 0
         self.win_rows_live = 0
+        # the dense model (no indexer, no kinds of layer): its decode rows
+        # read K and V in the slots' pools where they lie, so the same
+        # pair, host arithmetic on the lengths too: the positions a row
+        # attends, its own among them, and the positions its attention
+        # passes over (`decode_positions_read`: whole key blocks up to the
+        # row's last live one, and its own). A speculative draft's verify
+        # step scores k + 1 rows a slot another way and counts nothing
+        self._kv_streamed = None
+        if not (mcfg.index_heads or mcfg.mixer_kinds or self._spec):
+            from ray_tpu.models import sparse_attention
+            self._kv_streamed = functools.partial(
+                sparse_attention.decode_positions_read,
+                M=self._slots.shape[2], Hkv=mcfg.n_kv_heads,
+                D=mcfg.head_dim)
+        self.kv_rows_streamed = 0
+        self.kv_rows_live = 0
         # an expert layer that holds a share of its experts: [rows the
         # expert matmuls computed, picks of real rows that landed on a held
         # expert], summed over layers and calls (models/moe.py sows them).
@@ -1034,20 +1050,23 @@ class InferenceEngine:
     def _rows_ran(self, slots):
         """The decode rows of `slots` were issued: the mirror of their
         lengths and what the counters make of them, host arithmetic."""
+        lens = [int(self._lengths[slot]) for slot in slots]
         if self._topk:      # before the rows' own
-            self.dsa_rows_streamed += self._dsa_streamed(
-                [int(self._lengths[slot]) for slot in slots])
+            self.dsa_rows_streamed += self._dsa_streamed(lens)
+        if self._kv_streamed:
+            self.kv_rows_streamed += self._kv_streamed(lens)
         if self._win:       # (less each row's own, which no ring holds)
             _, ring, streamed = self._win
             self.win_rows_streamed += streamed(
-                [min(int(self._lengths[slot]), ring) for slot in slots]
-            ) - len(slots)
+                [min(n, ring) for n in lens]) - len(slots)
         self._lengths[slots] += 1
         for slot in slots:
             live = int(self._lengths[slot])
             if self._topk:      # the row attended itself too
                 self.dsa_rows_live += live
                 self.dsa_rows_read += min(live, self._topk)
+            if self._kv_streamed:
+                self.kv_rows_live += live
             if self._win:
                 self.win_rows_live += min(live, self._win[0])
             if self._blk:
@@ -1398,6 +1417,9 @@ class InferenceEngine:
             out["win_pool_bytes"] = self._slots.nbytes(("wk", "wv"))
             out["win_rows_streamed"] = self.win_rows_streamed
             out["win_rows_live"] = self.win_rows_live
+        if self._kv_streamed:
+            out["kv_rows_streamed"] = self.kv_rows_streamed
+            out["kv_rows_live"] = self.kv_rows_live
         if self._topk:
             out["dsa_rows_read"] = self.dsa_rows_read
             out["dsa_rows_live"] = self.dsa_rows_live

@@ -211,7 +211,8 @@ KIND_CACHES = {"blk": ("k", "v", "kp"), "lin": ("s",),
                "hyb": ("k", "v", "s", "c"), "win": ("wk", "wv"),
                "att": ("k", "v")}
 # the kinds whose decode rows read K and V in the WHOLE pools, by the
-# layer's number (`Attention._in_place`)
+# layer's number (`Attention._in_place`; a model without kinds:
+# `TransformerLM._decode`, `whole`)
 _IN_PLACE = ("hyb", "win", "att")
 
 _PARTITION_OFF = __import__("threading").local()
@@ -267,17 +268,19 @@ def rope(x, positions, theta: float):
 
 
 def _cached_attention(q, k_cache, v_cache, q_pos0):
-    """Decode-path attention against a padded KV cache.
+    """S rows at once against a padded KV cache, every position of it
+    scored: for a SMALL cache (`generate.py`'s, the speculative verify
+    step's k + 1 rows, a dense tile against its scratch). One row a slot
+    against the slot pools does not come here: it reads its slot's live
+    key blocks where they lie (`_row_attention`).
 
     q [B,S,H,D] are the S newest positions (absolute start q_pos0);
     caches [B,M,Hkv,D] already contain the new keys/values written at
     [q_pos0, q_pos0+S). q_pos0 is a scalar (shared start, the
-    make_generate_fn shape) or a [B] vector (per-slot starts — the
-    continuous-batching slot pool, where every sequence sits at its own
-    length). Mask: query i attends cache slots j <= q_pos0+i (causal
+    make_generate_fn shape) or a [B] vector (per-row starts, the verify
+    step's). Mask: query i attends cache slots j <= q_pos0+i (causal
     over absolute positions; padded tail masked out). Plain dot-product
-    in fp32 — decode is bandwidth-bound on the cache read, not
-    MXU-bound, so there is nothing for the flash kernel to win here."""
+    in fp32."""
     B, S, H, D = q.shape
     M, Hkv = k_cache.shape[1], k_cache.shape[2]
     # GQA via grouped einsum against the UNEXPANDED cache: a repeat of
@@ -523,14 +526,21 @@ class Attention(nn.Module):
         or per-slot [B] vector) for attention to see, and returned as
         (out, new_rows) [B,L,..], in the pools' order, for the caller to
         add to the pools: the pools themselves are not written here.
+        cache=(pools, idx, layer): one row a slot (L == 1) against the
+        WHOLE pools [n_layers,B,M,Hkv,D] and the layer's number: K and V
+        are read where they lie, each slot's live key blocks only, and
+        the row's own key and value folded in beside them
+        (`_row_attention`); nothing is placed anywhere.
 
-        slots=(rows, lengths, on) (a model without an indexer): the
-        sequence [1, T + S] is a prefill tile of T rows against `cache`
-        followed by one decode row for each of S slots against `slots`
-        (row b at position lengths[b] of slot b; `on` False: no slot is
-        live, their attention is skipped). The projections run once
-        over all T + S rows; only the attention splits them. new_rows
-        is then the pair (the tile's [1,T,..], the slots' [S,1,..])."""
+        slots=(pools, lengths, on, layer) (a model without an indexer):
+        the sequence [1, T + S] is a prefill tile of T rows against
+        `cache` followed by one decode row for each of S slots against
+        the slots' whole pools, in place as above (row b at position
+        lengths[b] of slot b; with no slot live the rows are computed all
+        the same, and `_decode` writes none of them). The projections run
+        once over all T + S rows; only the attention splits them.
+        new_rows is then the pair (the tile's [1,T,..], the slots'
+        [S,1,..])."""
         cfg = self.cfg
         B, L, E = x.shape
         H, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -580,54 +590,49 @@ class Attention(nn.Module):
             out = attention_dispatch(q, k, v, causal=True,
                                      impl=cfg.attention_impl)
             return proj(out)
-        (k_layer, v_layer), idx = cache
-        if slots is not None:
-            (k_pool, v_pool), lens, on = slots
-            (q, qr), (k, kr), (v, vr) = (
-                _split_rows(a, len(lens)) for a in (q, k, v))
-            out = _join_rows(
-                _cached_attention(q, _cache_write(k_layer, k, idx),
-                                  _cache_write(v_layer, v, idx), idx),
-                jax.lax.cond(
-                    on, lambda: _cached_attention(
-                        qr, _cache_write(k_pool, kr, lens),
-                        _cache_write(v_pool, vr, lens), lens),
-                    lambda: jnp.zeros_like(qr)))
-            return proj(out), ((k, v), (kr, vr))
-        if L > 1 and not self.chunked:
-            # one-shot prefill (L is static): the block attends only
-            # within itself, so the fused flash/ring kernel computes it
-            # — the cache is just written, never read. This assumes
-            # prefill starts from an EMPTY cache (idx==0, the
-            # make_generate_fn contract); chunked prefill (idx>0) sets
-            # `chunked` and takes the cached path below, which attends
-            # the earlier chunks at the correct causal offset.
-            out = attention_dispatch(q, k, v, causal=True,
-                                     impl=cfg.attention_impl)
-        else:
-            out = _cached_attention(q, _cache_write(k_layer, k, idx),
-                                    _cache_write(v_layer, v, idx), idx)
-        return proj(out), (k, v)
 
-    def _in_place(self, cache, slots, q, k, v, proj):
+        def tile(q, k, v):
+            if L > 1 and not self.chunked and slots is None:
+                # one-shot prefill (L is static): the block attends only
+                # within itself, so the fused flash/ring kernel computes
+                # it — the cache is just written, never read. This assumes
+                # prefill starts from an EMPTY cache (idx==0, the
+                # make_generate_fn contract); chunked prefill (idx>0) sets
+                # `chunked` and takes the cached path below, which attends
+                # the earlier chunks at the correct causal offset.
+                return attention_dispatch(q, k, v, causal=True,
+                                          impl=cfg.attention_impl)
+            (k_layer, v_layer), idx, *_ = cache
+            return _cached_attention(q, _cache_write(k_layer, k, idx),
+                                     _cache_write(v_layer, v, idx), idx)
+
+        return self._in_place(cache, slots, q, k, v, proj, tile)
+
+    def _in_place(self, cache, slots, q, k, v, proj, tile=None):
         """The heads of a "hyb", "att" or "win" layer against caches of
         thousands of positions: a tile against ITS layer of the scratch,
         over the key blocks up to its last position only, a running softmax
         (`_tile_attention`); a decode row against the WHOLE pools and the
         layer's number, read where they lie, its own key and value beside
         them (`_row_attention`), and under no `cond` (as in
-        `_block_sparse`). A "win" layer's caches are rings: the tile is
+        `_block_sparse`: a branch handed the pools makes XLA copy them).
+        Which of the two a call without `slots` is, the cache says: the
+        layer's number comes with whole pools (`TransformerLM._decode`,
+        `whole`). A "win" layer's caches are rings: the tile is
         written at its positions modulo the ring (`_ring_write`) and both
         forms attend the window alone. "win" and "att" name their forms to
-        the trace (`win_attend` / `win_row`, `att_attend` / `att_row`)."""
-        (k_layer, v_layer), idx, layer = cache
+        the trace (`win_attend` / `win_row`, `att_attend` / `att_row`).
+        The dense model's layers (no kind) come here with a `tile` form of
+        their own and share the row's, as `att_row`."""
+        (k_layer, v_layer), idx, *layer = cache
         window = self.cfg.window if self.kind == "win" else 0
         put = _ring_write if window else _cache_write
-        named = self.kind in ("win", "att")
+        # ("hyb": its two branches are named by the block, `hyb_attn`)
+        name = {"win": "win", "att": "att", None: "att"}.get(self.kind)
         scope = lambda form: jax.named_scope(  # noqa: E731
-            f"{self.kind}_{form}") if named else contextlib.nullcontext()
+            f"{name}_{form}") if name else contextlib.nullcontext()
 
-        def tile(q, k, v):
+        def blocked(q, k, v):
             with scope("attend"):
                 return _tile_attention(q, put(k_layer, k, idx),
                                        put(v_layer, v, idx), idx, window)
@@ -637,16 +642,17 @@ class Attention(nn.Module):
                 return _row_attention(q, k, v, k_pool, v_pool, layer, lens,
                                       window)
 
+        tile = tile or blocked
         if slots is not None:
-            (k_pool, v_pool), lens, _, layer = slots
+            (k_pool, v_pool), lens, _, number = slots
             (q, qr), (k, kr), (v, vr) = (
                 _split_rows(a, len(lens)) for a in (q, k, v))
             out = _join_rows(tile(q, k, v),
-                             row(qr, kr, vr, k_pool, v_pool, layer, lens))
+                             row(qr, kr, vr, k_pool, v_pool, number, lens))
             return proj(out), ((k, v), (kr, vr))
-        if q.shape[1] > 1:
-            return proj(tile(q, k, v)), (k, v)
-        return proj(row(q, k, v, k_layer, v_layer, layer, idx)), (k, v)
+        if layer:
+            return proj(row(q, k, v, k_layer, v_layer, *layer, idx)), (k, v)
+        return proj(tile(q, k, v)), (k, v)
 
     def _sparse(self, x, positions, cache, q, k, v, proj, dense):
         """The model with an indexer (models/sparse_attention.py): the
@@ -1020,9 +1026,12 @@ class DecodeScanBlock(nn.Module):
     indexer keys) ride in as a scanned input (axis 0 of the pools =
     layers), READ-ONLY, and only the call's new rows [B,L,Hkv,D] come
     back in the ys — never the layer, so no pool is stacked up again.
-    `slot_rows`: the same layer of the slots' pools, where decode rows
-    ride behind a prefill tile. Param names mirror ScanBlock ('block'
-    under the scan) so the SAME trained/stacked params apply."""
+    Where the call is one row a slot, `layer_rows` are the WHOLE pools,
+    broadcast, and `layer` the layer's number, scanned (`_decode`,
+    `whole`). `slot_rows`: the slots' pools, where decode rows ride
+    behind a prefill tile: whole and by the same number. Param names
+    mirror ScanBlock ('block' under the scan) so the SAME trained/stacked
+    params apply."""
     cfg: TransformerConfig
     chunked: bool = False
 
@@ -1031,7 +1040,7 @@ class DecodeScanBlock(nn.Module):
         x, positions, idx, real, slots = carry
         out, _aux, new_rows = Block(self.cfg, self.chunked, name="block")(
             x, positions, (layer_rows, idx, *layer), real,
-            slots and (slot_rows, *slots))
+            slots and (slot_rows, *slots, *layer))
         return (out, positions, idx, real, slots), new_rows
 
 
@@ -1291,9 +1300,12 @@ class TransformerLM(nn.Module):
         cache and returns (logits|hidden, new_cache). The pools
         cache["k"], cache["v"] [n_layers,B,M,Hkv,D] (and cache["ki"], the
         indexer's keys, where the model has them) are only READ by the
-        layer loop: layer i reads pool[i], places its new [B,L,Hkv,D]
-        rows in that read-out for its attention, and hands the rows
-        back; after the loop ONE write per pool adds all layers' rows
+        layer loop: layer i of a tile reads pool[i], places its new
+        [B,L,Hkv,D] rows in that read-out for its attention, and hands
+        the rows back; layer i of one row a slot reads the live key
+        blocks of pool[i] where they lie, its own key and value beside
+        them (`whole` below), and hands its row back; after the loop ONE
+        write per pool adds all layers' rows
         at (.., b, idx_b), so new_cache holds the input pools updated
         in place (a jitted caller that donates them gets its own
         buffers back; nothing pool-shaped is copied or stacked).
@@ -1309,9 +1321,8 @@ class TransformerLM(nn.Module):
         takes the tile against `cache` and the rows against the slots'
         pools, and after the loop the tile's new rows are written to
         `cache` and the slots' to theirs (new_cache["slots"]). With "on"
-        False the slots' attention and write are skipped and their pools
-        come back untouched; what the tile computes does not depend on
-        it."""
+        False the slots' write is skipped and their pools come back
+        untouched; what the tile computes does not depend on it."""
         cfg = self.cfg
         idx = cache["idx"]
         names = tuple(n for n in CACHE_POS_AXIS if n in cache)
@@ -1330,25 +1341,41 @@ class TransformerLM(nn.Module):
         # layer routes no other
         real = cache.get("real") if cfg.n_experts > 0 or cfg.mixer_kinds \
             else None
-        # one row a slot of a model with an indexer reads its K and V in
-        # the pools, blocks of its live positions only: the layers' loop
-        # is handed the whole pools and the layer's number, not the layer
-        # sliced out (which would be copied whole to be read from)
-        whole = bool(cfg.index_heads) and L == 1
+
+        def whole(kind):
+            """Whether a layer of `kind` is handed the WHOLE pools and its
+            number, not the layer sliced out (which would be copied whole
+            to be read from): (the call's own pools, the slots'). So where
+            one row a slot reads K and V in place, each slot's live key
+            blocks only: the kinds of `_IN_PLACE`; an indexer's rows; the
+            dense model's where each sits at its own length (the slot
+            pools: `generate.py`'s rows share one start and a small cache,
+            `_cached_attention`)."""
+            def in_place(at):
+                if kind is None:
+                    return bool(cfg.index_heads) or jnp.ndim(at) == 1
+                return kind in _IN_PLACE
+            return (L == 1 and in_place(idx),
+                    bool(slots) and in_place(slots["idx"]))
+
         carry = (x, positions, idx, real,
                  slots and (slots["idx"], slots["on"]))
         if cfg.scan_layers:
+            # whole pools ride broadcast beside the layers' numbers; a
+            # tile's scratch stays a scanned input
+            ride = whole(None)
             stack = nn.scan(
                 DecodeScanBlock,
                 variable_axes={"params": 0, "counters": 0},
                 split_rngs={"params": True},
-                in_axes=(nn.broadcast, 0, 0) if whole else 0,
+                in_axes=tuple(nn.broadcast if w else 0 for w in ride)
+                + (0,) * any(ride),
                 length=cfg.n_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, chunked_prefill, name="layers")
             (x, *_), rows = stack(
                 carry, pools, slot_pools,
-                *((jnp.arange(cfg.n_layers),) if whole else ()))
+                *((jnp.arange(cfg.n_layers),) if any(ride) else ()))
         else:
             # layer i reads entry j of ITS kind's pools (every pool's,
             # where the layers are of one kind) and hands its rows back.
@@ -1370,22 +1397,21 @@ class TransformerLM(nn.Module):
                 of = KIND_CACHES.get(kind, names)
                 j = seen[kind] = seen.get(kind, -1) + 1
                 hyb = kind == "hyb"
-                whole = kind in _IN_PLACE
+                ride = whole(kind)
+                number = (j,) * any(ride)
 
-                def read(now, rows):
-                    # one row a slot of a "hyb", "att" or "win" layer reads
-                    # K and V in the WHOLE pools, by the layer's number (a
-                    # layer sliced out to be attended is copied whole)
-                    return tuple(now[n] if whole and rows
+                def read(now, ride):
+                    # (a state has no position: always the layer's own)
+                    return tuple(now[n] if ride
                                  and CACHE_POS_AXIS[n] is not None
                                  else now[n][j] for n in of)
                 x, _aux, new_rows = Block(
                     cfg, chunked_prefill, kind, i < cfg.n_dense_layers,
                     name=f"layer_{i}")(
                     x, positions,
-                    (read(running[0], L == 1), idx) + (j,) * whole, real,
-                    slots and (read(running[1], True), *carry[-1])
-                    + (j,) * whole)
+                    (read(running[0], ride[0]), idx) + number, real,
+                    slots and (read(running[1], ride[1]), *carry[-1])
+                    + number)
                 for into, now, new in zip(got, running, new_rows if slots
                                           else (new_rows,)):
                     for n, r in zip(of, new):

@@ -248,11 +248,11 @@ INDEXER = dict(index_heads=2, index_head_dim=16, index_topk=16)
 
 
 @pytest.mark.parametrize("model,text,digest", [
-    ({}, _decode_text, "13226c2622d8400b"),
-    ({}, _tile_text, "2bbc047cde5e404f"),
+    ({}, _decode_text, "84285cf3fb9f06eb"),
+    ({}, _tile_text, "fd9f5c4aa916b342"),
     (INDEXER, _decode_text, "8702c029e6542675"),
     (INDEXER, _tile_text, "2d7af74232ada8e8"),
-    (dict(draft_k=2), _verify_text, "0c4d3e949e620057"),
+    (dict(draft_k=2), _verify_text, "8eadf6e765d8e455"),
     (None, _train_text, "8aecbfdae32759c2")],
     ids=["dense_decode", "dense_tile_with_rows", "indexer_decode",
          "indexer_tile", "draft_verify", "train_step"])
@@ -274,11 +274,18 @@ def test_the_step_programs_are_the_parents(model, text, digest):
     (the prompt's would-be next token and the riding rows) and the cached
     forward gathers them before the final norm and the head, so the head's
     dot has 1 + S rows (1 for the indexer's model) where it had T + S.
-    Both decode programs and the training step pass with the digests they
-    had, and the verify step with PR 44's tree's (read there by
+    Both decode programs and the training step passed with the digests
+    they had, and the verify step with PR 44's tree's (read there by
     `_verify_text`): a cached call that names no rows is the program it
     was, and the `lm_head` scope is metadata the lowered text does not
-    print."""
+    print. The three programs that hold a DENSE model's decode rows were
+    re-pinned on PR 47's final tree: the decode program, the tile program
+    with the riding rows, and the verify step (whose draft's own k + 1
+    steps are one row a slot): such a row attends its slot's live key
+    blocks in the pools where they lie (`_row_attention`) and no longer a
+    layer sliced out of the pool; tests/test_step_order.py holds their
+    greedy tokens to the parent's. The indexer's two programs and the
+    training step pass with the digests they had."""
     eng = None if model is None else _engine(**model)
     got = hashlib.sha256(text(eng).encode()).hexdigest()[:16]
     assert got == digest

@@ -212,11 +212,14 @@ def tile_text(eng):
 
 
 def tile_head_rows(eng):
-    """The rows ([.., rows, vocab] -> rows) of every dot in the lowered
-    program of `eng`'s largest tile whose result ends in the vocabulary,
-    and that tile's length."""
-    dots = re.findall(r"dot_general.*-> tensor<([\dx]+)x\w+>",
-                      tile_text(eng))
+    """The rows ([.., rows, vocab] -> rows) of every dot of a WEIGHT (a
+    parameter of the program; a decode row's scores against a key block of
+    64 positions x 2 KV heads are 128 wide too) in the lowered program of
+    `eng`'s largest tile whose result ends in the vocabulary, and that
+    tile's length."""
+    dots = re.findall(
+        r"dot_general %\w+, %arg\d+,.*-> tensor<([\dx]+)x\w+>",
+        tile_text(eng))
     assert dots
     shapes = [tuple(map(int, d.split("x"))) for d in dots]
     vocab = eng.model.cfg.vocab_size
