@@ -408,6 +408,17 @@ class InferenceEngine:
                 M=ring, Hkv=mcfg.n_kv_heads, D=mcfg.head_dim))
         self.win_rows_streamed = 0
         self.win_rows_live = 0
+        # a tile of T rows: the layers whose tile attends its scratch by
+        # `_tile_attention` (the kinds "win", "att" and "hyb") and those
+        # of them the Pallas kernel takes (ops/tile_attention.py: on a
+        # TPU, where the shapes fit), known from the shapes each tile
+        # program is built on and summed over the prefill dispatches
+        from ray_tpu.models.transformer import tile_attention_layers
+        self._tile_layers = {
+            T: tile_attention_layers(mcfg, T, scratch_len, dtype)
+            for T in self._prefill_tiles}
+        self.tile_attn_layers = 0
+        self.tile_kernel_layers = 0
         # the dense model (no indexer, no kinds of layer): its decode rows
         # read K and V in the slots' pools where they lie, so the same
         # pair, host arithmetic on the lengths too: the positions a row
@@ -1219,6 +1230,9 @@ class InferenceEngine:
         compiles0 = self.prefill_compile_count
         self.prefill_dispatches += 1
         self.prefill_tokens += ch.length
+        layers, kernel = self._tile_layers[tile]
+        self.tile_attn_layers += layers
+        self.tile_kernel_layers += kernel
         with self._mesh_ctx():
             slot, scratch = self._call_prefill(scratch, host)
         if self.prefill_compile_count > compiles0:
@@ -1417,6 +1431,9 @@ class InferenceEngine:
             out["win_pool_bytes"] = self._slots.nbytes(("wk", "wv"))
             out["win_rows_streamed"] = self.win_rows_streamed
             out["win_rows_live"] = self.win_rows_live
+        if any(layers for layers, _ in self._tile_layers.values()):
+            out["tile_attn_layers"] = self.tile_attn_layers
+            out["tile_kernel_layers"] = self.tile_kernel_layers
         if self._kv_streamed:
             out["kv_rows_streamed"] = self.kv_rows_streamed
             out["kv_rows_live"] = self.kv_rows_live

@@ -46,7 +46,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import decode_attention
+from ray_tpu.ops import decode_attention, tile_attention
 
 _NEG = -1e30
 _MAX_BLOCK = 512        # keys a block of the blocked forms holds
@@ -290,6 +290,16 @@ def _kernel_reads(M: int, Hkv: int, D: int) -> bool:
     Elsewhere the same blocks are read by an XLA loop."""
     return jax.default_backend() == "tpu" and decode_attention.fits(
         M, Hkv, D)
+
+
+def _tile_kernel_takes(S: int, M: int, H: int, Hkv: int, D: int,
+                       window: int = 0) -> bool:
+    """Whether a prefill tile of S rows attends its scratch of M places
+    through the Pallas kernel (ops/tile_attention.py): on a TPU, where the
+    shapes fit it. Elsewhere `_tile_attention`'s XLA loop folds the same
+    blocks."""
+    return jax.default_backend() == "tpu" and tile_attention.fits(
+        S, M, H, Hkv, D, window)
 
 
 def decode_positions_read(lens, M: int, Hkv: int, D: int) -> int:

@@ -382,7 +382,7 @@ def _join_rows(tile, rows):
     return jnp.concatenate([tile, jnp.swapaxes(rows, 0, 1)], axis=1)
 
 
-def _tile_attention(q, k_cache, v_cache, pos0, window: int = 0):
+def _tile_attention(q, k_cache, v_cache, pos0, window: int = 0, own=None):
     """A tile q [B, S, H, D] at absolute positions pos0 + 0..S-1 (pos0 a
     scalar or [B]) against caches [B, M, Hkv, D] that already hold the
     tile's own rows: causal over absolute positions, blocked over the keys
@@ -394,10 +394,40 @@ def _tile_attention(q, k_cache, v_cache, pos0, window: int = 0):
     positions up to its own; the loop runs over the blocks of POSITIONS
     from the first row's oldest key to the last row's own, each read at
     its place in the ring (M is whole blocks, so a block of positions is
-    a block of the ring), and no other block is read or computed."""
+    a block of the ring), and no other block is read or computed.
+    `own`: the tile's own K and V [B, S, Hkv, D], which the caches do NOT
+    hold yet: they are written first, at their positions (a ring's modulo
+    the ring).
+
+    On a TPU one tile (B == 1, one scalar start) whose shapes fit it goes
+    through the Pallas kernel of ops/tile_attention.py: the same blocks,
+    each block of query rows over its own range of them, the running
+    softmax held in VMEM. The kernel reads a cache as the matrix
+    [M, Hkv * D]; the scratch is tiled over (Hkv, D), so on the TPU that
+    view is a relayout, one copy of the layer's K and V, and the tile's
+    own rows are written into THAT copy. (Written into the scratch first
+    and the result relaid, the unwritten scratch had to outlive the write
+    for the caller's own after the layers, and XLA copied the layer's
+    whole K and V twice more: PERF.md section 6, PR 48.) The loop below is
+    the kernel's reference, step for step, and what runs everywhere
+    else."""
     from ray_tpu.models import sparse_attention as sa
+    from ray_tpu.ops import tile_attention
     B, S, H, D = q.shape
     M, Hkv = k_cache.shape[1], k_cache.shape[2]
+    kernel = B == 1 and not jnp.ndim(pos0) and q.dtype == k_cache.dtype \
+        and sa._tile_kernel_takes(S, M, H, Hkv, D, window)
+    if kernel:                      # the caches as the kernel reads them
+        k_cache, v_cache = (c.reshape(B, M, Hkv * D)
+                            for c in (k_cache, v_cache))
+    if own is not None:
+        put = _ring_write if window else _cache_write
+        k_cache, v_cache = (
+            put(c, new.reshape(B, S, *c.shape[2:]), pos0, 1 - c.ndim)
+            for c, new in zip((k_cache, v_cache), own))
+    if kernel:
+        return tile_attention.tile_attention(q, k_cache, v_cache, pos0,
+                                             window)
     qpos = jnp.broadcast_to(
         jnp.reshape(pos0, (-1, 1)) + jnp.arange(S)[None, :], (B, S))
     kb = sa._block_of(M)
@@ -434,6 +464,26 @@ def _tile_attention(q, k_cache, v_cache, pos0, window: int = 0):
         return s, mb, vblk
 
     return sa._blocked_softmax(q, Hkv, n_live, block_of)
+
+
+def tile_attention_layers(cfg: "TransformerConfig", tile: int,
+                          scratch_len: int, cache_dtype=None):
+    """Host arithmetic on what a tile program is built from: (the layers
+    whose tile of `tile` rows goes through `_tile_attention`, a "win"
+    layer's against its ring, an "att" or "hyb" layer's against
+    `scratch_len` positions; those of them the Pallas kernel takes)."""
+    from ray_tpu.models import sparse_attention as sa
+    same = jnp.dtype(cache_dtype or cfg.dtype) == jnp.dtype(cfg.dtype)
+    layers = kernel = 0
+    for kind in cfg.mixer_kinds or ():
+        if kind not in _IN_PLACE:
+            continue
+        window, M = (cfg.window, cfg.win_ring) if kind == "win" \
+            else (0, scratch_len)
+        layers += 1
+        kernel += bool(same and sa._tile_kernel_takes(
+            tile, M, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, window))
+    return layers, kernel
 
 
 def _row_attention(q, k_new, v_new, k_pool, v_pool, layer, lens,
@@ -626,7 +676,6 @@ class Attention(nn.Module):
         their own and share the row's, as `att_row`."""
         (k_layer, v_layer), idx, *layer = cache
         window = self.cfg.window if self.kind == "win" else 0
-        put = _ring_write if window else _cache_write
         # ("hyb": its two branches are named by the block, `hyb_attn`)
         name = {"win": "win", "att": "att", None: "att"}.get(self.kind)
         scope = lambda form: jax.named_scope(  # noqa: E731
@@ -634,8 +683,8 @@ class Attention(nn.Module):
 
         def blocked(q, k, v):
             with scope("attend"):
-                return _tile_attention(q, put(k_layer, k, idx),
-                                       put(v_layer, v, idx), idx, window)
+                return _tile_attention(q, k_layer, v_layer, idx, window,
+                                       own=(k, v))
 
         def row(q, k, v, k_pool, v_pool, layer, lens):
             with scope("row"):

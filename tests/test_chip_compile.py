@@ -149,6 +149,53 @@ def test_pool_decode_kernel_takes_the_pool_as_it_lies(one_chip,
     assert re.search(rf"bf16\[{n},{B},{M * Hkv},128\]\S* bitcast\(", text)
 
 
+def _step_program_text(config, program, one_chip, monkeypatch):
+    """(the engine, the compiled text of its "decode" or "tile" program)
+    at a benchmark configuration's own size, for a described v5e. The
+    engine is built on shapes: nothing is allocated and nothing runs. The
+    two predicates that ask for the backend (`_kernel_reads`, a decode
+    row's pool kernel; `_tile_kernel_takes`, a tile's flash kernel) see
+    the CPU's here: they are made to answer as on the chip, so that the
+    program compiled is the one the cell runs."""
+    import numpy as np
+    from flax.core import meta
+
+    from perfbench import spec
+    from ray_tpu.inference import kv_cache
+    from ray_tpu.inference.engine import EngineConfig, InferenceEngine
+    from ray_tpu.models import sparse_attention
+    from ray_tpu.ops import decode_attention, tile_attention
+    cfg = spec.load_config(spec.load_benchmark(), config)
+    family = spec.family_of(cfg)
+    model = family.build_model(family.model_kwargs(cfg))
+    params = meta.unbox(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    monkeypatch.setattr(kv_cache, "zeros", lambda shape, dtype, sh=None:
+                        jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype)))
+    monkeypatch.setattr(InferenceEngine, "_compile_prefill_tiles",
+                        lambda self: None)
+    monkeypatch.setattr(sparse_attention, "_kernel_reads",
+                        decode_attention.fits)
+    monkeypatch.setattr(sparse_attention, "_tile_kernel_takes",
+                        tile_attention.fits)
+    engine = dict(cfg["engine"], prefix_cache_slots=0)
+    del engine["max_ongoing_requests"]
+    eng = InferenceEngine(model, params, EngineConfig(**engine))
+    S, tile = eng.config.n_slots, eng._prefill_tiles[-1]
+    if program == "decode":
+        fn, args = eng._decode_fn, (
+            eng.params, *eng._slots.pools(), eng._carry,
+            np.zeros((S,), np.int32))
+    else:
+        fn, args = eng._prefill_fn, (
+            eng.params, *eng._slots.new_scratch(), *eng._slots.pools(),
+            eng._carry, eng._tile_args(
+                tile, np.zeros((tile,), np.int32), 0, 0, False, 0.0, []))
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        np.shape(a), a.dtype, sharding=one_chip), args)
+    return eng, fn.lower(*args).compile().as_text()
+
+
 @pytest.mark.parametrize("program", ["decode", "tile"])
 def test_step_programs_keep_a_layers_kv_in_fast_memory(
         one_chip, no_compile_cache, monkeypatch, program):
@@ -164,50 +211,13 @@ def test_step_programs_keep_a_layers_kv_in_fast_memory(
     compiler's memory-space assignment left one of the two in HBM and the
     attention's ops ran 1.6 times as long; `decode_prog_ms` 20.13 -> 23.27,
     `prefill_prog_ms` 24.57 -> 28.19, my chip run, PR 43; PERF.md section
-    6. With no copy there is nothing to drop.) `_kernel_reads` asks for
-    the backend, which here is the CPU's: it is made to answer as on the
-    chip, so that the program compiled is the one the cell runs. The engine
-    is built on shapes: nothing is allocated and nothing runs."""
-    import json
+    6. With no copy there is nothing to drop.) The dense model's tile
+    keeps its own form (`_cached_attention` against its scratch): no tile
+    kernel in either program."""
     import re
-
-    import numpy as np
-
-    from perfbench import spec
-    from perfbench.families import mistral
-    from ray_tpu.inference import kv_cache
-    from ray_tpu.inference.engine import EngineConfig, InferenceEngine
-    from ray_tpu.models import sparse_attention
-    from ray_tpu.ops import decode_attention
-    with open(os.path.join(spec.ROOT, "perfbench", "configs",
-                           "mistral-7b.json")) as f:
-        cfg = json.load(f)
-    model = mistral.build_model(mistral.model_kwargs(cfg))
-    params = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
-    monkeypatch.setattr(kv_cache, "zeros", lambda shape, dtype, sh=None:
-                        jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype)))
-    monkeypatch.setattr(InferenceEngine, "_compile_prefill_tiles",
-                        lambda self: None)
-    monkeypatch.setattr(sparse_attention, "_kernel_reads",
-                        decode_attention.fits)
-    engine = dict(cfg["engine"], prefix_cache_slots=0)
-    del engine["max_ongoing_requests"]
-    eng = InferenceEngine(model, params, EngineConfig(**engine))
-    S, tile = eng.config.n_slots, eng._prefill_tiles[-1]
+    eng, text = _step_program_text("mistral-7b", program, one_chip,
+                                   monkeypatch)
     assert eng._slots.shape == (20, 16, 2048, 8, 128)
-    if program == "decode":
-        fn, args = eng._decode_fn, (
-            eng.params, *eng._slots.pools(), eng._carry,
-            np.zeros((S,), np.int32))
-    else:
-        fn, args = eng._prefill_fn, (
-            eng.params, *eng._slots.new_scratch(), *eng._slots.pools(),
-            eng._carry, eng._tile_args(
-                tile, np.zeros((tile,), np.int32), 0, 0, False, 0.0, []))
-    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-        np.shape(a), a.dtype, sharding=one_chip), args)
-    text = fn.lower(*args).compile().as_text()
     # no op makes a layer of a pool, and none copies a pool
     assert not re.findall(r"= bf16\[16,2048,8,128\]", text)
     assert not re.findall(r"= bf16\[20,16,2048,8,128\]\S* copy\(", text)
@@ -216,6 +226,48 @@ def test_step_programs_keep_a_layers_kv_in_fast_memory(
     assert views == ["bitcast", "bitcast"], views
     assert text.count("tpu_custom_call") == 1 and re.search(
         r"%pool_decode_attention\S* = .* custom-call\(", text)
+
+
+TILE_KERNEL_CELLS = {
+    # (layers that attend, those of them by position, KV heads, G,
+    # positions of the scratch by position): Trinity S S F S S (four rings
+    # beside the one cache by position), Falcon-H1's six
+    "trinity-large-preview": (5, 1, 8, 6, 26624),
+    "falcon-h1-34b": (6, 6, 4, 5, 9216),
+}
+
+
+@pytest.mark.parametrize("config", list(TILE_KERNEL_CELLS))
+def test_tile_programs_attend_through_the_flash_kernel(
+        one_chip, no_compile_cache, monkeypatch, config):
+    """The tile programs of `trinity-large-preview.longdoc-report` (1,024
+    rows; four rings of 5,120 places under a window of 4,096 and one cache
+    of 26,624 positions; 48 heads over 8) and `falcon-h1-34b.rag-answer`
+    (1,024 rows against 9,216 positions, 20 heads over 4, six layers) at
+    the cells' own sizes: every layer that attends holds the kernel of
+    ops/tile_attention.py ONCE, which is what the engine's counters say
+    (`tile_attn_layers` / `tile_kernel_layers` a dispatch), and the XLA
+    loop's float32 carry `[1, Hkv, G, 1024, 128]`, which went through HBM
+    every key block, is in no op. A layer's K and V of the scratch by
+    position reach the kernel as `[M, Hkv * 128]`, one relayout each (the
+    scratch is tiled over (Hkv, 128)) with the tile's rows written into
+    it, and are copied no second time (written into the scratch first,
+    Trinity's 54 MB layer was copied twice more for K and for V, 0.68 ms a
+    tile: my chip run, PR 48). The decode rows that ride behind the tile
+    keep the pool kernel, once a layer that attends."""
+    import re
+    layers, by_position, Hkv, G, M = TILE_KERNEL_CELLS[config]
+    eng, text = _step_program_text(config, "tile", one_chip, monkeypatch)
+    assert eng._tile_layers == {1024: (layers, layers)}
+    assert len(re.findall(r"%tile_attention\S* = .* custom-call\(",
+                          text)) == layers
+    assert len(re.findall(r"%pool_decode_attention\S* = .* custom-call\(",
+                          text)) == layers
+    assert text.count("tpu_custom_call") == 2 * layers
+    assert f"f32[1,{Hkv},{G},1024,128]" not in text
+    assert len(re.findall(rf"= bf16\[{M},{Hkv * 128}\]\S* fusion\(",
+                          text)) == 2 * by_position
+    assert not re.search(rf"= bf16\[(1,)*{M},{Hkv},128\]\S* copy\(", text)
 
 
 def test_flash_by_name_never_returns_the_reference():

@@ -301,6 +301,12 @@ def test_engine_counts_the_window_and_refuses_what_carries_no_ring(small):
     # and passes over the whole ring (the XLA loop, blocks of 8)
     assert st["win_rows_live"] == 5 * WINDOW
     assert st["win_rows_streamed"] == 5 * 24
+    # five tiles of 8 rows hold the prompt's 40: each dispatch sends the
+    # five layers' tiles (S S F S S) through `_tile_attention`, and on the
+    # CPU the Pallas kernel takes none of them
+    assert st["prefill_dispatches"] == 5
+    assert st["tile_attn_layers"] == 5 * 5
+    assert st["tile_kernel_layers"] == 0
     with pytest.raises(ValueError, match="beyond K and V"):
         _engine(model, params, prefix_cache_slots=2)
     with pytest.raises(ValueError, match="beyond K and V"):
