@@ -9,11 +9,10 @@ from ray_tpu.inference.scheduler import (FINISH_CANCELLED, FINISH_DEADLINE,
                                          FINISH_EOS, FINISH_LENGTH,
                                          Request, RequestHandle, Scheduler)
 from ray_tpu.inference.api import LLMDeployment
-from ray_tpu.inference.spec_decode import SpecDecodeConfig
 from ray_tpu.inference.kv_quant import slot_gain as kv_quant_slot_gain
 
 __all__ = ["EngineConfig", "InferenceEngine", "LLMDeployment",
            "RadixPrefixCache", "Request", "RequestHandle", "Scheduler",
-           "SpecDecodeConfig", "kv_quant_slot_gain",
+           "kv_quant_slot_gain",
            "FINISH_CANCELLED", "FINISH_DEADLINE", "FINISH_EOS",
            "FINISH_LENGTH"]

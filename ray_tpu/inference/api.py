@@ -88,7 +88,7 @@ class LLMDeployment:
                  stream_coalesce_tokens: int = 8,
                  stream_coalesce_ms: float = 20.0,
                  weights_key: Optional[str] = "auto",
-                 spec_decode=None, kv_quant: str = "none"):
+                 kv_quant: str = "none"):
         import jax
 
         self.model = _resolve_model(model)
@@ -124,12 +124,9 @@ class LLMDeployment:
                            temperature=temperature, top_k=top_k,
                            top_p=top_p, kv_quant=kv_quant,
                            prefix_cache_slots=max(0, int(prefix_cache_slots)))
-        # spec_decode: None | SpecDecodeConfig | kwargs dict — draft-model
-        # speculative decoding (inference/spec_decode.py); greedy output
-        # is bit-identical to non-speculative serving, only throughput
-        # moves. kv_quant="int8" halves+ the prefix-block HBM footprint.
+        # kv_quant="int8" halves+ the prefix-block HBM footprint.
         self.engine = InferenceEngine(self.model, params, cfg, mesh=mesh,
-                                      seed=seed, spec=spec_decode)
+                                      seed=seed)
         self._metrics = _EngineMetrics()
         self.engine.on_step = self._metrics.on_step
         self.engine.start()

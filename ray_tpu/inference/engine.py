@@ -1,67 +1,67 @@
-"""Continuous-batching inference engine: a fixed-shape KV slot pool and
-a persistent decode loop.
+"""Continuous-batching inference engine: fixed-shape slots, a carry on
+the device, and a loop that runs one step program after another.
 
-Architecture (the TPU-serving shape — cf. slot-based continuous
-batching in the Gemma-on-TPU serving stack):
+What it owns:
 
-- The engine owns ``n_slots`` KV-cache slots, allocated once as two
-  pools (K and V) of ``[n_layers, n_slots, max_len, Hkv, D]`` and, for
-  a model with a sparse-attention indexer, a third of its keys,
-  ``[n_layers, n_slots, 1, DI, max_len]``
-  (kv_cache.py ``SlotPool``; a model with layers of several kinds keeps
-  each kind's pools over that kind's layers: K, V and pooled keys for its
-  block-sparse layers, a float32 state with no position axis for its
-  linear ones, and for layers of attention heads beside a state-space
-  mixer K and V AND two such states, the mixer's and its convolution's
-  tail; the prefix blocks and their fp/int8 format are its
-  ``BlockStore``, which holds K and V only: a model with any cache beyond
-  those runs without a prefix cache) and donated through every step. The
-  cached forward's layer loop only reads a pool; one write after the loop adds
-  the step's new rows of all layers to it (models/transformer.py
-  ``_decode``, ``_cache_write``), so the program's output pool IS the donated input
-  buffer: the decode step compiles exactly ONCE and then mutates the
-  pool in place for the life of the engine, moving ``n_slots`` rows a
-  layer and never the pool.
-- Each iteration of the loop (a) admits queued prompts via *chunked
-  prefill* under a per-step prefill-token budget — a long prompt is
-  split across steps, and the tokens one step gives one request run as
-  ONE fixed-shape tile through the cached-attention path
-  (``chunked_prefill=True``; the forward is told which of the tile's
-  rows the request owns, its padded tail is zeros) into a scratch cache,
-  so admission never stalls in-flight decodes for more than
-  ``prefill_budget`` tokens of work; the tile is chosen from the
-  prompt's length, so every token of a prompt passes through one
-  program whatever shared its steps — and (b) advances EVERY occupied
-  slot one token (per-slot ``idx`` vector: each row attends and writes
-  at its own length). In a step that carries prompt, (a) and (b) are ONE
-  program (``prefill``): the sequence is the tile's rows and behind them
-  one decode row a slot, everything but attention runs once over all of
-  them, so every weight streams once a step; a step with no prompt runs
-  the decode program (``decode``), and the two are the only step
-  programs. The tile's K/V never depend on the rows behind it. (Two
-  engines keep two calls a step: with a speculative draft, whose step
-  replaces decode, and with a model that has an indexer, whose decode
-  row is not bound by the weights' stream; ``_build_fns``.)
-- Between two step programs the engine's thread does only what the next
-  program needs (``step``: read, decide, plan, dispatch, deliver). The
-  slots' CARRY (lengths, last tokens, temperatures, the key) lives on
-  the device and only the programs write it; the host hands each program
-  ONE packed array (the live mask, a tile's tokens and place) and keeps a
-  mirror of the lengths. A step's programs are issued back to back and
-  read once; what the read decides is delivered to consumers behind the
-  NEXT step's dispatch, under the program then running.
-- Tokens stream out per request through ``RequestHandle`` queues;
-  slots are evicted (and immediately reusable) on EOS, max-tokens,
-  slot-capacity, cancellation, or deadline.
+- ``n_slots`` decode slots, allocated once as the model's pools (K and V,
+  ``[n_layers, n_slots, max_len, Hkv, D]``, and whatever else the model's
+  layers keep: models/transformer.py ``cache_shapes`` names them,
+  kv_cache.py ``SlotPool`` holds them) and donated through every step
+  program. The cached forward's layers only read a pool; one write after
+  them adds the step's new rows of all layers (``TransformerLM._decode``),
+  so a program's output pool IS its donated input: the pools are mutated
+  in place for the life of the engine. The prefix cache's blocks
+  (``BlockStore``, fp or int8) hold K and V only: a model with any cache
+  beyond those runs without a prefix cache.
+- The slots' CARRY, one int32 array on the device that only the step
+  programs write and that is all the host reads of a step: the slots'
+  lengths [S], their last tokens [S], their temperatures' bits [S], the
+  tokens the decode program last consumed [S] (a prompt's first token,
+  where its tile wrote it), the key's two words, and where expert rows are
+  counted their running sum [2] (``_build_fns``). The host keeps a mirror
+  of the lengths and says what it alone knows in ONE packed array a
+  program: the live mask (how an eviction or a cancel reaches the carry)
+  and a tile's tokens, place, slot and temperature.
 
-Shapes are static everywhere — the carry's vectors [n_slots], prompt
-tiles [1, T] (with the slots' rows, [1, T + n_slots]) with T
-``prefill_budget`` and, where that is four chunks or more, a handful of
-shorter lengths (``prefill_tiles``), every one compiled when the engine
-is built — so XLA compiles the tile programs, the slot insert and the
-decode step, and nothing ever recompiles across admissions/evictions.
-``decode_compile_count`` counts decode retraces; tests assert it stays
-at 1.
+A step (``step``) runs read, decide, plan, dispatch, deliver, and between
+two step programs the engine's thread does only what the next one needs:
+
+- plan: reap cancels and deadlines, evict full slots, spend the prefill
+  budget. A long prompt is split across steps (*chunked prefill*); what a
+  step gives one request runs as ONE fixed-shape tile into a scratch
+  cache, the tile chosen from the prompt's length, so every token of a
+  prompt passes through one program whatever shared its steps.
+- dispatch: the step's programs back to back, no call waiting for a value.
+  A step has one of TWO shapes. Where decode rows ride (every model but
+  one with a sparse-attention indexer), a step that carries prompt is ONE
+  program (``prefill``): the tile's rows and behind them one decode row a
+  slot, everything but attention run once over all of them, so every
+  weight streams once a step; a step with no prompt runs the decode
+  program (``decode``: every occupied slot one token, each row attending
+  and writing at its own length). Where they do not ride (the indexer's
+  model, whose decode row is not bound by the weights' stream), the tile
+  programs run without rows and the decode program right behind them. The
+  tile's K/V never depend on the rows behind it.
+- deliver: under the programs now running, what the step BEFORE decided
+  goes to its consumers (tokens to ``RequestHandle`` queues, finishes,
+  the recorder's spans).
+- read: once, the carry as the last program left it.
+- decide: each live request's token is appended, its finish decided (EOS,
+  max tokens; slot capacity, cancellation and deadline at the next plan)
+  and its slot freed, at once reusable. What was decided is held for the
+  next step's deliver; where no work follows it is delivered at once.
+
+Shapes are static everywhere: the carry, prompt tiles [1, T] (with the
+slots' rows, [1, T + n_slots]) with T ``prefill_budget`` and, where that
+is four chunks or more, a handful of shorter lengths (``prefill_tiles``),
+every one compiled when the engine is built. XLA compiles the tile
+programs, the slot insert and the decode step, and nothing recompiles
+across admissions and evictions: ``decode_compile_count`` counts decode
+retraces and tests assert it stays at 1.
+
+What the rows and tiles read of the pools is counted by host arithmetic
+on the lengths, which the model layer owns (``decode_rows_read``,
+``tile_attention_layers``): this module knows no kind of layer by name.
 
 Sampling is shared with ``make_generate_fn`` via models/sampling.py:
 greedy engine output is bit-identical to the one-program generator.
@@ -70,7 +70,6 @@ greedy engine output is bit-identical to the one-program generator.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -246,7 +245,7 @@ class InferenceEngine:
     `tensor`, same as make_generate_fn's cache)."""
 
     def __init__(self, model, params, config: Optional[EngineConfig] = None,
-                 mesh=None, rules=None, seed: int = 0, spec=None):
+                 mesh=None, rules=None, seed: int = 0):
         import jax
         import jax.numpy as jnp
 
@@ -256,35 +255,20 @@ class InferenceEngine:
         self.mesh = mesh
         cfg = self.config
         mcfg = model.cfg
-        from ray_tpu.inference import spec_decode as spec_lib
-        # speculative decoding (spec_decode.py): both slot pools grow by
-        # k positions so the fixed [len, len+k+1) verify write window
-        # never clamps back onto live entries
-        self._spec = spec_lib.resolve_spec(spec)
-        self._spec_k = self._spec.k if self._spec is not None else 0
-        pool_len = cfg.max_len + self._spec_k
-        if pool_len > mcfg.max_seq_len:
+        if cfg.max_len > mcfg.max_seq_len:
             raise ValueError(
-                f"max_len={cfg.max_len} (+ spec k={self._spec_k}) exceeds "
-                f"the model's max_seq_len={mcfg.max_seq_len}")
-        self._draft_model = self._draft_params = None
-        if self._spec is not None:
-            self._draft_model, self._draft_params = spec_lib.resolve_draft(
-                self._spec, mcfg)
-            if pool_len > self._draft_model.cfg.max_seq_len:
-                raise ValueError(
-                    f"draft max_seq_len={self._draft_model.cfg.max_seq_len}"
-                    f" < max_len + k = {pool_len}")
-        from ray_tpu.models.transformer import cache_shapes
-        beyond = sorted(set(cache_shapes(mcfg, 1, 1)) - {"k", "v"})
-        if beyond and (cfg.prefix_cache_slots > 0 or self._spec is not None):
+                f"max_len={cfg.max_len} exceeds the model's "
+                f"max_seq_len={mcfg.max_seq_len}")
+        from ray_tpu.models import transformer
+        beyond = sorted(set(transformer.cache_shapes(mcfg, 1, 1))
+                        - {"k", "v"})
+        if beyond and cfg.prefix_cache_slots > 0:
             raise ValueError(
                 f"the model keeps caches beyond K and V ({', '.join(beyond)}"
                 f": an indexer's keys, pooled keys, a recurrent state, a "
                 f"convolution's tail, a sliding window's ring), "
-                f"which prefix blocks and a speculative draft's verify step "
-                f"do not carry: run it with prefix_cache_slots=0 and no "
-                f"spec")
+                f"which prefix blocks do not carry: run it with "
+                f"prefix_cache_slots=0")
         dtype = cfg.cache_dtype or mcfg.dtype
         self._kv_quant = kv_cache.check_format(cfg.kv_quant)
         self._lock = threading.RLock()
@@ -306,19 +290,16 @@ class InferenceEngine:
         # tile can never clamp its write window back onto real entries
         scratch_len = cfg.max_len + self._prefill_tiles[-1]
         self._slots = kv_cache.SlotPool(
-            mcfg, cfg.n_slots, pool_len, cfg.max_len, scratch_len, dtype,
+            mcfg, cfg.n_slots, cfg.max_len, cfg.max_len, scratch_len, dtype,
             mesh, rules)
-        # the draft's slots: the same positions (incl. the k padding)
-        # in the draft's own widths
-        self._draft_slots = None
-        if self._spec is not None:
-            self._draft_slots = kv_cache.SlotPool(
-                self._draft_model.cfg, cfg.n_slots, pool_len, cfg.max_len,
-                scratch_len, dtype, mesh, rules)
-        self._pools = [p for p in (self._slots, self._draft_slots)
-                       if p is not None]
+        # the pools' bytes as `stats()` names them: all of them, and of
+        # those beyond K and V the ones this model keeps
+        self._pool_bytes = {"kv_pool_bytes": self._slots.nbytes(), **{
+            key: self._slots.nbytes(names)
+            for key, names in transformer.POOL_BYTES_KEYS.items()
+            if names[0] in self._slots.shapes}}
         # prefix blocks: prefix_cache_slots more rows of a slot's shape,
-        # allocated after the slot pools
+        # allocated after the slots' pools
         self.prefix_cache = self._blocks = None
         if cfg.prefix_cache_slots > 0:
             from ray_tpu.inference.prefix_cache import RadixPrefixCache
@@ -334,20 +315,14 @@ class InferenceEngine:
                                prefix_cache=self.prefix_cache)
 
         # the host's mirror of the carry's lengths, for its own
-        # bookkeeping (`max_len` evictions, the dsa_* / blk_* counters):
-        # it knows them without reading
+        # bookkeeping (`max_len` evictions, the `*_rows_*` counters): it
+        # knows them without reading
         self._lengths = np.zeros((cfg.n_slots,), np.int32)
 
         self.decode_compile_count = 0
         self.prefill_compile_count = 0
         self.prefill_dispatches = 0
         self.prefill_tokens = 0       # real prompt tokens, not padding
-        # spec decode accounting (greedy rows only: sampled rows always
-        # force accept = 0 and would just dilute the rate)
-        self.spec_verify_compile_count = 0
-        self.draft_prefill_compile_count = 0
-        self.spec_tokens_proposed = 0
-        self.spec_tokens_accepted = 0
         self.steps = 0
         # steps in which a prefill span and live decode rows ran as ONE
         # program (of `steps`; the rest are decode-only, prefill with no
@@ -358,83 +333,24 @@ class InferenceEngine:
         # their consumers under this step's program
         self.issued_ahead = 0
         self.tokens_generated = 0
-        # sparse attention (a model with an indexer), summed over the
-        # decode rows of every step, all host arithmetic on the lengths:
-        # the positions live (the row's own among them); the positions
-        # the selection leaves a row to attend, min(live, topk): the
-        # selection's arithmetic, NOT the chip's reads; and the positions
-        # of K and V the decode attention passes over for it, which is
-        # what the chip reads of the cache (sparse_attention.
-        # decode_positions_read: whole key blocks, each row's own where
-        # the kernel runs, the longest live row's for every row where the
-        # XLA loop does)
-        self._topk = mcfg.index_topk if mcfg.index_heads else 0
-        self.dsa_rows_read = 0
-        self.dsa_rows_live = 0
-        self.dsa_rows_streamed = 0
-        if self._topk:
-            from ray_tpu.models import sparse_attention
-            self._dsa_streamed = functools.partial(
-                sparse_attention.decode_positions_read,
-                M=cfg.max_len, Hkv=mcfg.n_kv_heads, D=mcfg.head_dim)
-        # selection by block (a model with "blk" layers): the positions the
-        # selection leaves a decode row to attend (those up to its own of
-        # the blocks it selects) and the positions live, summed alike. Host
-        # arithmetic too, and the LEAST a kernel could read, not what the
-        # chip reads: block_decode_attention passes over the slot's whole
-        # length and masks what was not selected
-        self._blk = (mcfg.blk_size, mcfg.blk_topk) \
-            if "blk" in (mcfg.mixer_kinds or ()) else None
-        self.blk_rows_read = 0
-        self.blk_rows_live = 0
-        # a sliding window (a model with "win" layers), summed over the
-        # decode rows of every step, host arithmetic on the lengths too:
-        # the window's positions a row attends, min(length, window), its
-        # own among them; and the places of the ring its attention passes
-        # over (`decode_positions_read` of what the ring holds of the slot:
-        # all of it once the slot has filled it). A tile longer than the
-        # ring's slack would overwrite keys its own first rows attend
-        self._win = None
-        if "win" in (mcfg.mixer_kinds or ()):
-            from ray_tpu.models import sparse_attention
-            ring = mcfg.win_ring
-            if self._prefill_tiles[-1] + mcfg.window - 1 > ring:
-                raise ValueError(
-                    f"win_ring={ring} holds no tile of "
-                    f"{self._prefill_tiles[-1]} rows beside a window of "
-                    f"{mcfg.window}: it takes window + prefill budget")
-            self._win = (mcfg.window, ring, functools.partial(
-                sparse_attention.decode_positions_read,
-                M=ring, Hkv=mcfg.n_kv_heads, D=mcfg.head_dim))
-        self.win_rows_streamed = 0
-        self.win_rows_live = 0
-        # a tile of T rows: the layers whose tile attends its scratch by
-        # `_tile_attention` (the kinds "win", "att" and "hyb") and those
-        # of them the Pallas kernel takes (ops/tile_attention.py: on a
-        # TPU, where the shapes fit), known from the shapes each tile
-        # program is built on and summed over the prefill dispatches
-        from ray_tpu.models.transformer import tile_attention_layers
+        # what the decode rows read of the slots' pools, summed over the
+        # rows of every step: host arithmetic on the lengths, which the
+        # model layer does for each kind of attention it has
+        # (`decode_rows_read`: the `*_rows_*` keys of `stats()`)
+        self._rows_read_of = transformer.decode_rows_read(mcfg, cfg.max_len)
+        self._rows_read = self._rows_read_of([])
+        # a tile of T rows: the layers whose tile attends its scratch (or
+        # its ring, which must hold the tile beside its window) by
+        # `_tile_attention` and those of them the Pallas kernel takes
+        # (ops/tile_attention.py: on a TPU, where the shapes fit), known
+        # from the shapes each tile program is built on (the largest
+        # first: the one a ring too short is told of) and summed over the
+        # prefill dispatches
         self._tile_layers = {
-            T: tile_attention_layers(mcfg, T, scratch_len, dtype)
-            for T in self._prefill_tiles}
+            T: transformer.tile_attention_layers(mcfg, T, scratch_len, dtype)
+            for T in self._prefill_tiles[::-1]}
         self.tile_attn_layers = 0
         self.tile_kernel_layers = 0
-        # the dense model (no indexer, no kinds of layer): its decode rows
-        # read K and V in the slots' pools where they lie, so the same
-        # pair, host arithmetic on the lengths too: the positions a row
-        # attends, its own among them, and the positions its attention
-        # passes over (`decode_positions_read`: whole key blocks up to the
-        # row's last live one, and its own). A speculative draft's verify
-        # step scores k + 1 rows a slot another way and counts nothing
-        self._kv_streamed = None
-        if not (mcfg.index_heads or mcfg.mixer_kinds or self._spec):
-            from ray_tpu.models import sparse_attention
-            self._kv_streamed = functools.partial(
-                sparse_attention.decode_positions_read,
-                M=self._slots.shape[2], Hkv=mcfg.n_kv_heads,
-                D=mcfg.head_dim)
-        self.kv_rows_streamed = 0
-        self.kv_rows_live = 0
         # an expert layer that holds a share of its experts: [rows the
         # expert matmuls computed, picks of real rows that landed on a held
         # expert], summed over layers and calls (models/moe.py sows them).
@@ -497,16 +413,15 @@ class InferenceEngine:
         S = cfg.n_slots
         count_moe = self._count_moe
         # the slots' decode rows ride in the program of a step's prefill
-        # tile, and the weights stream once for both. Two engines keep
-        # the tile program without them and two calls a step: one with a
-        # speculative draft, whose own step replaces decode; and one whose
-        # model has an indexer: while its decode row sorted and gathered
-        # (8.8 of 15.55 ms) riding saved nothing (one program 57.2-59.4
-        # ms against 41.7 + 15.55) and a first token waited for the rows'
-        # work (my chip runs, PR 35; PERF.md section 6). The row reads
-        # its cache in place since PR 41 and this was not measured again
-        # (section 7)
-        ride = self._ride = self._spec is None and not model.cfg.index_heads
+        # tile, and the weights stream once for both. The other shape of
+        # a step, the tile program without them and two calls, is kept for
+        # a model with an indexer: while its decode row sorted and
+        # gathered (8.8 of 15.55 ms) riding saved nothing (one program
+        # 57.2-59.4 ms against 41.7 + 15.55) and a first token waited for
+        # the rows' work (my chip runs, PR 35; PERF.md section 6). The row
+        # reads its cache in place since PR 41 and this was not measured
+        # again (section 7)
+        ride = self._ride = not model.cfg.index_heads
 
         def forward(params, tokens, pools, idx, real=None, slots=None,
                     **kw):
@@ -668,57 +583,6 @@ class InferenceEngine:
         self._decode_fn = jax.jit(
             decode, donate_argnums=tuple(range(1, 2 + n)))
 
-        self._spec_step_fn = None
-        self._draft_prefill_fn = None
-        if self._spec is not None:
-            from ray_tpu.inference.spec_decode import build_spec_step
-            draft_model = self._draft_model
-
-            def _count_verify_trace():
-                # the fused draft+verify program REPLACES decode as the
-                # per-step program: both counters watch the same
-                # compile-once contract (tests assert 1 and 1)
-                self.decode_compile_count += 1
-                self.spec_verify_compile_count += 1
-
-            spec_step = build_spec_step(model, draft_model, self._spec.k,
-                                        top_k, top_p,
-                                        on_trace=_count_verify_trace)
-
-            def spec(params, dparams, pk, pv, dk, dv, state, host):
-                # the draft's step on the same carry: a live row's length
-                # grows by what it accepted and one, its last token is
-                # the last it emits. -> (carry, every position's choice
-                # [S, K+1] and behind them the accepted counts [S], the
-                # four pools)
-                lengths, toks, temps, _, rng, moe = unpack(state)
-                live = host != 0
-                out, acc, pk, pv, dk, dv, rng = spec_step(
-                    params, dparams, pk, pv, dk, dv, lengths, toks, rng,
-                    temps)
-                last = jnp.take_along_axis(out, acc[:, None], axis=1)[:, 0]
-                state = pack(lengths + jnp.where(live, acc + 1, 0),
-                             jnp.where(live, last, toks), temps, toks, rng,
-                             moe, 0)
-                return (state, jnp.concatenate([out.reshape(-1), acc]),
-                        pk, pv, dk, dv)
-
-            self._spec_step_fn = jax.jit(spec, donate_argnums=(2, 3, 4, 5))
-
-            def draft_prefill(dparams, sk, sv, tokens, pos0):
-                # prompt KV for the draft cache: same chunked path as
-                # the target's prefill, no sampling (the draft never
-                # emits during prefill)
-                self.draft_prefill_compile_count += 1
-                cache = {"k": sk, "v": sv, "idx": pos0}
-                _, new = draft_model.apply({"params": dparams}, tokens,
-                                           cache=cache,
-                                           chunked_prefill=True)
-                return new["k"], new["v"]
-
-            self._draft_prefill_fn = jax.jit(
-                draft_prefill, donate_argnums=(1, 2))
-
     def _new_carry(self, seed: int):
         """The slots' carry with the key of `seed` (`_build_fns` lays it
         out), made where it lives: on the device, replicated on a mesh
@@ -757,11 +621,11 @@ class InferenceEngine:
 
     def _compile_prefill_tiles(self):
         """Run every prefill tile on a throwaway scratch, so no request
-        is the first user of a shape: ``prefill_compile_count`` (and the
-        draft's) reads the family's size before the first submit and
-        never moves again. Each tile runs twice, on a new scratch and on
-        the one it handed back, as a prompt's first and later spans do:
-        on a mesh the two differ in sharding and XLA compiles each. The
+        is the first user of a shape: ``prefill_compile_count`` reads the
+        family's size before the first submit and never moves again. Each
+        tile runs twice, on a new scratch and on the one it handed back,
+        as a prompt's first and later spans do: on a mesh the two differ
+        in sharding and XLA compiles each. The
         slots' pools pass through with no slot live, and the carry with
         no slot written and its key as it was (a tile program draws from
         it and does not advance it); the warm-up's expert rows are
@@ -773,12 +637,6 @@ class InferenceEngine:
                 scratch = self._slots.new_scratch()
                 for _ in range(2):
                     scratch = self._call_prefill(scratch, host)[1]
-                if self._spec is not None:
-                    dk, dv = self._draft_slots.new_scratch()
-                    for _ in range(2):
-                        dk, dv = self._draft_prefill_fn(
-                            self._draft_params, dk, dv,
-                            np.zeros((1, tile), np.int32), np.int32(0))
                 events.record_instant(
                     "engine.compile", category="engine",
                     trace_id=self._trace_id, fn="prefill", tile=tile,
@@ -888,11 +746,11 @@ class InferenceEngine:
           each weight's read; a step's further spans run the same program
           with no slot live). A step with no prompt to prefill runs the
           decode program. A request whose prompt ends in this step
-          decodes from the next. (With a speculative draft or a model
-          with an indexer: the tile programs, then right behind them the
-          draft's step or the decode program for every occupied slot, the
-          new request among them.) The slots' CARRY (lengths, last
-          tokens, temperatures, the key; `_build_fns`) lives on the device
+          decodes from the next. (The other shape, a model with an
+          indexer: the tile programs, then right behind them the decode
+          program for every occupied slot, the new request among them.)
+          The slots' CARRY (lengths, last tokens, temperatures, the
+          key; `_build_fns`) lives on the device
           and only the programs write it; the host says what it alone
           knows in one packed array a program (the live mask, which is
           how an eviction or a cancel reaches the carry, and a tile's
@@ -926,8 +784,7 @@ class InferenceEngine:
         sched = self.sched
         ahead = sched.holding()     # the step before left tokens held
         for st in sched.reap(now):
-            for pool in self._pools:
-                pool.scratch.pop(st.rid, None)
+            self._slots.scratch.pop(st.rid, None)
         # capacity eviction BEFORE the step: a full slot has nowhere
         # to write its next token
         for st in sched.active_states():
@@ -941,7 +798,6 @@ class InferenceEngine:
             self._issue_prefill(span, now, active if ride and i == 0 else ())
         ended = [span.state for span in spans if span.is_last]
         rows = [(st, st.slot) for st in active]
-        drafted = None
         if not ride:
             # a prompt that ended in this step decodes in it: its first
             # token is in the carry (unless one token is all it may have)
@@ -949,7 +805,7 @@ class InferenceEngine:
                      if st.request.max_new_tokens > 1]
         slots = [slot for _, slot in rows]
         if rows and not ride:
-            drafted = self._issue_rows(slots)
+            self._issue_rows(slots)
         did = bool(spans or rows)
         self.issued_ahead += did and ahead
         # under the programs now running: what the step before decided,
@@ -983,8 +839,7 @@ class InferenceEngine:
                 step=self.steps, slots_active=len(rows),
                 slots_occupied=sched.occupancy(),
                 queue_depth=sched.queue_depth())
-            if self._spec is None:
-                self._rows_ran(slots)
+            self._rows_ran(slots)
         n_emitted = 0
         if rows or ended:
             ph.enter("read")
@@ -997,34 +852,12 @@ class InferenceEngine:
                 self.first_tokens += 1
                 self.prefill_span_s += now - st.admitted_t
                 sched.prefill_done(st, int(fed[st.slot]), now)
-            if drafted is not None:
-                # accepted prefix + one bonus token per slot. ALL
-                # accept-count control flow happens HERE, on
-                # materialized numpy values — a Python branch on the
-                # traced count inside the program is the classic
-                # retrace bug (rtlint RT002 fixture).
-                S = self.config.n_slots
-                drafted = np.asarray(drafted)
-                outs = drafted[:-S].reshape(S, -1)
-                for st, slot in rows:
-                    accepted = int(drafted[-S + slot])
-                    self._lengths[slot] += accepted + 1
-                    if st.temperature <= 0.0:
-                        self.spec_tokens_proposed += self._spec_k
-                        self.spec_tokens_accepted += accepted
-                    for j in range(accepted + 1):
-                        if st.slot is None:
-                            break    # finished (EOS / max tokens)
-                        self.tokens_generated += 1
-                        n_emitted += 1
-                        sched.decode_emit(st, int(outs[slot, j]), now)
-            else:
-                for st, slot in rows:
-                    if st.slot is None:
-                        continue     # its first token ended it
-                    self.tokens_generated += 1
-                    n_emitted += 1
-                    sched.decode_emit(st, int(toks[slot]), now)
+            for st, slot in rows:
+                if st.slot is None:
+                    continue     # its first token ended it
+                self.tokens_generated += 1
+                n_emitted += 1
+                sched.decode_emit(st, int(toks[slot]), now)
         self.steps += 1
         if self.on_step is not None:
             try:
@@ -1059,55 +892,23 @@ class InferenceEngine:
         return host[S:2 * S], host[3 * S:4 * S]
 
     def _rows_ran(self, slots):
-        """The decode rows of `slots` were issued: the mirror of their
-        lengths and what the counters make of them, host arithmetic."""
-        lens = [int(self._lengths[slot]) for slot in slots]
-        if self._topk:      # before the rows' own
-            self.dsa_rows_streamed += self._dsa_streamed(lens)
-        if self._kv_streamed:
-            self.kv_rows_streamed += self._kv_streamed(lens)
-        if self._win:       # (less each row's own, which no ring holds)
-            _, ring, streamed = self._win
-            self.win_rows_streamed += streamed(
-                [min(n, ring) for n in lens]) - len(slots)
+        """The decode rows of `slots` were issued: what the counters make
+        of their lengths, and the mirror of them."""
+        read = self._rows_read_of([int(n) for n in self._lengths[slots]])
+        for key, n in read.items():
+            self._rows_read[key] += n
         self._lengths[slots] += 1
-        for slot in slots:
-            live = int(self._lengths[slot])
-            if self._topk:      # the row attended itself too
-                self.dsa_rows_live += live
-                self.dsa_rows_read += min(live, self._topk)
-            if self._kv_streamed:
-                self.kv_rows_live += live
-            if self._win:
-                self.win_rows_live += min(live, self._win[0])
-            if self._blk:
-                # the row's own block is among the selected
-                # and holds the positions up to the row's only
-                size, topk = self._blk
-                self.blk_rows_live += live
-                self.blk_rows_read += min(
-                    live, size * topk - (-live % size))
 
     def _issue_rows(self, slots):
-        """Issue the decode program for the rows of `slots` (or, where
-        there is a draft, its step -> every position's choice and the
-        accepted counts, still on the device)."""
+        """Issue the decode program for the rows of `slots`."""
         self._phases.enter("dispatch")
         live = np.zeros((self.config.n_slots,), np.int32)
         live[slots] = 1
         compiles0 = self.decode_compile_count
-        pool, dpool = self._slots, self._draft_slots
-        out = None
         with self._mesh_ctx():
-            if self._spec is not None:
-                (self._carry, out, pool.k, pool.v, dpool.k,
-                 dpool.v) = self._spec_step_fn(
-                    self.params, self._draft_params, pool.k, pool.v,
-                    dpool.k, dpool.v, self._carry, live)
-            else:
-                self._carry, *new = self._decode_fn(
-                    self.params, *pool.pools(), self._carry, live)
-                pool.rebind(new)
+            self._carry, *new = self._decode_fn(
+                self.params, *self._slots.pools(), self._carry, live)
+            self._slots.rebind(new)
         if self.decode_compile_count > compiles0:
             # a decode retrace is THE perf cliff this engine is
             # built to avoid — make every occurrence a first-class
@@ -1116,7 +917,6 @@ class InferenceEngine:
                 "engine.compile", category="engine",
                 trace_id=self._trace_id, fn="decode",
                 compile_count=self.decode_compile_count)
-        return out
 
     def _prefill_spans(self, chunks: List[PrefillChunk]):
         """The step's plan, one piece a dispatch: the consecutive chunks
@@ -1199,16 +999,6 @@ class InferenceEngine:
                 # block store as device-side copies — no forward pass
                 # runs over [0, prefix_matched)
                 scratch = self._restore_prefix(st, scratch)
-        dk_dv = None
-        if self._spec is not None:
-            dk_dv = self._draft_slots.scratch.get(st.rid)
-            if dk_dv is None:
-                dk_dv = self._draft_slots.new_scratch()
-                if st.prefix_matched:
-                    # the block pool holds TARGET KV only; the (cheap)
-                    # draft re-prefills the matched range so its cache
-                    # stays aligned with the target's
-                    dk_dv = self._draft_replay(st, *dk_dv)
         prompt = st.request.tokens
         tile = self._tile_of(len(prompt))
         slots = [a.slot for a in active]
@@ -1221,10 +1011,6 @@ class InferenceEngine:
             rid=st.rid, slot=st.slot, offset=ch.start, length=ch.length,
             tile=tile, is_last=ch.is_last,
             live=ch.start + ch.length,    # positions the tile attended
-            # of them, those its rows attend in a "win" layer
-            **({"win_live": min(ch.start + ch.length,
-                                ch.length + self._win[0] - 1)}
-               if self._win else {}),
             decode_rows=len(active),      # slots advanced in its program
             slots_occupied=self.sched.occupancy())
         compiles0 = self.prefill_compile_count
@@ -1242,27 +1028,16 @@ class InferenceEngine:
                 parent_span_id=pspan.span_id, fn="prefill",
                 compile_count=self.prefill_compile_count)
         self._after.append((pspan.end, {"end": time.time()}))
-        if self._spec is not None:
-            with self._mesh_ctx():
-                dk_dv = self._draft_prefill_fn(
-                    self._draft_params, *dk_dv,
-                    host[None, self.config.n_slots + _TILE_HEAD:],
-                    np.int32(ch.start))
         if ch.is_last:
             if self.prefix_cache is not None:
                 self._populate_prefix(st, scratch)
             self._slots.insert(scratch, slot)
-            if self._spec is not None:
-                self._draft_slots.insert(dk_dv, slot)
-            for pool in self._pools:
-                pool.scratch.pop(st.rid, None)
+            self._slots.scratch.pop(st.rid, None)
             self._lengths[st.slot] = len(prompt)
         else:
             if self._write_through:
                 scratch = self._publish_chunk(st, scratch, ch)
             self._slots.scratch[st.rid] = scratch
-            if self._spec is not None:
-                self._draft_slots.scratch[st.rid] = dk_dv
             self.sched.advance_prefill(st, ch.length)
 
     # ------------------------------------------------------- prefix cache
@@ -1306,22 +1081,6 @@ class InferenceEngine:
             self._blocks.save(scratch, block, off)
             scratch = self._blocks.load(scratch, block, off)
         return scratch
-
-    def _draft_replay(self, st, dk, dv):
-        """Prefix-hit draft warmup: re-prefill the matched range through
-        the draft model (chunk-aligned by construction; prefix_matched
-        is a multiple of prefill_chunk)."""
-        import jax.numpy as jnp
-        C = self.config.prefill_chunk
-        prompt = st.request.tokens
-        with self._mesh_ctx():
-            for off in range(0, st.prefix_matched, C):
-                chunk = np.zeros((1, C), np.int32)
-                chunk[0, :] = prompt[off:off + C]
-                dk, dv = self._draft_prefill_fn(
-                    self._draft_params, dk, dv, jnp.asarray(chunk),
-                    np.int32(off))
-        return dk, dv
 
     # --------------------------------------------------- disagg hand-off
     def export_kv_blocks(self, tokens, max_chunks: Optional[int] = None):
@@ -1410,40 +1169,13 @@ class InferenceEngine:
             out["kv_exports"] = self.kv_exports
             out["kv_imports"] = self.kv_imports
             out["remote_prefix_tokens"] = self.remote_prefix_tokens
-        if self._spec is not None:
-            prop = self.spec_tokens_proposed
-            out["spec_k"] = self._spec_k
-            out["spec_verify_compile_count"] = \
-                self.spec_verify_compile_count
-            out["spec_tokens_proposed"] = prop
-            out["spec_tokens_accepted"] = self.spec_tokens_accepted
-            out["spec_accept_rate"] = (
-                round(self.spec_tokens_accepted / prop, 4) if prop
-                else 0.0)
         out.update(kv_cache.format_stats(
             self._kv_quant, self.model.cfg.head_dim, self._kv_itemsize))
-        out["kv_pool_bytes"] = sum(p.nbytes() for p in self._pools)
-        if "s" in self._slots.shapes:
-            out["state_pool_bytes"] = self._slots.nbytes(("s",))
-        if "c" in self._slots.shapes:
-            out["conv_pool_bytes"] = self._slots.nbytes(("c",))
-        if self._win:
-            out["win_pool_bytes"] = self._slots.nbytes(("wk", "wv"))
-            out["win_rows_streamed"] = self.win_rows_streamed
-            out["win_rows_live"] = self.win_rows_live
+        out.update(self._pool_bytes)
         if any(layers for layers, _ in self._tile_layers.values()):
             out["tile_attn_layers"] = self.tile_attn_layers
             out["tile_kernel_layers"] = self.tile_kernel_layers
-        if self._kv_streamed:
-            out["kv_rows_streamed"] = self.kv_rows_streamed
-            out["kv_rows_live"] = self.kv_rows_live
-        if self._topk:
-            out["dsa_rows_read"] = self.dsa_rows_read
-            out["dsa_rows_live"] = self.dsa_rows_live
-            out["dsa_rows_streamed"] = self.dsa_rows_streamed
-        if self._blk:
-            out["blk_rows_read"] = self.blk_rows_read
-            out["blk_rows_live"] = self.blk_rows_live
+        out.update(self._rows_read)
         if self._count_moe:
             out["moe_rows_computed"] = int(self._moe_counts[0])
             out["moe_local_picks"] = int(self._moe_counts[1])

@@ -25,8 +25,7 @@ what the engine keeps in that layout and nothing else knows how:
   p mod ``win_ring``, in a slot and in a scratch alike, so a slot takes
   the scratch's ring whole too (the places a short prompt never wrote
   hold the fresh scratch's zeros, and the attention masks every place
-  whose position is not in the row's window). Built twice: for the
-  target and for a speculative draft.
+  whose position is not in the row's window).
 - ``BlockStore``: the prefix cache's blocks. A block's FORMAT is the
   tuple of arrays that hold it, which is also its wire form between
   replicas: ``"none"`` = (k, v) in the cache dtype; ``"int8"`` =
@@ -88,7 +87,7 @@ class SlotPool:
     model has an indexer (None where not), or whatever else
     ``cache_shapes`` names (each an attribute of its name, in the type
     ``cache_dtype`` gives it), sharded as the layout says
-    (pruned against THIS model's shape: a draft's KV heads may not
+    (pruned against THIS model's shape: a single KV head does not
     divide the tensor axis). The step programs take them donated, as the
     tuple ``pools()``, and the engine ``rebind``s them after each call.
     ``scratch`` holds the same tuple of one row of ``scratch_len``
@@ -105,11 +104,6 @@ class SlotPool:
         self.shapes = cache_shapes(mcfg, n_slots, length)
         self.scratch_shapes = cache_shapes(mcfg, 1, scratch_len)
         self.dtypes = {n: cache_dtype(n, dtype) for n in self.shapes}
-        # K's (and V's) own, as they were named before there was a third
-        self.shape = self.shapes.get("k")
-        self.scratch_shape = self.scratch_shapes.get("k")
-        self.sharding = (kv_cache_sharding(self.shape, mesh, rules)
-                         if mesh is not None and self.shape else None)
         self._scratch_sharding = replicated(mesh)
         self.k = self.v = self.ki = None
         self.rebind(tuple(

@@ -311,7 +311,7 @@ def decode_positions_read(lens, M: int, Hkv: int, D: int) -> int:
     loop does."""
     block = decode_attention.block_of(M)
     if not _kernel_reads(M, Hkv, D):
-        lens = [max(lens)] * len(lens)
+        lens = [max(lens, default=0)] * len(lens)
     return sum(-(-n // block) * block + 1 for n in lens)
 
 

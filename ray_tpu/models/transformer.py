@@ -269,18 +269,17 @@ def rope(x, positions, theta: float):
 
 def _cached_attention(q, k_cache, v_cache, q_pos0):
     """S rows at once against a padded KV cache, every position of it
-    scored: for a SMALL cache (`generate.py`'s, the speculative verify
-    step's k + 1 rows, a dense tile against its scratch). One row a slot
+    scored: for a SMALL cache (`generate.py`'s decode rows, and the tile
+    of a model without kinds against its scratch). One row a slot
     against the slot pools does not come here: it reads its slot's live
     key blocks where they lie (`_row_attention`).
 
     q [B,S,H,D] are the S newest positions (absolute start q_pos0);
     caches [B,M,Hkv,D] already contain the new keys/values written at
     [q_pos0, q_pos0+S). q_pos0 is a scalar (shared start, the
-    make_generate_fn shape) or a [B] vector (per-row starts, the verify
-    step's). Mask: query i attends cache slots j <= q_pos0+i (causal
-    over absolute positions; padded tail masked out). Plain dot-product
-    in fp32."""
+    make_generate_fn shape) or a [B] vector (per-row starts). Mask: query
+    i attends cache slots j <= q_pos0+i (causal over absolute positions;
+    padded tail masked out). Plain dot-product in fp32."""
     B, S, H, D = q.shape
     M, Hkv = k_cache.shape[1], k_cache.shape[2]
     # GQA via grouped einsum against the UNEXPANDED cache: a repeat of
@@ -471,8 +470,15 @@ def tile_attention_layers(cfg: "TransformerConfig", tile: int,
     """Host arithmetic on what a tile program is built from: (the layers
     whose tile of `tile` rows goes through `_tile_attention`, a "win"
     layer's against its ring, an "att" or "hyb" layer's against
-    `scratch_len` positions; those of them the Pallas kernel takes)."""
+    `scratch_len` positions; those of them the Pallas kernel takes). A
+    tile longer than the ring's slack beside the window would overwrite
+    keys its own first rows attend, and is refused."""
     from ray_tpu.models import sparse_attention as sa
+    if "win" in (cfg.mixer_kinds or ()) \
+            and tile + cfg.window - 1 > cfg.win_ring:
+        raise ValueError(
+            f"win_ring={cfg.win_ring} holds no tile of {tile} rows beside "
+            f"a window of {cfg.window}: it takes window + prefill budget")
     same = jnp.dtype(cache_dtype or cfg.dtype) == jnp.dtype(cfg.dtype)
     layers = kernel = 0
     for kind in cfg.mixer_kinds or ():
@@ -484,6 +490,55 @@ def tile_attention_layers(cfg: "TransformerConfig", tile: int,
         kernel += bool(same and sa._tile_kernel_takes(
             tile, M, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, window))
     return layers, kernel
+
+
+def decode_rows_read(cfg: "TransformerConfig", slot_len: int):
+    """Host arithmetic on what a decode program is built from: a function
+    of the lengths of the slots whose rows a step issued (before the rows'
+    own; slots of `slot_len` positions) -> {counter: its increment}, for
+    each kind of attention the model has and nothing for a kind it has
+    not. `*_live`: the positions live, the row's own among them (in a
+    "win" layer those of its window). `*_streamed`: the positions of K
+    and V the row's attention passes over, which is what the chip reads of
+    the pool (`decode_positions_read`: whole key blocks; of a ring what it
+    holds of the slot, less the row's own, which no ring holds).
+    `*_read`: the positions a selection leaves the row to attend, the
+    selection's arithmetic and the LEAST a kernel could read, not the
+    chip's reads: min(live, `index_topk`) under an indexer; in a "blk"
+    layer the positions up to the row's own of the blocks it selects, its
+    own block among them (`block_decode_attention` passes over the slot's
+    whole length and masks the rest). A model without kinds or indexer
+    reads K and V in the pools where they lie (`_row_attention`):
+    `kv_rows_*`; an indexer's rows `dsa_rows_*`; "hyb", "att" and "lin"
+    layers are not counted."""
+    from ray_tpu.models.sparse_attention import decode_positions_read
+    kinds = cfg.mixer_kinds or ()
+    Hkv, D = cfg.n_kv_heads, cfg.head_dim
+
+    def read(lens):
+        live = [n + 1 for n in lens]
+        out = {}
+        if cfg.index_heads or not kinds:
+            streamed = decode_positions_read(lens, slot_len, Hkv, D)
+            if cfg.index_heads:
+                out.update(
+                    dsa_rows_read=sum(min(n, cfg.index_topk) for n in live),
+                    dsa_rows_live=sum(live), dsa_rows_streamed=streamed)
+            else:
+                out.update(kv_rows_streamed=streamed, kv_rows_live=sum(live))
+        if "blk" in kinds:
+            size, most = cfg.blk_size, cfg.blk_size * cfg.blk_topk
+            out.update(
+                blk_rows_read=sum(min(n, most - (-n % size)) for n in live),
+                blk_rows_live=sum(live))
+        if "win" in kinds:
+            ring = cfg.win_ring
+            out.update(
+                win_rows_streamed=decode_positions_read(
+                    [min(n, ring) for n in lens], ring, Hkv, D) - len(lens),
+                win_rows_live=sum(min(n, cfg.window) for n in live))
+        return out
+    return read
 
 
 def _row_attention(q, k_new, v_new, k_pool, v_pool, layer, lens,
@@ -1129,6 +1184,10 @@ CACHE_POS_AXIS = {"k": -3, "v": -3, "ki": -1, "kp": -3, "wk": -3,
 # axis of `win_ring` places whatever the cache's length, position p lies at
 # p mod win_ring, and a slot takes the scratch's ring whole
 CACHE_RINGS = ("wk", "wv")
+# the keys of `engine.stats()` that give the bytes of the slots' pools
+# beyond K and V (`kv_pool_bytes` is all of them), and the pools of each
+POOL_BYTES_KEYS = {"state_pool_bytes": ("s",), "conv_pool_bytes": ("c",),
+                   "win_pool_bytes": CACHE_RINGS}
 
 
 def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int):

@@ -8,8 +8,8 @@ gang-places the ranks — PACK on commodity nodes, STRICT_SPREAD over one
 slice's hosts with a ``topology`` — and joins them into one
 jax.distributed world). Each rank builds the SAME model over the same
 global mesh from the same seed, so the continuous-batching engine's
-fixed-shape programs (prefill chunk / insert / decode or the fused
-spec-decode step) are identical SPMD programs on every rank:
+fixed-shape programs (prefill tile / insert / decode) are identical
+SPMD programs on every rank:
 
 - rank 0 owns admission and streaming — routers hold only the rank-0
   facade; a streamed request fans out so every rank's generator drives
@@ -31,11 +31,8 @@ spec-decode step) are identical SPMD programs on every rank:
   group together. Severed streams re-route with ``resume_tokens`` —
   exactly-once token delivery, greedy-identical continuation.
 
-Raw-speed multipliers (both compile-once, both optional):
-``spec_decode=`` stacks draft-model speculative decoding (exactly one
-extra fixed-shape verify program; greedy output bit-identical to
-non-speculative serving) and ``kv_quant="int8"`` doubles+ the prefix
-block count per HBM byte (inference/kv_quant.py).
+``kv_quant="int8"`` doubles+ the prefix block count per HBM byte
+(inference/kv_quant.py; compile-once like the rest).
 
 Chaos: :class:`~ray_tpu.util.chaos.GangRankKiller` arms
 ``RAY_TPU_TESTING_RPC_FAILURE="gang_rank=p"``; a NON-ZERO rank checks
@@ -79,8 +76,8 @@ class ShardedEngineReplica:
     works identically: the gang is then one rank over the local
     devices.
 
-    Engine knobs mirror :class:`LLMDeployment`; ``spec_decode`` /
-    ``kv_quant`` thread through to the engine. ``mesh=None`` builds
+    Engine knobs mirror :class:`LLMDeployment`; ``kv_quant`` threads
+    through to the engine. ``mesh=None`` builds
     :func:`default_serving_mesh` over the global device set.
     """
 
@@ -93,7 +90,7 @@ class ShardedEngineReplica:
                  temperature: float = 0.0, top_k: int = 0,
                  top_p: float = 1.0, params_fn=None, mesh=None,
                  seed: int = 0, prefix_cache_slots: int = 2,
-                 spec_decode=None, kv_quant: str = "none",
+                 kv_quant: str = "none",
                  stream_coalesce_tokens: int = 8,
                  stream_coalesce_ms: float = 20.0):
         import jax
@@ -116,8 +113,7 @@ class ShardedEngineReplica:
         # generators drive step() so every rank executes the identical
         # program sequence (module docstring)
         self.engine = InferenceEngine(self.model, params, cfg,
-                                      mesh=self.mesh, seed=seed,
-                                      spec=spec_decode)
+                                      mesh=self.mesh, seed=seed)
         self._stream_seq = 0
         self._last_digest: Optional[tuple] = None
         self._requests_served = 0
@@ -312,7 +308,7 @@ def build_sharded_app(model="llama-debug", *, num_hosts: int = 1,
                       **engine_kwargs):
     """One-call deployment graph for a sharded serving app:
     ``serve.run(build_sharded_app("llama-debug", num_hosts=4,
-    topology="v4-32", spec_decode={...}, kv_quant="int8"))``."""
+    topology="v4-32", kv_quant="int8"))``."""
     from ray_tpu import serve
     return serve.deployment(
         ShardedEngineReplica, name=name, num_hosts=num_hosts,

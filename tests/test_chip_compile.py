@@ -217,7 +217,7 @@ def test_step_programs_keep_a_layers_kv_in_fast_memory(
     import re
     eng, text = _step_program_text("mistral-7b", program, one_chip,
                                    monkeypatch)
-    assert eng._slots.shape == (20, 16, 2048, 8, 128)
+    assert eng._slots.shapes["k"] == (20, 16, 2048, 8, 128)
     # no op makes a layer of a pool, and none copies a pool
     assert not re.findall(r"= bf16\[16,2048,8,128\]", text)
     assert not re.findall(r"= bf16\[20,16,2048,8,128\]\S* copy\(", text)

@@ -199,14 +199,8 @@ def test_engine_counts_what_the_rows_read_and_attend(kind):
     assert 1.0 < st["kv_rows_streamed"] / st["kv_rows_live"]
 
 
-@pytest.mark.parametrize("kind,kw", [("indexer", {}),
-                                     ("dense", {"spec": "draft"})])
-def test_no_count_where_the_rows_go_another_way(kind, kw):
-    """An indexer's rows have their own pair (`dsa_rows_*`); a speculative
-    draft's verify step scores k + 1 rows a slot with `_cached_attention`."""
-    if kw:
-        draft, dparams = model_of("dense")
-        kw = {"spec": {"draft_model": draft,
-                       "draft_params_fn": lambda: dparams, "k": 2}}
-    st = engine_of(kind, **kw).stats()
+@pytest.mark.parametrize("kind", ["indexer"])
+def test_no_count_where_the_rows_go_another_way(kind):
+    """An indexer's rows have their own counters (`dsa_rows_*`)."""
+    st = engine_of(kind).stats()
     assert "kv_rows_streamed" not in st and "kv_rows_live" not in st

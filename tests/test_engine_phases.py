@@ -32,20 +32,16 @@ def _clean_recorder():
     events.drain()
 
 
-def _engine(n_slots=2, max_len=64, d_model=32, n_layers=2, draft_k=0,
-            **model):
-    cfg = TransformerConfig(vocab_size=64, d_model=d_model,
-                            n_layers=n_layers, n_heads=2, n_kv_heads=2,
-                            d_ff=2 * d_model, max_seq_len=max_len + draft_k,
-                            **model)
+def _engine(n_slots=2, max_len=64, d_model=32, n_layers=2, **model):
+    cfg = TransformerConfig(**dict(
+        vocab_size=64, d_model=d_model, n_layers=n_layers, n_heads=2,
+        n_kv_heads=2, d_ff=2 * d_model, max_seq_len=max_len) | model)
     lm = TransformerLM(cfg)
     params = lm.init(jax.random.PRNGKey(0),
                      np.zeros((1, 8), np.int32))["params"]
     return InferenceEngine(lm, params, EngineConfig(
         n_slots=n_slots, max_len=max_len, prefill_chunk=8,
-        prefill_budget=16), spec={
-            "draft_model": lm, "draft_params_fn": lambda: params,
-            "k": draft_k} if draft_k else None)
+        prefill_budget=16))
 
 
 # ------------------------------------------------ the profiler's own trace
@@ -220,13 +216,6 @@ def _decode_text(eng):
         np.zeros((eng.config.n_slots,), np.int32)).as_text()
 
 
-def _verify_text(eng):
-    pool, dpool = eng._slots, eng._draft_slots
-    return eng._spec_step_fn.lower(
-        eng.params, eng._draft_params, pool.k, pool.v, dpool.k, dpool.v,
-        eng._carry, np.zeros((eng.config.n_slots,), np.int32)).as_text()
-
-
 def _train_text(_):
     import optax
 
@@ -245,6 +234,25 @@ def _train_text(_):
 
 
 INDEXER = dict(index_heads=2, index_head_dim=16, index_topk=16)
+# the other four kinds a cell runs, each at its own model test's widths and
+# at the depth that holds every kind of layer it has once
+MOE = dict(n_experts=4, expert_top_k=2, capacity_factor=2.0)   # Mixtral's
+_KINDS = dict(d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=96,
+              dtype=jnp.float32, remat=False, scan_layers=False)
+BLK_LIN = dict(_KINDS, mixer_kinds=("lin", "blk"), qk_norm=True,
+               blk_size=4, blk_kernel=2, blk_stride=1, blk_window=8,
+               blk_topk=6, attn_rope=False, out_gate=True,
+               scale_emb=12, residual_scale=0.25, logit_scale=0.25)
+HYB = dict(_KINDS, n_heads=5, n_kv_heads=1, mixer_kinds=("hyb", "hyb"),
+           ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2,
+           key_mult=0.011, ssm_in_mult=0.25, ssm_out_mult=0.088,
+           ssm_mults=(0.35, 0.25, 0.18, 0.5, 0.35), mlp_mults=(0.18, 0.011))
+WIN_ATT = dict(_KINDS, n_layers=3, n_heads=6, logits_fp32=True,
+               mixer_kinds=("win", "att", "win"), window=16, win_ring=32,
+               n_experts=16, capacity_factor=16.0, router="sigmoid",
+               route_scale=2.448, expert_d_ff=24, n_shared_experts=1,
+               n_dense_layers=1, sandwich_norm=True, qk_norm=True,
+               attn_rope=False, out_gate=True, scale_emb=8.0)
 
 
 @pytest.mark.parametrize("model,text,digest", [
@@ -252,14 +260,24 @@ INDEXER = dict(index_heads=2, index_head_dim=16, index_topk=16)
     ({}, _tile_text, "fd9f5c4aa916b342"),
     (INDEXER, _decode_text, "8702c029e6542675"),
     (INDEXER, _tile_text, "2d7af74232ada8e8"),
-    (dict(draft_k=2), _verify_text, "8eadf6e765d8e455"),
+    (MOE, _decode_text, "cbbf4a6f0b472052"),
+    (MOE, _tile_text, "6ccc4bf6ba702693"),
+    (BLK_LIN, _decode_text, "f0e47b713fd975e2"),
+    (BLK_LIN, _tile_text, "0a6a860030ec656f"),
+    (HYB, _decode_text, "dc36b41b20baaf12"),
+    (HYB, _tile_text, "0a4b070ac3459947"),
+    (WIN_ATT, _decode_text, "e7f775e807a1e544"),
+    (WIN_ATT, _tile_text, "1067fd48cb7c2a56"),
     (None, _train_text, "8aecbfdae32759c2")],
     ids=["dense_decode", "dense_tile_with_rows", "indexer_decode",
-         "indexer_tile", "draft_verify", "train_step"])
+         "indexer_tile", "moe_decode", "moe_tile_with_rows",
+         "blk_lin_decode", "blk_lin_tile_with_rows", "hyb_decode",
+         "hyb_tile_with_rows", "win_att_decode", "win_att_tile_with_rows",
+         "train_step"])
 def test_the_step_programs_are_the_parents(model, text, digest):
-    """The lowered text of the decode program, of the tile program (with
-    the riding rows, and an indexer model's without), of a speculative
-    draft's verify step and of a training step. The training step's digest
+    """The lowered text of the decode program and of the tile program (with
+    the riding rows, and an indexer model's without) for every kind of
+    model a cell runs, and of a training step. The training step's digest
     is PR 41's tree's (read there by this same function, before the step
     was told by phase): the marks are the host's, no program recompiles
     for them and set-up has no reason to move. The four engine programs'
@@ -275,17 +293,21 @@ def test_the_step_programs_are_the_parents(model, text, digest):
     forward gathers them before the final norm and the head, so the head's
     dot has 1 + S rows (1 for the indexer's model) where it had T + S.
     Both decode programs and the training step passed with the digests
-    they had, and the verify step with PR 44's tree's (read there by
-    `_verify_text`): a cached call that names no rows is the program it
-    was, and the `lm_head` scope is metadata the lowered text does not
-    print. The three programs that hold a DENSE model's decode rows were
-    re-pinned on PR 47's final tree: the decode program, the tile program
-    with the riding rows, and the verify step (whose draft's own k + 1
-    steps are one row a slot): such a row attends its slot's live key
-    blocks in the pools where they lie (`_row_attention`) and no longer a
-    layer sliced out of the pool; tests/test_step_order.py holds their
-    greedy tokens to the parent's. The indexer's two programs and the
-    training step pass with the digests they had."""
+    they had: a cached call that names no rows is the program it was, and
+    the `lm_head` scope is metadata the lowered text does not print. The
+    two programs that hold a DENSE model's decode rows were re-pinned on
+    PR 47's final tree, the decode program and the tile program with the
+    riding rows: such a row attends its slot's live key blocks in the
+    pools where they lie (`_row_attention`) and no longer a layer sliced
+    out of the pool; tests/test_step_order.py holds their greedy tokens to
+    the parent's. The indexer's two programs and the training step pass
+    with the digests they had. The eight digests of the other four kinds
+    (all experts held, "blk" + "lin", "hyb", "win" + "att" with the
+    sigmoid router) were read by this function on PR 48's tree, at the
+    start of PR 49 and before a line of it was written. PR 49 removed the
+    speculative draft's step (and its pinned verify program with it) and
+    moved the rows' counters into the model layer: all thirteen passed
+    unchanged on its final tree, so it recompiled nothing."""
     eng = None if model is None else _engine(**model)
     got = hashlib.sha256(text(eng).encode()).hexdigest()[:16]
     assert got == digest

@@ -268,17 +268,10 @@ def test_the_tile_program_unembeds_the_rows_it_samples(small):
 
 
 def test_engine_refuses_what_does_not_carry_the_caches(small):
-    from ray_tpu.models.transformer import TransformerConfig
     m, model, params, _ = small
-    draft = TransformerConfig(vocab_size=VOCAB, d_model=32, n_layers=1,
-                              n_heads=2, n_kv_heads=2, d_ff=48,
-                              max_seq_len=512)
     with pytest.raises(ValueError, match="beyond K and V"):
         InferenceEngine(model, params, EngineConfig(
             **dict(ENGINE, prefix_cache_slots=1)))
-    with pytest.raises(ValueError, match="beyond K and V"):
-        InferenceEngine(model, params, EngineConfig(**ENGINE),
-                        spec={"draft_model": draft, "k": 2})
     ok = {"max_len": 480, "prefill_budget": 32}
     falcon_h1.model_kwargs(config(engine=ok))
     for bad, why in (({"prefix_cache_slots": 1}, "prefix_cache_slots"),

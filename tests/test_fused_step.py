@@ -7,8 +7,7 @@ programs a step: its decode row is not bound by the weights' stream).
 
 - greedy tokens over a schedule that mixes prefill and decode equal the
   one-request generator's (``make_generate_fn``: one-shot prefill, then
-  scalar-``idx`` decode, the two-program form), and a speculative draft's
-  engine (tile program without decode rows, then its own step) agrees;
+  scalar-``idx`` decode, the two-program form);
 - PR 28's rule: a prompt's K/V are the same bits whether its spans ran
   beside live slots, beside idle ones, or as a step's second span;
 - the tile programs and the decode program compile when the engine is
@@ -18,7 +17,7 @@ programs a step: its decode row is not bound by the weights' stream).
 - the tile program's head runs over the rows it samples (the prompt's
   would-be next token and the riding rows, 1 + S of T + S) and no dot of
   it has a tile of vocabulary rows for a result; a cached call that names
-  no rows gets every row's logits, as the speculative verify step needs.
+  no rows gets every row's logits.
 """
 import functools
 import re
@@ -63,9 +62,8 @@ def model_of(kind):
 def engine_of(kind, **kw):
     model, params = model_of(kind)
     cfg = dict(n_slots=4, max_len=64, prefill_chunk=4, prefill_budget=8)
-    spec = kw.pop("spec", None)
     cfg.update(kw)
-    return InferenceEngine(model, params, EngineConfig(**cfg), spec=spec)
+    return InferenceEngine(model, params, EngineConfig(**cfg))
 
 
 def run_until(eng, cond, max_steps=400):
@@ -115,29 +113,6 @@ def test_mixed_schedule_gives_the_two_program_tokens(kind):
     assert eng._prefill_fn._cache_size() == len(eng._prefill_tiles)
     assert eng.decode_compile_count == 1
     assert eng._decode_fn._cache_size() == 1
-
-
-def test_the_draft_fork_keeps_two_calls_and_agrees():
-    """An engine built with a speculative draft keeps the tile program
-    without decode rows and its own step; greedy output is the same."""
-    model, params = model_of("dense")
-    rng = np.random.RandomState(8)
-    prompts = [rng.randint(1, 128, n) for n, _ in _SCHEDULE]
-    out = {}
-    for fork in ("ride", "draft"):
-        eng = engine_of("dense", spec=None if fork == "ride" else {
-            "draft_model": model, "draft_params_fn": lambda: params,
-            "k": 2})
-        handles = []
-        for prompt, (_, n_new) in zip(prompts, _SCHEDULE):
-            handles.append(eng.submit(prompt, max_new_tokens=n_new))
-            eng.step()
-        run_until(eng, lambda: all(h.finish_reason for h in handles))
-        out[fork] = [h.tokens() for h in handles]
-        assert (eng.stats()["fused_steps"] > 0) == (fork == "ride")
-        assert eng.prefill_compile_count == len(eng._prefill_tiles)
-        assert eng.decode_compile_count == 1
-    assert out["ride"] == out["draft"]
 
 
 def _prompt_kv(kind, prompt, decoders, second):
@@ -238,10 +213,9 @@ def test_the_tile_program_unembeds_the_rows_it_samples(kind):
 
 
 def test_a_cached_call_that_names_no_rows_gets_every_rows_logits():
-    """The speculative verify step scores all its k + 1 rows
-    (inference/spec_decode.py) and asks for none by name: the cached
-    forward then returns every row's logits, and the rows a caller does
-    name are those rows of it."""
+    """A cached call that asks for no rows by name (`generate.py`'s, a
+    teacher-forced reference's) gets every row's logits, and the rows a
+    caller does name are those rows of it."""
     from ray_tpu.models.transformer import init_cache
     model, params = model_of("dense")
     toks = jnp.asarray(np.random.RandomState(3).randint(1, 128, (2, 9)))

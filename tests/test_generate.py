@@ -221,7 +221,8 @@ _PARITY_MODES = {
     # a row at 0, a row mid-way and a row at M-1, one token each: the
     # engine's decode step
     "rows_decode": ([0, 5, _PARITY_M - 1], 1, False),
-    # per-row idx with L > 1: the speculative verify forward
+    # per-row idx with L > 1: several new rows a slot, each slot at its
+    # own length (no program of the repo calls the model so)
     "rows_verify": ([0, 3, _PARITY_M - 4], 4, True),
     # scalar idx, one token: make_generate_fn's decode step
     "scalar_decode": (6, 1, False),

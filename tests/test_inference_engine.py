@@ -218,7 +218,7 @@ def test_pool_is_updated_in_place_not_rebuilt(tiny, program):
         args = (eng.params, *pools, eng._carry, np.ones((3,), np.int32))
         rows, new_len, first = 3, 1, 1
     else:
-        shape = eng._slots.scratch_shape
+        shape = eng._slots.scratch_shapes["k"]
         scratch = (jnp.zeros(shape, jnp.float32),
                    jnp.ones(shape, jnp.float32))
         slots = (eng._slots.k, eng._slots.v)
@@ -593,44 +593,6 @@ def test_int8_prefix_cache_keeps_single_chunk_dispatches(tiny):
     assert eng.prefill_compile_count == 1
     assert _engine(model, params, prefill_budget=16, prefix_cache_slots=1
                    )._prefill_tiles == (4, 16)
-
-
-def test_spec_draft_pool_after_tiled_prefill_equals_chunked(tiny):
-    """With speculative decoding the draft's prefill takes the same
-    tile as the target's: the draft pool a tiled prefill leaves is the
-    chunk-by-chunk one, and both families compile when the engine is
-    built."""
-    import jax.numpy as jnp
-
-    from ray_tpu.inference import EngineConfig, InferenceEngine
-    from ray_tpu.models.transformer import TransformerConfig
-    _, model, params = tiny
-    draft = TransformerConfig(
-        vocab_size=128, d_model=32, n_layers=1, n_heads=2, n_kv_heads=1,
-        d_ff=64, max_seq_len=128, dtype=jnp.float32,
-        param_dtype=jnp.float32, remat=False)
-    prompt = np.random.RandomState(14).randint(0, 128, 27)
-    pools, toks = [], []
-    for budget in (16, 4):
-        eng = InferenceEngine(
-            model, params, EngineConfig(n_slots=1, max_len=48,
-                                        prefill_chunk=4,
-                                        prefill_budget=budget),
-            spec={"draft_model": draft, "k": 3})
-        assert eng.draft_prefill_compile_count == len(eng._prefill_tiles)
-        h = eng.submit(prompt, max_new_tokens=9)
-        assert _run_until(eng, lambda: h.first_token_t is not None, 40)
-        pools.append((np.asarray(eng._draft_slots.k[:, 0, :27]),
-                      np.asarray(eng._draft_slots.v[:, 0, :27])))
-        assert _run_until(eng, lambda: h.finish_reason is not None)
-        toks.append(h.tokens())
-        assert eng.draft_prefill_compile_count == len(eng._prefill_tiles)
-        assert eng.prefill_compile_count == len(eng._prefill_tiles)
-    np.testing.assert_allclose(pools[0][0], pools[1][0], rtol=1e-5,
-                               atol=1e-5)
-    np.testing.assert_allclose(pools[0][1], pools[1][1], rtol=1e-5,
-                               atol=1e-5)
-    assert toks[0] == toks[1]
 
 
 def test_no_prefill_executable_after_build_on_a_mesh(tiny, jax_cpu):

@@ -34,7 +34,8 @@ def _cfg(**kw):
 CONFIGS = {
     "dense": dict(),
     "moe": dict(n_experts=4, expert_top_k=2, capacity_factor=2.0),
-    "draft": dict(d_model=32, n_layers=1, n_heads=2, n_kv_heads=1, d_ff=64),
+    # one KV head: nothing for a tensor axis to divide
+    "narrow": dict(d_model=32, n_layers=1, n_heads=2, n_kv_heads=1, d_ff=64),
 }
 
 
@@ -194,7 +195,7 @@ def test_format_is_the_tuple_of_arrays(jax_cpu, fmt):
 @pytest.mark.parametrize("kind", sorted(CONFIGS))
 def test_layout_is_said_once(jax_cpu, kind):
     """kv_cache_shape is init_cache's shape and the shape of every array
-    a built engine keeps, for the target's widths and for a draft's."""
+    a built engine keeps, whatever the model's widths."""
     import jax
     import jax.numpy as jnp
 
@@ -207,35 +208,27 @@ def test_layout_is_said_once(jax_cpu, kind):
     cache = init_cache(mcfg, 3, 24)
     assert cache["k"].shape == cache["v"].shape == \
         kv_cache_shape(mcfg, 3, 24)
-    # the engine: `kind` is the target, or the draft beside a dense one
-    target = _cfg() if kind == "draft" else mcfg
-    model = TransformerLM(target)
+    model = TransformerLM(mcfg)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 8), jnp.int32))["params"]
-    k = 3 if kind == "draft" else 0
     eng = InferenceEngine(
         model, params,
         EngineConfig(n_slots=2, max_len=32, prefill_chunk=4,
-                     prefill_budget=8, prefix_cache_slots=1),
-        spec={"draft_model": mcfg, "k": k} if k else None)
-    pool = eng._draft_slots if kind == "draft" else eng._slots
-    assert pool.k.shape == pool.v.shape == pool.shape == \
-        kv_cache_shape(mcfg, 2, 32 + k)
-    assert pool.scratch_shape == kv_cache_shape(mcfg, 1, 32 + 8)
-    assert [a.shape for a in pool.new_scratch()] == [pool.scratch_shape] * 2
+                     prefill_budget=8, prefix_cache_slots=1))
+    pool = eng._slots
+    assert pool.k.shape == pool.v.shape == kv_cache_shape(mcfg, 2, 32)
+    assert pool.shapes == dict.fromkeys("kv", kv_cache_shape(mcfg, 2, 32))
+    assert [a.shape for a in pool.new_scratch()] == \
+        [kv_cache_shape(mcfg, 1, 32 + 8)] * 2
     assert [a.shape for a in eng._blocks.arrays] == \
-        [kv_cache_shape(target, 1, 32)] * 2
+        [kv_cache_shape(mcfg, 1, 32)] * 2
     assert eng.prefix_cache.n_blocks == eng._blocks.n_blocks == 8
-    if kind == "draft":
-        assert eng._slots.shape == kv_cache_shape(target, 2, 32 + k)
-    else:
-        assert eng._draft_slots is None
 
 
 def test_sharding_is_pruned_against_each_pools_own_shape(jax_cpu):
     """Batch over the data axes, KV heads over `tensor`; an axis the
-    shape does not divide is left whole: the draft's single KV head on a
-    2-way tensor axis, where the target's two heads split."""
+    shape does not divide is left whole: a narrow model's single KV head
+    on a 2-way tensor axis, where two heads split."""
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.inference.kv_cache import SlotPool
@@ -247,23 +240,23 @@ def test_sharding_is_pruned_against_each_pools_own_shape(jax_cpu):
         pytest.skip("needs four devices")
     mesh = make_mesh(MeshConfig(data=2, fsdp=1, seq=1, tensor=2),
                      devices=devices[:4])
-    target, draft = _cfg(), _cfg(**CONFIGS["draft"])
+    target, narrow = _cfg(), _cfg(**CONFIGS["narrow"])
     sh = kv_cache_sharding(kv_cache_shape(target, 2, 16), mesh)
     assert sh.spec[3] == "tensor" and sh.spec[1] is not None
     # the form a program hands a donated pool back in: no trailing None
     assert sh.spec[0] is sh.spec[2] is None and len(sh.spec) == 4
-    assert len(kv_cache_sharding(kv_cache_shape(draft, 2, 16),
+    assert len(kv_cache_sharding(kv_cache_shape(narrow, 2, 16),
                                  mesh).spec) == 2
     # three slots do not divide the 2-way data axis
     assert kv_cache_sharding(kv_cache_shape(target, 3, 16), mesh).spec[1] \
         is None
-    pool = SlotPool(draft, 2, 16, 16, 24, jax_cpu.numpy.float32, mesh)
-    assert pool.k.sharding == pool.sharding == \
-        kv_cache_sharding(pool.shape, mesh)
+    pool = SlotPool(narrow, 2, 16, 16, 24, jax_cpu.numpy.float32, mesh)
+    sharding = kv_cache_sharding(pool.shapes["k"], mesh)
+    assert pool.k.sharding == sharding
     assert pool.new_scratch()[0].sharding.spec == P()
     sk, sv = pool.new_scratch()
     pool.insert((sk + 1.0, sv + 2.0), 1)
-    assert pool.k.sharding == pool.sharding      # still where it was
+    assert pool.k.sharding == sharding          # still where it was
     assert float(pool.k[0, 1, 3, 0, 0]) == 1.0 and \
         float(pool.v[0, 1, 15, 0, 0]) == 2.0 and not np.asarray(
             pool.k[:, 0]).any()

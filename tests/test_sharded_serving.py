@@ -1,15 +1,11 @@
-"""Sharded serving plane (ray_tpu/serve/sharded.py + spec_decode.py +
-kv_quant.py): mesh-gang replicas with speculative decoding and int8 KV.
+"""Sharded serving plane (ray_tpu/serve/sharded.py + kv_quant.py):
+mesh-gang replicas with int8 KV.
 
 CPU unit tier (tier-1, any interpreter):
-- greedy bit-exactness: spec-decode ON output == spec-decode OFF output
-- accept/reject bookkeeping at K in {1, 4}: self-draft pins the rate at
-  its 1.0 upper bound, a random-init draft lands near the floor
 - int8 KV: quantize/dequantize round-trip tolerance, jnp/numpy mirror
   bit-identity, and prefix-cache HIT vs MISS greedy parity with the
   quantized block pool
-- compile-once with speculation AND quantization both ON:
-  decode_compile_count == 1 and exactly one verify program across
+- compile-once with quantization ON: decode_compile_count == 1 across
   requests of different lengths
 - gang plumbing without a cluster: token digests, resume_tokens
   exactly-once, streaming protocol, GangRankKiller arming + the
@@ -51,17 +47,6 @@ def tiny(jax_cpu):
     return cfg, model, params
 
 
-@pytest.fixture(scope="module")
-def draft_cfg(jax_cpu):
-    import jax.numpy as jnp
-
-    from ray_tpu.models.transformer import TransformerConfig
-    return TransformerConfig(
-        vocab_size=128, d_model=32, n_layers=1, n_heads=2, n_kv_heads=1,
-        d_ff=64, max_seq_len=128, dtype=jnp.float32,
-        param_dtype=jnp.float32, remat=False)
-
-
 def _replica(model, params, **kw):
     from ray_tpu.serve.sharded import ShardedEngineReplica
     base = dict(n_slots=2, max_len=64, prefill_chunk=4, prefill_budget=8,
@@ -71,59 +56,6 @@ def _replica(model, params, **kw):
 
 
 PROMPT = [3, 1, 4, 1, 5, 9, 2, 6]
-
-
-# ==========================================================================
-# speculative decoding: greedy exactness + accept bookkeeping
-# ==========================================================================
-
-def test_spec_decode_greedy_bit_exact_vs_no_spec(tiny, draft_cfg):
-    """The raw-speed multiplier must be invisible in the tokens: a
-    spec-ON replica (random-init draft, so real rejections happen) and
-    a spec-OFF replica produce identical greedy output."""
-    _, model, params = tiny
-    spec = _replica(model, params,
-                    spec_decode={"draft_model": draft_cfg, "k": 4})
-    base = _replica(model, params)
-    for prompt, n in [(PROMPT, 24), ([7, 7, 7], 16), (list(range(20)), 8)]:
-        assert spec.generate(prompt, max_new_tokens=n) == \
-            base.generate(prompt, max_new_tokens=n)
-    st = spec.stats()
-    assert st["spec_tokens_proposed"] > 0
-
-
-@pytest.mark.parametrize("k", [1, 4])
-def test_spec_accept_bookkeeping_self_draft_upper_bound(tiny, k):
-    """Self-draft (draft IS the target): every proposal verifies, so
-    accepted == proposed and the rate sits at its 1.0 upper bound for
-    any K."""
-    _, model, params = tiny
-    rep = _replica(model, params,
-                   spec_decode={"draft_model": model.cfg, "k": k,
-                                "draft_params_fn": lambda: params})
-    out = rep.generate(PROMPT, max_new_tokens=24)
-    assert len(out) == 24
-    st = rep.stats()
-    assert st["spec_tokens_proposed"] > 0
-    assert st["spec_tokens_accepted"] == st["spec_tokens_proposed"]
-    assert st["spec_accept_rate"] == 1.0
-
-
-@pytest.mark.parametrize("k", [1, 4])
-def test_spec_accept_bookkeeping_random_draft_rejects(tiny, draft_cfg, k):
-    """A random-init draft disagrees with the target almost always:
-    acceptance stays well below the self-draft bound and the counters
-    stay consistent (accepted <= proposed, rate == accepted/proposed)."""
-    _, model, params = tiny
-    rep = _replica(model, params,
-                   spec_decode={"draft_model": draft_cfg, "k": k,
-                                "draft_seed": 3})
-    rep.generate(PROMPT, max_new_tokens=24)
-    st = rep.stats()
-    prop, acc = st["spec_tokens_proposed"], st["spec_tokens_accepted"]
-    assert prop > 0 and 0 <= acc <= prop
-    assert st["spec_accept_rate"] == round(acc / prop, 4)
-    assert st["spec_accept_rate"] < 1.0
 
 
 # ==========================================================================
@@ -178,28 +110,24 @@ def test_int8_prefix_hit_greedy_parity(tiny):
 
 
 # ==========================================================================
-# compile-once with BOTH multipliers on
+# compile-once with int8 blocks
 # ==========================================================================
 
-def test_compile_once_spec_and_int8_together(tiny, draft_cfg):
+def test_compile_once_with_int8_blocks(tiny):
     _, model, params = tiny
-    rep = _replica(model, params, kv_quant="int8", prefix_cache_slots=2,
-                   spec_decode={"draft_model": draft_cfg, "k": 4})
-    # the reference has the same block format: speculative decoding is
-    # greedy-exact, int8 blocks are not (a miss attends its finished
-    # chunks as the store gives them back, so that a hit is bit-identical
-    # to it; against a plain fp replica the 8-token prompt's first chunk
-    # alone moves token 4 of this random-weight model, with or without
-    # a draft)
+    rep = _replica(model, params, kv_quant="int8", prefix_cache_slots=2)
+    # the reference has the same block format: int8 blocks are not
+    # greedy-exact against fp ones (a miss attends its finished chunks as
+    # the store gives them back, so that a hit is bit-identical to it;
+    # against a plain fp replica the 8-token prompt's first chunk alone
+    # moves token 4 of this random-weight model)
     base = _replica(model, params, kv_quant="int8", prefix_cache_slots=2)
     for prompt, n in [(PROMPT, 20), (list(range(30)), 12), ([5], 24)]:
         assert rep.generate(prompt, max_new_tokens=n) == \
             base.generate(prompt, max_new_tokens=n)
     st = rep.stats()
-    # one decode program (the fused draft+verify) and exactly one
-    # verify trace across three request shapes
+    # one decode program across three request shapes
     assert st["decode_compile_count"] == 1
-    assert st["spec_verify_compile_count"] == 1
     assert st["requests_served"] == 3
 
 
@@ -265,12 +193,11 @@ def test_digest_divergence_wedges_gang(tiny):
         ray_tpu.get = orig
 
 
-def test_resume_tokens_exactly_once(tiny, draft_cfg):
+def test_resume_tokens_exactly_once(tiny):
     """Severed-stream re-route: delivered tokens ride the prompt, the
     continuation is the bit-identical greedy suffix, nothing repeats."""
     _, model, params = tiny
-    rep = _replica(model, params,
-                   spec_decode={"draft_model": draft_cfg, "k": 4})
+    rep = _replica(model, params)
     out = rep.generate(PROMPT, max_new_tokens=24)
     res = rep.generate(PROMPT, max_new_tokens=24, resume_tokens=out[:10])
     assert res == out[10:]

@@ -309,12 +309,6 @@ def test_engine_counts_the_window_and_refuses_what_carries_no_ring(small):
     assert st["tile_kernel_layers"] == 0
     with pytest.raises(ValueError, match="beyond K and V"):
         _engine(model, params, prefix_cache_slots=2)
-    with pytest.raises(ValueError, match="beyond K and V"):
-        InferenceEngine(model, params, EngineConfig(
-            n_slots=2, max_len=64, prefill_chunk=4, prefill_budget=TILE),
-            spec={"draft_model": TransformerConfig(
-                vocab_size=VOCAB, d_model=32, n_layers=1, n_heads=2,
-                n_kv_heads=2, d_ff=48, max_seq_len=512), "k": 2})
 
 
 # ------------------------------------------------------ the expert layer
