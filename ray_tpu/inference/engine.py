@@ -266,7 +266,7 @@ class InferenceEngine:
             raise ValueError(
                 f"the model keeps caches beyond K and V ({', '.join(beyond)}"
                 f": an indexer's keys, pooled keys, a recurrent state, a "
-                f"convolution's tail, a sliding window's ring), "
+                f"convolution's tail, a sliding window's ring, a latent), "
                 f"which prefix blocks do not carry: run it with "
                 f"prefix_cache_slots=0")
         dtype = cfg.cache_dtype or mcfg.dtype

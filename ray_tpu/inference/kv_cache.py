@@ -15,7 +15,9 @@ what the engine keeps in that layout and nothing else knows how:
   heads and a state-space mixer side by side K and V AND two float32
   states, the mixer's and its convolution's tail; for sliding-window
   layers beside full-attention ones K and V in RINGS beside K and V by
-  position), the scratch a prompt prefills into, and the program that
+  position; for latent attention ONE pool of latent + rope key a position
+  with no head axis and the positions last), the scratch a prompt
+  prefills into, and the program that
   makes a finished scratch a slot. Pools are of three natures
   (``CACHE_POS_AXIS``, ``CACHE_RINGS``): with a position axis, of which a
   slot takes the scratch's first ``slot_len`` positions; without (a
@@ -127,8 +129,8 @@ class SlotPool:
         self._insert_fn = jax.jit(insert, donate_argnums=(0,))
 
     def pools(self) -> Tuple[Any, ...]:
-        """(k, v), (k, v, ki), (k, v, kp, s), (k, v, s, c) or
-        (k, v, wk, wv): the order of ``cache_shapes``."""
+        """(k, v), (k, v, ki), (k, v, kp, s), (k, v, s, c), (k, v, wk, wv)
+        or (lat,): the order of ``cache_shapes``."""
         return tuple(getattr(self, n) for n in self.shapes)
 
     def rebind(self, pools) -> None:

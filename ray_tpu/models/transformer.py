@@ -20,6 +20,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 from typing import Any, Optional, Tuple
 
 import jax
@@ -160,11 +161,27 @@ class TransformerConfig:
     ssm_out_mult: float = 1.0
     ssm_mults: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
     mlp_mults: Tuple[float, float] = (1.0, 1.0)
+    # an "mla" layer (of `mixer_kinds`; models/latent_attention.py): latent
+    # attention. A position keeps ONE row of `latent_dim` + `rope_dim`
+    # values for all heads, a normed latent and one rotated key; a head's
+    # query is `head_dim` = (head_dim - rope_dim) ‖ rope_dim wide, the
+    # rotary on the second part alone, and its key and its value
+    # (`v_head_dim`) are up-projections of the latent. `rope_yarn`: the
+    # rotary's frequencies blended and the softmax's scale raised as YaRN
+    # has them (`Yarn`); None: plain rotary, head_dim ** -0.5
+    latent_dim: int = 0
+    rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_yarn: Optional["Yarn"] = None
 
     def __post_init__(self):
         if not self.head_dim:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.n_heads)
+        if self.rope_yarn is not None and "mla" not in (
+                self.mixer_kinds or ()):
+            raise ValueError("rope_yarn: only \"mla\" layers' rotary "
+                             "takes it")
         if self.router not in ("softmax", "sigmoid") or (
                 self.n_dense_layers and self.scan_layers):
             raise ValueError(
@@ -192,6 +209,14 @@ class TransformerConfig:
                 raise ValueError(
                     "\"att\" layers beside \"blk\" or \"hyb\": each "
                     "counts its own layers of the pools \"k\" and \"v\"")
+            if "mla" in kinds and (
+                    set(kinds) != {"mla"} or not 0 < self.rope_dim
+                    < self.head_dim or self.rope_dim % 2
+                    or not self.latent_dim or not self.v_head_dim):
+                raise ValueError(
+                    "\"mla\" layers: every layer of the stack, with "
+                    "latent_dim, v_head_dim and an even rope_dim below "
+                    "head_dim")
             if "win" in kinds and not 0 < self.window <= self.win_ring:
                 raise ValueError(
                     f"\"win\" layers: window {self.window} > 0 and "
@@ -209,11 +234,12 @@ class TransformerConfig:
 # keeps (`cache_shapes` says their shapes, CACHE_POS_AXIS their nature)
 KIND_CACHES = {"blk": ("k", "v", "kp"), "lin": ("s",),
                "hyb": ("k", "v", "s", "c"), "win": ("wk", "wv"),
-               "att": ("k", "v")}
-# the kinds whose decode rows read K and V in the WHOLE pools, by the
-# layer's number (`Attention._in_place`; a model without kinds:
-# `TransformerLM._decode`, `whole`)
-_IN_PLACE = ("hyb", "win", "att")
+               "att": ("k", "v"), "mla": ("lat",)}
+# the kinds whose decode rows read K and V (an "mla" layer's: the latents)
+# in the WHOLE pools, by the layer's number (`Attention._in_place`,
+# `LatentAttention`; a model without kinds: `TransformerLM._decode`,
+# `whole`)
+_IN_PLACE = ("hyb", "win", "att", "mla")
 
 _PARTITION_OFF = __import__("threading").local()
 
@@ -255,10 +281,52 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(self.dtype)
 
 
-def rope(x, positions, theta: float):
-    """Rotary embeddings. x[B,L,H,D], positions[B,L]."""
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's scaling of a rotary trained on `original_len` positions to
+    `factor` times as many (DeepSeek-V2's `deepseek_yarn`): a frequency
+    that turns more than `beta_fast` times over the original length is
+    kept, one that turns fewer than `beta_slow` times is divided by
+    `factor`, those between blended by a linear ramp over their dimensions
+    (`yarn_blend`); the softmax's scale times yarn_mscale(factor,
+    mscale_all_dim) ** 2. (A published `mscale` other than
+    `mscale_all_dim` would put a factor on cos and sin: no model here has
+    one, and the family that maps a configuration refuses it.)"""
+    factor: float = 1.0
+    original_len: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_blend(d: int, theta: float, yarn: Yarn):
+    """Host arithmetic: what each of rope's d / 2 frequencies is multiplied
+    by, float32 [d / 2]: 1 below the ramp, 1 / factor above it. All ones at
+    factor 1, so that `rope` is then bit for bit the plain one."""
+    import numpy as np
+
+    def dim_of(turns):
+        """The dimension that turns so often over the original length."""
+        return d * math.log(yarn.original_len / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low)
+                   / ((high if high != low else high + 0.001) - low), 0, 1)
+    return ((1.0 - ramp) + ramp / yarn.factor).astype(np.float32)
+
+
+def rope(x, positions, theta: float, yarn: Optional[Yarn] = None):
+    """Rotary embeddings. x[B,L,H,D], positions[B,L]. `yarn`: its
+    frequencies (`Yarn`)."""
     d = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if yarn is not None:
+        freqs = freqs * yarn_blend(d, theta, yarn)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B,L,D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
@@ -298,7 +366,7 @@ def _cached_attention(q, k_cache, v_cache, q_pos0):
     return out.reshape(B, S, H, D).astype(q.dtype)
 
 
-def _cache_write(cache, new, idx, pos_axis: int = -3):
+def _cache_write(cache, new, idx, pos_axis: int = -3, row_axis: int = -4):
     """Write `new` [..., B, L, Hkv, D] into `cache` [..., B, M, Hkv, D]
     at position `idx` of every row: a scalar (all rows share one write
     offset) or a [B] vector (per-slot offsets — each row lands at its
@@ -308,9 +376,10 @@ def _cache_write(cache, new, idx, pos_axis: int = -3):
     operand is `new`, never [.., M, ..], so a donated pool is updated in
     place. Out-of-range starts are clamped, so a full/free slot writes
     at M-L harmlessly. `pos_axis` is where the positions lie (the
-    indexer's keys keep them last: `index_cache_shape`)."""
+    indexer's keys keep them last: `index_cache_shape`) and `row_axis`
+    where the rows (the latents, with no head axis, [..., B, W, M]: -3)."""
     new = new.astype(cache.dtype)
-    b_axis = cache.ndim - 4
+    b_axis = cache.ndim + row_axis
     p_axis = cache.ndim + pos_axis
     if jnp.ndim(idx) == 0:
         start = [0] * cache.ndim
@@ -470,9 +539,11 @@ def tile_attention_layers(cfg: "TransformerConfig", tile: int,
     """Host arithmetic on what a tile program is built from: (the layers
     whose tile of `tile` rows goes through `_tile_attention`, a "win"
     layer's against its ring, an "att" or "hyb" layer's against
-    `scratch_len` positions; those of them the Pallas kernel takes). A
-    tile longer than the ring's slack beside the window would overwrite
-    keys its own first rows attend, and is refused."""
+    `scratch_len` positions, or, an "mla" layer's, through the loop of
+    models/latent_attention.py that stands where it does; those of them
+    the Pallas kernel takes: no "mla" layer's, whose query and value
+    widths differ). A tile longer than the ring's slack beside the window
+    would overwrite keys its own first rows attend, and is refused."""
     from ray_tpu.models import sparse_attention as sa
     if "win" in (cfg.mixer_kinds or ()) \
             and tile + cfg.window - 1 > cfg.win_ring:
@@ -487,7 +558,7 @@ def tile_attention_layers(cfg: "TransformerConfig", tile: int,
         window, M = (cfg.window, cfg.win_ring) if kind == "win" \
             else (0, scratch_len)
         layers += 1
-        kernel += bool(same and sa._tile_kernel_takes(
+        kernel += bool(kind != "mla" and same and sa._tile_kernel_takes(
             tile, M, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, window))
     return layers, kernel
 
@@ -509,8 +580,9 @@ def decode_rows_read(cfg: "TransformerConfig", slot_len: int):
     own block among them (`block_decode_attention` passes over the slot's
     whole length and masks the rest). A model without kinds or indexer
     reads K and V in the pools where they lie (`_row_attention`):
-    `kv_rows_*`; an indexer's rows `dsa_rows_*`; "hyb", "att" and "lin"
-    layers are not counted."""
+    `kv_rows_*`; an indexer's rows `dsa_rows_*`; an "mla" layer's rows pass
+    over the latents, ONE row a position for all heads, by the XLA loop
+    (`mla_rows_*`); "hyb", "att" and "lin" layers are not counted."""
     from ray_tpu.models.sparse_attention import decode_positions_read
     kinds = cfg.mixer_kinds or ()
     Hkv, D = cfg.n_kv_heads, cfg.head_dim
@@ -537,6 +609,11 @@ def decode_rows_read(cfg: "TransformerConfig", slot_len: int):
                 win_rows_streamed=decode_positions_read(
                     [min(n, ring) for n in lens], ring, Hkv, D) - len(lens),
                 win_rows_live=sum(min(n, cfg.window) for n in live))
+        if "mla" in kinds:
+            out.update(
+                mla_rows_streamed=decode_positions_read(
+                    lens, slot_len, 1, cfg.latent_dim + cfg.rope_dim),
+                mla_rows_live=sum(live))
         return out
     return read
 
@@ -1052,6 +1129,10 @@ class Block(nn.Module):
         elif self.kind == "lin":
             att = LightningAttention(cfg, name="attn")(
                 normed, positions, cache, slots, real)
+        elif self.kind == "mla":
+            from ray_tpu.models.latent_attention import LatentAttention
+            att = LatentAttention(cfg, name="attn")(
+                normed, positions, cache, slots)
         else:
             att = Attention(cfg, self.chunked, self.kind, name="attn")(
                 normed, positions, cache, slots)
@@ -1179,7 +1260,7 @@ def index_cache_shape(cfg: TransformerConfig, batch: int,
 # "s" and the convolution's tail "c" of a "hyb" layer) and a call replaces
 # a row's entry whole
 CACHE_POS_AXIS = {"k": -3, "v": -3, "ki": -1, "kp": -3, "wk": -3,
-                  "wv": -3, "s": None, "c": None}
+                  "wv": -3, "lat": -1, "s": None, "c": None}
 # the third nature: a RING, K and V of the "win" layers. It has a position
 # axis of `win_ring` places whatever the cache's length, position p lies at
 # p mod win_ring, and a slot takes the scratch's ring whole
@@ -1187,7 +1268,8 @@ CACHE_RINGS = ("wk", "wv")
 # the keys of `engine.stats()` that give the bytes of the slots' pools
 # beyond K and V (`kv_pool_bytes` is all of them), and the pools of each
 POOL_BYTES_KEYS = {"state_pool_bytes": ("s",), "conv_pool_bytes": ("c",),
-                   "win_pool_bytes": CACHE_RINGS}
+                   "win_pool_bytes": CACHE_RINGS,
+                   "latent_pool_bytes": ("lat",)}
 
 
 def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int):
@@ -1199,12 +1281,21 @@ def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int):
     [n, rows, heads, d_head, d_state] and its convolution's tails
     [n, rows, taps - 1, channels] of the "hyb" layers; K and V of the
     "att" layers by position, and of the "win" layers in rings of
-    `win_ring` places, [n, rows, win_ring, Hkv, D], whatever `max_len`."""
+    `win_ring` places, [n, rows, win_ring, Hkv, D], whatever `max_len`;
+    the latents of the "mla" layers, [n, rows, latent_dim + rope_dim,
+    max_len]: one entry a position, the normed latent and behind it the
+    one rotated key, no head axis, and the positions LAST, in the lanes,
+    as the indexer's keys keep theirs: with the 576 values in the lanes
+    the TPU stores each in 640, and its compiler, which wants a key
+    block's positions there for both of the decode row's products, relaid
+    the WHOLE pool at each end of a step (read off the programs compiled
+    for a described v5e, PERF.md section 6, PR 50)."""
     if cfg.mixer_kinds:
         kv = kv_cache_shape(cfg, batch, max_len)[1:]
         hyb = "hyb" in cfg.mixer_kinds
         ring = (batch, cfg.win_ring) + kv[2:]
         entry = {"k": kv, "v": kv, "wk": ring, "wv": ring,
+                 "lat": (batch, cfg.latent_dim + cfg.rope_dim, max_len),
                  "kp": (batch, max_len // cfg.blk_stride) + kv[2:],
                  "s": (batch, cfg.ssm_heads, cfg.ssm_head_dim,
                        cfg.ssm_state) if hyb
@@ -1233,7 +1324,8 @@ def kv_cache_sharding(shape, mesh, rules=None, name: str = "k"):
     """That layout on a mesh: batch over the data axes, KV heads over
     `tensor` (the split the k/v projection weights carry), an axis the
     shape does not divide left replicated. The indexer's keys
-    (`name="ki"`) have one head: batch over the data axes alone."""
+    (`name="ki"`) have one head, the latents (`"lat"`) none: batch over
+    the data axes alone."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ray_tpu.parallel import sharding as sharding_lib
@@ -1241,7 +1333,7 @@ def kv_cache_sharding(shape, mesh, rules=None, name: str = "k"):
                                              logical_pspec_to_mesh)
     spec = _prune_indivisible(logical_pspec_to_mesh(
         P(*(None, "batch", None,
-            "kv_heads" if name not in ("ki", "s", "c") else None,
+            "kv_heads" if name not in ("ki", "s", "c", "lat") else None,
             None)[:len(shape)]),
         rules or sharding_lib.DEFAULT_RULES), shape, mesh)
     # no trailing None: the spec a program hands a pool back with, so a
@@ -1563,6 +1655,10 @@ class TransformerLM(nn.Module):
                     if tile:
                         return _ring_write(pool, rows, idx)
                     return _cache_write(pool, rows, idx % pool.shape[2])
+            if n == "lat":                  # under the form that made them
+                with jax.named_scope("mla_attend" if tile else "mla_row"):
+                    return _cache_write(pool, rows, idx, CACHE_POS_AXIS[n],
+                                        row_axis=-3)
             return _cache_write(pool, rows, idx, CACHE_POS_AXIS[n])
 
         rows, slot_rows = rows if slots else (rows, None)
@@ -1573,8 +1669,16 @@ class TransformerLM(nn.Module):
             # a `cond` a pool: one around both cost the tile's program
             # 3.6 ms at 20 layers (28.17 against 24.58 ms, my chip runs,
             # PR 35)
+            # (the latents under none: in a branch the compiler lays the
+            # pool out for the write, positions first, and relays the WHOLE
+            # pool at both ends, 2 x 2.7 GB a tile step, read off the
+            # program compiled for a described v5e, PR 50; with no slot
+            # live the rows land at an idle slot's length, as the decode
+            # program's do, in places its next owner's insert overwrites)
             new_cache["slots"] = {
-                n: p if isinstance(r, tuple) and not r else jax.lax.cond(
+                n: p if isinstance(r, tuple) and not r
+                else write(n, p, r, slots["idx"]) if n == "lat"
+                else jax.lax.cond(
                     slots["on"], functools.partial(write, n),
                     lambda pool, *_: pool, p, r, slots["idx"])
                 for n, p, r in zip(names, slot_pools, slot_rows)}

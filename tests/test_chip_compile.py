@@ -270,6 +270,33 @@ def test_tile_programs_attend_through_the_flash_kernel(
     assert not re.search(rf"= bf16\[(1,)*{M},{Hkv},128\]\S* copy\(", text)
 
 
+@pytest.mark.parametrize("program", ["decode", "tile"])
+def test_latent_step_programs_read_the_pool_where_it_lies(
+        one_chip, no_compile_cache, monkeypatch, program):
+    """The step programs of `sarvam-105b.longdoc-answer` (8 layers of
+    latent attention, 16 slots of 18,432 positions, 576 values a position a
+    layer): the pool keeps its positions LAST and is in ONE layout through
+    the whole program, never copied and no layer of it sliced out, and so
+    is the slots' pool behind a tile, whose write stands under no `cond`.
+    (With the positions before the 576 values the compiler relaid the whole
+    pool, 2.7 GB, at each end of both programs, to have a key block's
+    positions in the lanes for the decode row's two products; with the
+    slots' write in a branch it relaid it for the write: temp 3.04 GB
+    against 0.02 and 0.38, read off these compiles, PR 50.) No kernel takes
+    either form yet, and no array holds the keys of a whole scratch."""
+    import re
+    eng, text = _step_program_text("sarvam-105b", program, one_chip,
+                                   monkeypatch)
+    assert eng._slots.shapes == {"lat": (8, 16, 576, 18432)}
+    assert eng._tile_layers == {1024: (8, 0)}
+    assert not re.findall(r"= bf16\[16,576,18432\]", text)
+    assert not re.findall(r"= bf16\[8,16,576,18432\]\S* copy\(", text)
+    assert set(re.findall(r"bf16\[8,16,576,18432\](\{[^}]*\})", text)) \
+        == {"{3,2,1,0:T(8,128)(2,1)}"}
+    assert "tpu_custom_call" not in text
+    assert not re.findall(r"\[(?:1,)?19456,64,(?:128|192|256)\]", text)
+
+
 def test_flash_by_name_never_returns_the_reference():
     """A length the kernel cannot tile raises; it is "auto" that chooses
     by platform and shape (here, on the CPU: the reference)."""

@@ -1,5 +1,5 @@
 """What a decode row reads, said once (models/transformer.py
-`decode_rows_read`): for each of the six served families' small test model,
+`decode_rows_read`): for each of the seven served families' small test model,
 the counters `engine.stats()` has and their increments for a fixed list of
 slot lengths, against closed forms. Host arithmetic: no program runs."""
 import pytest
@@ -22,9 +22,9 @@ def _cfg(family):
         from tests.test_fused_step import model_of
         return model_of(family)[0].cfg
     from tests import (test_falcon_h1_model, test_hybrid_mixer_model,
-                       test_trinity_model)
+                       test_sarvam_model, test_trinity_model)
     mod = {"blk_lin": test_hybrid_mixer_model, "hyb": test_falcon_h1_model,
-           "win_att": test_trinity_model}[family]
+           "win_att": test_trinity_model, "mla": test_sarvam_model}[family]
     return mod.build(mod.config()).cfg
 
 
@@ -50,7 +50,11 @@ def _cfg(family):
     ("win_att", dict(win_rows_streamed=5 * 24,
                      win_rows_live=1 + 6 + 16 + 16 + 16),
      dict(win_rows_streamed=0 + 8 + 24 + 24 + 24)),
-], ids=["dense", "moe", "indexer", "blk_lin", "hyb", "win_att"])
+    # sarvam's "mla" layers: the latents where they lie, one KV "head" of
+    # latent + rope key, as K and V of the dense model's rows
+    ("mla", dict(mla_rows_streamed=LOOP, mla_rows_live=LIVE),
+     dict(mla_rows_streamed=KERNEL)),
+], ids=["dense", "moe", "indexer", "blk_lin", "hyb", "win_att", "mla"])
 def test_the_counters_of_a_family_and_their_closed_forms(family, loop,
                                                           kernel,
                                                           monkeypatch):
