@@ -24,7 +24,14 @@ Two forms compute the same numbers (`tests/test_sarvam_model.py`):
   rows: S * H * 2 * (head_dim + v_head_dim) + 2 * latent_dim * H * (nope +
   v_head_dim) FLOPs a cached position, against S * H * 2 * (2 * latent_dim
   + rope_dim) absorbed: at S = 1,024 and the widths 512 / 128 / 64 / 128,
-  58.7 M against 142.6 M.
+  58.7 M against 142.6 M. On a TPU one tile (B == 1, one scalar start, q in
+  the scratch's type) whose shapes fit it goes through the latent kernel of
+  ops/tile_attention.py (`sparse_attention._latent_tile_kernel_takes`): the
+  same blocks, the up-projection inside the kernel, once a group of heads,
+  and the block's float32 scores `[H, S, blk]`, its probabilities and the
+  running softmax's carry held in VMEM where the XLA loop passes them
+  through HBM every block. `tile_attention`'s loop is the kernel's
+  reference, step for step, and what runs everywhere else.
 - ABSORBED (`row_attention`, one decode row a slot against the WHOLE pool
   and the layer's number): q_h . k_h = (W_uk,h^T q_nope,h) . c + q_rope,h .
   k_r and sum_s p_s v_h,s = W_uv,h (sum_s p_s c_s), so the row meets the
@@ -54,6 +61,7 @@ from ray_tpu.models import sparse_attention as sa
 from ray_tpu.models.transformer import (RMSNorm, _cache_write, _join_rows, _p,
                                         _split_rows, rope, yarn_mscale)
 from ray_tpu.ops import decode_attention
+from ray_tpu.ops import tile_attention as ops_tile_attention
 
 
 def softmax_scale(cfg) -> float:
@@ -114,10 +122,17 @@ def tile_attention(q, cache, own, pos0, w_uk, w_uv, scale):
     [B, W, M], into which the tile's own latents `own` [B, W, S] are
     written first: causal over absolute positions, blocked over the keys up
     to the tile's last position only, each block's K and V made from its
-    latents inside the loop -> [B, S, H, v_head_dim]."""
-    B, S, H, _ = q.shape
+    latents inside the loop (or inside the Pallas kernel that takes the
+    loop's place where `_latent_tile_kernel_takes`: the module's
+    docstring) -> [B, S, H, v_head_dim]."""
+    B, S, H, D = q.shape
     M, Dv = cache.shape[2], w_uv.shape[-1]
+    R, _, Dn = w_uk.shape
     cache = _cache_write(cache, own, pos0, -1, -3)
+    if B == 1 and not jnp.ndim(pos0) and q.dtype == cache.dtype \
+            and sa._latent_tile_kernel_takes(S, M, H, R, Dn, D - Dn, Dv):
+        return ops_tile_attention.latent_tile_attention(
+            q, cache, pos0, w_uk, w_uv, scale)
     qpos = jnp.broadcast_to(
         jnp.reshape(pos0, (-1, 1)) + jnp.arange(S)[None, :], (B, S))
     kb = sa._block_of(M)

@@ -302,6 +302,17 @@ def _tile_kernel_takes(S: int, M: int, H: int, Hkv: int, D: int,
         S, M, H, Hkv, D, window)
 
 
+def _latent_tile_kernel_takes(S: int, M: int, H: int, R: int, Dn: int,
+                              Dr: int, Dv: int) -> bool:
+    """`_tile_kernel_takes`' twin for a layer of latents
+    (models/latent_attention.py `tile_attention`): whether a tile of S
+    rows of H heads, `Dn + Dr` wide for the scores and `Dv` for the
+    values, attends its scratch of M positions of `R + Dr` values through
+    the latent kernel of ops/tile_attention.py."""
+    return jax.default_backend() == "tpu" and tile_attention.latent_fits(
+        S, M, H, R, Dn, Dr, Dv)
+
+
 def decode_positions_read(lens, M: int, Hkv: int, D: int) -> int:
     """Host arithmetic: the positions of K and V that
     `sparse_decode_attention` passes over for decode rows whose slots hold

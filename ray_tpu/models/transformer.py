@@ -539,11 +539,13 @@ def tile_attention_layers(cfg: "TransformerConfig", tile: int,
     """Host arithmetic on what a tile program is built from: (the layers
     whose tile of `tile` rows goes through `_tile_attention`, a "win"
     layer's against its ring, an "att" or "hyb" layer's against
-    `scratch_len` positions, or, an "mla" layer's, through the loop of
-    models/latent_attention.py that stands where it does; those of them
-    the Pallas kernel takes: no "mla" layer's, whose query and value
-    widths differ). A tile longer than the ring's slack beside the window
-    would overwrite keys its own first rows attend, and is refused."""
+    `scratch_len` positions, or, an "mla" layer's, through
+    `tile_attention` of models/latent_attention.py, which stands where it
+    does; those of them a Pallas kernel of ops/tile_attention.py takes:
+    `_tile_kernel_takes` says it of K and V by head, its twin
+    `_latent_tile_kernel_takes` of an "mla" layer's latents). A tile longer
+    than the ring's slack beside the window would overwrite keys its own
+    first rows attend, and is refused."""
     from ray_tpu.models import sparse_attention as sa
     if "win" in (cfg.mixer_kinds or ()) \
             and tile + cfg.window - 1 > cfg.win_ring:
@@ -558,8 +560,14 @@ def tile_attention_layers(cfg: "TransformerConfig", tile: int,
         window, M = (cfg.window, cfg.win_ring) if kind == "win" \
             else (0, scratch_len)
         layers += 1
-        kernel += bool(kind != "mla" and same and sa._tile_kernel_takes(
-            tile, M, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, window))
+        if kind == "mla":
+            took = sa._latent_tile_kernel_takes(
+                tile, M, cfg.n_heads, cfg.latent_dim,
+                cfg.head_dim - cfg.rope_dim, cfg.rope_dim, cfg.v_head_dim)
+        else:
+            took = sa._tile_kernel_takes(
+                tile, M, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, window)
+        kernel += bool(same and took)
     return layers, kernel
 
 

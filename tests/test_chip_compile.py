@@ -149,13 +149,14 @@ def test_pool_decode_kernel_takes_the_pool_as_it_lies(one_chip,
     assert re.search(rf"bf16\[{n},{B},{M * Hkv},128\]\S* bitcast\(", text)
 
 
-def _step_program_text(config, program, one_chip, monkeypatch):
-    """(the engine, the compiled text of its "decode" or "tile" program)
+def _step_program(config, program, one_chip, monkeypatch):
+    """(the engine, its "decode" or "tile" program compiled)
     at a benchmark configuration's own size, for a described v5e. The
     engine is built on shapes: nothing is allocated and nothing runs. The
-    two predicates that ask for the backend (`_kernel_reads`, a decode
-    row's pool kernel; `_tile_kernel_takes`, a tile's flash kernel) see
-    the CPU's here: they are made to answer as on the chip, so that the
+    three predicates that ask for the backend (`_kernel_reads`, a decode
+    row's pool kernel; `_tile_kernel_takes` and
+    `_latent_tile_kernel_takes`, a tile's flash kernels) see the CPU's
+    here: they are made to answer as on the chip, so that the
     program compiled is the one the cell runs."""
     import numpy as np
     from flax.core import meta
@@ -178,6 +179,8 @@ def _step_program_text(config, program, one_chip, monkeypatch):
                         decode_attention.fits)
     monkeypatch.setattr(sparse_attention, "_tile_kernel_takes",
                         tile_attention.fits)
+    monkeypatch.setattr(sparse_attention, "_latent_tile_kernel_takes",
+                        tile_attention.latent_fits)
     engine = dict(cfg["engine"], prefix_cache_slots=0)
     del engine["max_ongoing_requests"]
     eng = InferenceEngine(model, params, EngineConfig(**engine))
@@ -193,7 +196,12 @@ def _step_program_text(config, program, one_chip, monkeypatch):
                 tile, np.zeros((tile,), np.int32), 0, 0, False, 0.0, []))
     args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         np.shape(a), a.dtype, sharding=one_chip), args)
-    return eng, fn.lower(*args).compile().as_text()
+    return eng, fn.lower(*args).compile()
+
+
+def _step_program_text(config, program, one_chip, monkeypatch):
+    eng, compiled = _step_program(config, program, one_chip, monkeypatch)
+    return eng, compiled.as_text()
 
 
 @pytest.mark.parametrize("program", ["decode", "tile"])
@@ -282,19 +290,40 @@ def test_latent_step_programs_read_the_pool_where_it_lies(
     pool, 2.7 GB, at each end of both programs, to have a key block's
     positions in the lanes for the decode row's two products; with the
     slots' write in a branch it relaid it for the write: temp 3.04 GB
-    against 0.02 and 0.38, read off these compiles, PR 50.) No kernel takes
-    either form yet, and no array holds the keys of a whole scratch."""
+    against 0.02 and 0.38, read off these compiles, PR 50.) No array holds
+    the keys of a whole scratch. The decode row takes no kernel yet. The
+    TILE goes through the latent kernel of ops/tile_attention.py once a
+    layer, which is what the engine's counters say (8 of 8): the layer of
+    the scratch reaches it as `[576, 19456]`, row-major like the scratch
+    itself and copied by no op (the loop relaid each layer twice a tile,
+    `{2,3,1,0}` and `{1,2,0}`, 2 x 22 MB), the loop's float32 score block
+    `[64, 1024, 512]` (134 MB, through HBM three times a key block) and
+    its carry `[1, 64, 1, 1024, 128]` are in no op, and the program's
+    temporaries are no more than the loop's 0.384 GB (0.292, read off this
+    compile, PR 51)."""
     import re
-    eng, text = _step_program_text("sarvam-105b", program, one_chip,
-                                   monkeypatch)
+    eng, compiled = _step_program("sarvam-105b", program, one_chip,
+                                  monkeypatch)
+    text = compiled.as_text()
     assert eng._slots.shapes == {"lat": (8, 16, 576, 18432)}
-    assert eng._tile_layers == {1024: (8, 0)}
+    assert eng._tile_layers == {1024: (8, 8)}
     assert not re.findall(r"= bf16\[16,576,18432\]", text)
     assert not re.findall(r"= bf16\[8,16,576,18432\]\S* copy\(", text)
     assert set(re.findall(r"bf16\[8,16,576,18432\](\{[^}]*\})", text)) \
         == {"{3,2,1,0:T(8,128)(2,1)}"}
-    assert "tpu_custom_call" not in text
     assert not re.findall(r"\[(?:1,)?19456,64,(?:128|192|256)\]", text)
+    kernels = re.findall(r"%latent_tile_attention\S* = .* custom-call\(",
+                         text)
+    if program == "decode":
+        assert "tpu_custom_call" not in text
+        return
+    assert len(kernels) == text.count("tpu_custom_call") == 8
+    assert not re.findall(r"f32\[(?:1,)*64,(?:1,)?1024,(?:512|128)\]", text)
+    layouts = set(re.findall(r"bf16\[(?:\d+,)*576,19456\]\{([\d,]+)", text))
+    assert layouts <= {"1,0", "2,1,0", "3,2,1,0"}, layouts
+    assert not re.findall(
+        r"= bf16\[(?:\d+,)*576,19456\]\S* (?:copy|transpose)\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 384_121_344
 
 
 def test_flash_by_name_never_returns_the_reference():

@@ -305,8 +305,46 @@ def test_a_slot_reused_inherits_nothing_from_its_last_owner(small):
     assert _is_the_references_greedy(params, m, short, again)
 
 
-def test_engine_counts_the_latents_and_refuses_a_prefix_cache(small):
+@pytest.fixture
+def tile_kernel(monkeypatch):
+    """The tile's predicate made to answer as on the chip for these small
+    widths (no lane tiles: the interpreter asks for none) and the latent
+    kernel of ops/tile_attention.py made to interpret; -> the shapes of q
+    it was handed."""
+    from ray_tpu.models import sparse_attention as sa
+    from ray_tpu.ops import tile_attention as ta
+    calls, compiled = [], ta.latent_tile_attention
+
+    def interpreted(*a):
+        calls.append(a[0].shape)
+        return compiled(*a, interpret=True)
+
+    monkeypatch.setattr(ta, "latent_fits", lambda *a: True)
+    monkeypatch.setattr(sa, "_latent_tile_kernel_takes", ta.latent_fits)
+    monkeypatch.setattr(ta, "latent_tile_attention", interpreted)
+    return calls
+
+
+def test_engine_greedy_tokens_through_the_tile_kernel_are_the_references(
+        small, tile_kernel):
+    """`test_engine_greedy_tokens_are_the_references` with every layer's
+    tile through the Pallas kernel: traced once a layer of the one tile
+    program, a tile of 8 rows of 4 heads."""
+    m, model, params, _ = small
+    prompts = [tokens(n, seed=60 + n) for n in (57, 21, 35)]
+    n_new = [12, 30, 20]
+    got = _greedy(_engine(model, params), prompts, n_new)
+    assert tile_kernel == [(1, TILE, 4, 24)] * 3
+    for p, n, g in zip(prompts, n_new, got):
+        assert len(g) == n and _is_the_references_greedy(params, m, p, g)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["loop", "kernel"])
+def test_engine_counts_the_latents_and_refuses_a_prefix_cache(
+        small, request, kernel):
     _, model, params, _ = small
+    if kernel:
+        request.getfixturevalue("tile_kernel")
     eng = _engine(model, params)
     _greedy(eng, [tokens(40, seed=50)], [6])
     st = eng.stats()
@@ -318,10 +356,11 @@ def test_engine_counts_the_latents_and_refuses_a_prefix_cache(small):
     assert st["mla_rows_live"] == sum(n + 1 for n in range(40, 45))
     assert st["mla_rows_streamed"] == 1 * (40 + 1) + 4 * (48 + 1)
     # five tiles of 8 rows hold the prompt's 40: each dispatch sends the
-    # three layers' tiles through the blocked loop, none through a kernel
+    # three layers' tiles through the blocked loop, or, where the
+    # predicate takes them, every one through the kernel
     assert st["prefill_dispatches"] == 5
     assert st["tile_attn_layers"] == 5 * 3
-    assert st["tile_kernel_layers"] == 0
+    assert st["tile_kernel_layers"] == (5 * 3 if kernel else 0)
     with pytest.raises(ValueError, match="beyond K and V"):
         _engine(model, params, prefix_cache_slots=2)
 
