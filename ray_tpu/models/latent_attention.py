@@ -38,10 +38,16 @@ Two forms compute the same numbers (`tests/test_sarvam_model.py`):
   cached rows as they lie, ONE KV "head" of latent_dim + rope_dim for the
   scores whose first latent_dim columns are also the value: one read of a
   block serves both, and nothing is expanded (a row that expanded 9 k
-  positions would pay 155 GFLOP a layer). The row's own latent is folded
-  into the running softmax beside the pool's, as `_row_attention` folds a
-  row's own key and value, so the caller's one write after the layers is
-  the only one.
+  positions would pay 155 GFLOP a layer). On a TPU, where the shapes fit it
+  (`sparse_attention._latent_row_kernel_takes`), the pool is read through
+  the latent kernel of ops/decode_attention.py: of each slot the key blocks
+  up to ITS last live one and of a slot that holds nothing none, a block's
+  float32 scores, its probabilities and the running softmax held in VMEM.
+  `row_attention`'s loop, which walks every slot's blocks up to the longest
+  slot's last, is the kernel's reference, step for step, and what runs
+  everywhere else. The row's own latent is folded into the running softmax
+  beside the pool's, as `_row_attention` folds a row's own key and value,
+  so the caller's one write after the layers is the only one.
 
 The softmax's scale is head_dim ** -0.5, times YaRN's factor squared where
 the rotary is scaled (`softmax_scale`). The cached forward hands the call's
@@ -155,11 +161,13 @@ def tile_attention(q, cache, own, pos0, w_uk, w_uv, scale):
 def row_attention(q, own, pool, layer, lens, w_uk, w_uv, scale):
     """One row a slot, absorbed: q [B, 1, H, head_dim] at position lens[b]
     against layer `layer` (traced) of the pool [n_layers, B, W, M], which
-    holds the positions below lens[b] and is read where it lies, in key
-    blocks up to the LONGEST live slot's last (what
-    `sparse_attention.decode_positions_read` counts for a pool no kernel
-    takes), every slot's block in each step; `own` [B, 1, W], the row's own
-    latent as projected, beside them -> [B, 1, H, v_head_dim]."""
+    holds the positions below lens[b] and is read where it lies: through
+    the Pallas kernel where `_latent_row_kernel_takes` (the module's
+    docstring), each slot's key blocks up to its own last; else in key
+    blocks up to the LONGEST live slot's last, every slot's block in each
+    step (`sparse_attention.latent_positions_read` counts either); `own`
+    [B, 1, W], the row's own latent as projected, beside them
+    -> [B, 1, H, v_head_dim]."""
     B, _, H, _ = q.shape
     W, M = pool.shape[2:]
     R, Dn = w_uk.shape[0], w_uk.shape[-1]
@@ -189,10 +197,15 @@ def row_attention(q, own, pool, layer, lens, w_uk, w_uv, scale):
         return fold(carry, rows, i * block + jnp.arange(block)[None, :]
                     < lens[:, None])
 
-    carry = jax.lax.fori_loop(
-        0, (jnp.max(lens) + block - 1) // block, step,
-        (jnp.full((B, H), sa._NEG, jnp.float32),
-         jnp.zeros((B, H), jnp.float32), jnp.zeros((B, H, R), jnp.float32)))
+    if sa._latent_row_kernel_takes(M, H, W, R):
+        carry = decode_attention.latent_pool_decode_attention(
+            qa, pool, layer, lens, R, scale)
+    else:
+        carry = jax.lax.fori_loop(
+            0, (jnp.max(lens) + block - 1) // block, step,
+            (jnp.full((B, H), sa._NEG, jnp.float32),
+             jnp.zeros((B, H), jnp.float32),
+             jnp.zeros((B, H, R), jnp.float32)))
     _, l, acc = fold(carry, jnp.swapaxes(own, 1, 2).astype(pool.dtype),
                      jnp.ones((B, 1), bool))
     mean = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)

@@ -313,6 +313,24 @@ def _latent_tile_kernel_takes(S: int, M: int, H: int, R: int, Dn: int,
         S, M, H, R, Dn, Dr, Dv)
 
 
+def _latent_row_kernel_takes(M: int, H: int, W: int, R: int) -> bool:
+    """`_kernel_reads`' twin for a pool of latents
+    (models/latent_attention.py `row_attention`): whether one absorbed row
+    a slot of H heads reads a pool of M positions of W values, the first R
+    the value, through the latent kernel of ops/decode_attention.py."""
+    return jax.default_backend() == "tpu" and decode_attention.latent_fits(
+        M, H, W, R)
+
+
+def _blocks_passed(lens, block: int, by_row: bool) -> int:
+    """Whole key blocks of `block` positions and the row's own: up to each
+    row's own last live block, or up to the longest row's, for every
+    row."""
+    if not by_row:
+        lens = [max(lens, default=0)] * len(lens)
+    return sum(-(-n // block) * block + 1 for n in lens)
+
+
 def decode_positions_read(lens, M: int, Hkv: int, D: int) -> int:
     """Host arithmetic: the positions of K and V that
     `sparse_decode_attention` passes over for decode rows whose slots hold
@@ -320,10 +338,18 @@ def decode_positions_read(lens, M: int, Hkv: int, D: int) -> int:
     too. Whole key blocks: up to each row's own last live one where the
     kernel reads, up to the longest row's, for every row, where the XLA
     loop does."""
-    block = decode_attention.block_of(M)
-    if not _kernel_reads(M, Hkv, D):
-        lens = [max(lens, default=0)] * len(lens)
-    return sum(-(-n // block) * block + 1 for n in lens)
+    return _blocks_passed(lens, decode_attention.block_of(M),
+                          _kernel_reads(M, Hkv, D))
+
+
+def latent_positions_read(lens, M: int, H: int, W: int, R: int) -> int:
+    """`decode_positions_read` of a pool of latents [.., W, M] under H
+    heads (`latent_attention.row_attention`): each row's own blocks, of
+    the latent kernel's length, where `_latent_row_kernel_takes`; the
+    loop's blocks up to the longest row's, for every row, elsewhere."""
+    if _latent_row_kernel_takes(M, H, W, R):
+        return _blocks_passed(lens, decode_attention.latent_block_of(M), True)
+    return _blocks_passed(lens, decode_attention.block_of(M), False)
 
 
 def sparse_decode_attention(q, k_new, v_new, qi, w, ki_new, k_cache,

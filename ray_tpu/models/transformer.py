@@ -589,9 +589,13 @@ def decode_rows_read(cfg: "TransformerConfig", slot_len: int):
     whole length and masks the rest). A model without kinds or indexer
     reads K and V in the pools where they lie (`_row_attention`):
     `kv_rows_*`; an indexer's rows `dsa_rows_*`; an "mla" layer's rows pass
-    over the latents, ONE row a position for all heads, by the XLA loop
-    (`mla_rows_*`); "hyb", "att" and "lin" layers are not counted."""
-    from ray_tpu.models.sparse_attention import decode_positions_read
+    over the latents, ONE row a position for all heads
+    (`latent_positions_read`: through the latent kernel of
+    ops/decode_attention.py, each row's own blocks, or by the XLA loop, the
+    longest row's for every row: `mla_rows_*`); "hyb", "att" and "lin"
+    layers are not counted."""
+    from ray_tpu.models.sparse_attention import (decode_positions_read,
+                                                 latent_positions_read)
     kinds = cfg.mixer_kinds or ()
     Hkv, D = cfg.n_kv_heads, cfg.head_dim
 
@@ -619,8 +623,9 @@ def decode_rows_read(cfg: "TransformerConfig", slot_len: int):
                 win_rows_live=sum(min(n, cfg.window) for n in live))
         if "mla" in kinds:
             out.update(
-                mla_rows_streamed=decode_positions_read(
-                    lens, slot_len, 1, cfg.latent_dim + cfg.rope_dim),
+                mla_rows_streamed=latent_positions_read(
+                    lens, slot_len, cfg.n_heads,
+                    cfg.latent_dim + cfg.rope_dim, cfg.latent_dim),
                 mla_rows_live=sum(live))
         return out
     return read

@@ -24,6 +24,11 @@ weighted values) so that the caller folds further keys in (a decode row's
 own) before dividing. Off the TPU it only runs with `interpret=True`; the
 choice between this kernel and the XLA form belongs to the caller
 (`models/sparse_attention.py` `sparse_decode_attention`).
+
+`latent_pool_decode_attention`, below the first, is a second kernel for a
+pool of LATENTS (`models/latent_attention.py` `row_attention`): the two
+share `block_of`'s rule and the index map that repeats a slot's last live
+block, and nothing else.
 """
 
 from __future__ import annotations
@@ -203,3 +208,149 @@ def pool_decode_attention(q, k_pool, v_pool, layer, lens, mask=None, *,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), lens.astype(jnp.int32),
       q, kf, vf, code)
     return m[..., 0], l[..., 0], acc
+
+
+# ------------------------------------------------------------------ latent
+# A pool of LATENTS (models/latent_attention.py): a position keeps
+# `[c ‖ k_r]`, W = R + Dr values that all H heads share, and the pool keeps
+# the positions last, `[n_layers, B, W, M]`: no head axis, the positions in
+# the lanes, ONE array that is key (all W rows) and value (the first R).
+# The decode row comes ABSORBED, `qa [B, H, W]`, so a grid step is two
+# products on one block `[W, block]`: the scores `[H, W] x [W, block]` and
+# the probabilities against the block's first R rows, contracted over the
+# lanes; the only mask is `position < lens[b]`. A slot that holds nothing
+# is sent to the block the step before it fetched, so sixteen idle slots
+# cost no read. (My chip run, PR 52, one layer of 16 slots of 18,432
+# positions of 576, 64 heads, ms at 8 slots live of 6.8k-9.6k / all 16 of
+# 6.4k-9.6k: `row_attention`'s XLA loop 0.464 / 0.463; this kernel at
+# blocks of 512 / 1,024 / 2,048 0.200 / 0.154 / 0.148 and 0.331 / 0.273 /
+# 0.274; the scores held transposed `[block, H]` and the accumulator
+# `[R, H]` 0.231 / 0.223 / 0.223 and 0.390 / 0.411 / 0.423; masking a
+# slot's last block alone no faster; idle slots fetching their own first
+# block 0.172 for 0.154. A grid step that computes nothing costs 0.09 us.)
+_LATENT_MAX_BLOCK = 2048
+# two buffers of a block [576, 2048] are 4.7 MB in bf16 and 9.4 in float32,
+# the float32 scores and probabilities 0.5 MB each
+_LATENT_VMEM = 32 * 2 ** 20
+
+
+def latent_block_of(M: int) -> int:
+    """The positions a grid step of the latent kernel reads of a slot."""
+    return block_of(M, _LATENT_MAX_BLOCK)
+
+
+def latent_fits(M: int, H: int, W: int, R: int) -> bool:
+    """Whether the compiled latent kernel takes a pool of M positions of W
+    values, the first R of them the value, under H heads: a block of
+    positions and the value whole lane tiles, the W rows and the heads
+    whole sublane tiles of either type."""
+    return (latent_block_of(M) % _LANES == 0 and R % _LANES == 0
+            and 0 < R <= W and W % 16 == 0 and H % 8 == 0)
+
+
+def _latent_kernel(layer_ref, lens_ref, slot_ref, last_ref, q_ref, lat_ref,
+                   acc_ref, m_ref, l_ref, *, block: int, scale: float):
+    """One block of one slot's latents into the slot's running softmax,
+    which lives in the output blocks as `_kernel`'s does:
+    `latent_attention.row_attention`'s `fold`, one slot of it."""
+    b, i = pl.program_id(0), pl.program_id(1)
+    R = acc_ref.shape[-1]
+
+    @pl.when(i == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(i * block < lens_ref[b])
+    def _():
+        q, rows = q_ref[...], lat_ref[...]
+        both = jnp.promote_types(q.dtype, rows.dtype)
+        s = jnp.dot(q.astype(both), rows.astype(both),
+                    preferred_element_type=jnp.float32) * scale  # [H, block]
+        ok = i * block + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block), 1) < lens_ref[b]
+        s = jnp.where(ok, s, NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = jnp.broadcast_to(
+            alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True),
+            l_ref.shape)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:R], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+
+def latent_pool_decode_attention(qa, pool, layer, lens, R: int, scale: float,
+                                 *, max_block: int = _LATENT_MAX_BLOCK,
+                                 interpret: bool = False):
+    """The absorbed rows qa [B, H, W] against layer `layer` (traced) of the
+    pool [n_layers, B, W, M], slot b's positions below lens[b], the scores
+    times `scale` (a Python number) and the first `R` of a position's W
+    values its value -> the running softmax's (largest score [B, H], sum
+    [B, H], weighted latents [B, H, R]), all float32 and not yet divided.
+    Of each slot the blocks up to its last live one are read, of a slot
+    that holds nothing none."""
+    B, H, W = qa.shape
+    M = pool.shape[3]
+    if not latent_fits(M, H, W, R) or pool.shape[1:3] != (B, W):
+        raise ValueError(
+            f"the latent decode kernel takes one absorbed row a slot "
+            f"against whole lane tiles of positions and of values: got qa "
+            f"{qa.shape}, a pool {pool.shape}, values of {R}")
+    block = block_of(M, max_block)
+    lens = lens.astype(jnp.int32)
+    # the slot whose block a step fetches, and that slot's last live block:
+    # a slot's own, or where it holds nothing those of the nearest slot
+    # below it that does (slot 0's first block where there is none)
+    slot = jax.lax.cummax(jnp.where(lens > 0, jnp.arange(B, dtype=jnp.int32),
+                                    0))
+    last = jnp.maximum((lens[slot] + block - 1) // block - 1, 0)
+    acc, m, l = _latent_call(B, M, H, W, R, block, float(scale), interpret)(
+        jnp.reshape(layer, (1,)).astype(jnp.int32), lens, slot, last, qa,
+        pool)
+    return m[..., 0], l[..., 0], acc
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_call(B: int, M: int, H: int, W: int, R: int, block: int,
+                 scale: float, interpret: bool):
+    """The latent kernel's `pallas_call` of one shape, built ONCE, so that
+    a stack's layers share one traced kernel (ops/tile_attention.py
+    `_call`)."""
+
+    def lat_block(b, i, layer_ref, lens_ref, slot_ref, last_ref):
+        # past the slot's last live block: that block again (no new DMA);
+        # a slot that holds nothing: the block the step before fetched
+        at = jnp.where(lens_ref[b] > 0, jnp.minimum(i, last_ref[b]),
+                       last_ref[b])
+        return layer_ref[0], slot_ref[b], 0, at
+
+    def per_slot(b, i, layer_ref, lens_ref, slot_ref, last_ref):
+        return b, 0, 0
+
+    stat = jax.ShapeDtypeStruct((B, H, _LANES), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, block=block, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, M // block),
+            in_specs=[
+                pl.BlockSpec((None, H, W), per_slot),
+                pl.BlockSpec((None, None, W, block), lat_block),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, H, R), per_slot),
+                pl.BlockSpec((None, H, _LANES), per_slot),
+                pl.BlockSpec((None, H, _LANES), per_slot),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((B, H, R), jnp.float32), stat, stat],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_LATENT_VMEM),
+        name="latent_pool_decode_attention",
+        interpret=interpret,
+    )

@@ -153,11 +153,11 @@ def _step_program(config, program, one_chip, monkeypatch):
     """(the engine, its "decode" or "tile" program compiled)
     at a benchmark configuration's own size, for a described v5e. The
     engine is built on shapes: nothing is allocated and nothing runs. The
-    three predicates that ask for the backend (`_kernel_reads`, a decode
-    row's pool kernel; `_tile_kernel_takes` and
-    `_latent_tile_kernel_takes`, a tile's flash kernels) see the CPU's
-    here: they are made to answer as on the chip, so that the
-    program compiled is the one the cell runs."""
+    four predicates that ask for the backend (`_kernel_reads` and
+    `_latent_row_kernel_takes`, a decode row's pool kernels;
+    `_tile_kernel_takes` and `_latent_tile_kernel_takes`, a tile's flash
+    kernels) see the CPU's here: they are made to answer as on the chip,
+    so that the program compiled is the one the cell runs."""
     import numpy as np
     from flax.core import meta
 
@@ -181,6 +181,8 @@ def _step_program(config, program, one_chip, monkeypatch):
                         tile_attention.fits)
     monkeypatch.setattr(sparse_attention, "_latent_tile_kernel_takes",
                         tile_attention.latent_fits)
+    monkeypatch.setattr(sparse_attention, "_latent_row_kernel_takes",
+                        decode_attention.latent_fits)
     engine = dict(cfg["engine"], prefix_cache_slots=0)
     del engine["max_ongoing_requests"]
     eng = InferenceEngine(model, params, EngineConfig(**engine))
@@ -291,7 +293,17 @@ def test_latent_step_programs_read_the_pool_where_it_lies(
     positions in the lanes for the decode row's two products; with the
     slots' write in a branch it relaid it for the write: temp 3.04 GB
     against 0.02 and 0.38, read off these compiles, PR 50.) No array holds
-    the keys of a whole scratch. The decode row takes no kernel yet. The
+    the keys of a whole scratch. The DECODE ROWS read the pool through the
+    latent kernel of ops/decode_attention.py once a layer, alone and behind
+    a tile: eight calls in the decode program and eight more in the tile
+    program, whose sixth operand is the program's own parameter, the pool
+    whole and row-major (no `copy` or `transpose` makes it); no `while` is
+    left under `mla_row` (the XLA loop over key blocks, eight of them in
+    either program before PR 52) and no op outside the kernel makes a key
+    block's scores or exponentials `[16, 64, 512]` float32. The decode
+    program's temporaries are 29.8 MB where the loop's were 16.6 (the
+    eight calls' outputs, 3 MB a layer; read off this compile, PR 52), the
+    tile program's 0.258 GB where the loop's rows left 0.254. The
     TILE goes through the latent kernel of ops/tile_attention.py once a
     layer, which is what the engine's counters say (8 of 8): the layer of
     the scratch reaches it as `[576, 19456]`, row-major like the scratch
@@ -309,21 +321,33 @@ def test_latent_step_programs_read_the_pool_where_it_lies(
     assert eng._tile_layers == {1024: (8, 8)}
     assert not re.findall(r"= bf16\[16,576,18432\]", text)
     assert not re.findall(r"= bf16\[8,16,576,18432\]\S* copy\(", text)
+    # (the second: the row kernel's constraint on its operand, row-major too)
     assert set(re.findall(r"bf16\[8,16,576,18432\](\{[^}]*\})", text)) \
-        == {"{3,2,1,0:T(8,128)(2,1)}"}
+        == {"{3,2,1,0:T(8,128)(2,1)}", "{3,2,1,0}"}
     assert not re.findall(r"\[(?:1,)?19456,64,(?:128|192|256)\]", text)
     kernels = re.findall(r"%latent_tile_attention\S* = .* custom-call\(",
                          text)
+    rows = re.findall(
+        r"%latent_pool_decode_attention\S* = .* custom-call\(([^)]*)\)", text)
+    pool = re.search(r"%(\S+) = bf16\[8,16,576,18432\]\S* parameter\(",
+                     text).group(1)
+    assert len(rows) == 8
+    assert all(ops.split(", ")[5].split("*/")[-1] == "%" + pool
+               for ops in rows), rows
+    assert not re.findall(r"mla_row/\S*while", text)
+    assert not re.findall(
+        r"= f32\[16,64,512\]\S* (?:exponential|convolution)\(", text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
     if program == "decode":
-        assert "tpu_custom_call" not in text
+        assert text.count("tpu_custom_call") == 8 and temp <= 32_000_000
         return
-    assert len(kernels) == text.count("tpu_custom_call") == 8
+    assert len(kernels) == 8 and text.count("tpu_custom_call") == 16
     assert not re.findall(r"f32\[(?:1,)*64,(?:1,)?1024,(?:512|128)\]", text)
     layouts = set(re.findall(r"bf16\[(?:\d+,)*576,19456\]\{([\d,]+)", text))
     assert layouts <= {"1,0", "2,1,0", "3,2,1,0"}, layouts
     assert not re.findall(
         r"= bf16\[(?:\d+,)*576,19456\]\S* (?:copy|transpose)\(", text)
-    assert compiled.memory_analysis().temp_size_in_bytes <= 384_121_344
+    assert temp <= 384_121_344
 
 
 def test_flash_by_name_never_returns_the_reference():

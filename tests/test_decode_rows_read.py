@@ -51,7 +51,8 @@ def _cfg(family):
                      win_rows_live=1 + 6 + 16 + 16 + 16),
      dict(win_rows_streamed=0 + 8 + 24 + 24 + 24)),
     # sarvam's "mla" layers: the latents where they lie, one KV "head" of
-    # latent + rope key, as K and V of the dense model's rows
+    # latent + rope key, by their own kernel's predicate and block (32 of
+    # 96 positions too)
     ("mla", dict(mla_rows_streamed=LOOP, mla_rows_live=LIVE),
      dict(mla_rows_streamed=KERNEL)),
 ], ids=["dense", "moe", "indexer", "blk_lin", "hyb", "win_att", "mla"])
@@ -66,6 +67,8 @@ def test_the_counters_of_a_family_and_their_closed_forms(family, loop,
     assert read([]) == dict.fromkeys(loop, 0)
     # where the Pallas kernel reads (a TPU, shapes that fit it)
     monkeypatch.setattr(sa, "_kernel_reads", lambda M, Hkv, D: True)
+    monkeypatch.setattr(sa, "_latent_row_kernel_takes",
+                        lambda M, H, W, R: True)
     assert read(LENS) == {**loop, **kernel}
     # each row alone, summed, is what the rows give together there
     alone = [read([n]) for n in LENS]

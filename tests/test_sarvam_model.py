@@ -16,9 +16,10 @@ tile); the absorbed row form is the expanded form on one latent to
 rounding; the pool is ONE array of latent + rope values a position with no
 head axis (the positions last), 1,152 B a position a layer at the published
 widths in bf16; the
-engine's greedy tokens are the reference's, a slot reused inherits nothing,
-the engine counts the latents its rows pass over and refuses a prefix
-cache; YaRN at factor 1 is `rope` bit for bit and at 40 the reference's
+engine's greedy tokens are the reference's (through the XLA loops and
+through each of the two Pallas kernels, interpreted), a slot reused inherits
+nothing, the engine counts the latents its rows pass over and refuses a
+prefix cache; YaRN at factor 1 is `rope` bit for bit and at 40 the reference's
 frequencies and scale; the ranks' shares of an expert layer add up to the
 whole layer; and each planted fault moves the logits.
 """
@@ -223,10 +224,17 @@ def _slots_cache(model, params, lens, toks):
     return cache
 
 
-def test_rows_behind_a_tile_give_what_they_give_alone(small):
+ROW_FORMS = pytest.mark.parametrize("form", ["loop", "row_kernel"])
+
+
+@ROW_FORMS
+def test_rows_behind_a_tile_give_what_they_give_alone(small, request, form):
     """The engine's step: a tile of another prompt and, behind it, one
-    decode row a slot at its own length."""
+    decode row a slot at its own length (read by the XLA loop, or through
+    the Pallas kernel where the predicate takes the pool)."""
     _, model, params, _ = small
+    if form == "row_kernel":
+        request.getfixturevalue(form)
     lens = [40, 24, 9]
     toks = [tokens(60, seed=10 + b) for b in range(3)]
     slots = _slots_cache(model, params, lens, toks)
@@ -292,10 +300,14 @@ def test_engine_greedy_tokens_are_the_references(small):
         assert len(g) == n and _is_the_references_greedy(params, m, p, g)
 
 
-def test_a_slot_reused_inherits_nothing_from_its_last_owner(small):
+@ROW_FORMS
+def test_a_slot_reused_inherits_nothing_from_its_last_owner(small, request,
+                                                            form):
     """One slot: a long request fills it, then a shorter one takes it and
     gives what a fresh engine gives, which is the reference's."""
     m, model, params, _ = small
+    if form == "row_kernel":
+        request.getfixturevalue(form)
     long_, short = tokens(70, seed=41), tokens(9, seed=42)
     eng = _engine(model, params, n_slots=1)
     _greedy(eng, [long_], [10])
@@ -337,6 +349,55 @@ def test_engine_greedy_tokens_through_the_tile_kernel_are_the_references(
     assert tile_kernel == [(1, TILE, 4, 24)] * 3
     for p, n, g in zip(prompts, n_new, got):
         assert len(g) == n and _is_the_references_greedy(params, m, p, g)
+
+
+@pytest.fixture
+def row_kernel(monkeypatch):
+    """`tile_kernel`'s twin for the decode rows: `row_attention`'s
+    predicate made to answer as on the chip for these small widths and the
+    latent kernel of ops/decode_attention.py made to interpret; -> the
+    shapes of the absorbed rows it was handed."""
+    from ray_tpu.models import sparse_attention as sa
+    from ray_tpu.ops import decode_attention as da
+    calls, compiled = [], da.latent_pool_decode_attention
+
+    def interpreted(qa, *a):
+        calls.append(qa.shape)
+        return compiled(qa, *a, interpret=True)
+
+    monkeypatch.setattr(da, "latent_fits", lambda *a: True)
+    monkeypatch.setattr(sa, "_latent_row_kernel_takes", da.latent_fits)
+    monkeypatch.setattr(da, "latent_pool_decode_attention", interpreted)
+    return calls
+
+
+def test_engine_greedy_tokens_through_the_row_kernel_are_the_references(
+        small, row_kernel, monkeypatch):
+    """`test_engine_greedy_tokens_are_the_references` with every layer's
+    decode rows through the Pallas kernel, alone and behind a tile: traced
+    once a layer of the decode program and of the tile program, three
+    slots' rows of 4 heads absorbed to 32 + 8 values. And what the engine
+    counts of their reads: where the kernel reads, each row's own key
+    blocks of 8 and its own position; where the loop runs, the longest
+    row's for every row."""
+    m, model, params, _ = small
+    prompts = [tokens(n, seed=70 + n) for n in (57, 21, 35)]
+    n_new = [12, 30, 20]
+    eng = _engine(model, params)
+    issued, count = [], eng._rows_read_of
+    eng._rows_read_of = lambda lens: issued.append(lens) or count(lens)
+    got = _greedy(eng, prompts, n_new)
+    assert row_kernel == [(3, 4, 40)] * 6
+    for p, n, g in zip(prompts, n_new, got):
+        assert len(g) == n and _is_the_references_greedy(params, m, p, g)
+    blocks = lambda n: -(-n // 8) * 8 + 1                    # noqa: E731
+    by_row = sum(blocks(n) for lens in issued for n in lens)
+    by_longest = sum(len(lens) * blocks(max(lens)) for lens in issued)
+    assert eng.stats()["mla_rows_streamed"] == by_row < by_longest
+    from ray_tpu.models import sparse_attention as sa
+    monkeypatch.setattr(sa, "_latent_row_kernel_takes", lambda *a: False)
+    assert sum(count(lens)["mla_rows_streamed"] for lens in issued) \
+        == by_longest
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["loop", "kernel"])
