@@ -351,6 +351,17 @@ class InferenceEngine:
             for T in self._prefill_tiles[::-1]}
         self.tile_attn_layers = 0
         self.tile_kernel_layers = 0
+        # a model whose last layers keep no cache
+        # (`transformer.cacheless_tail`): the rows the tile programs held
+        # (a tile and the slots' rows behind it), summed over the prefill
+        # dispatches, and those of them that entered those layers: a
+        # number the model hands back with the caches when a program is
+        # traced, taken where it picks the rows (`TransformerLM._decode`),
+        # kept here by the program's rows
+        self._has_tail = transformer.cacheless_tail(mcfg) < mcfg.n_layers
+        self._tail_rows: dict = {}
+        self.tile_rows = 0
+        self.tail_rows_run = 0
         # an expert layer that holds a share of its experts: [rows the
         # expert matmuls computed, picks of real rows that landed on a held
         # expert], summed over layers and calls (models/moe.py sows them).
@@ -439,6 +450,8 @@ class InferenceEngine:
             out = model.apply({"params": params}, tokens, cache=cache, **kw,
                               mutable=["counters"] if count_moe else False)
             (logits, new), counted = out if count_moe else (out, None)
+            if "tail_rows" in new:      # at trace time, a program once
+                self._tail_rows[tokens.shape[1]] = new["tail_rows"]
             new = tuple(c[name] for c in (new, new.get("slots"))
                         if c is not None for name in names)
             if not count_moe:
@@ -1021,6 +1034,10 @@ class InferenceEngine:
         self.tile_kernel_layers += kernel
         with self._mesh_ctx():
             slot, scratch = self._call_prefill(scratch, host)
+        if self._has_tail:
+            rows = tile + (self.config.n_slots if self._ride else 0)
+            self.tile_rows += rows
+            self.tail_rows_run += self._tail_rows[rows]
         if self.prefill_compile_count > compiles0:
             events.record_instant(
                 "engine.compile", category="engine",
@@ -1176,6 +1193,9 @@ class InferenceEngine:
             out["tile_attn_layers"] = self.tile_attn_layers
             out["tile_kernel_layers"] = self.tile_kernel_layers
         out.update(self._rows_read)
+        if self._has_tail:
+            out["tile_rows"] = self.tile_rows
+            out["tail_rows_run"] = self.tail_rows_run
         if self._count_moe:
             out["moe_rows_computed"] = int(self._moe_counts[0])
             out["moe_local_picks"] = int(self._moe_counts[1])

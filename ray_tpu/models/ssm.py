@@ -30,6 +30,10 @@ Two forms of the same mathematics, chosen by the caller from what it holds
 K - 1 rows a request owns, carried from tile to tile and from decode row to
 decode row as the other state is.
 
+A second recurrence, Mamba-1's ("S6": `s6_scan`, `s6_step`, below), is a
+layer's whole mixer where the first is a branch: its decay differs by
+channel AND by state column, so it has no matmul form.
+
 A row no request owns (`real` False: a tile's padded tail, a dead slot's
 row behind a tile) neither decays the state nor adds to it nor enters the
 tail. Every decay is exp of a DIFFERENCE of cumulated exponents, never
@@ -43,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 CHUNK = 128          # rows a chunk of `ssd_scan` holds
+S6_CHUNK = 16        # rows `s6_scan` unrolls in one turn of its loop
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -152,4 +157,84 @@ def ssd_scan(x, dt, A, Bm, Cm, D, state, real=None, chunk: int = CHUNK):
         state, out = jax.lax.scan(
             body, state, tuple(chunks(a) for a in (x, dt, Bm, Cm, real)))
         out = out.swapaxes(0, 1).reshape(B, T + pad, H, P)
+        return out[:, :T], state
+
+
+# ------------------------------------------------------------ Mamba-1 (S6)
+# For each of I channels i, with x_t, a step dt_t > 0 A CHANNEL, A [N, I]
+# < 0, D [I], and B_t, C_t [N] shared by all channels, a state S [N, I] in
+# float32:
+#
+#     S_t = exp(dt_t[i] A[n, i]) S_{t-1} + dt_t[i] x_t[i] B_t[n]
+#     y_t[i] = sum_n S_t[n, i] C_t[n] + D[i] x_t[i]
+#
+# The decay exp(dt_t[i] A[n, i]) differs in every element of the state, so
+# the products of a chunk's rows are no matmul (`ssd_scan`'s decay is one
+# number a head): the recurrence is elementwise, 7 operations an element of
+# the state a row. The state keeps its N columns FIRST and the channels in
+# the lanes ([N, I], 16 x 5120: whole vector registers; [I, N] would fill
+# an eighth of each). The scan's form, by count (PERF.md section 6, PR 53):
+# a `lax.scan` a ROW pays the loop's turn and its few fusions' launches a
+# row, 1,024 times a layer a tile, to move 30 KB; an associative scan passes
+# [rows, N, I] float32 through HBM a dozen times. `s6_scan` is between: a
+# `lax.scan` over chunks of S6_CHUNK rows that carries the state, the
+# chunk's rows unrolled, so that a turn's intermediates are [S6_CHUNK, N, I]
+# (5 MB) and no array is [rows, N, I] a tile long.
+
+
+def _s6_row(S, x, dt, A, Bm, Cm, D):
+    """One row of the recurrence: S [B, N, I] float32, x and dt [B, I]
+    float32 (dt 0 on a row no request owns: the state is then left as it
+    was, exactly), Bm and Cm [B, N] float32 -> (the new state, y [B, I])."""
+    S = jnp.exp(dt[:, None, :] * A) * S \
+        + (dt * x)[:, None, :] * Bm[:, :, None]
+    return S, jnp.sum(S * Cm[:, :, None], axis=1) + D * x
+
+
+def s6_step(x, dt, A, Bm, Cm, D, state, real=None):
+    """One row a slot: x [B, 1, I], dt [B, 1, I] float32 (> 0), A [N, I]
+    float32 (< 0), Bm and Cm [B, 1, N], D [I], state [B, N, I] float32 ->
+    (y [B, 1, I] in x's type, the new state). `real` [B] bool: a row that
+    is not leaves its state as it was."""
+    with jax.named_scope("s6_step"):
+        dt = dt[:, 0] if real is None else dt[:, 0] * real[:, None]
+        new, y = _s6_row(state, x[:, 0].astype(jnp.float32), dt, A,
+                         Bm[:, 0].astype(jnp.float32),
+                         Cm[:, 0].astype(jnp.float32),
+                         D.astype(jnp.float32))
+        return y[:, None].astype(x.dtype), new
+
+
+def s6_scan(x, dt, A, Bm, Cm, D, state, real=None, chunk: int = S6_CHUNK):
+    """A sequence x [B, T, I], dt [B, T, I] float32 (> 0), A [N, I]
+    float32, Bm and Cm [B, T, N], D [I], from `state` [B, N, I] float32 ->
+    (y [B, T, I] in x's type, the state after the sequence's real rows).
+    `real` [B, T] bool: the rows a request owns (absent: all)."""
+    B, T, I = x.shape
+    C = min(chunk, T)
+    pad = -T % C
+    if real is not None:
+        dt = dt * real[..., None]
+    if pad:                             # rows of dt 0: no request's
+        x, dt, Bm, Cm = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+                         for a in (x, dt, Bm, Cm))
+    n = (T + pad) // C
+    # [n, C, B, ..]: the chunks are the scan's axis, a chunk's rows next
+    chunks = lambda a: a.reshape((B, n, C) + a.shape[2:]).transpose(  # noqa: E731,E501
+        1, 2, 0, 3)
+    D32 = D.astype(jnp.float32)
+
+    def body(S, xs):
+        xn, dtn, bn, cn = xs
+        xn, bn, cn = (a.astype(jnp.float32) for a in (xn, bn, cn))
+        ys = []
+        for t in range(C):
+            S, y = _s6_row(S, xn[t], dtn[t], A, bn[t], cn[t], D32)
+            ys.append(y)
+        return S, jnp.stack(ys).astype(x.dtype)
+
+    with jax.named_scope("s6_scan"):
+        state, out = jax.lax.scan(
+            body, state, tuple(chunks(a) for a in (x, dt, Bm, Cm)))
+        out = out.transpose(2, 0, 1, 3).reshape(B, T + pad, I)
         return out[:, :T], state

@@ -173,6 +173,24 @@ class TransformerConfig:
     rope_dim: int = 0
     v_head_dim: int = 0
     rope_yarn: Optional["Yarn"] = None
+    # a decoder whose second half keeps no cache (models/diff_attention.py).
+    # "s6": a Mamba-1 mixer as a layer of its own, `s6_inner` channels with
+    # a float32 state of `s6_state` columns a channel and a step a channel
+    # through `s6_dt_rank`, behind a convolution of `s6_conv` taps; "gmu": a
+    # gated memory unit, which reads the LAST "s6" layer's output at its
+    # position and keeps nothing; "xat": attention that projects a query
+    # alone and reads the K and V of the last "att" layer before it, whose
+    # pools it reads and does not write. `diff_attn`: the "win", "att" and
+    # "xat" layers are DIFFERENTIAL attention, heads in pairs, K and V kept
+    # by pair ([.., n_kv_heads / 2, 2 head_dim]), no rotary, biases on
+    # their projections. `layer_norm`: the blocks' and the final norm are
+    # LayerNorm, scale and bias
+    s6_inner: int = 0
+    s6_state: int = 16
+    s6_conv: int = 4
+    s6_dt_rank: int = 0
+    diff_attn: bool = False
+    layer_norm: bool = False
 
     def __post_init__(self):
         if not self.head_dim:
@@ -217,6 +235,28 @@ class TransformerConfig:
                     "\"mla\" layers: every layer of the stack, with "
                     "latent_dim, v_head_dim and an even rope_dim below "
                     "head_dim")
+            if "s6" in kinds and ({"hyb", "lin"} & set(kinds)
+                                  or not self.s6_inner
+                                  or not self.s6_dt_rank):
+                raise ValueError(
+                    "\"s6\" layers: s6_inner and s6_dt_rank, and no \"hyb\" "
+                    "or \"lin\" layer beside them (each keeps the pool "
+                    "\"s\" in a shape of its own)")
+            first = {k: kinds.index(k) for k in set(kinds)}
+            if ("gmu" in kinds and first.get("s6", len(kinds))
+                    > first["gmu"]) or ("xat" in kinds and not (
+                        self.diff_attn and first.get("att", len(kinds))
+                        < first["xat"])):
+                raise ValueError(
+                    "a \"gmu\" layer follows an \"s6\" layer, an \"xat\" "
+                    "layer an \"att\" layer of a model with diff_attn")
+            if self.diff_attn and (
+                    set(kinds) & {"blk", "hyb", "mla", "lin"}
+                    or self.n_heads % 2 or self.n_kv_heads % 2
+                    or self.n_heads % self.n_kv_heads):
+                raise ValueError(
+                    "diff_attn: heads and KV heads in pairs, beside "
+                    "\"s6\", \"gmu\", \"win\", \"att\" and \"xat\" layers")
             if "win" in kinds and not 0 < self.window <= self.win_ring:
                 raise ValueError(
                     f"\"win\" layers: window {self.window} > 0 and "
@@ -234,12 +274,37 @@ class TransformerConfig:
 # keeps (`cache_shapes` says their shapes, CACHE_POS_AXIS their nature)
 KIND_CACHES = {"blk": ("k", "v", "kp"), "lin": ("s",),
                "hyb": ("k", "v", "s", "c"), "win": ("wk", "wv"),
-               "att": ("k", "v"), "mla": ("lat",)}
+               "att": ("k", "v"), "mla": ("lat",), "s6": ("s", "c"),
+               "gmu": (), "xat": ()}
+# a kind that keeps no cache and reads another kind's: the pools of the
+# last layer of that kind before it
+KIND_READS = {"xat": "att"}
 # the kinds whose decode rows read K and V (an "mla" layer's: the latents)
 # in the WHOLE pools, by the layer's number (`Attention._in_place`,
 # `LatentAttention`; a model without kinds: `TransformerLM._decode`,
 # `whole`)
-_IN_PLACE = ("hyb", "win", "att", "mla")
+_IN_PLACE = ("hyb", "win", "att", "mla", "xat")
+# the kinds whose states (the pools with no position) are read out of the
+# RUNNING pool and written back to it before the next layer
+# (`TransformerLM._decode`), and the scopes their writes stand under
+_STATE_SCOPES = {"hyb": ("hyb_ssm", "ssd"), "s6": (None, "s6")}
+
+
+def _hands_on(cfg: "TransformerConfig") -> bool:
+    """Whether the stack's layers hand values on beside the hidden state
+    (`Block`, `shared`): the kinds of models/diff_attention.py."""
+    return cfg.diff_attn or bool(
+        {"s6", "gmu", "xat"} & set(cfg.mixer_kinds or ()))
+
+
+def cacheless_tail(cfg: "TransformerConfig") -> int:
+    """The number of the first layer from which on no layer keeps a cache
+    (`n_layers` where the last layer keeps one, as in every model without
+    "gmu" or "xat" layers): a row whose logits nobody reads needs none of
+    those layers, since nothing of it is kept for a later row."""
+    kinds = cfg.mixer_kinds or (None,) * cfg.n_layers
+    keeps = [i for i, k in enumerate(kinds) if KIND_CACHES.get(k, ("k",))]
+    return keeps[-1] + 1
 
 _PARTITION_OFF = __import__("threading").local()
 
@@ -385,11 +450,14 @@ def _cache_write(cache, new, idx, pos_axis: int = -3, row_axis: int = -4):
         start = [0] * cache.ndim
         start[p_axis] = idx
         return jax.lax.dynamic_update_slice(cache, new, start)
-    if p_axis == cache.ndim - 1:
+    if p_axis == cache.ndim - 1 or not _heads_tile(cache):
         # positions in the lanes (the indexer's keys): a scatter there
         # makes XLA relay the whole pool for the write and back (2.7 ms a
         # step at 8 slots x 17k, my chip run, PR 34); a row at a time is
-        # written in place
+        # written in place. So is a pool whose heads the chip does not
+        # keep together (`_heads_tile`): the scatter relaid the eight
+        # rings whole, 1 GB in and out a step (read off the decode program
+        # compiled for a described v5e, PR 53)
         for b in range(cache.shape[b_axis]):
             start = [0] * cache.ndim
             start[b_axis], start[p_axis] = b, idx[b]
@@ -411,6 +479,17 @@ def _cache_write(cache, new, idx, pos_axis: int = -3, row_axis: int = -4):
             scatter_indices_batching_dims=(0,)),
         indices_are_sorted=True, unique_indices=True,
         mode=jax.lax.GatherScatterMode.CLIP)
+
+
+def _heads_tile(cache) -> bool:
+    """Whether the chip keeps a position's KV heads together in a pool
+    [.., M, Hkv, D]: Hkv whole sublane tiles of 8, or a divisor of one. At
+    10 heads it keeps the pool
+    positions-minor with the heads outside them, and what is written for
+    the row-major order (a scatter batched over the rows, a `cond`'s
+    branch) makes it relay the whole pool."""
+    Hkv = cache.shape[-2]
+    return Hkv % 8 == 0 or 8 % Hkv == 0
 
 
 def _ring_write(ring, new, pos0, pos_axis: int = -3):
@@ -554,8 +633,10 @@ def tile_attention_layers(cfg: "TransformerConfig", tile: int,
             f"a window of {cfg.window}: it takes window + prefill budget")
     same = jnp.dtype(cache_dtype or cfg.dtype) == jnp.dtype(cfg.dtype)
     layers = kernel = 0
+    H, Hkv, D = _attended_heads(cfg)
     for kind in cfg.mixer_kinds or ():
-        if kind not in _IN_PLACE:
+        # (an "xat" layer attends the rows its caller samples, no tile)
+        if kind not in _IN_PLACE or kind in KIND_READS:
             continue
         window, M = (cfg.window, cfg.win_ring) if kind == "win" \
             else (0, scratch_len)
@@ -565,10 +646,17 @@ def tile_attention_layers(cfg: "TransformerConfig", tile: int,
                 tile, M, cfg.n_heads, cfg.latent_dim,
                 cfg.head_dim - cfg.rope_dim, cfg.rope_dim, cfg.v_head_dim)
         else:
-            took = sa._tile_kernel_takes(
-                tile, M, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, window)
+            took = sa._tile_kernel_takes(tile, M, H, Hkv, D, window)
         kernel += bool(same and took)
     return layers, kernel
+
+
+def _attended_heads(cfg: "TransformerConfig"):
+    """(query heads, KV heads, head size) as the attention's loops and
+    kernels meet them: a model with `diff_attn` keeps K and V by pair."""
+    if cfg.diff_attn:
+        return cfg.n_heads, cfg.n_kv_heads // 2, 2 * cfg.head_dim
+    return cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
 
 def decode_rows_read(cfg: "TransformerConfig", slot_len: int):
@@ -592,12 +680,26 @@ def decode_rows_read(cfg: "TransformerConfig", slot_len: int):
     over the latents, ONE row a position for all heads
     (`latent_positions_read`: through the latent kernel of
     ops/decode_attention.py, each row's own blocks, or by the XLA loop, the
-    longest row's for every row: `mla_rows_*`); "hyb", "att" and "lin"
-    layers are not counted."""
-    from ray_tpu.models.sparse_attention import (decode_positions_read,
-                                                 latent_positions_read)
+    longest row's for every row: `mla_rows_*`); a cache by position that
+    SEVERAL layers read (an "att" layer's and the "xat" layers' behind it):
+    `xkv_rows_*`, each summed over the layers that read it; "hyb", other
+    "att" and "lin" layers are not counted. A model with `diff_attn` reads
+    by `diff_attention.row_attention`'s loop wherever it runs: the longest
+    row's blocks for every row."""
+    from ray_tpu.models import sparse_attention as sa
+    from ray_tpu.models.sparse_attention import latent_positions_read
     kinds = cfg.mixer_kinds or ()
-    Hkv, D = cfg.n_kv_heads, cfg.head_dim
+    _, Hkv, D = _attended_heads(cfg)
+
+    def decode_positions_read(lens, M, Hkv, D):
+        if cfg.diff_attn:
+            return sa._blocks_passed(
+                lens, sa.decode_attention.block_of(M), False)
+        return sa.decode_positions_read(lens, M, Hkv, D)
+    # the layers that read ONE cache: the "xat" layers and the "att" layer
+    # before them
+    readers = sum(k in KIND_READS for k in kinds)
+    readers += bool(readers)
 
     def read(lens):
         live = [n + 1 for n in lens]
@@ -621,6 +723,11 @@ def decode_rows_read(cfg: "TransformerConfig", slot_len: int):
                 win_rows_streamed=decode_positions_read(
                     [min(n, ring) for n in lens], ring, Hkv, D) - len(lens),
                 win_rows_live=sum(min(n, cfg.window) for n in live))
+        if readers:
+            out.update(
+                xkv_rows_streamed=readers * decode_positions_read(
+                    lens, slot_len, Hkv, D),
+                xkv_rows_live=readers * sum(live))
         if "mla" in kinds:
             out.update(
                 mla_rows_streamed=latent_positions_read(
@@ -1128,16 +1235,31 @@ class Block(nn.Module):
     kind: Optional[str] = None      # of cfg.mixer_kinds, where it has them
     # one of a model with experts' leading layers that keep the dense MLP
     dense_mlp: bool = False
+    # the layer's number in the stack, and whether the sequence's rows are
+    # some the caller picked (`_shared_mixer`)
+    depth: int = 0
+    picked: bool = False
 
     @nn.compact
-    def __call__(self, x, positions, cache=None, real=None, slots=None):
+    def __call__(self, x, positions, cache=None, real=None, slots=None,
+                 shared=None):
+        """`shared` (a model with "gmu" or "xat" layers; absent elsewhere):
+        what the stack hands on beside the hidden state, {"mem": the last
+        "s6" layer's output before its gate, "kv": the last "att" layer's K
+        and V (no cache), "kv_rows": its decode rows' own}; the call then
+        returns it, brought up to date, behind its other results."""
         cfg = self.cfg
         # (a sandwich block norms each branch at its output too)
         after = lambda y, name: RMSNorm(  # noqa: E731
             cfg.norm_eps, cfg.dtype, name=name)(y) if cfg.sandwich_norm \
             else y
-        normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="attn_norm")(x)
-        if self.kind == "hyb":
+        pre = lambda name: (LayerNorm if cfg.layer_norm else RMSNorm)(  # noqa: E731,E501
+            cfg.norm_eps, cfg.dtype, name=name)
+        normed = pre("attn_norm")(x)
+        if shared is not None:
+            att, shared = self._shared_mixer(normed, positions, cache, slots,
+                                             real, shared)
+        elif self.kind == "hyb":
             att = self._hybrid(normed, positions, cache, slots, real)
         elif self.kind == "lin":
             att = LightningAttention(cfg, name="attn")(
@@ -1156,7 +1278,7 @@ class Block(nn.Module):
         if cfg.residual_scale != 1.0:       # muP's depth scaling
             att = cfg.residual_scale * att
         h = x + att
-        normed = RMSNorm(cfg.norm_eps, cfg.dtype, name="mlp_norm")(h)
+        normed = pre("mlp_norm")(h)
         if cfg.n_experts > 0 and not self.dense_mlp:
             from ray_tpu.models.moe import MoEMLP
             # serving drops no pick; `real`: the rows a request owns;
@@ -1169,9 +1291,37 @@ class Block(nn.Module):
         y = after(y, "post_mlp_norm")
         if cfg.residual_scale != 1.0:
             y = cfg.residual_scale * y
-        if cache is not None:
-            return h + y, aux, new_rows
-        return h + y, aux
+        out = (h + y, aux) + ((new_rows,) if cache is not None else ())
+        return out if shared is None else out + (shared,)
+
+    def _shared_mixer(self, normed, positions, cache, slots, real, shared):
+        """The mixer of a layer of a stack whose layers hand values on
+        (models/diff_attention.py) -> (what `Attention` returns, `shared`
+        brought up to date): an "s6" layer leaves its output before the
+        gate as "mem", a "gmu" layer reads it; an "att" layer leaves its K
+        and V ("kv", without a cache) or its decode rows' own ("kv_rows"),
+        an "xat" layer reads them."""
+        from ray_tpu.models import diff_attention as da
+        cfg, kind = self.cfg, self.kind
+        if kind == "s6":
+            att, new, mem = da.S6Mixer(cfg, name="attn")(
+                normed, cache, slots, real)
+            shared = dict(shared, mem=mem)
+        elif kind == "gmu":
+            att, new = da.GatedMemory(cfg, name="attn")(
+                normed, shared["mem"]), (((), ()) if slots else ())
+        elif cfg.diff_attn and kind in ("win", "att", "xat"):
+            att, new = da.DiffAttention(
+                cfg, kind, self.depth, self.picked, name="attn")(
+                normed, positions, cache, slots, shared)
+            if kind == "att":
+                shared = dict(shared, **{
+                    "kv" if cache is None else "kv_rows":
+                    new[1] if slots else new})
+        else:
+            raise ValueError(f"a {kind!r} layer in a stack that hands "
+                             f"values on: not written")
+        return (att if cache is None else (att, new)), shared
 
     def _hybrid(self, normed, positions, cache, slots, real):
         """A "hyb" layer's mixer: the attention heads and the state-space
@@ -1294,7 +1444,10 @@ def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int):
     [n, rows, heads, d_head, d_state] and its convolution's tails
     [n, rows, taps - 1, channels] of the "hyb" layers; K and V of the
     "att" layers by position, and of the "win" layers in rings of
-    `win_ring` places, [n, rows, win_ring, Hkv, D], whatever `max_len`;
+    `win_ring` places, [n, rows, win_ring, Hkv, D], whatever `max_len`
+    (with `diff_attn` by PAIR of heads, [.., Hkv / 2, 2 D]); the states
+    [n, rows, s6_state, s6_inner] and the tails [n, rows, taps - 1,
+    s6_inner] of the "s6" layers; no pool for a "gmu" or an "xat" layer;
     the latents of the "mla" layers, [n, rows, latent_dim + rope_dim,
     max_len]: one entry a position, the normed latent and behind it the
     one rotated key, no head axis, and the positions LAST, in the lanes,
@@ -1305,6 +1458,8 @@ def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int):
     for a described v5e, PERF.md section 6, PR 50)."""
     if cfg.mixer_kinds:
         kv = kv_cache_shape(cfg, batch, max_len)[1:]
+        if cfg.diff_attn:               # by pair (models/diff_attention.py)
+            kv = kv[:2] + _attended_heads(cfg)[1:]
         hyb = "hyb" in cfg.mixer_kinds
         ring = (batch, cfg.win_ring) + kv[2:]
         entry = {"k": kv, "v": kv, "wk": ring, "wv": ring,
@@ -1316,6 +1471,11 @@ def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int):
                  "c": (batch, cfg.ssm_conv - 1, cfg.ssm_heads
                        * cfg.ssm_head_dim + 2 * cfg.ssm_groups
                        * cfg.ssm_state)}
+        if "s6" in cfg.mixer_kinds:
+            # the state's columns FIRST, the channels in the lanes
+            # (models/ssm.py); the tail over the convolution's channels
+            entry.update(s=(batch, cfg.s6_state, cfg.s6_inner),
+                         c=(batch, cfg.s6_conv - 1, cfg.s6_inner))
         layers = {n: sum(n in KIND_CACHES[k] for k in cfg.mixer_kinds)
                   for n in CACHE_POS_AXIS if n in entry}
         return {n: (count,) + entry[n] for n, count in layers.items()
@@ -1468,8 +1628,14 @@ class TransformerLM(nn.Module):
                 block = nn.remat(
                     Block, prevent_cse=False, policy=remat_policy)
             aux_total = jnp.zeros((), jnp.float32)
+            shared = {} if _hands_on(cfg) else None
             for i in range(cfg.n_layers):
                 kind = cfg.mixer_kinds[i] if cfg.mixer_kinds else None
+                if shared is not None:
+                    x, aux_i, shared = block(
+                        cfg, kind=kind, depth=i, name=f"layer_{i}")(
+                        x, positions, shared=shared)
+                    continue
                 x, aux_i = block(cfg, kind=kind,
                                  dense_mlp=i < cfg.n_dense_layers,
                                  name=f"layer_{i}")(x, positions)
@@ -1480,7 +1646,8 @@ class TransformerLM(nn.Module):
             self.sow("losses", "moe_aux", aux_total,
                      reduce_fn=lambda a, b: a + b,
                      init_fn=lambda: jnp.zeros((), jnp.float32))
-        x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
+        x = (LayerNorm if cfg.layer_norm else RMSNorm)(
+            cfg.norm_eps, cfg.dtype, name="final_norm")(x)
         x = constrain(x, ("batch", "seq", None))
         unembed = None if cfg.tie_embeddings else self._unembed_param()
         if return_hidden:
@@ -1573,6 +1740,8 @@ class TransformerLM(nn.Module):
 
         carry = (x, positions, idx, real,
                  slots and (slots["idx"], slots["on"]))
+        picked = False      # the named rows alone passed the last layers
+        tail_rows = None    # how many rows entered the layers without caches
         if cfg.scan_layers:
             # whole pools ride broadcast beside the layers' numbers; a
             # tile's scratch stays a scanned input
@@ -1605,30 +1774,70 @@ class TransformerLM(nn.Module):
             running = [dict(zip(names, pools)),
                        slots and dict(zip(names, slot_pools))]
             seen: dict = {}
+            # a stack whose layers hand values on (`Block`, `shared`), and
+            # the layer from which on none keeps a cache: the rows the
+            # caller names pass those layers and no other (nothing of a
+            # row is kept there for a later one)
+            shared = {} if _hands_on(cfg) else None
+            tail = cacheless_tail(cfg)
+            lenders = {KIND_READS[k] for k in cfg.mixer_kinds or ()
+                       if k in KIND_READS}
             for i in range(cfg.n_layers):
                 kind = cfg.mixer_kinds[i] if cfg.mixer_kinds else None
                 of = KIND_CACHES.get(kind, names)
                 j = seen[kind] = seen.get(kind, -1) + 1
-                hyb = kind == "hyb"
+                scopes = _STATE_SCOPES.get(kind)
                 ride = whole(kind)
+                reads = of
+                if kind in KIND_READS:  # another layer's pools, its number
+                    lender = KIND_READS[kind]
+                    reads, of, j = KIND_CACHES[lender], (), seen[lender]
                 number = (j,) * any(ride)
 
                 def read(now, ride):
                     # (a state has no position: always the layer's own)
                     return tuple(now[n] if ride
                                  and CACHE_POS_AXIS[n] is not None
-                                 else now[n][j] for n in of)
-                x, _aux, new_rows = Block(
+                                 else now[n][j] for n in reads)
+                if i == tail and logit_rows is not None and (
+                        slots or L > 1):
+                    picked = True
+                    x, positions = (jnp.take(a, logit_rows, axis=1,
+                                             mode="clip")
+                                    for a in (x, positions))
+                    if "mem" in shared:
+                        shared = dict(shared, mem=jnp.take(
+                            shared["mem"], logit_rows, axis=1, mode="clip"))
+                if i == tail:       # the rows that go on from here
+                    tail_rows = x.shape[0] * x.shape[1]
+                out = Block(
                     cfg, chunked_prefill, kind, i < cfg.n_dense_layers,
-                    name=f"layer_{i}")(
+                    i, picked, name=f"layer_{i}")(
                     x, positions,
                     (read(running[0], ride[0]), idx) + number, real,
                     slots and (read(running[1], ride[1]), *carry[-1])
-                    + number)
+                    + number, *(() if shared is None else (shared,)))
+                x, _aux, new_rows = out[:3]
+                if shared is not None:
+                    shared = out[3]
                 for into, now, new in zip(got, running, new_rows if slots
                                           else (new_rows,)):
                     for n, r in zip(of, new):
-                        if not (hyb and CACHE_POS_AXIS[n] is None):
+                        if kind in lenders and now is running[0] \
+                                and L > 1 and n not in CACHE_RINGS:
+                            # a tile's K and V that later layers read: in
+                            # the scratch before them
+                            if jnp.ndim(idx):
+                                raise ValueError("a tile whose K and V "
+                                                 "later layers read: one "
+                                                 "start for its rows")
+                            at = [0] * now[n].ndim
+                            at[0], at[CACHE_POS_AXIS[n]] = j, idx
+                            with jax.named_scope("diff_attend"):
+                                now[n] = jax.lax.dynamic_update_slice(
+                                    now[n], r[None].astype(now[n].dtype), at)
+                            continue
+                        if not (scopes and CACHE_POS_AXIS[n] is None):
                             into[n].append(r)
                             continue
                         # under the scope of the form that made it: XLA
@@ -1636,21 +1845,27 @@ class TransformerLM(nn.Module):
                         # write, and the trace files a fusion under its
                         # root's scope (models/ssm.py names them)
                         rows = now is running[1] or L == 1
-                        with jax.named_scope("hyb_ssm"), jax.named_scope(
-                                "ssm_conv" if n == "c" else
-                                "ssd_step" if rows else "ssd_scan"):
+                        with (jax.named_scope(scopes[0]) if scopes[0]
+                              else contextlib.nullcontext()), \
+                                jax.named_scope(
+                                "ssm_conv" if n == "c" else scopes[1]
+                                + ("_step" if rows else "_scan")):
                             now[n] = jax.lax.dynamic_update_index_in_dim(
                                 now[n], r.astype(now[n].dtype), j, 0)
             pools = tuple(running[0][n] for n in names)
             slot_pools = slots and tuple(running[1][n] for n in names)
             # a state is not stacked: its layers are written one by one
+            # (a pool written above hands no rows on: an empty tuple)
             rows = tuple(
-                tuple(jnp.stack(into[n]) if CACHE_POS_AXIS[n] is not None
+                tuple(jnp.stack(into[n]) if into[n]
+                      and CACHE_POS_AXIS[n] is not None
                       else tuple(into[n]) for n in names)
-                if into[names[0]] else None for into in got)
+                if any(into.values()) else None for into in got)
             rows = rows if slots else rows[0]
 
         def write(n, pool, rows, idx, tile=False):
+            if isinstance(rows, tuple) and not rows:
+                return pool                 # written where it was made
             if CACHE_POS_AXIS[n] is None:
                 # a state: each layer's replaced whole, in place (the
                 # layers stacked and handed back cost two more passes
@@ -1663,7 +1878,8 @@ class TransformerLM(nn.Module):
                 idx = sa.pooled_at(idx, _block_geometry(cfg),
                                    None if tile else pool.shape[2])
             if n in CACHE_RINGS:            # at the position modulo the ring
-                scope = "win_attend" if tile else "win_row"
+                scope = ("diff" if cfg.diff_attn else "win") \
+                    + ("_attend" if tile else "_row")
                 with jax.named_scope(scope):
                     if tile:
                         return _ring_write(pool, rows, idx)
@@ -1678,6 +1894,10 @@ class TransformerLM(nn.Module):
         new_cache = {n: write(n, p, r, idx, L > 1)
                      for n, p, r in zip(names, pools, rows)}
         new_cache["idx"] = idx + L
+        if tail_rows is not None:
+            # a number of the program's shapes, for whoever counts what the
+            # program ran (`InferenceEngine.stats()`, `tail_rows_run`)
+            new_cache["tail_rows"] = tail_rows
         if slots:
             # a `cond` a pool: one around both cost the tile's program
             # 3.6 ms at 20 layers (28.17 against 24.58 ms, my chip runs,
@@ -1688,20 +1908,26 @@ class TransformerLM(nn.Module):
             # program compiled for a described v5e, PR 50; with no slot
             # live the rows land at an idle slot's length, as the decode
             # program's do, in places its next owner's insert overwrites)
+            # (nor a pool whose heads do not tile, `_heads_tile`: the
+            # branch wanted it row-major, 2 x 1 GB relaid a tile step,
+            # read off the same compile, PR 53)
             new_cache["slots"] = {
                 n: p if isinstance(r, tuple) and not r
-                else write(n, p, r, slots["idx"]) if n == "lat"
+                else write(n, p, r, slots["idx"])
+                if n == "lat" or not _heads_tile(p)
                 else jax.lax.cond(
                     slots["on"], functools.partial(write, n),
                     lambda pool, *_: pool, p, r, slots["idx"])
                 for n, p, r in zip(names, slot_pools, slot_rows)}
-        if logit_rows is not None:
+        if logit_rows is not None and not picked:
             # before the norm and the head: a tile's caller samples 1 + S
             # of its T + S rows, and the head over all of them was 14.6 of
             # a 51.6 ms tile at a 261k vocabulary (PERF.md section 6, PR 45)
+            # (a stack with a cacheless tail took them before that tail)
             x = jnp.take(x, logit_rows, axis=1, mode="clip")
         with jax.named_scope("lm_head"):
-            x = RMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
+            x = (LayerNorm if cfg.layer_norm else RMSNorm)(
+                cfg.norm_eps, cfg.dtype, name="final_norm")(x)
             if return_hidden:
                 return x, new_cache
             unembed = None if cfg.tie_embeddings else self._unembed_param()

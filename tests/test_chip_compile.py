@@ -350,6 +350,50 @@ def test_latent_step_programs_read_the_pool_where_it_lies(
     assert temp <= 384_121_344
 
 
+@pytest.mark.parametrize("program", ["decode", "tile"])
+def test_paired_pools_are_read_and_written_where_they_lie(
+        one_chip, no_compile_cache, monkeypatch, program):
+    """The step programs of `phi-4-mini-flash-reasoning.reason-longctx` (32
+    unrolled layers of five kinds, 16 slots of 12,288 positions): K and V
+    are kept by PAIR of heads, 10 pairs of 128 a position, which is no
+    whole sublane tile, so the chip keeps the pools positions-minor with
+    the heads outside them and whatever wants them row-major makes it
+    copy a pool WHOLE. No op does: the decode rows read the ONE cache by
+    position (eight layers) and the eight rings through
+    `diff_attention.row_attention`'s loop over the five axes (the pool
+    kernel's matrix view copied both, temp 1.55 GB a decode step, and the
+    kind does not call it), their rows are written a
+    slot at a time and not by a scatter (which relaid the rings, 1 GB in
+    and out), and the slots' writes behind a tile stand under no `cond`
+    (the branch relaid the one cache, 2 x 1 GB): read off these compiles,
+    PR 53. The tile's nine layers that attend go through the flash kernel
+    of ops/tile_attention.py on the padded queries."""
+    import re
+    eng, compiled = _step_program("phi-4-mini-flash-reasoning", program,
+                                  one_chip, monkeypatch)
+    text = compiled.as_text()
+    assert eng._slots.shapes == {
+        "k": (1, 16, 12288, 10, 128), "v": (1, 16, 12288, 10, 128),
+        "wk": (8, 16, 1536, 10, 128), "wv": (8, 16, 1536, 10, 128),
+        "s": (9, 16, 16, 5120), "c": (9, 16, 3, 5120)}
+    assert eng._tile_layers == {1024: (9, 9)}
+    # no op copies a pool, a layer of one, or the pool as a matrix of rows
+    assert not re.findall(
+        r"= bf16\[(?:1|8),16,(?:12288|1536),10,128\]\S* copy\(", text)
+    assert not re.findall(r"= bf16\[16,(?:12288|1536),10,128\]", text)
+    assert not re.findall(r"= bf16\[(?:1|8),16,(?:122880|15360),128\]", text)
+    assert not re.findall(r"= f32\[9,16,16,5120\]\S* copy\(", text)
+    assert not re.findall(r"%pool_decode_attention\S* = .* custom-call\(",
+                          text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if program == "decode":
+        assert "tpu_custom_call" not in text and temp <= 64_000_000
+        return
+    assert len(re.findall(r"%tile_attention\S* = .* custom-call\(",
+                          text)) == 9
+    assert text.count("tpu_custom_call") == 9 and temp <= 420_000_000
+
+
 def test_flash_by_name_never_returns_the_reference():
     """A length the kernel cannot tile raises; it is "auto" that chooses
     by platform and shape (here, on the CPU: the reference)."""
