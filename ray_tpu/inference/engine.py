@@ -259,7 +259,7 @@ class InferenceEngine:
             raise ValueError(
                 f"max_len={cfg.max_len} exceeds the model's "
                 f"max_seq_len={mcfg.max_seq_len}")
-        from ray_tpu.models import transformer
+        from ray_tpu.models import moe, transformer
         beyond = sorted(set(transformer.cache_shapes(mcfg, 1, 1))
                         - {"k", "v"})
         if beyond and cfg.prefix_cache_slots > 0:
@@ -362,15 +362,18 @@ class InferenceEngine:
         self._tail_rows: dict = {}
         self.tile_rows = 0
         self.tail_rows_run = 0
-        # an expert layer that holds a share of its experts: [rows the
+        # an expert layer that holds a share of its experts, or whose
+        # capacity is the whole group at every length (its tile then runs
+        # the grouped form, whose rows follow the routing): [rows the
         # expert matmuls computed, picks of real rows that landed on a held
         # expert], summed over layers and calls (models/moe.py sows them).
         # Each program adds its pair to a running sum in the carry, which
         # comes back in the array the step reads anyway (it wraps at 32
         # bits; `_read` adds the difference since the last one read).
-        # With every expert held both counts are the shapes' (E x rows,
-        # K x rows): nothing to read, and the carry holds no sum
-        self._count_moe = mcfg.n_experts > 0 and bool(mcfg.experts_held)
+        # With every expert held under the dense dispatch both counts are
+        # the shapes' (E x C, K x rows): nothing to read, and the carry
+        # holds no sum
+        self._count_moe = moe.rows_follow_routing(mcfg)
         self._moe_counts = np.zeros((2,), np.int64)
         self._moe_seen = np.zeros((2,), np.uint32)
         # disagg hand-off accounting (serve/disagg.py)

@@ -18,10 +18,10 @@ slots' decode rows behind it), by one of two routers (`cfg.router`):
   top-k of score + `router_bias` (a learned bias an expert that CHOOSES
   and does not weigh) are taken and weighed by their scores, renormalized
   where `route_norm`, times `route_scale`;
-- per-expert capacity C = ceil(capacity_factor * L * k / E). Training
-  drops the tokens over capacity (standard Switch behavior, keeps shapes
-  static); serving (`exact`: the cached forward) drops nothing: a pick
-  past its expert's capacity is computed by the overflow route below;
+- per-expert capacity C = min(L, ceil(capacity_factor * L * k / E)).
+  Training drops the tokens over capacity (standard Switch behavior, keeps
+  shapes static); serving (`exact`: the cached forward) drops nothing: a
+  pick past its expert's capacity is computed by the overflow route below;
 - aux load-balancing loss (Switch eq. 4): E * Σ_e frac_tokens_e · mean_prob_e.
 
 The experts are SwiGLUs of `expert_d_ff` (a width of their own beside the
@@ -38,16 +38,45 @@ experts' alone: what the absent experts would add to a token is left out
 (no code stands in for the other ranks or their exchange), so the shares
 of all ranks add up to the whole layer's result.
 
-The cost of the expert matmuls is held-experts x groups x C rows, and the
-form is chosen from the shapes alone, here: where C is the group's length
-(few wide experts, Mixtral's 8 with top-2, at capacity_factor = E / k;
-any layer at one row a group) nothing can overflow and the dispatch is
-all there is; where C < L (many narrow experts: 128, top-8, 16 held would
-compute E / k = 16 times the rows really routed at C = L, and take a C
-about twice the expected load) the serving forward adds the overflow
-route: the picks past an expert's capacity, rare, go through every held
-expert over the whole group under a `lax.cond` that runs only in a step
-where some pick overflowed.
+The cost of the expert matmuls, and which form runs where. The form is
+chosen from the shapes alone, here (`takes_grouped`), between two:
+
+- the DENSE DISPATCH (`_dispatched`): held-experts x groups x C rows,
+  every held expert over C rows of every group. Training (`exact` False:
+  the drops and the aux loss as they are); a group of one row or of a few
+  (a decode step: 16 groups of L = 1, every expert is hit and the weights'
+  stream is the floor); a layer sharded over a mesh; and C < L (many
+  narrow experts: 128, top-8, 16 held would compute E / k = 16 times the
+  rows really routed at C = L, and take a C about twice the expected
+  load), where the serving forward adds the overflow route: the picks
+  past an expert's capacity, rare, go through every held expert over the
+  whole group under a `lax.cond` that runs only in a step where some pick
+  overflowed.
+- the GROUPED FORM (`_grouped`): the rows that were routed. Where the
+  serving forward's C is the group's whole length (few wide experts,
+  Mixtral's 8 with top-2, at capacity_factor = E / k) the dense dispatch
+  computes E / k times the rows routed and no pick can overflow, so the
+  picks are sorted by expert (`ops/grouped_matmul.py` `sort_picks`: a
+  stable sort on (expert, tail, row, k)) and ONE grouped SwiGLU runs over
+  them, each expert's weights read where they lie, and each row takes its
+  k results back weighed by its gates (the gates rounded to the rows'
+  type as the dense form rounds them, the sum in float32). It is taken
+  where the sorted layout's static row bound k L + E (tile - 1) is under
+  the dense form's E L (Mixtral's step of 272 rows: 544 + 8 x 127 = 1,560
+  against 2,176 at a tile of 128). **Each expert's rows start on a
+  boundary of a span of two tiles**: a span then belongs to one expert,
+  whose weight blocks pass it once; a kernel that lets two experts share
+  a block of rows visits it twice and streams the second's blocks again,
+  and so does a span of one tile that an expert's load passes, and this
+  step is bound by the weights' stream. What is computed follows the
+  tile: a tile of a span that holds no row is skipped. On a TPU the rows
+  go through the Pallas kernel where its `fits` says the widths tile
+  (`_kernel_takes`), and through its XLA form everywhere else (the sort,
+  the gather and the combine are then XLA's too). Under a scan over the
+  kernel is handed EVERY layer's experts and the layer's number
+  (`TransformerLM._stacked_experts`): a layer's own weights are a slice
+  of the stacked parameters there, which XLA fuses into the dense form's
+  einsums and copies whole, 2.8 GB a layer, for a custom call.
 
 Rows no request owns (the padded tail of a prefill tile, an idle slot's
 decode row; `real` False) are routed nowhere where that matters: their
@@ -63,8 +92,11 @@ in a matmul in a last bit: it does on the CPU backend).
 
 Counters: where the caller makes the "counters" collection mutable
 (`apply(..., mutable=["counters"])`, the engine for a layer that holds a
-share of its experts) the layer sows int32[2]: the rows its expert matmuls
-computed and the picks of real rows that landed on a held expert.
+share of its experts or whose capacity is the whole group) the layer sows
+int32[2]: the rows its expert matmuls computed (the grouped form's: each
+held expert's picks rounded up to the tile) and the picks of real rows
+that landed on a held expert. `rows_follow_routing` says for which models
+the engine has something to read.
 """
 
 from __future__ import annotations
@@ -77,7 +109,44 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ray_tpu.models.transformer import _p
+from ray_tpu.ops import grouped_matmul
+from ray_tpu.parallel.mesh import current_mesh
 from ray_tpu.parallel.sharding import constrain
+
+
+def _kernel_takes(N: int, D: int, F: int, tile: int, dtype) -> bool:
+    """Whether the grouped form's rows go through the Pallas kernel (a TPU
+    backend and widths that tile) or through its XLA form."""
+    return jax.default_backend() == "tpu" \
+        and grouped_matmul.fits(N, D, F, tile, dtype)
+
+
+def capacity(cfg, L: int) -> int:
+    """The rows of a group of L an expert holds."""
+    return min(L, max(1, math.ceil(
+        cfg.capacity_factor * L * cfg.expert_top_k / cfg.n_experts)))
+
+
+def takes_grouped(cfg, L: int, exact: bool = True) -> bool:
+    """Whether the expert layer runs a group of L rows in the grouped form
+    (the module's docstring): from `exact`, the shapes and the mesh."""
+    E, K = cfg.n_experts, cfg.expert_top_k
+    held = (cfg.experts_held or (0, E))[1]
+    tile = grouped_matmul.row_tile(L, K, E)
+    mesh = current_mesh()
+    return (exact and capacity(cfg, L) == L
+            and K * L + held * (tile - 1) < held * L
+            and (mesh is None or mesh.size == 1))
+
+
+def rows_follow_routing(cfg) -> bool:
+    """Whether the rows the expert matmuls compute are more than the
+    shapes say: a layer that holds a share of its experts, or one whose
+    capacity is the whole group at every length (its longer groups run
+    the grouped form)."""
+    return cfg.n_experts > 0 and (
+        bool(cfg.experts_held)
+        or cfg.capacity_factor * cfg.expert_top_k >= cfg.n_experts)
 
 
 def sigmoid_route(x, router, bias, k: int):
@@ -98,16 +167,19 @@ class MoEMLP(nn.Module):
     cfg: Any
 
     @nn.compact
-    def __call__(self, x, real=None, exact: bool = False, tail: int = 0):
+    def __call__(self, x, real=None, exact: bool = False, tail: int = 0,
+                 stack=None):
         """-> (out, aux loss). `real` [B, L] bool: the rows a request
         owns (None: all). `exact`: the serving forward, which drops no
         pick. `tail`: the group's last rows that are counted after the
-        others (the module's docstring)."""
+        others (the module's docstring). `stack`: ((gate, up, down) of ALL
+        the scanned layers, [n_layers, E, ..]; this layer's number), for
+        the grouped form to read this layer's experts where they lie."""
         cfg = self.cfg
         B, L, D = x.shape
         E, K = cfg.n_experts, cfg.expert_top_k
         first, held = cfg.experts_held or (0, E)
-        C = min(L, max(1, math.ceil(cfg.capacity_factor * L * K / E)))
+        C = capacity(cfg, L)
 
         F = cfg.expert_d_ff or cfg.d_ff
         router = self.param(
@@ -130,17 +202,73 @@ class MoEMLP(nn.Module):
             if cfg.route_scale != 1.0:
                 gate_vals = cfg.route_scale * gate_vals
 
-        # expert-choice position: for the j-th routing slot, a token's slot
-        # in expert e's buffer is the number of earlier (token, slot) picks
-        # of e, counting slots in priority order (slot 0 of every token
-        # first — standard top-k dispatch priority)
         sel_all = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # [B,L,K,E]
         counting = self.is_mutable_collection("counters")
         if real is not None and (C < L or counting):
             sel_all = sel_all * real[:, :, None, None]
-        # the held experts' columns; E below is their count from here on
+        # the held experts' columns
         sel = sel_all if held == E else sel_all[..., first:first + held]
-        E = held
+
+        # one stacked array per projection
+        w_gate = self.param(
+            "gate", _p(nn.initializers.lecun_normal(),
+                       "experts", "embed", "mlp"),
+            (held, D, F), cfg.param_dtype)
+        w_up = self.param(
+            "up", _p(nn.initializers.lecun_normal(),
+                     "experts", "embed", "mlp"),
+            (held, D, F), cfg.param_dtype)
+        w_down = self.param(
+            "down", _p(nn.initializers.lecun_normal(),
+                       "experts", "mlp", "embed"),
+            (held, F, D), cfg.param_dtype)
+        weights = w_gate, w_up, w_down
+        if takes_grouped(cfg, L, exact):
+            out, rows, picks = self._grouped(x, gate_vals, gate_idx, real,
+                                             tail, weights, stack)
+        else:
+            out, rows = self._dispatched(x, gate_vals, sel, C, exact, tail,
+                                         weights)
+            picks = None
+        if cfg.n_shared_experts:
+            # the shared expert: every row passes it, whatever it picked
+            # (and whatever this rank holds: it is whole on each)
+            dense = lambda feats, axes, name: nn.DenseGeneral(  # noqa: E731
+                feats, axis=-1, use_bias=False, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, name=name,
+                kernel_init=_p(nn.initializers.lecun_normal(), *axes))
+            with jax.named_scope("moe_shared"):
+                wide = cfg.n_shared_experts * F
+                y = nn.silu(dense(wide, ("embed", "mlp"), "shared_gate")(x)) \
+                    * dense(wide, ("embed", "mlp"), "shared_up")(x)
+                out = out + dense(D, ("mlp", "embed"), "shared_down")(y)
+        if counting:
+            # what `moe_rows_per_pick` divides
+            if picks is None:
+                picks = sel.sum().astype(jnp.int32)
+            self.sow("counters", "rows_and_picks", jnp.stack(
+                [jnp.asarray(rows, jnp.int32), picks]))
+
+        # Switch load-balance loss: encourages uniform routing
+        frac_tokens = sel_all.sum((1, 2)) / (L * K)            # [B,E]
+        mean_probs = probs.mean(1)                             # [B,E]
+        aux = cfg.n_experts * (frac_tokens * mean_probs).sum(-1).mean()
+        return out, aux
+
+    def _dispatched(self, x, gate_vals, sel, C: int, exact: bool, tail: int,
+                    weights):
+        """The dense dispatch: every held expert over C rows a group ->
+        (the routed experts' result, the rows the expert matmuls
+        computed). `sel` [B, L, K, E]: the picks of the E held experts."""
+        B, L, _ = x.shape
+        K, E = sel.shape[2:]
+        cfg = self.cfg
+        w_gate, w_up, w_down = (w.astype(cfg.dtype) for w in weights)
+
+        # expert-choice position: for the j-th routing slot, a token's slot
+        # in expert e's buffer is the number of earlier (token, slot) picks
+        # of e, counting slots in priority order (slot 0 of every token
+        # first — standard top-k dispatch priority)
         def places(sel, taken=0.0):
             n = sel.shape[1]
             flat = sel.transpose(0, 2, 1, 3).reshape(B, K * n, E)
@@ -169,30 +297,11 @@ class MoEMLP(nn.Module):
         expert_in = jnp.einsum("blec,bld->ebcd", dispatch, x)
         expert_in = constrain(expert_in, ("experts", None, None, "embed"))
 
-        dense = lambda feats, axes, name: nn.DenseGeneral(  # noqa: E731
-            feats, axis=-1, use_bias=False, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, name=name,
-            kernel_init=_p(nn.initializers.lecun_normal(), *axes))
-        # one stacked DenseGeneral per projection: E batched matmuls
-        w_gate = self.param(
-            "gate", _p(nn.initializers.lecun_normal(),
-                       "experts", "embed", "mlp"),
-            (E, D, F), cfg.param_dtype)
-        w_up = self.param(
-            "up", _p(nn.initializers.lecun_normal(),
-                     "experts", "embed", "mlp"),
-            (E, D, F), cfg.param_dtype)
-        w_down = self.param(
-            "down", _p(nn.initializers.lecun_normal(),
-                       "experts", "mlp", "embed"),
-            (E, F, D), cfg.param_dtype)
         def experts(rows):                       # [E, .., D] -> [E, .., D]
-            h = jnp.einsum("ebcd,edf->ebcf", rows,
-                           w_gate.astype(cfg.dtype))
-            u = jnp.einsum("ebcd,edf->ebcf", rows, w_up.astype(cfg.dtype))
+            h = jnp.einsum("ebcd,edf->ebcf", rows, w_gate)
+            u = jnp.einsum("ebcd,edf->ebcf", rows, w_up)
             y = nn.silu(h) * u
-            return jnp.einsum("ebcf,efd->ebcd", y,
-                              w_down.astype(cfg.dtype))
+            return jnp.einsum("ebcf,efd->ebcd", y, w_down)
 
         with jax.named_scope("moe_experts"):
             expert_out = experts(expert_in)
@@ -217,21 +326,44 @@ class MoEMLP(nn.Module):
                     experts(jnp.broadcast_to(x, (E,) + x.shape))),
                 lambda: jnp.zeros_like(out))
             rows = rows + spilled.astype(jnp.int32) * (E * B * L)
-        if cfg.n_shared_experts:
-            # the shared expert: every row passes it, whatever it picked
-            # (and whatever this rank holds: it is whole on each)
-            with jax.named_scope("moe_shared"):
-                wide = cfg.n_shared_experts * F
-                y = nn.silu(dense(wide, ("embed", "mlp"), "shared_gate")(x)) \
-                    * dense(wide, ("embed", "mlp"), "shared_up")(x)
-                out = out + dense(D, ("mlp", "embed"), "shared_down")(y)
-        if counting:
-            # what `moe_rows_per_pick` divides
-            self.sow("counters", "rows_and_picks", jnp.stack(
-                [jnp.asarray(rows, jnp.int32), sel.sum().astype(jnp.int32)]))
+        return out, rows
 
-        # Switch load-balance loss: encourages uniform routing
-        frac_tokens = sel_all.sum((1, 2)) / (L * K)            # [B,E]
-        mean_probs = probs.mean(1)                             # [B,E]
-        aux = cfg.n_experts * (frac_tokens * mean_probs).sum(-1).mean()
-        return out, aux
+    def _grouped(self, x, gate_vals, gate_idx, real, tail: int, weights,
+                 stack=None):
+        """The grouped form: the picks of real rows on a held expert,
+        sorted by expert from span boundaries, through ONE grouped SwiGLU
+        over the rows routed, and each row's K results back, weighed by its
+        gates -> (the routed experts' result, the rows computed, the picks
+        computed). With `stack` the kernel is handed every layer's experts
+        as ONE stack [n_layers x E, ..] and the spans' table counts from
+        this layer's first: under a scan a layer's own weights are a slice
+        of the parameters, which XLA copies whole for a custom call."""
+        cfg = self.cfg
+        B, L, D = x.shape
+        K, N = cfg.expert_top_k, B * L
+        first, held = cfg.experts_held or (0, cfg.n_experts)
+        tile = grouped_matmul.row_tile(L, K, cfg.n_experts)
+        local = gate_idx - first
+        valid = (local >= 0) & (local < held)
+        if real is not None:
+            valid &= real[:, :, None]
+        late = jnp.tile(jnp.arange(L) >= L - tail, B) if tail else None
+        span_expert, span_rows, *layout = grouped_matmul.sort_picks(
+            local.reshape(N, K), valid.reshape(N, K), held,
+            grouped_matmul.SPAN * tile, late)
+        if stack is not None:
+            weights, layer = stack
+            weights = (w.reshape((-1,) + w.shape[2:]) for w in weights)
+            span_expert = span_expert + layer * held
+        w_gate, w_up, w_down = (w.astype(cfg.dtype) for w in weights)
+        # the gates rounded to the rows' type as the dense form rounds them
+        gates = (gate_vals * valid).astype(x.dtype).astype(jnp.float32)
+        form = grouped_matmul.routed_swiglu if _kernel_takes(
+            N, D, w_gate.shape[-1], tile, x.dtype) \
+            else grouped_matmul.routed_swiglu_reference
+        with jax.named_scope("moe_experts"):
+            out = form(x.reshape(N, D), w_gate, w_up, w_down, span_expert,
+                       span_rows, *layout, gates.reshape(N, K),
+                       tile=tile).reshape(B, L, D)
+        return (out, grouped_matmul.rows_computed(span_rows, tile),
+                valid.sum().astype(jnp.int32))

@@ -1242,8 +1242,11 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, cache=None, real=None, slots=None,
-                 shared=None):
-        """`shared` (a model with "gmu" or "xat" layers; absent elsewhere):
+                 shared=None, experts=None):
+        """`experts`: the scanned layers' expert weights WHOLE and this
+        layer's number (`TransformerLM._stacked_experts`), where the expert
+        layer reads them in place. `shared` (a model with "gmu" or "xat"
+        layers; absent elsewhere):
         what the stack hands on beside the hidden state, {"mem": the last
         "s6" layer's output before its gate, "kv": the last "att" layer's K
         and V (no cache), "kv_rows": its decode rows' own}; the call then
@@ -1285,7 +1288,7 @@ class Block(nn.Module):
             # the slots' decode rows behind a tile are counted after it
             y, aux = MoEMLP(cfg, name="moe")(
                 normed, real, exact=cache is not None,
-                tail=0 if slots is None else len(slots[1]))
+                tail=0 if slots is None else len(slots[1]), stack=experts)
         else:
             y, aux = MLP(cfg, name="mlp")(normed), jnp.zeros((), jnp.float32)
         y = after(y, "post_mlp_norm")
@@ -1377,18 +1380,24 @@ class DecodeScanBlock(nn.Module):
     Where the call is one row a slot, `layer_rows` are the WHOLE pools,
     broadcast, and `layer` the layer's number, scanned (`_decode`,
     `whole`). `slot_rows`: the slots' pools, where decode rows ride
-    behind a prefill tile: whole and by the same number. Param names
-    mirror ScanBlock ('block' under the scan) so the SAME trained/stacked
-    params apply."""
+    behind a prefill tile: whole and by the same number. `experts_ride`:
+    the first of `layer` is (the layers' expert weights whole, broadcast;
+    this layer's number, scanned), `TransformerLM._stacked_experts`. Param
+    names mirror ScanBlock ('block' under the scan) so the SAME
+    trained/stacked params apply."""
     cfg: TransformerConfig
     chunked: bool = False
+    experts_ride: bool = False
 
     @nn.compact
     def __call__(self, carry, layer_rows, slot_rows, *layer):
         x, positions, idx, real, slots = carry
+        experts = None
+        if self.experts_ride:
+            experts, *layer = layer
         out, _aux, new_rows = Block(self.cfg, self.chunked, name="block")(
             x, positions, (layer_rows, idx, *layer), real,
-            slots and (slot_rows, *slots, *layer))
+            slots and (slot_rows, *slots, *layer), experts=experts)
         return (out, positions, idx, real, slots), new_rows
 
 
@@ -1674,6 +1683,23 @@ class TransformerLM(nn.Module):
                                 unembed.astype(cfg.dtype))
         return logits.astype(jnp.float32) if cfg.logits_fp32 else logits
 
+    def _stacked_experts(self, rows: int):
+        """Where the scanned layers' expert layer takes the grouped form
+        for a group of `rows` rows (models/moe.py): ((gate, up, down)
+        [n_layers, E, ..] as the parameters hold them, the layers'
+        numbers), which ride beside the scan so that the kernel reads a
+        layer's experts in place; else ()."""
+        cfg = self.cfg
+        if not cfg.n_experts or self.is_initializing():
+            return ()
+        from ray_tpu.models.moe import takes_grouped
+        if not takes_grouped(cfg, rows):
+            return ()
+        held = self.variables["params"]["layers"]["block"]["moe"]
+        return ((tuple(nn.meta.unbox(held[w])
+                       for w in ("gate", "up", "down")),
+                 jnp.arange(cfg.n_layers)),)
+
     def _decode(self, x, positions, cache, embed, return_hidden,
                 chunked_prefill=False, logit_rows=None):
         """Serving decode forward: applies every layer against the KV
@@ -1746,17 +1772,22 @@ class TransformerLM(nn.Module):
             # whole pools ride broadcast beside the layers' numbers; a
             # tile's scratch stays a scanned input
             ride = whole(None)
+            # so do the experts' weights where the layer reads them in
+            # place (a layer of them sliced out is copied whole for the
+            # kernel, 2.8 GB a layer at Mixtral's widths)
+            experts = self._stacked_experts(x.shape[1])
             stack = nn.scan(
                 DecodeScanBlock,
                 variable_axes={"params": 0, "counters": 0},
                 split_rngs={"params": True},
                 in_axes=tuple(nn.broadcast if w else 0 for w in ride)
-                + (0,) * any(ride),
+                + ((nn.broadcast, 0),) * len(experts) + (0,) * any(ride),
                 length=cfg.n_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
-            )(cfg, chunked_prefill, name="layers")
+            )(cfg, chunked_prefill, experts_ride=bool(experts),
+              name="layers")
             (x, *_), rows = stack(
-                carry, pools, slot_pools,
+                carry, pools, slot_pools, *experts,
                 *((jnp.arange(cfg.n_layers),) if any(ride) else ()))
         else:
             # layer i reads entry j of ITS kind's pools (every pool's,

@@ -157,15 +157,16 @@ def _step_program(config, program, one_chip, monkeypatch):
     `_latent_row_kernel_takes`, a decode row's pool kernels;
     `_tile_kernel_takes` and `_latent_tile_kernel_takes`, a tile's flash
     kernels) see the CPU's here: they are made to answer as on the chip,
-    so that the program compiled is the one the cell runs."""
+    so that the program compiled is the one the cell runs; so is the
+    expert layer's `moe._kernel_takes`, the grouped form's kernel."""
     import numpy as np
     from flax.core import meta
 
     from perfbench import spec
     from ray_tpu.inference import kv_cache
     from ray_tpu.inference.engine import EngineConfig, InferenceEngine
-    from ray_tpu.models import sparse_attention
-    from ray_tpu.ops import decode_attention, tile_attention
+    from ray_tpu.models import moe, sparse_attention
+    from ray_tpu.ops import decode_attention, grouped_matmul, tile_attention
     cfg = spec.load_config(spec.load_benchmark(), config)
     family = spec.family_of(cfg)
     model = family.build_model(family.model_kwargs(cfg))
@@ -183,6 +184,7 @@ def _step_program(config, program, one_chip, monkeypatch):
                         tile_attention.latent_fits)
     monkeypatch.setattr(sparse_attention, "_latent_row_kernel_takes",
                         decode_attention.latent_fits)
+    monkeypatch.setattr(moe, "_kernel_takes", grouped_matmul.fits)
     engine = dict(cfg["engine"], prefix_cache_slots=0)
     del engine["max_ongoing_requests"]
     eng = InferenceEngine(model, params, EngineConfig(**engine))
@@ -274,6 +276,8 @@ def test_tile_programs_attend_through_the_flash_kernel(
     assert len(re.findall(r"%pool_decode_attention\S* = .* custom-call\(",
                           text)) == layers
     assert text.count("tpu_custom_call") == 2 * layers
+    # (Trinity's experts hold 32 of 256 rows a group: the dense dispatch)
+    assert "grouped_swiglu" not in text
     assert f"f32[1,{Hkv},{G},1024,128]" not in text
     assert len(re.findall(rf"= bf16\[{M},{Hkv * 128}\]\S* fusion\(",
                           text)) == 2 * by_position
@@ -335,6 +339,8 @@ def test_latent_step_programs_read_the_pool_where_it_lies(
     assert all(ops.split(", ")[5].split("*/")[-1] == "%" + pool
                for ops in rows), rows
     assert not re.findall(r"mla_row/\S*while", text)
+    # (its experts hold a part of a group's rows: the dense dispatch)
+    assert "grouped_swiglu" not in text
     assert not re.findall(
         r"= f32\[16,64,512\]\S* (?:exponential|convolution)\(", text)
     temp = compiled.memory_analysis().temp_size_in_bytes
@@ -392,6 +398,56 @@ def test_paired_pools_are_read_and_written_where_they_lie(
     assert len(re.findall(r"%tile_attention\S* = .* custom-call\(",
                           text)) == 9
     assert text.count("tpu_custom_call") == 9 and temp <= 420_000_000
+
+
+@pytest.mark.parametrize("program", ["decode", "tile"])
+def test_mixtrals_step_runs_its_experts_through_the_grouped_kernel(
+        one_chip, no_compile_cache, monkeypatch, program):
+    """The step programs of `mixtral-8x7b.batch-longprompt` (4 scanned
+    layers, 8 experts of 4,096 x 14,336, a 256-row tile with the 16 decode
+    rows behind it). The TILE program's expert layer, whose capacity is
+    the group's 272 rows, goes through the grouped kernel of
+    ops/grouped_matmul.py (one call in the scan's body), which takes the
+    group's rows `[272, 4096]` and hands back their result: the sorted
+    rows and their results (eleven spans of two tiles of 128) never pass
+    through HBM. The layers' experts reach it WHOLE, `[32, 4096, 14336]`,
+    as bitcasts of the program's own parameters: no op's output is an
+    expert's, a layer's or the stack's weights (under the scan a layer's
+    weights are a slice of the parameters, and handed that slice the
+    custom call had XLA copy it out, 2.82 GB a layer, temporaries 2.84 GB:
+    read off this compile, PR 55; the dense form's einsums fuse the
+    slice), no fusion makes the hidden of the dense dispatch
+    `[8, 272, 14336]`, and the temporaries are 2.6 MB (the dense form's
+    pair of `[8, 1, 272, 14336]` and their kin made them 79.7 MB). The
+    DECODE program's groups are one row each: the dense dispatch, no
+    grouped kernel. The decode rows keep the pool kernel in both."""
+    import re
+    eng, compiled = _step_program("mixtral-8x7b", program, one_chip,
+                                  monkeypatch)
+    text = compiled.as_text()
+    calls = re.findall(r"%grouped_swiglu\S* = (\S+) custom-call\(([^)]*)\)",
+                       text)
+    assert len(re.findall(r"%pool_decode_attention\S* = .* custom-call\(",
+                          text)) == 1
+    made = set(re.findall(
+        r"= bf16\[(?:\d+,)*(?:4096,14336|14336,4096)\]\S* ([\w-]+)\(", text))
+    if program == "decode":
+        assert not calls and text.count("tpu_custom_call") == 1
+        assert made <= {"parameter", "get-tuple-element", "dynamic-slice",
+                        "bitcast", "fusion"}
+        return
+    assert len(calls) == 1 and text.count("tpu_custom_call") == 2
+    assert calls[0][0].startswith("bf16[272,4096]")
+    assert made == {"parameter", "get-tuple-element", "bitcast"}, made
+    views = re.findall(
+        r"= bf16\[32,(?:4096,14336|14336,4096)\]\S* (\w+)\(", text)
+    assert views == ["bitcast"] * 3, views
+    assert not re.findall(r"\[8,(?:1,)?272,(?:14336|4096)\]", text)
+    assert not re.findall(r"= bf16\[2816,4096\]", text)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= 4_000_000
+    # the weights, the pools and the scratch, as the parent's (12.709 GB)
+    assert 12_709_000_000 < memory.argument_size_in_bytes < 12_710_000_000
 
 
 def test_flash_by_name_never_returns_the_reference():

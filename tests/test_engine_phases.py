@@ -260,14 +260,14 @@ WIN_ATT = dict(_KINDS, n_layers=3, n_heads=6, logits_fp32=True,
     ({}, _tile_text, "fd9f5c4aa916b342"),
     (INDEXER, _decode_text, "8702c029e6542675"),
     (INDEXER, _tile_text, "2d7af74232ada8e8"),
-    (MOE, _decode_text, "cbbf4a6f0b472052"),
-    (MOE, _tile_text, "6ccc4bf6ba702693"),
+    (MOE, _decode_text, "f059ce00b6dd0b8c"),
+    (MOE, _tile_text, "8c13b7ec957e1f51"),
     (BLK_LIN, _decode_text, "f0e47b713fd975e2"),
     (BLK_LIN, _tile_text, "0a6a860030ec656f"),
     (HYB, _decode_text, "dc36b41b20baaf12"),
     (HYB, _tile_text, "0a4b070ac3459947"),
-    (WIN_ATT, _decode_text, "e7f775e807a1e544"),
-    (WIN_ATT, _tile_text, "1067fd48cb7c2a56"),
+    (WIN_ATT, _decode_text, "b09edbdff40eaf1e"),
+    (WIN_ATT, _tile_text, "961b4ef1e9971aa7"),
     (None, _train_text, "8aecbfdae32759c2")],
     ids=["dense_decode", "dense_tile_with_rows", "indexer_decode",
          "indexer_tile", "moe_decode", "moe_tile_with_rows",
@@ -307,7 +307,17 @@ def test_the_step_programs_are_the_parents(model, text, digest):
     start of PR 49 and before a line of it was written. PR 49 removed the
     speculative draft's step (and its pinned verify program with it) and
     moved the rows' counters into the model layer: all thirteen passed
-    unchanged on its final tree, so it recompiled nothing."""
+    unchanged on its final tree, so it recompiled nothing. The four
+    programs of the two kinds whose experts' capacity is the group's whole
+    length ("moe": 4 experts, top-2 at capacity_factor 2; "win" + "att":
+    16 at 16) were re-pinned on PR 55's final tree: the engine counts the
+    expert layers' rows for such a model (a row no request owns is routed
+    nowhere, and the carry holds the counts), and where the group is long
+    enough the layer sorts its picks and runs the grouped form
+    (`models/moe.py` `takes_grouped`; here its XLA twin);
+    tests/test_step_order.py holds their greedy tokens to the parent's and
+    tests/test_grouped_moe.py the form to the dense dispatch. The other
+    nine passed with the digests they had."""
     eng = None if model is None else _engine(**model)
     got = hashlib.sha256(text(eng).encode()).hexdigest()[:16]
     assert got == digest

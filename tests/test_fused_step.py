@@ -33,10 +33,14 @@ from ray_tpu.models.generate import make_generate_fn
 from ray_tpu.models.transformer import TransformerConfig, TransformerLM
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 
-KINDS = ["dense", "moe", "moe-share-overflows", "indexer"]
+KINDS = ["dense", "moe", "moe-grouped", "moe-share-overflows", "indexer"]
 _OVER = {
     "dense": {},
     "moe": dict(n_experts=4, expert_top_k=2, capacity_factor=2.0),
+    # Mixtral's 8 experts, top-2, at C == L: the tile of 8 rows with the 4
+    # slots' rows behind it takes the expert layer's grouped form
+    # (models/moe.py `takes_grouped`), a decode step the dense dispatch
+    "moe-grouped": dict(n_experts=8, expert_top_k=2, capacity_factor=4.0),
     # 8 narrow experts, 4 held, top-2; capacity ceil(0.5 * L / 4): half the
     # expected load, so picks overflow in every tile and most decode steps
     "moe-share-overflows": dict(n_experts=8, expert_top_k=2,
