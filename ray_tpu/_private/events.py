@@ -50,7 +50,7 @@ __all__ = [
     "Span", "current_context", "trace_context", "new_trace_id",
     "new_span_id", "enabled", "set_enabled", "flush", "drain", "stats",
     "configure", "set_sink", "set_identity", "set_tap", "peek",
-    "annotate",
+    "annotate", "launch_phase", "launch_phases",
 ]
 
 _lock = threading.Lock()
@@ -256,6 +256,59 @@ def record_complete(name: str, start: float, end: float,
              "trace_id": trace_id or new_trace_id(),
              "span_id": new_span_id(), "parent_span_id": parent_span_id,
              "start": start, "end": max(end, start), "attrs": attrs})
+
+
+# ---------------------------------------------------------- launch phases
+# phase -> (time.monotonic() at its start, seconds) of the NEWEST
+# `launch.<phase>` this process ran. Kept beside the ring: a start's
+# records are a run's oldest, the first the ring rotates away, and
+# whoever asks how the process started (`InferenceEngine.stats()`) asks
+# long after
+_launch: Dict[str, Tuple[float, float]] = {}
+_launch_gauge = None
+
+
+@contextlib.contextmanager
+def launch_phase(phase: str, trace_id: Optional[str] = None,
+                 parent_span_id: Optional[str] = None, **attrs):
+    """One phase of a process's start: the span `launch.<phase>`
+    (category `launch`, with `t_mono`, `time.monotonic()` at its start,
+    by which a monotonic stamp taken elsewhere on the host meets the
+    recorder's wall clock) under the given context or the current one,
+    itself the context of what runs inside it; its duration kept for
+    `launch_phases()` and set on `runtime_launch_phase_ms{phase}`."""
+    global _launch_gauge
+    t_mono = time.monotonic()
+    sp = start_span("launch." + phase, "launch", trace_id=trace_id,
+                    parent_span_id=parent_span_id, t_mono=t_mono, **attrs)
+    inside = (contextlib.nullcontext() if sp is _NULL_SPAN
+              else trace_context(sp.trace_id, sp.span_id))
+    try:
+        with inside:
+            yield sp
+    except BaseException as e:
+        sp.set(error=type(e).__name__)
+        raise
+    finally:
+        seconds = time.monotonic() - t_mono
+        _launch[phase] = (t_mono, seconds)
+        sp.end()
+        try:
+            if _launch_gauge is None:
+                from ray_tpu.util.metrics import Gauge
+                _launch_gauge = Gauge(
+                    "runtime_launch_phase_ms",
+                    "most recent actor-launch phase duration (ms)")
+            _launch_gauge.set(round(seconds * 1e3, 3),
+                              tags={"phase": phase})
+        except Exception:
+            pass
+
+
+def launch_phases() -> Dict[str, Tuple[float, float]]:
+    """phase -> (t_mono, seconds) of each launch phase this process has
+    run, the newest of each."""
+    return dict(_launch)
 
 
 # ------------------------------------------------ the profiler's own trace

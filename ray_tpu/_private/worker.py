@@ -2964,28 +2964,19 @@ class CoreWorker:
             cls, "__ray_tpu_actual_class__") else cls
         # launch attribution: the callable-init phase (user __init__ —
         # model build, checkpoint load) records as a child of the
-        # actor.launch trace the node manager forwarded in the spec
+        # actor.launch trace the node manager forwarded in the spec, and
+        # is the context of what __init__ records itself (an
+        # LLMDeployment's weights, its engine's build, every compile)
         lt = spec.get("_launch_trace") or {}
-        t_init = time.time()
-        instance = await self.loop.run_in_executor(
-            self.executor, lambda: inner(*args, **kwargs))
-        init_ms = (time.time() - t_init) * 1e3
-        try:
+
+        def construct():
             from ray_tpu._private import events as _events
-            _events.record_complete(
-                "launch.callable_init", t_init, time.time(),
-                category="launch", trace_id=lt.get("trace_id"),
-                parent_span_id=lt.get("parent_span_id"),
-                actor_id=spec["actor_id"])
-            from ray_tpu.util.metrics import Gauge
-            if not hasattr(self, "_launch_phase_gauge"):
-                self._launch_phase_gauge = Gauge(
-                    "runtime_launch_phase_ms",
-                    "most recent actor-launch phase duration (ms)")
-            self._launch_phase_gauge.set(round(init_ms, 3),
-                                         tags={"phase": "callable_init"})
-        except Exception:
-            pass
+            with _events.launch_phase(
+                    "callable_init", trace_id=lt.get("trace_id"),
+                    parent_span_id=lt.get("parent_span_id"),
+                    actor_id=spec["actor_id"]):
+                return inner(*args, **kwargs)
+        instance = await self.loop.run_in_executor(self.executor, construct)
         self.actor_instance = instance
         return {"ok": True}
 

@@ -91,6 +91,8 @@ class LLMDeployment:
                  kv_quant: str = "none"):
         import jax
 
+        from ray_tpu._private import compile_cache, events
+        compile_cache.watch()       # before this process's first compile
         self.model = _resolve_model(model)
         # coalescing knobs: how many decoded tokens ride one streaming
         # frame (handle->router->replica->proxy round-trip) and how long
@@ -115,9 +117,11 @@ class LLMDeployment:
             params = resolve_weight_source(weights_key, params_fn)
         else:
             import jax.numpy as jnp
-            tokens0 = jnp.zeros((1, min(8, max_len)), jnp.int32)
-            params = self.model.init(jax.random.PRNGKey(seed),
-                                     tokens0)["params"]
+            with events.launch_phase("weights", source="init", key=None,
+                                     published=False):
+                tokens0 = jnp.zeros((1, min(8, max_len)), jnp.int32)
+                params = self.model.init(jax.random.PRNGKey(seed),
+                                         tokens0)["params"]
         cfg = EngineConfig(n_slots=n_slots, max_len=max_len,
                            prefill_chunk=prefill_chunk,
                            prefill_budget=prefill_budget, eos_id=eos_id,
@@ -125,11 +129,12 @@ class LLMDeployment:
                            top_p=top_p, kv_quant=kv_quant,
                            prefix_cache_slots=max(0, int(prefix_cache_slots)))
         # kv_quant="int8" halves+ the prefix-block HBM footprint.
-        self.engine = InferenceEngine(self.model, params, cfg, mesh=mesh,
-                                      seed=seed)
-        self._metrics = _EngineMetrics()
-        self.engine.on_step = self._metrics.on_step
-        self.engine.start()
+        with events.launch_phase("engine"):
+            self.engine = InferenceEngine(self.model, params, cfg,
+                                          mesh=mesh, seed=seed)
+            self._metrics = _EngineMetrics()
+            self.engine.on_step = self._metrics.on_step
+            self.engine.start()
 
     # ------------------------------------------------------------- serving
     def __call__(self, prompt_tokens, max_new_tokens: int = 64,

@@ -76,7 +76,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from ray_tpu._private import events
+from ray_tpu._private import compile_cache, events
 from ray_tpu.inference import kv_cache
 from ray_tpu.inference.scheduler import (FINISH_LENGTH, PrefillChunk,
                                          Request, RequestHandle,
@@ -91,8 +91,9 @@ def prefill_tiles(chunk: int, budget: int) -> tuple:
     more. A handful however large the
     ratio ({32, 128, 512, 2048} at 16 / 2048), ONE member where the
     budget is under four chunks ({256} at 128 / 256; the budget <= chunk
-    defaults): a tile costs a trace and an executable's load at every
-    start (1.2 s in a replica, PERF.md section 6), and a tile a quarter
+    defaults): a tile costs a trace, a lowering and an executable's load
+    at every start (2.6-6.1 s warm in a replica, most of it the trace:
+    PERF.md section 5, "Where set-up goes"), and a tile a quarter
     the size saves at most three quarters of a compute-bound call and
     next to nothing of one bound by the weights' stream. A prompt runs
     in the smallest tile that holds the longest span a step can give it
@@ -104,6 +105,12 @@ def prefill_tiles(chunk: int, budget: int) -> tuple:
 
 
 PHASES = ("plan", "dispatch", "read", "emit")
+# a launch phase (`events.launch_phase`) and its seconds' key in `stats()`
+_STARTUP_KEYS = {"callable_init": "startup_init_s",
+                 "weights": "startup_weights_s",
+                 "engine": "startup_engine_s",
+                 "engine.pools": "startup_pools_s",
+                 "engine.programs": "startup_programs_s"}
 # the scalars between the live mask and the tokens of the array a tile
 # program takes from the host (`InferenceEngine._tile_args`)
 _TILE_HEAD = 6
@@ -289,25 +296,26 @@ class InferenceEngine:
         # scratch is the largest tile longer than a slot so a padded
         # tile can never clamp its write window back onto real entries
         scratch_len = cfg.max_len + self._prefill_tiles[-1]
-        self._slots = kv_cache.SlotPool(
-            mcfg, cfg.n_slots, cfg.max_len, cfg.max_len, scratch_len, dtype,
-            mesh, rules)
+        with events.launch_phase("engine.pools"):
+            self._slots = kv_cache.SlotPool(
+                mcfg, cfg.n_slots, cfg.max_len, cfg.max_len, scratch_len,
+                dtype, mesh, rules)
+            # prefix blocks: prefix_cache_slots more rows of a slot's
+            # shape, allocated after the slots' pools
+            self.prefix_cache = self._blocks = None
+            if cfg.prefix_cache_slots > 0:
+                from ray_tpu.inference.prefix_cache import RadixPrefixCache
+                self._blocks = kv_cache.BlockStore(
+                    mcfg, cfg.prefix_cache_slots, cfg.max_len,
+                    cfg.prefill_chunk, dtype, cfg.kv_quant, mesh)
+                self.prefix_cache = RadixPrefixCache(cfg.prefill_chunk,
+                                                     self._blocks.n_blocks)
         # the pools' bytes as `stats()` names them: all of them, and of
         # those beyond K and V the ones this model keeps
         self._pool_bytes = {"kv_pool_bytes": self._slots.nbytes(), **{
             key: self._slots.nbytes(names)
             for key, names in transformer.POOL_BYTES_KEYS.items()
             if names[0] in self._slots.shapes}}
-        # prefix blocks: prefix_cache_slots more rows of a slot's shape,
-        # allocated after the slots' pools
-        self.prefix_cache = self._blocks = None
-        if cfg.prefix_cache_slots > 0:
-            from ray_tpu.inference.prefix_cache import RadixPrefixCache
-            self._blocks = kv_cache.BlockStore(
-                mcfg, cfg.prefix_cache_slots, cfg.max_len,
-                cfg.prefill_chunk, dtype, cfg.kv_quant, mesh)
-            self.prefix_cache = RadixPrefixCache(cfg.prefill_chunk,
-                                                 self._blocks.n_blocks)
         self.sched = Scheduler(cfg.n_slots, cfg.prefill_budget,
                                default_temperature=cfg.temperature,
                                eos_id=cfg.eos_id,
@@ -398,9 +406,10 @@ class InferenceEngine:
         # the recorder's work of a step (a span's end: (function,
         # keywords)), made with the tokens' delivery (`_deliver`)
         self._after: List[tuple] = []
-        self._build_fns()
-        self._carry = self._new_carry(seed)
-        self._compile_prefill_tiles()
+        with events.launch_phase("engine.programs"):
+            self._build_fns()
+            self._carry = self._new_carry(seed)
+            self._compile_prefill_tiles()
 
     # ------------------------------------------------------------ device fns
     def _mesh_ctx(self):
@@ -1202,4 +1211,13 @@ class InferenceEngine:
         if self._count_moe:
             out["moe_rows_computed"] = int(self._moe_counts[0])
             out["moe_local_picks"] = int(self._moe_counts[1])
+        # how this process started: its launch phases (the newest of
+        # each) and the compile watch's totals, cumulative for the process
+        for phase, (t_mono, seconds) in events.launch_phases().items():
+            if phase == "callable_init":
+                out["startup_t_mono"] = t_mono
+            if phase in _STARTUP_KEYS:
+                out[_STARTUP_KEYS[phase]] = seconds
+        out.update(("xla_" + key, value)
+                   for key, value in compile_cache.totals().items())
         return out

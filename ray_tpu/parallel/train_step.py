@@ -24,6 +24,7 @@ from flax import linen as nn
 from flax.core import FrozenDict
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu._private import compile_cache
 from ray_tpu.parallel import sharding as sharding_lib
 from ray_tpu.parallel.mesh import use_mesh
 
@@ -205,6 +206,7 @@ def make_train_fns(model: nn.Module, optimizer,
     the profiler) and each call is attributed compute-vs-host-gap and
     blocked on the loss, emitting runtime_<name>_mfu gauges + timeline
     spans (the in-runtime answer to the stuck train_step_mfu ratchet)."""
+    compile_cache.watch()       # before the state's and the step's compile
     rules = rules or sharding_lib.DEFAULT_RULES
     # init traces the model at the shape the step feeds it — the batch
     # minus its last position (inputs are tokens[:, :-1]) — so a kernel

@@ -98,11 +98,9 @@ def publish_weights(key: str, params: Any):
     host = _host_tree(params)
     try:
         ref = ray_tpu.broadcast_weights(host)
-        via = "broadcast"
     except Exception:
         try:
             ref = ray_tpu.put(host)
-            via = "put"
         except Exception:
             logger.warning("weight publish failed for %s", key,
                            exc_info=True)
@@ -113,9 +111,6 @@ def publish_weights(key: str, params: Any):
         logger.warning("weight-source ref record failed for %s", key,
                        exc_info=True)
         return None
-    from ray_tpu._private import events
-    events.record_instant("serve.weight_publish", category="serve",
-                          key=key, via=via)
     return ref
 
 
@@ -124,34 +119,36 @@ def resolve_weight_source(key: Optional[str], loader: Callable[[], Any],
                           timeout_s: Optional[float] = None) -> Any:
     """Resolve a deployment's params through the cluster weight plane
     (see module docstring). Any failure along the arena path falls back
-    to ``loader()`` — serving never breaks on weight-plane trouble."""
+    to ``loader()`` — serving never breaks on weight-plane trouble. The
+    whole of it is the launch phase ``launch.weights``, which says where
+    the tree came from (``source`` = ``arena`` / ``loader``) and whether
+    it was published."""
+    from ray_tpu._private import events
     from ray_tpu._private.config import cfg
     if enabled is None:
         enabled = cfg.fleet_weights_from_arena
-    if not enabled or not key or not _connected():
-        return loader()
-    from ray_tpu._private import events
-    ref = cached_ref(key)
-    if ref is not None:
-        try:
-            import ray_tpu
-            params = ray_tpu.get(
-                ref, timeout=(timeout_s if timeout_s is not None
-                              else cfg.fleet_attach_timeout_s))
-            events.record_instant("serve.weight_attach", category="serve",
-                                  key=key, source="arena")
-            return params
-        except Exception:
-            # ref outlived its object (node loss, store restart):
-            # forget it and reload below
-            logger.info("weight-source ref for %s unreadable; reloading",
-                        key, exc_info=True)
-            clear_ref(key)
-    params = loader()
-    published = publish_weights(key, params) is not None
-    events.record_instant("serve.weight_attach", category="serve",
-                          key=key, source="loader", published=published)
-    return params
+    with events.launch_phase("weights", key=key, source="loader",
+                             published=False) as phase:
+        if not enabled or not key or not _connected():
+            return loader()
+        ref = cached_ref(key)
+        if ref is not None:
+            try:
+                import ray_tpu
+                params = ray_tpu.get(
+                    ref, timeout=(timeout_s if timeout_s is not None
+                                  else cfg.fleet_attach_timeout_s))
+                phase.set(source="arena")
+                return params
+            except Exception:
+                # ref outlived its object (node loss, store restart):
+                # forget it and reload below
+                logger.info("weight-source ref for %s unreadable; "
+                            "reloading", key, exc_info=True)
+                clear_ref(key)
+        params = loader()
+        phase.set(published=publish_weights(key, params) is not None)
+        return params
 
 
 def checkpoint_weight_source(path: str,

@@ -71,6 +71,11 @@ class TrainWorker:
 
     def run(self, fn, config):
         import inspect
+        # a loop that came with JAX imported is watched from its first
+        # compile (make_train_fns asks again, for one that imports it
+        # only now): `xla.*` spans under this task's trace
+        from ray_tpu._private import compile_cache
+        compile_cache.watch()
         try:
             takes_arg = len(inspect.signature(fn).parameters) >= 1
         except (TypeError, ValueError):
