@@ -30,7 +30,13 @@ two step programs the engine's thread does only what the next one needs:
   budget. A long prompt is split across steps (*chunked prefill*); what a
   step gives one request runs as ONE fixed-shape tile into a scratch
   cache, the tile chosen from the prompt's length, so every token of a
-  prompt passes through one program whatever shared its steps.
+  prompt passes through one program whatever shared its steps. A span
+  that does not end its prompt is a whole tile (``Scheduler.plan_prefill``
+  gives a prompt its whole remainder, whole tiles, or nothing this step),
+  so a prompt is cut into the same spans and costs the same
+  ceil(remainder / tile) dispatches whatever else is in flight; a step
+  holds a second span only where the first ended its prompt and the
+  second ends its own (or the budget was raised to two tiles or more).
 - dispatch: the step's programs back to back, no call waiting for a value.
   A step has one of TWO shapes. Where decode rows ride (every model but
   one with a sparse-attention indexer), a step that carries prompt is ONE
@@ -208,7 +214,10 @@ class EngineConfig:
         largest tile: what a step gives one request runs as one call,
         in the tile of the prompt's length (a prompt of the budget's
         length or more: this one), and the step's decode rows ride in
-        that call.
+        that call. What a step has left when a prompt ends goes to the
+        next prompt only if it ends that one too (or, a budget raised
+        at run time, in whole tiles): a call carries a whole tile or a
+        prompt's end, never the head of a prompt it cannot finish.
         The knob that trades TTFT (higher = prompts land faster, and a
         call's weight reads are shared by more tokens) against
         inter-token latency of in-flight decodes (lower = a step that
@@ -320,7 +329,8 @@ class InferenceEngine:
                                default_temperature=cfg.temperature,
                                eos_id=cfg.eos_id,
                                chunk_size=cfg.prefill_chunk,
-                               prefix_cache=self.prefix_cache)
+                               prefix_cache=self.prefix_cache,
+                               tile=self._prefill_tiles[-1])
 
         # the host's mirror of the carry's lengths, for its own
         # bookkeeping (`max_len` evictions, the `*_rows_*` counters): it
@@ -947,7 +957,10 @@ class InferenceEngine:
         """The step's plan, one piece a dispatch: the consecutive chunks
         the scheduler gave one request are one span of its prompt, up to
         the largest compiled tile (a budget raised past it at run time
-        makes more dispatches, never a new shape)."""
+        makes more dispatches, never a new shape). The scheduler knows
+        that tile and gives a prompt that does not end in the step whole
+        tiles of it, so every span here is a whole tile or ends its
+        prompt."""
         cap = self._prefill_tiles[-1]
         spans: List[PrefillChunk] = []
         for ch in chunks:
@@ -966,12 +979,14 @@ class InferenceEngine:
         """The tile every span of a prompt runs in: the smallest that
         holds the longest span a step can give it, its tail padded
         (``n_real`` selects the logits row). Chosen from the prompt and
-        not from the span: how a prompt is cut into spans depends on
-        what else the step's budget went to, and two tiles are two
-        programs whose sums XLA may order differently, so a tile chosen
-        by the span would let co-traffic (and a prefix hit) change a
-        prompt's K/V in a last bit and with it, at a near-tie of logits
-        or of an MoE router, its greedy tokens."""
+        not from the span: a prompt's spans are whole tiles from where
+        its prefill starts (the cut is the prompt's own, whatever else
+        the step's budget went to: ``Scheduler.plan_prefill``), but a
+        prefix hit moves that start and a prompt's last span is as long
+        as what is left, and two tiles are two programs whose sums XLA
+        may order differently, so a tile chosen by the span would let a
+        prefix hit change a prompt's K/V in a last bit and with it, at a
+        near-tie of logits or of an MoE router, its greedy tokens."""
         tiles = self._prefill_tiles
         return next(t for t in tiles if t >= min(prompt_len, tiles[-1]))
 
@@ -1185,6 +1200,7 @@ class InferenceEngine:
             "issued_ahead": self.issued_ahead,
             "tokens_generated": self.tokens_generated,
             "prefill_dispatches": self.prefill_dispatches,
+            "prefill_deferred": self.sched.prefill_deferred,
             "prefill_tokens": self.prefill_tokens,
             "admitted": self.admitted,
             "queue_wait_s": self.queue_wait_s,

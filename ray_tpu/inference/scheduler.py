@@ -3,11 +3,12 @@
 The scheduler owns everything about a request EXCEPT the tensors: the
 FIFO admission queue, the per-step prefill-token budget (prefill must
 never stall in-flight decodes, so each engine iteration spends at most
-``prefill_budget`` prompt tokens), cancellation, and per-request
-deadlines. The engine (engine.py) asks it three questions per step —
-what to evict, what to prefill, what is active — and reports back what
-happened; all device-side state (KV pool, scratch caches) stays in the
-engine.
+``prefill_budget`` prompt tokens, and of those gives a prompt its whole
+remainder or whole tiles: ``Scheduler.plan_prefill``), cancellation, and
+per-request deadlines. The engine (engine.py) asks it three questions
+per step — what to evict, what to prefill, what is active — and reports
+back what happened; all device-side state (KV pool, scratch caches)
+stays in the engine.
 
 Decision and delivery are two moments. What decides the next plan (a
 token appended, a finish decided, a slot freed) happens where the engine
@@ -208,13 +209,15 @@ class Scheduler:
     A request occupies a slot from the moment its first chunk runs
     (chunked prefill writes straight into a scratch cache that is
     inserted into the slot when the prompt completes), so admission =
-    free slot AND budget. Multiple requests may be mid-prefill in one
-    step if the budget covers them.
+    free slot AND a grant of the budget (``plan_prefill``: a prompt's
+    whole remainder or whole tiles, so several requests share a step
+    only where all but the last of them end in it).
     """
 
     def __init__(self, n_slots: int, prefill_budget: int,
                  default_temperature: float = 0.0, eos_id: int = -1,
-                 chunk_size: Optional[int] = None, prefix_cache=None):
+                 chunk_size: Optional[int] = None, prefix_cache=None,
+                 tile: Optional[int] = None):
         self.n_slots = n_slots
         # optional RadixPrefixCache (prefix_cache.py): consulted once
         # per request at admission; matched spans skip prefill entirely
@@ -223,6 +226,13 @@ class Scheduler:
         # static shape of one prefill call; a planned chunk never
         # exceeds it (the engine pads shorter chunks up to it)
         self.chunk_size = int(chunk_size or self.prefill_budget)
+        # the most prompt one dispatch of the engine carries (its largest
+        # compiled tile, whatever `prefill_budget` is set to later); left
+        # out: the budget in whole chunks, the engine's own default
+        self.tile = int(tile or -(-self.prefill_budget // self.chunk_size)
+                        * self.chunk_size)
+        # plans that withheld their leftover from a prompt waiting for it
+        self.prefill_deferred = 0
         self.default_temperature = default_temperature
         self.default_eos = eos_id
         self._rid = itertools.count()
@@ -388,40 +398,77 @@ class Scheduler:
     def plan_prefill(self) -> List[PrefillChunk]:
         """Spend this step's prefill budget: continue mid-prefill
         requests first (their slot is already held), then admit queued
-        requests into free slots, FIFO. Chunks never exceed the
-        remaining budget, so one long prompt spreads across steps and
-        never stalls in-flight decodes for more than `prefill_budget`
-        tokens of work."""
+        requests into free slots, FIFO. A step never carries more than
+        `prefill_budget` prompt tokens, so one long prompt spreads across
+        steps and never stalls in-flight decodes for more than that.
+
+        What a prompt is given (``_grant``) is its whole remainder or
+        whole tiles, so every dispatch carries a whole tile or a prompt's
+        end and a prompt of remainder R costs ceil(R / tile) dispatches
+        whatever else is in flight. A prompt the leftover does not cover
+        that way is given nothing: it stays where it is (queued, its slot
+        not taken, or mid-prefill) and is the first the next plan serves,
+        with the whole budget: a part of a tile handed to it now would be
+        a dispatch of its own and save it none later. Nothing is planned
+        behind a prompt that does not end in the step (FIFO)."""
         budget = self.prefill_budget
         chunks: List[PrefillChunk] = []
-        for st in list(self._prefilling):
-            if budget <= 0:
+        # a held request (remote-prefill hand-off in flight) keeps its
+        # FIFO position but later arrivals may admit past it
+        waiting = self._prefilling + [st for st in self._queue
+                                      if not st.hold]
+        for st in waiting:
+            queued = st.slot is None
+            if budget <= 0 or (queued and not self._free_slots):
                 break
-            budget -= self._plan_one(st, budget, chunks)
-        qi = 0
-        while budget > 0 and qi < len(self._queue) and self._free_slots:
-            if self._queue[qi].hold:
-                # remote-prefill hand-off in flight: the request keeps
-                # its FIFO position but later arrivals may admit past it
-                qi += 1
-                continue
-            st = self._queue.pop(qi)
-            st.slot = self._free_slots.pop(0)
-            st.status = "PREFILLING"
-            if self.prefix_cache is not None:
-                matched, nodes = self.prefix_cache.match(st.request.tokens)
-                if matched:
-                    # the matched span's prefill is SKIPPED: the engine
-                    # copies the pinned blocks into scratch before the
-                    # first planned chunk runs; planning starts at the
-                    # first uncached token
-                    st.prefill_pos = matched
-                    st.prefix_matched = matched
-                    st.prefix_nodes = nodes
-                    st.handle.prefix_matched = matched
-            self._prefilling.append(st)
-            budget -= self._plan_one(st, budget, chunks)
+            remainder = self._remainder(st)
+            n = self._grant(remainder, budget, first=not chunks)
+            if not n:
+                self.prefill_deferred += 1
+                break
+            if queued:
+                self._admit(st)
+            budget -= self._plan_one(st, n, chunks)
+            if n < remainder:
+                break           # nothing ends ahead of a prompt in progress
         return chunks
+
+    def _remainder(self, st: RequestState) -> int:
+        """The prompt tokens `st` has yet to prefill; a queued prompt's
+        are counted behind its prefix hit (an unpinned look: a prompt
+        given nothing stays queued)."""
+        done = st.prefill_pos
+        if st.slot is None and self.prefix_cache is not None:
+            done = self.prefix_cache.peek(st.request.tokens)
+        return len(st.request.tokens) - done
+
+    def _admit(self, st: RequestState):
+        """A queued request takes a free slot, and its prefix hit."""
+        self._queue.remove(st)
+        st.slot = self._free_slots.pop(0)
+        st.status = "PREFILLING"
+        if self.prefix_cache is not None:
+            matched, nodes = self.prefix_cache.match(st.request.tokens)
+            if matched:
+                # the matched span's prefill is SKIPPED: the engine
+                # copies the pinned blocks into scratch before the
+                # first planned chunk runs; planning starts at the
+                # first uncached token
+                st.prefill_pos = matched
+                st.prefix_matched = matched
+                st.prefix_nodes = nodes
+                st.handle.prefix_matched = matched
+        self._prefilling.append(st)
+
+    def _grant(self, remainder: int, left: int, first: bool) -> int:
+        """Of the `left` of a step's budget, what a prompt with
+        `remainder` tokens to go is given: all of it where that ends the
+        prompt, else whole tiles. The `first` prompt a plan serves is
+        never given nothing (a budget set below a tile gives it the
+        budget, as ever)."""
+        if remainder <= left:
+            return remainder
+        return left // self.tile * self.tile or (left if first else 0)
 
     def unpin_prefix(self, st: RequestState):
         """Matched blocks have been copied into the request's scratch:

@@ -123,10 +123,10 @@ def _prompt_kv(kind, prompt, decoders, second):
     """Serve `prompt` on a fresh engine and return (its tokens, every
     pool's rows of its slot when its first token is out, the (offset,
     rows, decode rows) of its spans). `decoders`: two requests decode
-    throughout. `second`: a three-token prompt ahead of it takes the head
-    of the first step's budget, so its first span is that step's second
-    program; otherwise the first step's budget is cut to the same five
-    tokens and the span is the step's first."""
+    throughout. `second`: the first step's budget is two tiles and a
+    three-token prompt ahead of it ends in that step's first program, so
+    its first span (a whole tile: what the leftover of 13 holds) is that
+    step's second program; otherwise the span is the step's first."""
     eng = engine_of(kind)
     rng = np.random.RandomState(11)
     others = []
@@ -143,8 +143,7 @@ def _prompt_kv(kind, prompt, decoders, second):
     eng._call_prefill = spy
     if second:
         others.append(eng.submit(rng.randint(1, 128, 3), max_new_tokens=1))
-    else:
-        eng.sched.prefill_budget = 5
+        eng.sched.prefill_budget = 16
     h = eng.submit(prompt, max_new_tokens=5)
     eng.step()
     eng.sched.prefill_budget = 8
@@ -161,7 +160,7 @@ def _prompt_kv(kind, prompt, decoders, second):
 def test_a_prompts_kv_are_the_same_bits_whatever_rides_behind(kind):
     prompt = np.random.RandomState(12).randint(1, 128, 21)
     alone, kv0, spans0 = _prompt_kv(kind, prompt, False, False)
-    assert [s[:2] for s in spans0] == [(0, 5), (5, 8), (13, 8)]
+    assert [s[:2] for s in spans0] == [(0, 8), (8, 8), (16, 5)]
     assert {s[2] for s in spans0} == {0}
     seen = set()
     for decoders, second in ((True, False), (False, True), (True, True)):

@@ -326,6 +326,226 @@ def test_scheduler_prefill_budget_caps_per_step_tokens(tiny):
 
 
 # ==========================================================================
+# the leftover of a step's budget: a whole remainder, whole tiles, or nothing
+# (scheduler-level: no model)
+# ==========================================================================
+
+_T = 8          # the tile of the plans below; chunks of 4
+
+
+def _plans(sched, submit=(), max_steps=200):
+    """Drive `sched` until nothing is left to prefill (a prompt that ends
+    has its first token decided and holds its slot from then on); `submit`:
+    {step: prompt lengths that arrive before that step's plan} -> one
+    {rid: [(offset, length, ends its prompt)]} a plan, the chunks joined
+    into a dispatch's spans by the engine's own
+    `InferenceEngine._prefill_spans`."""
+    import types
+
+    from ray_tpu.inference import InferenceEngine
+    from ray_tpu.inference.scheduler import Request
+    eng = types.SimpleNamespace(_prefill_tiles=(sched.tile,))
+    submit, plans = dict(submit), []
+    for step in range(max_steps):
+        for n in submit.pop(step, ()):
+            sched.submit(Request(tokens=np.arange(n) % 128))
+        if not (submit or sched._queue or sched._prefilling):
+            return plans
+        spans = {}
+        for c in InferenceEngine._prefill_spans(eng, sched.plan_prefill()):
+            spans.setdefault(c.state.rid, []).append(
+                (c.start, c.length, c.is_last))
+            if c.is_last:
+                sched.prefill_done(c.state, 1, time.monotonic())
+            else:
+                sched.advance_prefill(c.state, c.length)
+        plans.append(spans)
+    raise AssertionError("the plans did not end")
+
+
+def _spans_of(plans, rid):
+    return [span for plan in plans for span in plan.get(rid, ())]
+
+
+def _holds_the_invariant(sched, plans, lengths):
+    """Every plan is within the budget, every span that does not end its
+    prompt is a whole tile, a prompt costs ceil(R / T) dispatches, and
+    prompts end in the order they came."""
+    for plan in plans:
+        assert sum(n for own in plan.values()
+                   for _, n, _ in own) <= sched.prefill_budget
+    ends = []
+    for rid, n in enumerate(lengths):
+        own = _spans_of(plans, rid)
+        assert [last for _, _, last in own] == [False] * (len(own) - 1) + \
+            [True]
+        assert all(m == sched.tile for _, m, _ in own[:-1]), own
+        assert len(own) == -(-n // sched.tile), (n, own)
+        # the cut is the prompt's own: tile by tile from its start
+        assert [(o, m) for o, m, _ in own] == [
+            (o, min(sched.tile, n - o)) for o in range(0, n, sched.tile)]
+        ends.append(next(i for i, plan in enumerate(plans)
+                         if any(last for _, _, last in plan.get(rid, ()))))
+    assert ends == sorted(ends)
+
+
+@pytest.mark.parametrize("budget", [8, 16, 20])
+@pytest.mark.parametrize("n", [1, 7, 8, 9, 16, 17, 23, 40])
+def test_a_prompt_costs_the_tiles_of_its_length_under_co_traffic(budget, n):
+    """Behind a prompt that ends mid-budget (every leftover from a token
+    to all but one), beside a queue that stands behind it, a prompt of n
+    tokens is cut tile by tile from its own start: ceil(n / T) dispatches,
+    and no span that is neither a whole tile nor the prompt's end."""
+    from ray_tpu.inference import Scheduler
+    for ahead in (1, 3, 4, 5, 7, 9, 12, 15, 19, 21):
+        sched = Scheduler(n_slots=4, prefill_budget=budget, chunk_size=4,
+                          tile=_T)
+        lengths = (ahead, n, 13, 2)
+        plans = _plans(sched, {0: lengths})
+        _holds_the_invariant(sched, plans, lengths)
+        assert len(_spans_of(plans, 1)) == -(-n // _T)
+
+
+@pytest.mark.parametrize("ahead,n", [(3, 5), (5, 3), (1, 7), (4, 4), (7, 1),
+                                     (11, 5), (18, 6)])
+def test_a_prompt_that_fits_whole_still_shares_the_step(ahead, n):
+    """What the budget has left when a prompt ends goes to the next one
+    where it ends that one too (short prompts share a step as ever)."""
+    from ray_tpu.inference import Scheduler
+    sched = Scheduler(n_slots=4, prefill_budget=8, chunk_size=4, tile=_T)
+    plans = _plans(sched, {0: (ahead, n)})
+    shared = next(plan for plan in plans if 1 in plan)
+    assert set(shared) == {0, 1} and shared[1] == [(0, n, True)]
+    assert len(plans) == -(-ahead // 8) and sched.prefill_deferred == 0
+
+
+@pytest.mark.parametrize("ahead,n,behind", [(3, 20, 2), (5, 6, 1),
+                                            (7, 9, 1), (12, 5, 3)])
+def test_a_deferred_prompt_is_first_in_the_next_plan_and_keeps_fifo(
+        ahead, n, behind):
+    """A prompt the leftover cannot finish stays QUEUED, its slot not
+    taken; nothing behind it passes it, even what the leftover would
+    hold whole; it is the first the next plan serves, with the budget
+    whole."""
+    from ray_tpu.inference import Scheduler
+    from ray_tpu.inference.scheduler import Request
+    sched = Scheduler(n_slots=4, prefill_budget=8, chunk_size=4, tile=_T)
+    for m in (ahead, n, behind):
+        sched.submit(Request(tokens=np.arange(m)))
+    for _ in range(-(-ahead // 8) - 1):
+        (c0, *_), = [sched.plan_prefill()]
+        sched.advance_prefill(c0.state, 8)
+    chunks = sched.plan_prefill()
+    assert {c.state.rid for c in chunks} == {0} and chunks[-1].is_last
+    assert sched.prefill_deferred == 1
+    assert [st.rid for st in sched._queue] == [1, 2]
+    assert sched._queue[0].slot is None and sched.occupancy() == 1
+    sched.prefill_done(chunks[0].state, 1, time.monotonic())
+    chunks = sched.plan_prefill()
+    assert chunks[0].state.rid == 1 and chunks[0].start == 0
+    assert sum(c.length for c in chunks if c.state.rid == 1) == min(n, 8)
+    # the one behind rides only where the deferred one ended first
+    assert ({c.state.rid for c in chunks} == {1, 2}) == (n + behind <= 8)
+    assert sched.prefill_deferred == 1 + (n <= 8 < n + behind)
+
+
+@pytest.mark.parametrize("hit,n,shares", [(16, 20, True), (16, 21, True),
+                                          (12, 20, False), (0, 20, False),
+                                          (16, 22, False)])
+def test_a_queued_prompts_remainder_is_counted_behind_its_prefix_hit(
+        hit, n, shares):
+    """A leftover of 5: a prompt of n tokens whose first `hit` lie in the
+    prefix cache shares the step where n - hit <= 5 and waits where not,
+    and the look that decides it pins nothing and counts no lookup."""
+    from ray_tpu.inference import Scheduler
+    from ray_tpu.inference.prefix_cache import RadixPrefixCache
+    from ray_tpu.inference.scheduler import Request
+    cache = RadixPrefixCache(4, 16)
+    prompt = (np.arange(n) * 7 + 1) % 128
+    cache.insert([int(t) for t in prompt[:hit]])
+    sched = Scheduler(n_slots=4, prefill_budget=8, chunk_size=4, tile=_T,
+                      prefix_cache=cache)
+    sched.submit(Request(tokens=np.full(3, 127)))
+    h = sched.submit(Request(tokens=prompt))
+    chunks = sched.plan_prefill()
+    own = [c for c in chunks if c.state.rid == h.rid]
+    assert bool(own) == shares and sched.prefill_deferred == (not shares)
+    if shares:
+        assert own[0].start == hit and own[-1].is_last
+        assert h.prefix_matched == hit
+        return
+    assert cache.lookups == 1                   # the one ahead of it
+    nodes = cache.walk(prompt, hit // 4)        # pins them itself
+    cache.release(nodes)
+    assert len(nodes) == hit // 4 and all(node.pins == 0 for node in nodes)
+    sched.prefill_done(chunks[0].state, 1, time.monotonic())
+    plans = _plans(sched)
+    own = _spans_of(plans, h.rid)
+    assert own[0][0] == hit and len(own) == -(-(n - hit) // 8)
+    assert all(m == 8 for _, m, _ in own[:-1])
+
+
+@pytest.mark.parametrize("ahead", [0, 3, 9])
+@pytest.mark.parametrize("budget", [16, 20, 24, 40])
+def test_a_budget_raised_past_the_tile_gives_whole_tiles(budget, ahead):
+    """A budget set past the largest compiled tile at run time (what
+    `LLMDeployment.reconfigure` assigns) makes more dispatches a step,
+    each a whole tile or a prompt's end; the leftover behind a prompt
+    that ended is given in whole tiles too."""
+    from ray_tpu.inference import Scheduler
+    sched = Scheduler(n_slots=4, prefill_budget=8, chunk_size=4)
+    assert sched.tile == _T
+    sched.prefill_budget = budget
+    lengths = (ahead, 45, 6) if ahead else (45, 6)
+    plans = _plans(sched, {0: lengths})
+    _holds_the_invariant(sched, plans, lengths)
+    first = plans[0].get(len(lengths) - 2, [])
+    assert len(first) == (budget - ahead) // _T
+    assert all(span[1] == _T for span in first)
+
+
+@pytest.mark.parametrize("case,deferred", [
+    ("withheld", 1), ("fits", 0), ("budget_spent", 0), ("no_free_slot", 0),
+    ("held", 0), ("behind_a_held_one", 1), ("nothing_waits", 0)])
+def test_prefill_deferred_counts_the_plans_that_withheld_a_leftover(
+        case, deferred):
+    """One a plan, and only where a prompt that could have started (a
+    free slot, not held) was given none of a leftover that was there."""
+    from ray_tpu.inference import Scheduler
+    from ray_tpu.inference.scheduler import Request
+    sched = Scheduler(n_slots=1 if case == "no_free_slot" else 4,
+                      prefill_budget=8, chunk_size=4, tile=_T)
+    ahead = 8 if case == "budget_spent" else 3
+    sched.submit(Request(tokens=np.arange(ahead)))
+    if case == "behind_a_held_one":
+        sched.submit(Request(tokens=np.arange(2)), hold=True)
+    if case != "nothing_waits":
+        sched.submit(Request(tokens=np.arange(4 if case == "fits" else 9)),
+                     hold=case == "held")
+    chunks = sched.plan_prefill()
+    assert sched.prefill_deferred == deferred
+    assert {c.state.rid for c in chunks} == ({0, 1} if case == "fits"
+                                             else {0})
+    # the next plan serves it first and withholds nothing
+    sched.prefill_done(chunks[0].state, 1, time.monotonic())
+    sched.plan_prefill()
+    assert sched.prefill_deferred == deferred
+
+
+def test_a_budget_below_the_tile_gives_the_first_prompt_the_budget():
+    """Budget 10 in chunks of 4 is a tile of 12: no plan could give a
+    whole tile, so the first prompt a plan serves gets the budget and
+    every other only what ends it."""
+    from ray_tpu.inference import Scheduler
+    sched = Scheduler(n_slots=4, prefill_budget=10, chunk_size=4)
+    assert sched.tile == 12
+    plans = _plans(sched, {0: (13, 9, 2)})
+    assert [{rid: sum(n for _, n, _ in own) for rid, own in plan.items()}
+            for plan in plans] == [{0: 10}, {0: 3}, {1: 9}, {2: 2}]
+    assert sched.prefill_deferred == 2
+
+
+# ==========================================================================
 # prefill tiles: what a step gives one request runs as one dispatch
 # ==========================================================================
 
@@ -472,6 +692,9 @@ def test_one_prefill_dispatch_per_request_per_step(tiny, budget):
     st = eng.stats()
     assert st["prefill_tokens"] == sum(lens)
     assert st["prefill_dispatches"] == sum(d for d, _ in seen)
+    # a prompt costs the tiles of its length, whatever shared its steps
+    assert st["prefill_dispatches"] == sum(-(-n // budget) for n in lens)
+    assert st["prefill_deferred"] > 0
 
 
 def _tiles_and_tokens(eng, fillers, prompt, n_new=4):
@@ -519,24 +742,29 @@ def _tiles_and_tokens(eng, fillers, prompt, n_new=4):
 def test_a_prompts_tile_does_not_depend_on_co_traffic(tiny, tiny_moe, kind,
                                                       n, tile):
     """Every span of a prompt runs in the tile of the prompt's length,
-    however the step's budget was shared: what else is in flight changes
-    how a prompt is cut, never which program computes its K/V (on the
-    chip two tiles differ in a last bit, and a near-tie then decodes
-    another token)."""
+    and the spans are the prompt's own: behind co-traffic that ends
+    mid-budget (a leftover of 13, of 3, of 5 behind a prompt of two
+    steps) it is cut at the same offsets into the same lengths as served
+    alone, so the same programs compute the same K/V bits (on the chip
+    two tiles, or two cuts, differ in a last bit, and a near-tie then
+    decodes another token)."""
     _, model, params = tiny if kind == "dense" else tiny_moe
     eng = _engine(model, params, n_slots=3, prefill_budget=16)
     prompt = np.random.RandomState(300 + n).randint(0, 128, n)
     alone, kv0, own0 = _tiles_and_tokens(eng, (), prompt)
-    cuts = {tuple(c[1:] for c in own0)}
+    assert {t for t, _, _ in own0} == {tile}
+    assert len(own0) == -(-n // 16)
+    deferred = 0
     for fillers in ((3,), (13,), (21, 6)):
+        d0 = eng.stats()["prefill_deferred"]
         toks, kv, own = _tiles_and_tokens(eng, fillers, prompt)
-        assert {t for t, _, _ in own} == {tile} == {t for t, _, _ in own0}
+        assert own == own0          # (tile, offset, real tokens) a span
         assert toks == alone
-        np.testing.assert_allclose(kv[0], kv0[0], rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(kv[1], kv0[1], rtol=1e-5, atol=1e-5)
-        cuts.add(tuple(c[1:] for c in own))
-    if n > 3:
-        assert len(cuts) > 1          # the co-traffic did cut it otherwise
+        np.testing.assert_array_equal(kv[0], kv0[0])
+        np.testing.assert_array_equal(kv[1], kv0[1])
+        deferred += eng.stats()["prefill_deferred"] - d0
+    # where a leftover could not end it, it waited for the next step
+    assert deferred >= (n > 13) + (n > 3) + (n > 5)
 
 
 def test_prefill_tiles_compile_before_first_submit_and_never_again(tiny):
@@ -556,10 +784,11 @@ def test_prefill_tiles_compile_before_first_submit_and_never_again(tiny):
     assert eng.prefill_compile_count == 2
     eng.sched.prefill_budget = 40
     want = eng.submit(np.arange(45) % 128, max_new_tokens=4)
-    d0 = eng.prefill_dispatches
+    d0, t0 = eng.prefill_dispatches, eng.prefill_tokens
     eng.step()
-    # 40 tokens planned for one request: spans of 16 + 16 + 8
-    assert eng.prefill_dispatches - d0 == 3
+    # a budget of 40 gives one request two whole tiles: spans of 16 + 16
+    assert eng.prefill_dispatches - d0 == 2
+    assert eng.prefill_tokens - t0 == 32
     assert _run_until(eng, lambda: want.finish_reason is not None)
     assert eng.prefill_compile_count == 2
     assert eng._prefill_fn._cache_size() == 2
