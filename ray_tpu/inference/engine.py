@@ -391,9 +391,13 @@ class InferenceEngine:
         # With every expert held under the dense dispatch both counts are
         # the shapes' (E x C, K x rows): nothing to read, and the carry
         # holds no sum
+        # (and, where the routing is in groups and the layer holds a share,
+        # two more: the real rows with a pick on a held expert, the real
+        # rows; `moe.counts_hits`)
         self._count_moe = moe.rows_follow_routing(mcfg)
-        self._moe_counts = np.zeros((2,), np.int64)
-        self._moe_seen = np.zeros((2,), np.uint32)
+        self._moe_width = 4 if moe.counts_hits(mcfg) else 2
+        self._moe_counts = np.zeros((self._moe_width,), np.int64)
+        self._moe_seen = np.zeros((self._moe_width,), np.uint32)
         # disagg hand-off accounting (serve/disagg.py)
         self.kv_exports = 0
         self.kv_imports = 0
@@ -444,7 +448,7 @@ class InferenceEngine:
         names = tuple(self._slots.shapes)
         n = len(names)
         S = cfg.n_slots
-        count_moe = self._count_moe
+        count_moe, moe_width = self._count_moe, self._moe_width
         # the slots' decode rows ride in the program of a step's prefill
         # tile, and the weights stream once for both. The other shape of
         # a step, the tile program without them and two calls, is kept for
@@ -479,7 +483,7 @@ class InferenceEngine:
             if not count_moe:
                 return logits, new, None
             # one pair a layer (stacked where the layers are scanned)
-            return logits, new, sum(c.reshape(-1, 2).sum(0)
+            return logits, new, sum(c.reshape(-1, moe_width).sum(0)
                                     for c in jax.tree.leaves(counted))
 
         # Every step program ends its arguments with the slots' CARRY and
@@ -625,7 +629,7 @@ class InferenceEngine:
         saw it on one device would make the second one retrace)."""
         import jax
         import jax.numpy as jnp
-        S, counts = self.config.n_slots, 2 * self._count_moe
+        S, counts = self.config.n_slots, self._moe_width * self._count_moe
 
         def carry():
             return jnp.concatenate([
@@ -921,7 +925,7 @@ class InferenceEngine:
         difference)."""
         host, S = np.asarray(self._carry), self.config.n_slots
         if self._count_moe:
-            seen = host[-2:].view(np.uint32)
+            seen = host[-self._moe_width:].view(np.uint32)
             self._moe_counts += seen - self._moe_seen
             self._moe_seen = seen
         return host[S:2 * S], host[3 * S:4 * S]
@@ -1227,6 +1231,9 @@ class InferenceEngine:
         if self._count_moe:
             out["moe_rows_computed"] = int(self._moe_counts[0])
             out["moe_local_picks"] = int(self._moe_counts[1])
+            if self._moe_width == 4:
+                out["moe_rows_hit"] = int(self._moe_counts[2])
+                out["moe_rows_real"] = int(self._moe_counts[3])
         # how this process started: its launch phases (the newest of
         # each) and the compile watch's totals, cumulative for the process
         for phase, (t_mono, seconds) in events.launch_phases().items():

@@ -95,8 +95,10 @@ Counters: where the caller makes the "counters" collection mutable
 share of its experts or whose capacity is the whole group) the layer sows
 int32[2]: the rows its expert matmuls computed (the grouped form's: each
 held expert's picks rounded up to the tile) and the picks of real rows
-that landed on a held expert. `rows_follow_routing` says for which models
-the engine has something to read.
+that landed on a held expert; where `counts_hits` (routing in groups, a
+share of the experts held) int32[4], behind them the real rows with at least
+one pick on a held expert and the real rows. `rows_follow_routing` says for
+which models the engine has something to read.
 """
 
 from __future__ import annotations
@@ -149,14 +151,45 @@ def rows_follow_routing(cfg) -> bool:
         or cfg.capacity_factor * cfg.expert_top_k >= cfg.n_experts)
 
 
-def sigmoid_route(x, router, bias, k: int):
+def sigmoid_route(x, router, bias, k: int, n_group: int = 1,
+                  topk_group: int = 1):
     """The sigmoid router: a score an expert in float32, the k experts of
     largest score + bias, weighed by their SCORES (the bias chooses and
-    does not weigh). -> (scores [B, L, E], the taken experts' scores and
-    numbers [B, L, k])."""
+    does not weigh). `n_group` > 1 (DeepSeek-V3's routing in groups): the
+    experts lie in `n_group` groups of E / n_group neighbours; a group's
+    score is the sum of its two largest score + bias; the `topk_group` best
+    groups stay and the k experts are taken among theirs. -> (scores
+    [B, L, E], the taken experts' scores and numbers [B, L, k])."""
     scores = jax.nn.sigmoid(x.astype(jnp.float32) @ router)
-    _, taken = jax.lax.top_k(scores + bias, k)
+    choose = scores + bias
+    if n_group > 1:
+        # (by maxima and ranks, not by sorting: three `top_k`s a layer over
+        # a 1,056-row tile were a millisecond a layer on the chip, PR 58)
+        by_group = choose.reshape(choose.shape[:-1] + (n_group, -1))
+        first = by_group.max(-1, keepdims=True)
+        at = jnp.argmax(by_group, axis=-1)[..., None]
+        second = jnp.where(jnp.arange(by_group.shape[-1]) == at, -jnp.inf,
+                           by_group).max(-1)
+        best = first[..., 0] + second                            # [B, L, G]
+        # a group stays where fewer than `topk_group` groups score above it
+        # (a tie to the lower number, as `top_k` has it)
+        g = jnp.arange(n_group)
+        above = (best[..., None, :] > best[..., :, None]) | (
+            (best[..., None, :] == best[..., :, None]) & (g < g[:, None]))
+        stays = above.sum(-1) < topk_group
+        choose = jnp.where(stays[..., None], by_group,
+                           -jnp.inf).reshape(choose.shape)
+    _, taken = jax.lax.top_k(choose, k)
     return scores, jnp.take_along_axis(scores, taken, axis=-1), taken
+
+
+def counts_hits(cfg) -> bool:
+    """Whether the layer's counters carry, beside the rows computed and the
+    picks, the real rows with at least one pick on a held expert and the
+    real rows: where the routing is in groups and the layer holds a share
+    of the experts, so that how many rows reach this share at all is the
+    grouping's doing."""
+    return cfg.n_group > 1 and bool(cfg.experts_held)
 
 
 class MoEMLP(nn.Module):
@@ -190,8 +223,10 @@ class MoEMLP(nn.Module):
                 bias = self.param("router_bias", _p(nn.initializers.zeros,
                                                     "experts"), (E,),
                                   jnp.float32)
-                probs, gate_vals, gate_idx = sigmoid_route(x, router, bias,
-                                                           K)
+                groups = (cfg.n_group, cfg.topk_group) \
+                    if cfg.n_group > 1 else ()
+                probs, gate_vals, gate_idx = sigmoid_route(
+                    x, router, bias, K, *groups)
             else:
                 probs = jax.nn.softmax(
                     x.astype(jnp.float32) @ router, axis=-1)   # [B,L,E]
@@ -246,8 +281,13 @@ class MoEMLP(nn.Module):
             # what `moe_rows_per_pick` divides
             if picks is None:
                 picks = sel.sum().astype(jnp.int32)
-            self.sow("counters", "rows_and_picks", jnp.stack(
-                [jnp.asarray(rows, jnp.int32), picks]))
+            counts = [jnp.asarray(rows, jnp.int32), picks]
+            if counts_hits(cfg):
+                # (`sel` is zeroed where a row is not real: above)
+                own = jnp.ones((B, L), bool) if real is None else real
+                counts += [(sel.sum((2, 3)) > 0).sum().astype(jnp.int32),
+                           own.sum().astype(jnp.int32)]
+            self.sow("counters", "rows_and_picks", jnp.stack(counts))
 
         # Switch load-balance loss: encourages uniform routing
         frac_tokens = sel_all.sum((1, 2)) / (L * K)            # [B,E]

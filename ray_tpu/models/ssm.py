@@ -51,21 +51,23 @@ S6_CHUNK = 16        # rows `s6_scan` unrolls in one turn of its loop
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def causal_conv(x, tail, w, b, real=None):
+def causal_conv(x, tail, w, b, real=None, scope: str = "ssm_conv"):
     """x [B, T, C] after `tail` [B, K - 1, C] (the K - 1 rows before it;
     zeros for a fresh sequence), taps w [K, C] (the last on the row
-    itself) and bias b [C] -> (y [B, T, C] float32, the new tail in
-    `tail`'s type: the last K - 1 of the rows before x and x's REAL
+    itself) and bias b [C] (None: no bias) -> (y [B, T, C] float32, the new
+    tail in `tail`'s type: the last K - 1 of the rows before x and x's REAL
     rows). `real` [B, T] bool, a prefix of each row (the engine pads a
-    tile at its end); absent: all."""
+    tile at its end); absent: all. `scope`: the name the trace files it
+    under."""
     B, T, _ = x.shape
     K = w.shape[0]
-    with jax.named_scope("ssm_conv"):
+    with jax.named_scope(scope):
         xin = jnp.concatenate([tail.astype(jnp.float32),
                                x.astype(jnp.float32)], axis=1)
         w32 = w.astype(jnp.float32)
-        y = sum(w32[j] * xin[:, j:j + T] for j in range(K)) \
-            + b.astype(jnp.float32)
+        y = sum(w32[j] * xin[:, j:j + T] for j in range(K))
+        if b is not None:
+            y = y + b.astype(jnp.float32)
         n_real = jnp.full((B,), T, jnp.int32) if real is None \
             else jnp.sum(real, axis=1, dtype=jnp.int32)
         new = jax.vmap(lambda rows, n: jax.lax.dynamic_slice_in_dim(

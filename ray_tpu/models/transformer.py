@@ -191,6 +191,24 @@ class TransformerConfig:
     s6_dt_rank: int = 0
     diff_attn: bool = False
     layer_norm: bool = False
+    # a "kda" layer (of `mixer_kinds`; models/kda.py): delta-rule linear
+    # attention with a decay a channel, `n_heads` heads of `kda_head_dim`
+    # (0: head_dim) for q, k and v behind a convolution of `kda_conv` taps,
+    # the log-decay in (`kda_gate_floor`, 0), a float32 state
+    # [kda_head_dim, kda_head_dim] a head and the convolution's tail; its
+    # scan takes chunks of `kda_chunk` rows in diagonal blocks of `kda_sub`.
+    # "mla" layers may stand beside "kda" layers, each kind's pools over
+    # its own layers
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_gate_floor: float = -5.0
+    kda_chunk: int = 64
+    kda_sub: int = 16
+    # the sigmoid router in groups (models/moe.py `sigmoid_route`): the
+    # experts lie in `n_group` groups, of which a row keeps the
+    # `topk_group` best and takes its experts among theirs. 1 group: none
+    n_group: int = 1
+    topk_group: int = 1
 
     def __post_init__(self):
         if not self.head_dim:
@@ -205,6 +223,14 @@ class TransformerConfig:
             raise ValueError(
                 f"router {self.router!r}: \"softmax\" or \"sigmoid\"; "
                 f"n_dense_layers needs scan_layers=False")
+        if self.n_group > 1 and (
+                self.router != "sigmoid" or self.n_experts % self.n_group
+                or not 0 < self.topk_group <= self.n_group
+                or self.expert_top_k > self.topk_group
+                * (self.n_experts // self.n_group)):
+            raise ValueError(
+                f"n_group={self.n_group}: the sigmoid router's experts in "
+                f"whole groups, topk_group of them holding expert_top_k")
         object.__setattr__(self, "ssm_mults", tuple(self.ssm_mults))
         object.__setattr__(self, "mlp_mults", tuple(self.mlp_mults))
         kinds = self.mixer_kinds
@@ -228,13 +254,26 @@ class TransformerConfig:
                     "\"att\" layers beside \"blk\" or \"hyb\": each "
                     "counts its own layers of the pools \"k\" and \"v\"")
             if "mla" in kinds and (
-                    set(kinds) != {"mla"} or not 0 < self.rope_dim
+                    set(kinds) - {"mla", "kda"} or not 0 < self.rope_dim
                     < self.head_dim or self.rope_dim % 2
                     or not self.latent_dim or not self.v_head_dim):
                 raise ValueError(
-                    "\"mla\" layers: every layer of the stack, with "
-                    "latent_dim, v_head_dim and an even rope_dim below "
-                    "head_dim")
+                    "\"mla\" layers: every layer of the stack but \"kda\" "
+                    "layers, with latent_dim, v_head_dim and an even "
+                    "rope_dim below head_dim")
+            if "kda" in kinds:
+                if not self.kda_head_dim:
+                    object.__setattr__(self, "kda_head_dim", self.head_dim)
+                if set(kinds) - {"mla", "kda"} or self.kda_chunk \
+                        % self.kda_sub or not 0 < -self.kda_gate_floor \
+                        * self.kda_sub <= 80:
+                    raise ValueError(
+                        "\"kda\" layers: beside none but \"mla\" layers "
+                        "(other kinds keep the pool \"s\" in shapes of "
+                        "their own), kda_chunk whole blocks of kda_sub, "
+                        "kda_gate_floor below 0 and a block's decay, "
+                        "-kda_gate_floor x kda_sub, at most 80 nats "
+                        "(models/kda.py SUB_SPAN)")
             if "s6" in kinds and ({"hyb", "lin"} & set(kinds)
                                   or not self.s6_inner
                                   or not self.s6_dt_rank):
@@ -275,7 +314,7 @@ class TransformerConfig:
 KIND_CACHES = {"blk": ("k", "v", "kp"), "lin": ("s",),
                "hyb": ("k", "v", "s", "c"), "win": ("wk", "wv"),
                "att": ("k", "v"), "mla": ("lat",), "s6": ("s", "c"),
-               "gmu": (), "xat": ()}
+               "gmu": (), "xat": (), "kda": ("s", "c")}
 # a kind that keeps no cache and reads another kind's: the pools of the
 # last layer of that kind before it
 KIND_READS = {"xat": "att"}
@@ -286,8 +325,11 @@ KIND_READS = {"xat": "att"}
 _IN_PLACE = ("hyb", "win", "att", "mla", "xat")
 # the kinds whose states (the pools with no position) are read out of the
 # RUNNING pool and written back to it before the next layer
-# (`TransformerLM._decode`), and the scopes their writes stand under
-_STATE_SCOPES = {"hyb": ("hyb_ssm", "ssd"), "s6": (None, "s6")}
+# (`TransformerLM._decode`), and the scopes their writes stand under (the
+# block's branch, the recurrence's forms, the convolution's)
+_STATE_SCOPES = {"hyb": ("hyb_ssm", "ssd", "ssm_conv"),
+                 "s6": (None, "s6", "ssm_conv"),
+                 "kda": (None, "kda", "kda_conv")}
 
 
 def _hands_on(cfg: "TransformerConfig") -> bool:
@@ -1271,6 +1313,10 @@ class Block(nn.Module):
             from ray_tpu.models.latent_attention import LatentAttention
             att = LatentAttention(cfg, name="attn")(
                 normed, positions, cache, slots)
+        elif self.kind == "kda":
+            from ray_tpu.models.kda import KimiDeltaAttention
+            att = KimiDeltaAttention(cfg, name="attn")(
+                normed, cache, slots, real)
         else:
             att = Attention(cfg, self.chunked, self.kind, name="attn")(
                 normed, positions, cache, slots)
@@ -1429,8 +1475,8 @@ def index_cache_shape(cfg: TransformerConfig, batch: int,
 # written at a position, a call's new rows beside what it holds ("kp", the
 # pooled keys of a "blk" layer, one row every `blk_stride` positions);
 # None: the pool has no position (the state of a "lin" layer; the state
-# "s" and the convolution's tail "c" of a "hyb" layer) and a call replaces
-# a row's entry whole
+# "s" and the convolution's tail "c" of a "hyb", an "s6" or a "kda" layer)
+# and a call replaces a row's entry whole
 CACHE_POS_AXIS = {"k": -3, "v": -3, "ki": -1, "kp": -3, "wk": -3,
                   "wv": -3, "lat": -1, "s": None, "c": None}
 # the third nature: a RING, K and V of the "win" layers. It has a position
@@ -1457,6 +1503,8 @@ def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int):
     (with `diff_attn` by PAIR of heads, [.., Hkv / 2, 2 D]); the states
     [n, rows, s6_state, s6_inner] and the tails [n, rows, taps - 1,
     s6_inner] of the "s6" layers; no pool for a "gmu" or an "xat" layer;
+    the states [n, rows, heads, kda_head_dim, kda_head_dim] and the tails
+    [n, rows, taps - 1, 3 heads kda_head_dim] of the "kda" layers;
     the latents of the "mla" layers, [n, rows, latent_dim + rope_dim,
     max_len]: one entry a position, the normed latent and behind it the
     one rotated key, no head axis, and the positions LAST, in the lanes,
@@ -1485,6 +1533,12 @@ def cache_shapes(cfg: TransformerConfig, batch: int, max_len: int):
             # (models/ssm.py); the tail over the convolution's channels
             entry.update(s=(batch, cfg.s6_state, cfg.s6_inner),
                          c=(batch, cfg.s6_conv - 1, cfg.s6_inner))
+        if "kda" in cfg.mixer_kinds:
+            # a state [keys, values] a head; the tail over q, k and v's
+            # channels side by side (models/kda.py)
+            D = cfg.kda_head_dim
+            entry.update(s=(batch, cfg.n_heads, D, D),
+                         c=(batch, cfg.kda_conv - 1, 3 * cfg.n_heads * D))
         layers = {n: sum(n in KIND_CACHES[k] for k in cfg.mixer_kinds)
                   for n in CACHE_POS_AXIS if n in entry}
         return {n: (count,) + entry[n] for n, count in layers.items()
@@ -1879,7 +1933,7 @@ class TransformerLM(nn.Module):
                         with (jax.named_scope(scopes[0]) if scopes[0]
                               else contextlib.nullcontext()), \
                                 jax.named_scope(
-                                "ssm_conv" if n == "c" else scopes[1]
+                                scopes[2] if n == "c" else scopes[1]
                                 + ("_step" if rows else "_scan")):
                             now[n] = jax.lax.dynamic_update_index_in_dim(
                                 now[n], r.astype(now[n].dtype), j, 0)

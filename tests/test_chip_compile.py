@@ -458,3 +458,44 @@ def test_flash_by_name_never_returns_the_reference():
     with pytest.raises(ValueError, match="cannot tile"):
         attention(x, x, x, impl="flash")
     assert attention(x, x, x, impl="auto").shape == x.shape
+
+
+@pytest.mark.parametrize("program", ["decode", "tile"])
+def test_a_latent_kind_beside_states_reads_each_pool_where_it_lies(
+        one_chip, no_compile_cache, monkeypatch, program):
+    """The step programs of `ling-3.0-flash-vl.longctx-wide` (13 unrolled
+    layers: 11 "kda" layers with a float32 state of 32 x 128 x 128 and a
+    convolution's tail a slot, 2 latent layers; 32 slots of 6,144
+    positions): the latent pool lies over the TWO latent layers alone and
+    both latent kernels take them, counting that kind's layers (the decode
+    rows' once a latent layer in either program, the tile's once a latent
+    layer, which is what the engine's counters say: 2 of 2); neither the
+    latent pool nor the states' or the tails' pool is copied by any op, no
+    layer of a pool is sliced out, and a layer's new state is written into
+    the running pool in place (eleven fusions whose result is the pool). The
+    tile program's temporaries stay under 0.7 GB (0.614 at slots of 10,240,
+    read off this compile, PR 58: the scans' float32 arrays of a 1,024-row tile), the
+    decode program's under 50 MB."""
+    import re
+    eng, compiled = _step_program("ling-3.0-flash-vl", program, one_chip,
+                                  monkeypatch)
+    text = compiled.as_text()
+    assert eng._slots.shapes == {
+        "lat": (2, 32, 576, 6144), "s": (11, 32, 32, 128, 128),
+        "c": (11, 32, 3, 12288)}
+    assert eng._tile_layers == {1024: (2, 2)}
+    for pool in (r"bf16\[2,32,576,6144\]", r"f32\[11,32,32,128,128\]",
+                 r"f32\[11,32,3,12288\]"):
+        assert not re.findall(rf"= {pool}\S* (?:copy|transpose)\(", text)
+    assert not re.findall(r"= bf16\[32,576,6144\]", text)
+    assert len(re.findall(r"= f32\[11,32,32,128,128\]\S* fusion\(",
+                          text)) == 11
+    rows = re.findall(r"%latent_pool_decode_attention\S* = .* custom-call\(",
+                      text)
+    tiles = re.findall(r"%latent_tile_attention\S* = .* custom-call\(", text)
+    assert not re.findall(r"mla_row/\S*while", text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if program == "decode":
+        assert len(rows) == 2 and not tiles and temp <= 50_000_000
+        return
+    assert len(rows) == 2 and len(tiles) == 2 and temp <= 700_000_000
